@@ -3,7 +3,9 @@
 The reference could only be tested on a real CUDA+MPI cluster (SURVEY.md §4 —
 manual mpirun scripts, no CI).  We instead force 8 virtual CPU devices so
 every collective path (psum, ppermute rings, shardings) runs in unit tests
-with no TPU attached.  force_host_devices handles the platform/flag overrides.
+with no TPU attached (the same as running under ``JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=8``; force_host_devices
+also replaces a device-count flag the environment already carries).
 """
 
 
@@ -22,30 +24,31 @@ import pytest  # noqa: E402
 def subproc_compile_cache(tmp_path_factory):
     """Shared persistent compile cache for subprocess-spawning tests
     (resilience e2e, runbook supervision): the first child pays the XLA
-    compile, every later child with the same program loads it.  Resumed
-    children skip it by design (the launcher's jaxlib cache-load guard)."""
+    compile, every later child with the same program loads it — resumed
+    children included."""
     return str(tmp_path_factory.mktemp("subproc-ccache"))
 
 
 @pytest.fixture(scope="session", autouse=True)
 def _session_compile_cache_env(subproc_compile_cache):
     """Tier-1 velocity (ISSUE 17 satellite): export the session compile
-    cache as ``THEANOMPI_COMPILE_CACHE`` so every ``python -m
-    theanompi_tpu.launcher`` subprocess — including the ones that never
-    passed ``--compile-cache-dir`` — shares the one warm XLA cache (the
-    launcher's ``__main__`` block injects the flag from the env).
-    In-process ``launcher.main([...])`` calls are untouched: every
-    production ``setup_compile_cache`` call site passes an explicit
-    directory, so the env fallback never fires inside the test process."""
+    cache as ``JAX_COMPILATION_CACHE_DIR`` — the one name every entry
+    point honours — so every launcher/tmserve subprocess shares the one
+    warm XLA cache.  In-process ``launcher.main([...])`` / ``serve()``
+    calls are untouched: jax read its configuration when this process
+    imported it, before the variable was set, and ``setup_compile_cache``
+    sets no directory where the variable is present — so the test process
+    itself runs without a persistent cache (and never writes the
+    checkout's ``.jax_cache``)."""
     import os
 
-    prev = os.environ.get("THEANOMPI_COMPILE_CACHE")
-    os.environ["THEANOMPI_COMPILE_CACHE"] = subproc_compile_cache
+    prev = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = subproc_compile_cache
     yield
     if prev is None:
-        os.environ.pop("THEANOMPI_COMPILE_CACHE", None)
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     else:
-        os.environ["THEANOMPI_COMPILE_CACHE"] = prev
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = prev
 
 
 @pytest.fixture(scope="session")

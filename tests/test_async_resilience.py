@@ -540,7 +540,6 @@ def test_easgd_supervised_sigkill_cadence_resume_bit_equal(
          "--rule-set", "checkpoint_every_n_iters=1",
          "--rule-set", "checkpoint_async=False",
          "--checkpoint-dir", ck,
-         "--compile-cache-dir", subproc_compile_cache,
          "--supervise", "--max-restarts", "3", "--backoff-base", "0.1"],
         env=_child_env(THEANOMPI_FAULT_PLAN="step:kill@3@1"),
         cwd=REPO, capture_output=True, text=True,

@@ -487,8 +487,12 @@ def test_supervised_sigkill_with_corrupt_latest_falls_back(
          "--modelfile", "theanompi_tpu.models.wide_resnet",
          "--modelclass", "WideResNet", *SUB_ARGS, "--quiet",
          "--set", "n_epochs=3",
+         # synchronous saves: epoch 1's (bit-flipped) checkpoint must be
+         # PUBLISHED before the kill one step later — against the async
+         # writer the kill wins that race on a fast machine, leaving a
+         # clean epoch-0 latest and nothing to fall back from
+         "--rule-set", "checkpoint_async=False",
          "--checkpoint-dir", ck,
-         "--compile-cache-dir", subproc_compile_cache,
          "--supervise", "--max-restarts", "3", "--backoff-base", "0.1"],
         # 2 steps/epoch (batch 4 x 4 workers over n_train=32): epoch-1's
         # checkpoint is bit-flipped as it publishes, then the child is

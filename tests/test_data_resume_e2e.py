@@ -99,7 +99,6 @@ def test_mid_epoch_sigkill_supervised_resume_no_replay_no_skip(
          # resume from the older boundary checkpoint instead
          "--rule-set", "checkpoint_async=False",
          "--checkpoint-dir", ck,
-         "--compile-cache-dir", subproc_compile_cache,
          "--supervise", "--max-restarts", "3", "--backoff-base", "0.1"],
         # iteration 3 = the SECOND step of epoch 1: the newest cadence
         # checkpoint at kill time is epoch 1's mid-epoch save (cursor 1),

@@ -554,12 +554,15 @@ def test_elastic_supervised_sigkill_shrink_and_grow(tmp_path,
              "--modelfile", "theanompi_tpu.models.wide_resnet",
              "--modelclass", "WideResNet", *TINY_ARGS,
              # n_train=64 -> 2 steps/epoch on mesh8, 4 on mesh4: the kills
-             # land one full step AFTER each epoch boundary, so the async
-             # checkpoint writer has a step's worth of time to publish
+             # land one full step AFTER each epoch boundary.  Saves are
+             # synchronous: a step's worth of time was not enough for the
+             # async writer to publish on a fast machine (the resumed
+             # attempt then found the older epoch and had nothing to
+             # reshard — KeyError 'reshard')
              "--set", "n_train=64", "--set", "n_epochs=3",
              "--rule-set", "exch_strategy=zero1",
-             "--checkpoint-dir", ck, "--record-dir", rec,
-             "--compile-cache-dir", subproc_compile_cache, "--quiet"]
+             "--rule-set", "checkpoint_async=False",
+             "--checkpoint-dir", ck, "--record-dir", rec, "--quiet"]
     env = {
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",

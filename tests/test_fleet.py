@@ -406,7 +406,7 @@ def test_tmfleet_submit_and_status_contract(tmp_path, capsys):
         "--min-devices", "2", "--max-devices", "4",
         "--set", "depth=10", "--set", "precision='fp32'",
         "--rule-set", "exch_strategy='zero1'",
-        "--extra-arg=--compile-cache-dir=/cache"])
+        "--extra-arg=--record-dir=/rec"])
     assert rc == EXIT_CLEAN
     assert "queued 'a'" in capsys.readouterr().out
     rec = read_record(d, "a")
@@ -414,7 +414,7 @@ def test_tmfleet_submit_and_status_contract(tmp_path, capsys):
     # the --set literal grammar: ints stay ints, strings stay strings
     assert rec.spec.model_config == {"depth": 10, "precision": "fp32"}
     assert rec.spec.rule_config == {"exch_strategy": "zero1"}
-    assert rec.spec.extra_args == ["--compile-cache-dir=/cache"]
+    assert rec.spec.extra_args == ["--record-dir=/rec"]
     # duplicate + invalid specs take the launcher's config exit code
     assert fleet_cli.main(["submit", "--fleet-dir", d,
                            "--job-id", "a"]) == EXIT_CONFIG
@@ -535,7 +535,6 @@ def test_fleet_two_job_contention_preempt_elastic_resume_bit_equal(
     fleet_dir = str(tmp_path / "fleet")
     trace_a = str(tmp_path / "trace_a")
     trace_b = str(tmp_path / "trace_b")
-    cache_args = ["--compile-cache-dir", subproc_compile_cache]
     # A: mesh8 2 steps/epoch at GB=32; after the shrink, mesh4 4 at 16.
     # Synchronous every-iter cadence saves make the preemption point an
     # exact checkpoint (same determinism note as the PR 9 runbook).
@@ -546,12 +545,12 @@ def test_fleet_two_job_contention_preempt_elastic_resume_bit_equal(
                      "checkpoint_every_n_iters": 1,
                      "checkpoint_async": False},
         env={**_child_env(), "THEANOMPI_DATA_TRACE": trace_a},
-        extra_args=cache_args, max_restarts=3, backoff_base=0.1)
+        max_restarts=3, backoff_base=0.1)
     spec_b = JobSpec(
         job_id="urgent", priority=10, min_devices=4, max_devices=4,
         model_config=dict(TINY_CFG),
         env={**_child_env(), "THEANOMPI_DATA_TRACE": trace_b},
-        extra_args=cache_args, max_restarts=3, backoff_base=0.1)
+        max_restarts=3, backoff_base=0.1)
 
     sched = FleetScheduler(fleet_dir, 8, poll_s=0.05)
     sched.submit(spec_a)
@@ -661,7 +660,6 @@ def test_fleet_chaos_easgd_straggler_absorbed_under_preemption(
     trace_b = str(tmp_path / "trace_b")
     tel_a = str(tmp_path / "tel_a")
     rec_dir_a = str(tmp_path / "rec_a")
-    cache_args = ["--compile-cache-dir", subproc_compile_cache]
     easgd_model = {**TINY_CFG, "n_train": 64, "n_epochs": 5}
     # stragglers at exchange ordinals 8-12: late enough that the stretch
     # detector's rolling median is anchored by a majority of good rounds
@@ -681,14 +679,13 @@ def test_fleet_chaos_easgd_straggler_absorbed_under_preemption(
              "THEANOMPI_EASGD_SLOW_S": "0.6",
              "THEANOMPI_FAULT_PLAN": ",".join(
                  f"easgd:worker_slow@{i}" for i in range(8, 13))},
-        extra_args=[*cache_args, "--telemetry-dir", tel_a,
-                    "--record-dir", rec_dir_a],
+        extra_args=["--telemetry-dir", tel_a, "--record-dir", rec_dir_a],
         max_restarts=3, backoff_base=0.1)
     spec_b = JobSpec(
         job_id="urgent", priority=10, min_devices=4, max_devices=4,
         model_config=dict(TINY_CFG),
         env={**_child_env(), "THEANOMPI_DATA_TRACE": trace_b},
-        extra_args=cache_args, max_restarts=3, backoff_base=0.1)
+        max_restarts=3, backoff_base=0.1)
 
     sched = FleetScheduler(fleet_dir, 8, poll_s=0.05)
     sched.submit(spec_a)

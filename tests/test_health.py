@@ -719,7 +719,6 @@ def test_launcher_hang_is_detected_killed_and_restarted(tmp_path,
            "telemetry_health={'hang_deadline_s': 3.0, "
            "'hang_warmup_steps': 1, 'tick_s': 0.25}",
            "--checkpoint-dir", str(tmp_path / "ckpt"),
-           "--compile-cache-dir", subproc_compile_cache,
            "--supervise", "--max-restarts", "2", "--backoff-base", "0.1"]
     env = _child_env(THEANOMPI_FAULT_PLAN="prefetch:stall@1@1")
     proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
@@ -748,7 +747,7 @@ def _perf_ledger(path, values):
 
 
 def test_perf_detector_warns_on_ledger_regression(tmp_path):
-    ledger = tmp_path / "PERF_LEDGER.jsonl"
+    ledger = tmp_path / "TMPROF_LEDGER.jsonl"
     _perf_ledger(ledger, [100.0, 101.0, 99.0, 100.0, 70.0])
     mon = _mon(tmp_path, perf_ledger_path=str(ledger),
                hang_warmup_steps=99)
@@ -760,7 +759,7 @@ def test_perf_detector_warns_on_ledger_regression(tmp_path):
 
 
 def test_perf_detector_clears_on_recovery(tmp_path):
-    ledger = tmp_path / "PERF_LEDGER.jsonl"
+    ledger = tmp_path / "TMPROF_LEDGER.jsonl"
     led = _perf_ledger(ledger, [100.0, 101.0, 99.0, 100.0, 70.0])
     mon = _mon(tmp_path, perf_ledger_path=str(ledger),
                hang_warmup_steps=99)
@@ -779,7 +778,7 @@ def test_perf_detector_clears_on_recovery(tmp_path):
 def test_perf_detector_mtime_gated(tmp_path, monkeypatch):
     """An armed detector costs one stat per tick — the ledger is only
     re-read when its mtime moves."""
-    ledger = tmp_path / "PERF_LEDGER.jsonl"
+    ledger = tmp_path / "TMPROF_LEDGER.jsonl"
     _perf_ledger(ledger, [100.0, 100.0])
     os.utime(str(ledger), (1.0, 1.0))
     mon = _mon(tmp_path, perf_ledger_path=str(ledger),

@@ -19,7 +19,6 @@ from theanompi_tpu.telemetry import PerfLedger, check_ledger, read_ledger
 from theanompi_tpu.telemetry import prof
 from theanompi_tpu.telemetry.ledger import (
     LEDGER_FILENAME,
-    bench_ledger_append,
     check_records,
     classify_artifact,
     lower_is_better,
@@ -75,10 +74,6 @@ def test_classify_bench_wrapper_and_stub():
     bad = {"n": 4, "cmd": "x", "rc": 1, "tail": "boom", "parsed": None}
     (rec,) = classify_artifact("BENCH_r04.json", bad)
     assert rec["kind"] == "backend_unavailable" and rec["value"] is None
-    stub = {"status": "backend_unavailable", "error": "no TPU",
-            "run_id": "r9"}
-    (rec,) = classify_artifact("BENCH_unavailable.json", stub)
-    assert rec["kind"] == "backend_unavailable"
 
 
 def test_classify_scaling_and_attrib():
@@ -305,20 +300,8 @@ def test_backfill_repo_artifacts_idempotent(tmp_path):
     written = led.backfill(REPO)
     assert len(written) >= 10, "repo artifacts did not classify"
     assert led.backfill(REPO) == []  # fingerprint-idempotent
-    # the committed rc=1 rounds arrive as stubs, excluded from baselines
-    kinds = {r["kind"] for r in led.records()}
-    assert "backend_unavailable" in kinds
     assert not regressions(led.check()), \
         "repo's own artifacts must not read as a regression"
-
-
-def test_committed_repo_ledger_is_clean():
-    """The PR ships a backfilled PERF_LEDGER.jsonl: it must read, parse
-    and check clean (the acceptance's exit-0 half)."""
-    path = os.path.join(REPO, LEDGER_FILENAME)
-    records = read_ledger(path)
-    assert len(records) >= 10, "committed ledger missing or empty"
-    assert not regressions(check_ledger(path))
 
 
 # -- tmprof --ledger exit contract --------------------------------------------
@@ -332,9 +315,15 @@ def test_tmprof_check_exits_1_on_regression(tmp_path, capsys):
     assert "regression" in out and "bench.imgs_per_sec" in out
 
 
-def test_tmprof_check_exits_0_on_repo_ledger(capsys):
-    rc = prof.main(["--ledger", "check", "--ledger-path",
-                    os.path.join(REPO, LEDGER_FILENAME)])
+def test_tmprof_check_exits_0_on_repo_artifacts(tmp_path, capsys):
+    """A ledger backfilled from the repo's committed artifacts checks
+    clean — built in a fixture dir: the repo-root ``PERF_LEDGER.jsonl``
+    is the driver's file, which nothing here reads or writes."""
+    path = str(tmp_path / LEDGER_FILENAME)
+    assert LEDGER_FILENAME != "PERF_LEDGER.jsonl"
+    assert prof.main(["--ledger", "backfill", REPO,
+                      "--ledger-path", path]) == 0
+    rc = prof.main(["--ledger", "check", "--ledger-path", path])
     capsys.readouterr()
     assert rc == 0
 
@@ -358,7 +347,7 @@ def test_tmprof_update_and_show(tmp_path, capsys):
     rc = prof.main(["--ledger", "update", str(art), "--ledger-path", path])
     assert rc == 0
     assert "ingested 1 new record(s)" in capsys.readouterr().out
-    assert os.path.exists(str(tmp_path / "PERF_LEDGER.json"))
+    assert os.path.exists(str(tmp_path / "TMPROF_LEDGER.json"))
     rc = prof.main(["--ledger", "show", "--ledger-path", path])
     assert rc == 0
     assert "imgs_per_sec" in capsys.readouterr().out
@@ -387,30 +376,6 @@ def test_tmprof_backfill_cli(tmp_path, capsys):
                     "--ledger-path", path])
     assert rc == 0
     assert "backfilled 1 record(s)" in capsys.readouterr().out
-
-
-# -- the bench.py hook --------------------------------------------------------
-
-def test_bench_ledger_append_env_override(tmp_path, monkeypatch):
-    path = str(tmp_path / "l.jsonl")
-    monkeypatch.setenv("BENCH_LEDGER", path)
-    bench_ledger_append({"metric": "imgs_per_sec", "value": 123.0,
-                         "unit": "images/sec", "run_id": "r1"}, "bench.wrn")
-    (rec,) = read_ledger(path)
-    assert rec["metric"] == "imgs_per_sec" and rec["source"] == "bench.wrn"
-
-
-def test_bench_ledger_append_disabled_and_safe(tmp_path, monkeypatch):
-    monkeypatch.setenv("BENCH_LEDGER", "0")
-    bench_ledger_append({"metric": "m", "value": 1.0}, "s",
-                        repo_dir=str(tmp_path))
-    assert not os.path.exists(str(tmp_path / LEDGER_FILENAME))
-    # an unwritable destination must not raise (the bench line wins):
-    # the parent "directory" is a regular file, so the append fails inside
-    blocker = tmp_path / "blocker"
-    blocker.write_text("")
-    monkeypatch.setenv("BENCH_LEDGER", str(blocker / "l.jsonl"))
-    bench_ledger_append({"metric": "m", "value": 1.0}, "s")
 
 
 def test_classify_converge_margin_records():

@@ -77,3 +77,37 @@ def test_replica_rng_distinct(mesh8):
     )(jnp.stack([jax.random.PRNGKey(0)] * 8))
     vals = np.asarray(out)
     assert len(np.unique(vals)) == 8  # every replica drew a different number
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(tmp_path):
+    """ISSUE 21 (e): where JAX_COMPILATION_CACHE_DIR is set the cache is
+    there and the code sets no other directory; unset, it is the one fixed
+    git-ignored path in the checkout.  In children: the policy flips
+    process-wide jax config."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import jax\n"
+            "from theanompi_tpu.parallel.mesh import (DEFAULT_COMPILE_CACHE,"
+            " setup_compile_cache)\n"
+            "print(setup_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n"
+            "print(DEFAULT_COMPILE_CACHE)\n")
+
+    def run(**env_over):
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(PYTHONPATH=repo, JAX_PLATFORMS="cpu", **env_over)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             cwd=str(tmp_path), capture_output=True,
+                             text=True, timeout=120, check=True)
+        return out.stdout.split()
+
+    outside = str(tmp_path / "cc")
+    assert run(JAX_COMPILATION_CACHE_DIR=outside)[:2] == [outside, outside]
+    used, configured, default = run()
+    assert used == configured == default == os.path.join(repo, ".jax_cache")
+    ignored = open(os.path.join(repo, ".gitignore")).read().split()
+    assert ".jax_cache/" in ignored

@@ -36,6 +36,9 @@ def test_two_process_distributed_bsp(tmp_path):
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
         "PYTHONPATH": os.path.dirname(os.path.dirname(WORKER)),
     }
+    # no persistent compile cache for the two-process workers (they never
+    # had one: the session fixture's variable is now jax's own name)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     procs = [
         subprocess.Popen(
             [sys.executable, WORKER, str(pid), str(port), dir0, dir1],

@@ -60,6 +60,9 @@ def test_native_build_is_cached(tmp_path):
         pytest.skip("no C compiler available")
     import os
 
-    assert os.path.exists(native._SO)
+    # named after the committed source's hash: a stale binary built from
+    # another augment.c can never be the one loaded
+    assert os.path.exists(native._so_path())
+    assert native.crop_impl() == "c"
     # second call must not rebuild (same handle)
     assert native.lib() is native.lib()

@@ -2,11 +2,14 @@
 
 Three layers of lock, all CPU tier-1:
 
-- **kernel vs fallback, bit-for-bit** — the pallas paged-decode kernel
+- **kernel vs fallback, to rounding** — the pallas paged-decode kernel
   (``interpret=True``) and the pure-JAX blockwise fallback compute the
-  SAME online-softmax recurrence in the same op order, so their outputs
-  must be bit-identical across null-block padding, prefix-shared blocks
-  (PR 17's copy-on-write cache) and ragged per-slot positions.
+  SAME online-softmax recurrence, so their outputs agree to a few fp32
+  rounding steps across null-block padding, prefix-shared blocks (PR
+  17's copy-on-write cache) and ragged per-slot positions.  (They were
+  bit-identical under jaxlib 0.4.3x; on jax 0.9.0 the interpreter and
+  XLA:CPU round the per-head reductions differently — 1.8e-7 apart — so
+  the lock is a tolerance set from the dtype, not equality.)
 - **fallback vs the PR 17 formula** — the fallback was restructured from
   one global softmax into the blockwise recurrence; the two are the same
   math up to the rounding association of the normalizer, pinned here
@@ -39,7 +42,11 @@ from theanompi_tpu.serving.kv_cache import PagedKVCache
 _NEG_INF = -1e30
 
 
-# -- kernel vs fallback: bit-for-bit ------------------------------------------
+# -- kernel vs fallback: to fp32 rounding -------------------------------------
+
+#: a few rounding steps of fp32 at the outputs' O(1) magnitude; the two
+#: paths differ only in how their reductions associate
+_F32_TOL = dict(rtol=0, atol=4 * float(np.finfo(np.float32).eps) * 4)
 
 def _pools(key, nblocks, bs, h, d, dtype=jnp.float32):
     kk, kv = jax.random.split(key)
@@ -61,7 +68,7 @@ TABLE_CASES = [
 
 @pytest.mark.parametrize("tables,positions", TABLE_CASES)
 @pytest.mark.parametrize("h,d", [(2, 16), (4, 8)])
-def test_kernel_bit_equal_to_fallback(tables, positions, h, d):
+def test_kernel_matches_fallback(tables, positions, h, d):
     bs, nblocks = 4, 6
     kp, vp = _pools(jax.random.PRNGKey(h * 100 + d), nblocks, bs, h, d)
     tbl = jnp.asarray(tables, jnp.int32)
@@ -73,13 +80,14 @@ def test_kernel_bit_equal_to_fallback(tables, positions, h, d):
         cache = PagedKVCache(kp, vp, tbl, bs, decode_impl=impl)
         outs[impl] = np.asarray(cache.attend_decode(0, q, pos))
     assert np.isfinite(outs["fallback"]).all()
-    np.testing.assert_array_equal(outs["kernel_interpret"],
-                                  outs["fallback"])
+    np.testing.assert_allclose(outs["kernel_interpret"], outs["fallback"],
+                               **_F32_TOL)
 
 
-def test_kernel_bit_equal_to_fallback_bf16():
+def test_kernel_matches_fallback_bf16():
     """Same lock in the serving cache's bf16 dtype: both paths upcast to
-    fp32 for the recurrence and downcast once at the end."""
+    fp32 for the recurrence and downcast once at the end, so they may
+    land one bf16 rounding step apart."""
     bs, nblocks, h, d = 4, 6, 2, 16
     kp, vp = _pools(jax.random.PRNGKey(3), nblocks, bs, h, d,
                     dtype=jnp.bfloat16)
@@ -92,8 +100,21 @@ def test_kernel_bit_equal_to_fallback_bf16():
         cache = PagedKVCache(kp, vp, tbl, bs, decode_impl=impl)
         outs[impl] = np.asarray(cache.attend_decode(0, q, pos)
                                 .astype(jnp.float32))
-    np.testing.assert_array_equal(outs["kernel_interpret"],
-                                  outs["fallback"])
+    np.testing.assert_allclose(
+        outs["kernel_interpret"], outs["fallback"], rtol=0,
+        atol=float(jnp.finfo(jnp.bfloat16).eps) * 4)
+
+
+def test_decode_parity_at_a_tileable_geometry():
+    """``decode_parity`` is the check chip_smoke.py runs compiled on the
+    chip at the served geometry; here the same function under the
+    interpreter at the smallest geometry the compiled gate admits."""
+    from theanompi_tpu.serving.kv_cache import decode_parity
+
+    res = decode_parity(16, 128, max_batch=4, max_context=64,
+                        decode_impl="kernel_interpret")
+    assert res["ok"] and res["finite"], res
+    assert res["shape"] == [4, 16, 128] and res["dtype"] == "bfloat16"
 
 
 # -- fallback vs the PR 17 global softmax -------------------------------------

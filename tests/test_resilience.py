@@ -217,20 +217,6 @@ def test_supervisor_clamps_sub_heartbeat_hang_timeout(tmp_path):
     assert sup.hang_timeout_s == 600
 
 
-def test_resume_compile_cache_env_parsing(monkeypatch):
-    from theanompi_tpu import launcher
-
-    args = launcher.build_parser().parse_args(["--resume"])
-    for off in ("0", "false", "False", "NO", " off "):
-        monkeypatch.setenv("THEANOMPI_RESUME_COMPILE_CACHE", off)
-        assert launcher._compile_cache_usable(args) is False, off
-    monkeypatch.setenv("THEANOMPI_RESUME_COMPILE_CACHE", "1")
-    assert launcher._compile_cache_usable(args) is True
-    args = launcher.build_parser().parse_args([])
-    monkeypatch.delenv("THEANOMPI_RESUME_COMPILE_CACHE")
-    assert launcher._compile_cache_usable(args) is True  # not resuming
-
-
 def test_supervisor_backoff_is_exponential_and_jittered(tmp_path):
     sleeps = []
     sup = Supervisor([sys.executable, "-c", "import sys; sys.exit(1)"],
@@ -625,7 +611,7 @@ def test_dist_init_already_initialized_short_circuits(monkeypatch, capsys):
     monkeypatch.setenv("JAX_COORDINATOR_ADDRESS", "10.0.0.1:8476")
 
     def already():
-        # the EXACT jax 0.4.37 double-init wording (no "already" in it!)
+        # jax's EXACT double-init wording (no "already" in it!)
         raise RuntimeError(
             "distributed.initialize should only be called once.")
 

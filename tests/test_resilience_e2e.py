@@ -109,7 +109,6 @@ def test_supervised_sigkill_restarts_and_resumes_equivalently(
         _launcher_cmd("--set", "n_epochs=2",
                       "--checkpoint-dir", ck, "--record-dir", rec,
                       "--telemetry-dir", tel,
-                      "--compile-cache-dir", subproc_compile_cache,
                       "--supervise", "--max-restarts", "3",
                       "--backoff-base", "0.1"),
         # kill at the entry of iteration 3 = one step INTO epoch 1 (two
@@ -219,29 +218,34 @@ def test_sigterm_mid_epoch_resumable_exit(tmp_path, subproc_compile_cache):
     """SIGTERM mid-training -> final synchronous checkpoint + the distinct
     EXIT_PREEMPTED code; a resumed run picks the lineage up and finishes."""
     ck = str(tmp_path / "ck")
-    child = subprocess.Popen(
-        _launcher_cmd("--set", "n_epochs=200",  # far more than we let run
-                      "--checkpoint-dir", ck,
-                      "--compile-cache-dir", subproc_compile_cache,
-                      "--rule-set", "handle_preemption=True"),
-        env=_child_env(), cwd=REPO,
-        stdout=subprocess.DEVNULL, stderr=subprocess.PIPE, text=True)
-    try:
-        deadline = time.perf_counter() + 240
-        latest = os.path.join(ck, "latest.json")
-        while not os.path.exists(latest):
-            assert child.poll() is None, \
-                f"child died early: {child.stderr.read()[-2000:]}"
-            assert time.perf_counter() < deadline, "no checkpoint in 240s"
-            time.sleep(0.05)
-        time.sleep(0.3)  # let it get a step or two into the next epoch
-        child.send_signal(signal.SIGTERM)
-        rc = child.wait(timeout=120)
-    finally:
-        if child.poll() is None:
-            child.kill()
-            child.wait()
-    err = child.stderr.read()
+    # stderr to a FILE: this test polls without reading, and a child that
+    # fills an unread pipe (XLA logs generously) blocks before it ever
+    # checkpoints
+    err_path = tmp_path / "child.err"
+    with open(err_path, "w") as err_f:
+        child = subprocess.Popen(
+            _launcher_cmd("--set", "n_epochs=200",  # far more than we run
+                          "--checkpoint-dir", ck,
+                          "--rule-set", "handle_preemption=True"),
+            env=_child_env(), cwd=REPO,
+            stdout=subprocess.DEVNULL, stderr=err_f, text=True)
+        try:
+            deadline = time.perf_counter() + 240
+            latest = os.path.join(ck, "latest.json")
+            while not os.path.exists(latest):
+                assert child.poll() is None, \
+                    f"child died early: {err_path.read_text()[-2000:]}"
+                assert time.perf_counter() < deadline, \
+                    "no checkpoint in 240s"
+                time.sleep(0.05)
+            time.sleep(0.3)  # let it get a step or two into the next epoch
+            child.send_signal(signal.SIGTERM)
+            rc = child.wait(timeout=120)
+        finally:
+            if child.poll() is None:
+                child.kill()
+                child.wait()
+    err = err_path.read_text()
     assert rc == EXIT_PREEMPTED, err[-2000:]
     assert "tmlauncher: preempted" in err
     meta = json.load(open(latest))

@@ -170,7 +170,6 @@ def test_router_autoscale_preempts_training_and_resumes_bit_equal(
         rule_config={"checkpoint_every_n_iters": 1,
                      "checkpoint_async": False},
         env={**_child_env(), "THEANOMPI_DATA_TRACE": trace},
-        extra_args=["--compile-cache-dir", subproc_compile_cache],
         max_restarts=3, backoff_base=0.1))
     t, box = _run_fleet(sched)
     try:

@@ -478,26 +478,3 @@ def test_tmserve_cli_exit_codes(tmp_path):
     # and read-only: the corrupt file was NOT quarantined
     assert (d / "ckpt_e0000.npz").exists()
     assert not (d / "corrupt").exists()
-
-
-def test_bench_serve_mode_writes_serve_json(tmp_path, monkeypatch):
-    """BENCH_SERVE=1 routes bench.py through the serving engine and
-    publishes SERVE.json (atomic, run_id-stamped) next to bench.py."""
-    import bench
-
-    monkeypatch.setattr(bench, "__file__",
-                        str(tmp_path / "bench.py"))
-    for k, v in {
-        "BENCH_SERVE": "1", "BENCH_SERVE_REQUESTS": "3",
-        "BENCH_SERVE_PROMPT": "4", "BENCH_SERVE_NEW": "4",
-        "BENCH_SERVE_BATCH": "2", "BENCH_SERVE_BLOCK_SIZE": "4",
-        "BENCH_DIM": "32", "BENCH_LAYERS": "1", "BENCH_SEQ": "32",
-        "BENCH_VOCAB": "61",
-    }.items():
-        monkeypatch.setenv(k, v)
-    monkeypatch.delenv("BENCH_TELEMETRY_DIR", raising=False)
-    bench._measure()
-    art = json.load(open(tmp_path / "SERVE.json"))
-    assert art["metric"] == "serve_tokens_per_sec"
-    assert art["requests"] == 3 and "run_id" in art
-    assert "p50" in art["token_ms"]

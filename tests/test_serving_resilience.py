@@ -482,28 +482,33 @@ def test_graceful_drain_under_load_supervised_classifies_clean(
     tel = str(tmp_path / "tel")
     # serve:stall@1 holds decode step 1 for 8s — a deterministic window
     # where all 8 requests are in flight (none can have finished: a
-    # completion needs >= 15 decode steps), however fast the compile was
-    child = subprocess.Popen(
-        [sys.executable, "-m", "theanompi_tpu.serving", *TMSERVE_TINY,
-         "--requests", "8", "--max-new-tokens", "16",
-         "--drain-s", "30", "--telemetry-dir", tel, "--quiet",
-         "--supervise", "--max-restarts", "2", "--backoff-base", "0.1"],
-        env=_child_env(subproc_compile_cache,
-                       THEANOMPI_FAULT_PLAN="serve:stall@1",
-                       THEANOMPI_SERVE_STALL_S="8"),
-        cwd=REPO, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-        text=True)
-    try:
-        log = os.path.join(tel, "REQUESTS.jsonl")
-        assert _wait_for(log, 240, child), \
-            f"replica never reached the serve loop: {child.communicate()}"
-        time.sleep(1.0)  # into the loop (handler installed, stall armed)
-        child.send_signal(signal.SIGTERM)  # supervisor forwards to replica
-        out, err = child.communicate(timeout=240)
-    finally:
-        if child.poll() is None:
-            child.kill()
-            child.communicate()
+    # completion needs >= 15 decode steps), however fast the compile was.
+    # stderr goes to a FILE: the wait below polls without reading, and a
+    # replica that fills an unread pipe blocks before its serve loop
+    err_path = tmp_path / "child.err"
+    with open(err_path, "w") as err_f:
+        child = subprocess.Popen(
+            [sys.executable, "-m", "theanompi_tpu.serving", *TMSERVE_TINY,
+             "--requests", "8", "--max-new-tokens", "16",
+             "--drain-s", "30", "--telemetry-dir", tel, "--quiet",
+             "--supervise", "--max-restarts", "2", "--backoff-base", "0.1"],
+            env=_child_env(subproc_compile_cache,
+                           THEANOMPI_FAULT_PLAN="serve:stall@1",
+                           THEANOMPI_SERVE_STALL_S="8"),
+            cwd=REPO, stdout=subprocess.DEVNULL, stderr=err_f, text=True)
+        try:
+            log = os.path.join(tel, "REQUESTS.jsonl")
+            assert _wait_for(log, 240, child), (
+                f"replica never reached the serve loop: "
+                f"{err_path.read_text()[-2000:]}")
+            time.sleep(1.0)  # into the loop (handler installed, stall armed)
+            child.send_signal(signal.SIGTERM)  # supervisor forwards it
+            child.wait(timeout=240)
+        finally:
+            if child.poll() is None:
+                child.kill()
+                child.wait()
+    err = err_path.read_text()
     assert child.returncode == 0, f"drained exit was not clean:\n{err}"
     recs = [json.loads(l) for l in open(log) if l.strip()]
     assert sorted(r["rid"] for r in recs) == list(range(8)), \

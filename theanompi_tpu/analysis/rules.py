@@ -108,6 +108,7 @@ SWALLOW_ALLOWLIST = {
     ("theanompi_tpu/parallel/trainer.py", "run"),    # teardown join
     ("theanompi_tpu/parallel/trainer.py", "wait"),   # telemetry finalize
     ("theanompi_tpu/launcher.py", "main"),           # exit-code contract
+    ("theanompi_tpu/launcher.py", "_run_session"),   # ... its session half
     ("theanompi_tpu/serving/cli.py", "main"),        # tmserve contract
     ("theanompi_tpu/analysis/cli.py", "main"),       # tmlint contract
     ("theanompi_tpu/fleet/cli.py", "main"),          # tmfleet contract

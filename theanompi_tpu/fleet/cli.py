@@ -84,7 +84,7 @@ def build_parser() -> argparse.ArgumentParser:
                     metavar="ARG",
                     help="verbatim extra launcher argv for the child "
                          "(repeatable; e.g. --extra-arg "
-                         "--compile-cache-dir=/cache)")
+                         "--record-dir=/records)")
     ps.add_argument("--max-restarts", type=int, default=3)
     ps.add_argument("--backoff-base", type=float, default=0.1)
 

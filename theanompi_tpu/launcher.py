@@ -68,8 +68,13 @@ def _maybe_init_distributed(retries: int | None = None,
     classifies that as a restartable crash, never a quiet downgrade.
     An "already initialized" runtime (harness-managed) still short-circuits.
     """
-    if not (os.environ.get("TPU_WORKER_HOSTNAMES")
-            or os.environ.get("JAX_COORDINATOR_ADDRESS")):
+    # one host has no runtime to join: a single TPU VM still exports
+    # TPU_WORKER_HOSTNAMES (naming only itself), and initialize() there
+    # goes looking for the cloud metadata server — on a sealed machine
+    # that is a ConnectionError before the first step (found on the v5e)
+    hosts = [h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
+             if h.strip()]
+    if len(hosts) <= 1 and not os.environ.get("JAX_COORDINATOR_ADDRESS"):
         return
     import jax
 
@@ -86,19 +91,17 @@ def _maybe_init_distributed(retries: int | None = None,
             return
         except (RuntimeError, ValueError) as e:
             msg = str(e).lower()
-            # double-init is fine (the harness beat us to it).  jax 0.4.37
-            # phrases it "distributed.initialize should only be called
-            # once."; older/newer versions say "already initialized".
-            # Match those SPECIFIC phrasings — a bare "already" would also
-            # swallow grpc's "Address already in use" (a stale coordinator
-            # port), which is a real failure that must retry/raise.
+            # double-init is fine (the harness beat us to it); jax says
+            # "distributed.initialize should only be called once.".  Match
+            # that SPECIFIC phrasing — a bare "already" would also swallow
+            # grpc's "Address already in use" (a stale coordinator port),
+            # which is a real failure that must retry/raise.
             # And only on the FIRST attempt: jax assigns its global client
             # BEFORE connect(), so after a failed attempt the retry raises
             # this same message about the half-initialized carcass —
             # honoring it then would silently report success on a runtime
             # that never connected
-            if ("already initialized" in msg
-                    or "only be called once" in msg):
+            if "only be called once" in msg:
                 if attempt == 1:
                     print(f"tmlauncher: distributed init skipped: {e}",
                           file=sys.stderr)
@@ -163,12 +166,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "a critical hang verdict kills and restarts the child "
                    "without waiting out --hang-timeout")
     p.add_argument("--checkpoint-dir", default=None)
-    p.add_argument("--compile-cache-dir", default=None,
-                   help="persistent XLA compilation-cache directory, shared "
-                   "across runs: a restart/resume/sweep subprocess with the "
-                   "same programs loads compiled executables instead of "
-                   "repaying the full compile (also: THEANOMPI_COMPILE_CACHE "
-                   "env var)")
     p.add_argument("--resume", action="store_true")
     p.add_argument("--resume-force", action="store_true",
                    help="override the checkpoint run-fingerprint check: "
@@ -293,33 +290,6 @@ def _supervise(argv: list[str], args) -> int:
     ).exit_code
 
 
-def _compile_cache_usable(args) -> bool:
-    """Work around a jaxlib 0.4.3x CPU-backend bug found while building the
-    supervisor (ISSUE 4): loading persistent-compilation-cache executables
-    into a *resumed* session intermittently corrupts the native heap
-    (malloc "invalid next size" / SIGSEGV under load — reproduced only
-    with the resume + warm-cache combination; fresh runs reading the
-    cache and resumed runs writing a cold cache are both fine).  Until
-    the toolchain moves, a resumed CPU-backend session skips the cache
-    and repays the compile; TPU backends (a different executable
-    serialization path) keep it.  ``THEANOMPI_RESUME_COMPILE_CACHE=1``
-    forces the cache back on, ``=0`` forces it off everywhere.
-    """
-    if not args.resume:
-        return True
-    force = os.environ.get("THEANOMPI_RESUME_COMPILE_CACHE")
-    if force is not None:
-        return force.strip().lower() not in ("0", "false", "no", "off", "")
-    import jax
-
-    if jax.default_backend() != "cpu":
-        return True
-    print("tmlauncher: compile cache disabled for this resumed CPU-backend "
-          "session (jaxlib 0.4.3x cache-load instability; "
-          "THEANOMPI_RESUME_COMPILE_CACHE=1 forces it on)", file=sys.stderr)
-    return False
-
-
 def _error_line(phase: str, e: BaseException) -> None:
     """The one-line exit-code-contract error report (ISSUE 4 satellite):
     no raw traceback unless THEANOMPI_DEBUG asks for one."""
@@ -386,19 +356,7 @@ def main(argv: list[str] | None = None) -> int:
     if args.supervise:
         return _supervise(argv, args)
 
-    from theanompi_tpu.resilience import (
-        EXIT_CKPT,
-        EXIT_CONFIG,
-        EXIT_CRASH,
-        EXIT_PREEMPTED,
-        EXIT_RESHARD,
-        PreemptionExit,
-    )
-    from theanompi_tpu.utils.checkpoint import (
-        CheckpointCorruptError,
-        CheckpointFingerprintError,
-        CheckpointReshardError,
-    )
+    from theanompi_tpu.resilience import EXIT_CONFIG, EXIT_CRASH
 
     # -- config phase: wrong flags/files will not fix themselves ------------
     try:
@@ -417,14 +375,49 @@ def main(argv: list[str] | None = None) -> int:
     # -- environment phase: transient by nature, restartable ----------------
     try:
         _maybe_init_distributed()
-        if args.compile_cache_dir and _compile_cache_usable(args):
-            # before the first jit dispatch (rule.init compiles lazily)
-            from theanompi_tpu.parallel.mesh import setup_compile_cache
+        from theanompi_tpu.parallel.mesh import (
+            CompileStats,
+            device_summary,
+            setup_compile_cache,
+        )
 
-            setup_compile_cache(args.compile_cache_dir)
+        # before the first compile (rule.init compiles lazily)
+        cache_dir = setup_compile_cache()
+        compiles = CompileStats()
+        if not args.quiet:
+            dev = device_summary()
+            print(f"tmlauncher: device platform={dev['platform']} "
+                  f"kind={dev['kind']!r} count={dev['count']} "
+                  f"compile_cache={cache_dir}", flush=True)
     except Exception as e:
-        _error_line("distributed init", e)
+        _error_line("environment", e)
         return EXIT_CRASH
+
+    try:
+        return _run_session(args, rule_cls, devices, model_config,
+                            rule_config, compiles)
+    finally:
+        compiles.close()
+
+
+def _run_session(args, rule_cls, devices, model_config, rule_config,
+                 compiles) -> int:
+    """The init and training phases of :func:`main` (same exit-code
+    contract), under one :class:`CompileStats` the caller closes."""
+    from theanompi_tpu.parallel.mesh import shard_report
+    from theanompi_tpu.resilience import (
+        EXIT_CKPT,
+        EXIT_CONFIG,
+        EXIT_CRASH,
+        EXIT_PREEMPTED,
+        EXIT_RESHARD,
+        PreemptionExit,
+    )
+    from theanompi_tpu.utils.checkpoint import (
+        CheckpointCorruptError,
+        CheckpointFingerprintError,
+        CheckpointReshardError,
+    )
 
     # -- init phase: model import / mesh build / compile / resume ----------
     try:
@@ -460,6 +453,13 @@ def main(argv: list[str] | None = None) -> int:
     except Exception as e:
         _error_line("init", e)
         return EXIT_CRASH
+    if not args.quiet:
+        trainer = rule.trainer
+        paths = " ".join(f"{k}={v}" for k, v in
+                         trainer.model.resolved_paths().items())
+        print(f"tmlauncher: mesh {dict(trainer.mesh.shape)} "
+              f"global_batch={trainer.global_batch} paths: {paths or '-'}",
+              flush=True)
 
     # -- training phase -----------------------------------------------------
     try:
@@ -483,6 +483,10 @@ def main(argv: list[str] | None = None) -> int:
         return EXIT_CRASH
     if not args.quiet:
         last = {k: v[-1] for k, v in recorder.val_history.items() if v}
+        shards = shard_report(rule.trainer.params)
+        print(f"tmlauncher: shards devices={shards['devices']} "
+              f"bytes_in_use={shards['bytes_in_use']}", flush=True)
+        print(f"tmlauncher: compiles {compiles.line()}", flush=True)
         print(f"tmlauncher: done. final val: {last}", flush=True)
         if args.telemetry_dir:
             print(f"tmlauncher: telemetry in {args.telemetry_dir} "
@@ -492,18 +496,4 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
-    # Subprocess entries only (tier-1 velocity, ISSUE 17 satellite): a
-    # test session exports THEANOMPI_COMPILE_CACHE at one shared tmpdir,
-    # and every ``python -m theanompi_tpu.launcher`` child that doesn't
-    # pass --compile-cache-dir picks it up here — one warm XLA cache
-    # across all subprocess e2e tests.  Deliberately NOT in main():
-    # in-process launcher.main([...]) calls keep their explicit-flag-only
-    # behavior, and the env supplies a default through the normal args
-    # path, so the resumed-CPU cache-load guard (_compile_cache_usable)
-    # still gates it.
-    _argv = sys.argv[1:]
-    _cache = os.environ.get("THEANOMPI_COMPILE_CACHE")
-    if _cache and not any(a.startswith("--compile-cache-dir")
-                          for a in _argv):
-        _argv += ["--compile-cache-dir", _cache]
-    raise SystemExit(main(_argv))
+    raise SystemExit(main())

@@ -116,6 +116,13 @@ class Model:
         """Linear LR scaling with worker count (reference EASGD hook)."""
         self.config["lr"] = self.config.get("lr", 0.1) * size
 
+    def resolved_paths(self) -> dict:
+        """The implementations this run resolved where the code chooses
+        from platform or shape (kernel vs XLA, C vs numpy): the launcher
+        prints them, so a fallback is visible in the run's own output."""
+        data_paths = getattr(self.data, "resolved_paths", None)
+        return data_paths() if data_paths else {}
+
     def cleanup(self) -> None:
         if hasattr(self.data, "cleanup"):
             self.data.cleanup()

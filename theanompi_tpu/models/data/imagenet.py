@@ -288,9 +288,17 @@ class ImageNetData(Dataset):
         self.sample_shape = (self.image_size, self.image_size, 3)
         self._shm_pool = None
 
+    def resolved_paths(self) -> dict:
+        """Which crop/mirror implementation feeds this run (the C helper
+        degrades to numpy when it cannot build)."""
+        from theanompi_tpu import native
+
+        return {"crop": native.crop_impl()}
+
     def _pool(self):
-        """The persistent worker ring, created lazily (spawn costs ~8 s on
-        this image — paid once per dataset, reused every epoch)."""
+        """The persistent worker ring, created lazily (workers are spawned,
+        so each re-imports the package — paid once per dataset, reused
+        every epoch)."""
         if self._shm_pool is None:
             from theanompi_tpu.models.data.shm_loader import ShmShardPool
 

@@ -290,6 +290,27 @@ class TransformerLM(SupervisedModel):
             return self.data.vocab >= 8192
         return bool(mode)
 
+    def attention_impl(self, t: int) -> str:
+        """The attention path a length-``t`` sequence takes on an
+        unsharded seq axis: ``pallas`` (compiled flash kernels),
+        ``pallas_interpret`` (the same kernels under the interpreter —
+        ``flash_attention``'s off-TPU mode) or ``blockwise``."""
+        from theanompi_tpu.ops.attention import resolve_attn_impl
+
+        cfg = self.config
+        impl = resolve_attn_impl(cfg["attn_impl"], t,
+                                 cfg["dim"] // cfg["heads"])
+        if impl == "pallas" and jax.default_backend() != "tpu":
+            impl = "pallas_interpret"
+        return impl
+
+    def resolved_paths(self) -> dict:
+        cfg = self.config
+        attn = ("ring" if cfg["seq_parallel"]
+                else self.attention_impl(cfg["seq_len"]))
+        return {**super().resolved_paths(), "attention": attn,
+                "fused_loss": self.fused_loss_enabled()}
+
     # -- serving path (ISSUE 6) ----------------------------------------------
     def _serving_layers(self):
         """(name, layer) pairs of the trunk Sequential, in order — the

@@ -3,15 +3,19 @@
 The TPU compute path is jax/XLA/pallas; the *host* runtime around it —
 here the input pipeline's per-image crop/mirror gather, the one loader
 step that can't vectorize in numpy — is native C, compiled on first use
-with the system compiler into ``_build/`` next to this file.  Everything
-degrades to the numpy reference implementation when no compiler is
-available (``lib() -> None``), and the numpy path stays the source of
-truth the C path is tested against.
+with the system compiler into ``_build/`` next to this file.  The binary
+is named after the hash of ``augment.c``, so only a library built from
+exactly the committed source is ever loaded (``_build/`` is git-ignored
+but travels with a copied tree).  Without a compiler everything runs on
+the numpy reference implementation (``lib() -> None``), which stays the
+source of truth the C path is tested against; :func:`crop_impl` says
+which one a run used.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 
@@ -19,7 +23,13 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "augment.c")
-_SO = os.path.join(_DIR, "_build", "libaugment.so")
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    return os.path.join(_DIR, "_build", f"libaugment-{digest}.so")
+
 
 _lib = None
 _tried = False
@@ -49,27 +59,33 @@ def available() -> bool:
     return lib() is not None
 
 
+def crop_impl() -> str:
+    """``"c"`` or ``"numpy"``: which crop/mirror implementation this
+    process runs (entry points print it)."""
+    return "c" if available() else "numpy"
+
+
 def _build_and_load():
-    if not (os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-        os.makedirs(os.path.dirname(_SO), exist_ok=True)
+    so = _so_path()
+    if not os.path.exists(so):
+        os.makedirs(os.path.dirname(so), exist_ok=True)
         # build to a per-process temp name, then atomic rename: concurrent
         # PROCESSES (multi-worker launch) must never CDLL a half-written .so
-        tmp = f"{_SO}.{os.getpid()}.tmp"
+        tmp = f"{so}.{os.getpid()}.tmp"
         for cc in ("cc", "gcc", "clang"):
             try:
                 subprocess.run(
                     [cc, "-O3", "-shared", "-fPIC", _SRC, "-o", tmp],
                     check=True, capture_output=True, timeout=120,
                 )
-                os.replace(tmp, _SO)
+                os.replace(tmp, so)
                 break
             except (FileNotFoundError, subprocess.CalledProcessError,
                     subprocess.TimeoutExpired):
                 continue
         else:
             return None
-    return _load(_SO)
+    return _load(so)
 
 
 def _load(path):

@@ -50,6 +50,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def quantize_chunk(x: jax.Array, key: jax.Array):
@@ -119,9 +120,16 @@ class QuantizedTensor:
 # ---------------------------------------------------------------------------
 
 
-def _int8_mm_kernel(x_ref, q_ref, s_ref, o_ref):
-    """One column band: scale the activation by the band's per-row scales
-    (fp32, on the VPU), then one MXU dot against the raw int8 tile."""
+def _int8_mm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref):
+    """One (column band, contraction tile) step: scale the activation by
+    the band's per-row scales (fp32, on the VPU), one MXU dot against the
+    raw int8 tile, accumulate in fp32 across contraction tiles."""
+    k = pl.program_id(1)
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[:, :] = jnp.zeros_like(acc_ref[:, :])
+
     xs = x_ref[:, :].astype(jnp.float32) * s_ref[0, :][None, :]
     qt = q_ref[:, :]
     if o_ref.dtype == jnp.bfloat16:
@@ -129,9 +137,31 @@ def _int8_mm_kernel(x_ref, q_ref, s_ref, o_ref):
         xs, qt = xs.astype(jnp.bfloat16), qt.astype(jnp.bfloat16)
     else:
         qt = qt.astype(jnp.float32)
-    o_ref[:, :] = jax.lax.dot_general(
+    acc_ref[:, :] += jax.lax.dot_general(
         xs, qt, dimension_numbers=((((1,), (0,)), ((), ()))),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _():
+        o_ref[:, :] = acc_ref[:, :].astype(o_ref.dtype)
+
+
+#: contraction rows resident per grid step.  A whole ``[Din, band]`` int8
+#: tile plus its bf16 copy overran the 16 MiB scoped VMEM at Din = 8192
+#: (the dim-2048 FFN down-projection, found on the v5e); 2048 rows is the
+#: largest tile the chip has compiled, and one step for every Din <= 2048.
+_MAX_K_TILE = 2048
+
+
+def _k_tile(din: int) -> int:
+    """Largest 128-multiple <= ``_MAX_K_TILE`` that divides ``din`` (all
+    of ``din`` when it is small or has no such divisor)."""
+    if din <= _MAX_K_TILE:
+        return din
+    for tk in range(_MAX_K_TILE, 0, -128):
+        if din % tk == 0:
+            return tk
+    return din
 
 
 def _band_layout(qt: QuantizedTensor):
@@ -175,9 +205,10 @@ def int8_matmul(x, qt: QuantizedTensor, interpret: bool | None = None):
     """``x @ dequantize(qt)`` without materializing the fp32 weight:
     ``x [..., Din]`` -> ``[..., Dout]`` in ``x.dtype``.
 
-    Grid over column bands; per band the kernel holds the full ``[M,
-    Din]`` activation (decode batches are tiny), the band's raw int8
-    tile, and its per-row scales.  ``interpret=None`` auto-selects like
+    Grid over (column bands, contraction tiles); per step the kernel
+    holds an ``[M, tk]`` slice of the activation (decode batches are
+    tiny), the band's raw ``[tk, band]`` int8 tile and its per-row
+    scales, accumulating in fp32.  ``interpret=None`` auto-selects like
     the attention kernels.  Tolerance vs dequantize-then-matmul: the
     scale application associates ``(x * s) @ q`` instead of ``x @ (s *
     q)``, so results differ by normal fp rounding (~1e-7 relative, locked
@@ -201,16 +232,18 @@ def int8_matmul(x, qt: QuantizedTensor, interpret: bool | None = None):
             [x2, jnp.zeros((m_pad - m, din), x2.dtype)], axis=0)
     scales_bd = jnp.broadcast_to(scales[:, None, :], (bands, 8, din))
     cc = dout // bands
+    tk = _k_tile(din)
     out = pl.pallas_call(
         _int8_mm_kernel,
-        grid=(bands,),
+        grid=(bands, din // tk),
         in_specs=[
-            pl.BlockSpec((m_pad, din), lambda b: (0, 0)),
-            pl.BlockSpec((din, cc), lambda b: (0, b)),
-            pl.BlockSpec((None, 8, din), lambda b: (b, 0, 0)),
+            pl.BlockSpec((m_pad, tk), lambda b, k: (0, k)),
+            pl.BlockSpec((tk, cc), lambda b, k: (k, b)),
+            pl.BlockSpec((None, 8, tk), lambda b, k: (b, 0, k)),
         ],
-        out_specs=pl.BlockSpec((m_pad, cc), lambda b: (0, b)),
+        out_specs=pl.BlockSpec((m_pad, cc), lambda b, k: (0, b)),
         out_shape=jax.ShapeDtypeStruct((m_pad, dout), x.dtype),
+        scratch_shapes=[pltpu.VMEM((m_pad, cc), jnp.float32)],
         interpret=interpret,
     )(x2, q2d, scales_bd)
     return out[:m].reshape(*lead, dout)

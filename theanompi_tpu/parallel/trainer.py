@@ -43,6 +43,7 @@ from theanompi_tpu.parallel.mesh import (
     SEQ_AXIS,
     make_mesh,
     replica_rng,
+    replicated,
 )
 from theanompi_tpu.utils.helper_funcs import import_model, shard_batch
 from theanompi_tpu.utils.recorder import Recorder
@@ -915,7 +916,12 @@ class BaseTrainer:
             self._lr_dev = jnp.float32(lr_f)
             self._lr_host = lr_f
         if self._step_dev is None or self._step_dev_iter != self.iteration:
-            self._step_dev = jnp.int32(self.iteration)
+            # placed ON THE MESH like the `_next_step` the step hands back:
+            # jax types carry the mesh, so a bare jnp.int32 here made the
+            # second call (fed the returned counter) a different signature
+            # — a full second trace and compile of the train step
+            self._step_dev = jax.device_put(np.int32(self.iteration),
+                                            replicated(self.mesh))
         self.params, self.state, self.opt_state, metrics = self._step_fn(
             self.params,
             self.state,

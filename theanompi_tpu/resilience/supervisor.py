@@ -7,8 +7,10 @@ how it died, and restarts it — auto-resuming from the latest checkpoint —
 under a bounded exponential-backoff budget.  A child process, not a
 thread or a try/except: SIGKILL, OOM, a wedged XLA runtime and a
 preempting hypervisor all kill *processes*, and only a fresh process can
-re-initialize a jax backend cleanly (the same lesson ``bench.py``'s
-re-exec retry learned in round 4).
+re-initialize a jax backend cleanly.  This parent never imports jax: a
+chip belongs to one process at a time, and the device probe below is a
+child that has exited — and so let go of the chip — before the next
+attempt starts.
 
 Exit-code contract (see the package ``__init__`` / README table)::
 

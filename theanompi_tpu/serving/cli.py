@@ -240,10 +240,28 @@ def serve(args) -> dict:
     already-answered ids; ``--rollout-watch`` polls the checkpoint dir
     between steps and hot-swaps verified checkpoints.
     """
+    import time
+
+    from theanompi_tpu.parallel.mesh import CompileStats, setup_compile_cache
+
+    t_start = time.perf_counter()
+    # before the first compile; router/fleet replicas share one cache the
+    # same way, so the first replica compiles and every later one loads
+    cache_dir = setup_compile_cache()
+    compiles = CompileStats()
+    try:
+        return _serve(args, t_start, cache_dir, compiles)
+    finally:
+        compiles.close()
+
+
+def _serve(args, t_start: float, cache_dir: str, compiles) -> dict:
     import importlib
     import signal
     import threading
+    import time
 
+    from theanompi_tpu.parallel.mesh import device_summary
     from theanompi_tpu.resilience.faults import FaultPlan
     from theanompi_tpu.serving.engine import InferenceEngine
     from theanompi_tpu.serving.lifecycle import (
@@ -260,14 +278,6 @@ def serve(args) -> dict:
         serve_report,
     )
     from theanompi_tpu.utils.checkpoint import load_for_inference
-
-    if os.environ.get("THEANOMPI_COMPILE_CACHE"):
-        # router/fleet replica children inherit the session compile cache
-        # the same way tmlauncher's __main__ does (ISSUE 19 satellite):
-        # the first replica compiles, every later one loads
-        from theanompi_tpu.parallel.mesh import setup_compile_cache
-
-        setup_compile_cache()
 
     cls = getattr(importlib.import_module(args.modelfile), args.modelclass)
     model = cls(_parse_kv(args.model_set))
@@ -386,6 +396,7 @@ def serve(args) -> dict:
             probation_s=args.rollout_probation_s,
             telemetry=telemetry, fault_plan=fault_plan)
 
+    setup_s = time.perf_counter() - t_start
     try:
         if queue_file:
             results, wall_s = run_queue_loop(
@@ -406,6 +417,14 @@ def serve(args) -> dict:
         if req_log is not None:
             req_log.close()
     report = serve_report(results, wall_s, sched)
+    # what ran where: the device as jax reports it, the kernel tiers the
+    # engine resolved, and set-up (model build + every compile, which the
+    # first prefill/decode of each shape pays inside wall_s) kept apart
+    # from the serving numbers
+    report["device"] = device_summary()
+    report["paths"] = engine.resolved_paths()
+    report["setup_s"] = round(setup_s, 3)
+    report["compile"] = {"cache_dir": cache_dir, **compiles.as_dict()}
     report["checkpoint_epoch"] = (rollout.current_epoch if rollout
                                   else epoch)
     report["attempt"] = attempt

@@ -34,10 +34,15 @@ from theanompi_tpu.ops.pallas_paged_attention import paged_decode_supported
 from theanompi_tpu.ops.quant import int8_matmul_supported
 from theanompi_tpu.serving.kv_cache import PagedKVCache, blocks_for
 from theanompi_tpu.serving.quant import (
+    QuantizedTensor,
     dequantize_tree,
     is_quantized_tree,
     quantize_tree,
 )
+
+
+def _is_quantized(leaf) -> bool:
+    return isinstance(leaf, QuantizedTensor)
 
 
 def sample_tokens(logits, temps, keys, top_k: int = 0):
@@ -147,6 +152,28 @@ class InferenceEngine:
     @property
     def quantized(self) -> bool:
         return is_quantized_tree(self.params)
+
+    def resolved_paths(self) -> dict:
+        """Which implementation serves each hot op of this engine — the
+        gates pick from platform and shape, so SERVE.json states the
+        outcome: the decode-attention variant, how many int8 leaves the
+        decode step feeds to the fused matmul vs dequantizes (prefill
+        always dequantizes), and the attention path of every prefill
+        bucket compiled so far."""
+        out: dict = {"decode_attention": self.decode_impl}
+        if self.quantized:
+            leaves = [leaf for leaf in jax.tree.leaves(
+                self.params, is_leaf=_is_quantized) if _is_quantized(leaf)]
+            fused = sum(1 for leaf in leaves
+                        if self._keep_quant is not None
+                        and self._keep_quant(leaf))
+            out["int8_matmul"] = {"decode_fused": fused,
+                                  "decode_dequantized": len(leaves) - fused,
+                                  "prefill": "dequantize"}
+        out["prefill_attention"] = {
+            str(t): self.model.attention_impl(t)
+            for t in sorted(self._prefill_fns)}
+        return out
 
     def swap_params(self, params):
         """Hot-swap the serving weights (ISSUE 14 live rollout); -> the

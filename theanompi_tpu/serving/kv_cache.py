@@ -259,6 +259,60 @@ class PagedKVCache:
         return (acc / jnp.swapaxes(l, 1, 2)).astype(q.dtype)
 
 
+def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,
+                  max_batch: int = 8, max_context: int = 2048,
+                  dtype=jnp.bfloat16, decode_impl: str = "kernel",
+                  seed: int = 0) -> dict:
+    """One decode-attention step through ``decode_impl`` and through the
+    pure-JAX fallback over the same random pools; -> the largest absolute
+    difference and the tolerance it is held to.
+
+    The slots sit at ragged positions (first token, a block boundary, the
+    last position of the context) with scattered block tables, one slot
+    inactive.  The two paths run one online-softmax recurrence and differ
+    by fp32 rounding only, so the bound is four rounding steps of the
+    OUTPUT dtype at the output's magnitude — the check ``chip_smoke.py``
+    runs on the chip at the served head geometry, and tier-1 runs under
+    the interpreter.
+    """
+    import numpy as np
+
+    nb = blocks_for(max_context, block_size)
+    num_blocks = max_batch * nb + 1
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (1, num_blocks, block_size, heads, head_dim)
+    k = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+    v = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
+    q = jax.random.normal(kq, (max_batch, heads, head_dim),
+                          jnp.float32).astype(dtype)
+    rng = np.random.RandomState(seed)
+    edges = [0, block_size - 1, block_size, max_context // 2,
+             max_context - 1]
+    positions = np.asarray(
+        [edges[i % len(edges)] for i in range(max_batch)], np.int32)
+    tables = np.zeros((max_batch, nb), np.int32)
+    for i, p in enumerate(positions[:-1]):      # last slot stays inactive
+        n = int(p) // block_size + 1
+        tables[i, :n] = rng.choice(np.arange(1, num_blocks), n,
+                                   replace=False)
+    positions[-1] = 0
+
+    def attend(impl):
+        fn = jax.jit(lambda k, v, t, q, p: PagedKVCache(
+            k, v, t, block_size, decode_impl=impl).attend_decode(0, q, p))
+        return np.array(fn(k, v, jnp.asarray(tables), q,
+                           jnp.asarray(positions)).astype(jnp.float32))
+
+    got, ref = attend(decode_impl), attend("fallback")
+    tol = 4 * float(jnp.finfo(dtype).eps) * max(1.0, float(np.abs(ref).max()))
+    err = float(np.abs(got - ref).max())
+    return {"decode_impl": decode_impl, "heads": heads,
+            "head_dim": head_dim, "dtype": jnp.dtype(dtype).name,
+            "shape": list(got.shape), "finite": bool(np.isfinite(got).all()),
+            "max_abs_err": err, "tolerance": tol,
+            "ok": bool(np.isfinite(got).all() and err <= tol)}
+
+
 class BlockPool:
     """Host-side refcounted allocator over the pool's block ids.
 

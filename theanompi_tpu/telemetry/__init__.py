@@ -25,7 +25,7 @@ which emitted structured events.  This package is the common substrate:
   ``attr.*`` gauges, per-device HBM watermarks, and ``ATTRIB.json``
   (ISSUE 16);
 - :mod:`~theanompi_tpu.telemetry.ledger` — the append-only
-  ``PERF_LEDGER.jsonl`` cross-run perf trajectory with typed regression
+  ``TMPROF_LEDGER.jsonl`` cross-run perf trajectory with typed regression
   verdicts (ISSUE 16);
 - :mod:`~theanompi_tpu.telemetry.cli` / ``.prof`` — the ``tmhealth`` and
   ``tmprof`` CLIs (``python -m theanompi_tpu.telemetry``).

@@ -107,7 +107,7 @@ class HealthConfig:
     #: CRITICAL verdict (not just a warn) — the serving rollout watcher's
     #: probation window rolls back on it
     slo_critical_factor: float = 2.0
-    #: ISSUE 16: watch a ``PERF_LEDGER.jsonl`` for typed regression
+    #: ISSUE 16: watch a ``TMPROF_LEDGER.jsonl`` for typed regression
     #: verdicts (``telemetry/ledger.py``) — None keeps the detector off.
     #: The file is re-checked only when its mtime moves, so an armed
     #: detector costs one ``stat`` per tick.

@@ -6,12 +6,12 @@ Every perf artifact this repo produces — the ``BENCH_*.json`` rounds,
 says nothing about whether round 6 regressed.  :class:`PerfLedger` turns
 them into one append-only trajectory:
 
-- ``PERF_LEDGER.jsonl`` — one normalized record per measurement,
+- ``TMPROF_LEDGER.jsonl`` — one normalized record per measurement,
   appended (never rewritten) with a line-granular crash contract: a torn
   final line is skipped on read, everything before it survives.  Each
   record carries a content fingerprint so re-ingesting the same artifact
-  (a re-run backfill, bench.py retrying) is idempotent.
-- ``PERF_LEDGER.json`` — an atomically-replaced (tmp + ``os.replace``)
+  (a re-run backfill) is idempotent.
+- ``TMPROF_LEDGER.json`` — an atomically-replaced (tmp + ``os.replace``)
   per-metric summary snapshot for dashboards that want one file.
 - ``check()`` — typed regression verdicts per metric: the latest point
   vs the trailing median of the previous ``window`` points, with the
@@ -20,10 +20,13 @@ them into one append-only trajectory:
   ``backend_unavailable`` stub runs are *recorded* (the trajectory shows
   the gap) but never enter a baseline and never regress.
 
-Consumers: ``bench.py`` appends at every publish site, ``tmprof
---ledger`` drives update/check/backfill from the CLI, and the
-HealthMonitor's ``perf`` detector surfaces regressions as live ``warn``
-verdicts (ISSUE 13 plumbing, new detector).
+Consumers: ``tmprof --ledger`` drives update/check/backfill from the
+CLI, and the HealthMonitor's ``perf`` detector surfaces regressions as
+live ``warn`` verdicts (ISSUE 13 plumbing, new detector).
+
+The file is this program's own trajectory and is named apart from the
+driver's ``PERF_LEDGER.jsonl`` at the repo root, which no code here reads
+or writes.
 """
 
 from __future__ import annotations
@@ -35,8 +38,8 @@ import os
 import threading
 import time
 
-LEDGER_FILENAME = "PERF_LEDGER.jsonl"
-SNAPSHOT_FILENAME = "PERF_LEDGER.json"
+LEDGER_FILENAME = "TMPROF_LEDGER.jsonl"
+SNAPSHOT_FILENAME = "TMPROF_LEDGER.json"
 
 #: default trailing-median window and relative tolerance for check()
 DEFAULT_WINDOW = 5
@@ -45,7 +48,7 @@ DEFAULT_TOLERANCE = 0.10
 #: artifact glob patterns backfill() ingests, in trajectory order —
 #: sorted() within a pattern keeps BENCH_r01..r05 chronological
 BACKFILL_PATTERNS = ("BENCH_r*.json", "BENCH_mfu_ladder.json",
-                     "BENCH_transformer.json", "BENCH_unavailable.json",
+                     "BENCH_transformer.json",
                      "SCALING*.json", "EXCHANGE*.json", "SERVE*.json",
                      "ROUTER*.json",
                      "ROOFLINE*.json", "ATTRIB.json", "CONVERGE*.json")
@@ -126,10 +129,6 @@ def classify_artifact(name: str, payload: dict) -> list[dict]:
         return []
     base = os.path.basename(name)
     run_id = payload.get("run_id")
-    # deterministic backend-absence stubs: recorded, never baselined
-    if payload.get("status") == "backend_unavailable":
-        return [make_record(base, "backend_unavailable", None, None,
-                            run_id=run_id, error=payload.get("error"))]
     # BENCH_rNN.json: a driver wrapper {n, cmd, rc, tail, parsed}
     if "parsed" in payload and "rc" in payload:
         parsed = payload.get("parsed")
@@ -423,8 +422,8 @@ def regressions(verdicts: list[dict]) -> list[dict]:
 class PerfLedger:
     """Append-only writer + snapshot publisher for one ledger file.
 
-    Thread-safe: bench.py's publish sites and a run's close path may
-    append concurrently.  Appends are line-granular (single ``write`` of
+    Thread-safe: concurrent appenders (the CLI, a run's close path) are
+    serialized.  Appends are line-granular (single ``write`` of
     complete lines, flushed) so a crash tears at most the final line,
     which :func:`read_ledger` skips.
     """
@@ -484,8 +483,7 @@ class PerfLedger:
         return self.append(classify_artifact(path, payload))
 
     def ingest(self, source: str, payload: dict) -> list[dict]:
-        """Classify + append an in-memory artifact (bench.py's publish
-        sites hand over the dict they just wrote)."""
+        """Classify + append an in-memory artifact."""
         return self.append(classify_artifact(source, payload))
 
     def check(self, tolerance: float = DEFAULT_TOLERANCE,
@@ -522,20 +520,3 @@ class PerfLedger:
             for path in sorted(glob.glob(os.path.join(root, pattern))):
                 written.extend(self.ingest_artifact(path))
         return written
-
-
-def bench_ledger_append(payload: dict, source: str,
-                        repo_dir: str | None = None) -> None:
-    """bench.py's one-liner: append one published artifact to the repo
-    ledger (``BENCH_LEDGER`` overrides the path; ``BENCH_LEDGER=0``
-    disables).  Never raises — a ledger hiccup must not cost the bench
-    its primary output line."""
-    dest = os.environ.get("BENCH_LEDGER")
-    if dest == "0":
-        return
-    if not dest:
-        dest = os.path.join(repo_dir or os.getcwd(), LEDGER_FILENAME)
-    try:
-        PerfLedger(dest).ingest(source, payload)
-    except Exception:  # lint: swallow-ok — advisory trajectory, bench line wins
-        pass

@@ -8,8 +8,8 @@ trainer) and its dict rides one ``metrics`` event through the sink.
 MFU reuses the repo's existing FLOP accounting rather than re-deriving it:
 ``step_flops_estimate`` asks XLA's cost analysis through the trainer's
 ``compiled_step`` hook (the same source ``bench.py`` uses for conv nets)
-and ``peak_flops`` defers to ``bench.chip_peak_flops()`` — one table, no
-second copy of the v5e/v5p/v6 peaks.
+and ``peak_flops`` reads :data:`DEVICE_PEAKS`, the one table of published
+chip peaks (bench.py and utils/roofline.py read it too).
 """
 
 from __future__ import annotations
@@ -273,7 +273,7 @@ PROF_GAUGES = ("prof.hbm_peak_bytes", "prof.hbm_live_bytes",
 #: trace with the event stream.
 PROF_INSTANTS = ("prof.window",)
 #: ``ledger.regression``: the HealthMonitor's perf detector mirrored a
-#: regression verdict from PERF_LEDGER.jsonl (tags: metric, delta_pct).
+#: regression verdict from TMPROF_LEDGER.jsonl (tags: metric, delta_pct).
 LEDGER_INSTANTS = ("ledger.regression",)
 
 
@@ -322,14 +322,41 @@ class MetricsRegistry:
         return out
 
 
-def peak_flops() -> float | None:
-    """Chip peak FLOP/s from bench.py's table (one source of truth)."""
-    try:
-        import bench
+#: Published peaks of one chip, keyed by jax's ``device_kind``.  Source:
+#: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s
+#: int8, 16 GB of HBM at 819 GB/s.  It lists the devices this repo has run
+#: on and nothing else: a kind that is not here is an error, not a default
+#: peak (bench.py, utils/roofline.py and the train.mfu gauge all read it).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "int8_tops": 393.0,
+                    "hbm_gbps": 819.0, "hbm_gb": 16.0},
+}
 
-        return bench.chip_peak_flops()
-    except Exception:  # lint: swallow-ok — optional probe, None = omit MFU
+
+def device_peaks(device_kind: str | None = None) -> dict:
+    """The :data:`DEVICE_PEAKS` row of ``device_kind`` (default: the kind
+    of jax's first device); ``KeyError`` naming the kind when unknown."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; add its "
+            f"row (with its source) to telemetry.metrics.DEVICE_PEAKS — a "
+            f"utilisation against a guessed peak is not a number") from None
+
+
+def peak_flops() -> float | None:
+    """bf16 peak FLOP/s of the chip under this process, or None on the
+    CPU backend (a utilisation is a device metric; a CPU run has none)."""
+    import jax
+
+    if jax.default_backend() == "cpu":
         return None
+    return device_peaks()["bf16_tflops"] * 1e12
 
 
 def step_flops_estimate(trainer, batch) -> float | None:
@@ -341,15 +368,13 @@ def step_flops_estimate(trainer, batch) -> float | None:
     cost analysis is unavailable; callers then simply omit MFU.
     """
     try:
-        analysis = trainer.compiled_step(batch).cost_analysis()
-        if isinstance(analysis, list):
-            analysis = analysis[0]
-        fl = float(analysis.get("flops", 0.0))
+        fl = float(trainer.compiled_step(batch).cost_analysis()
+                   .get("flops", 0.0))
         if fl <= 0:
             return None
         n_subb = int(trainer.model.config.get("n_subb", 1) or 1)
         return fl * n_subb if n_subb > 1 else fl
-    except Exception:  # lint: swallow-ok — cost analysis is best-effort
+    except Exception:  # lint: swallow-ok — an optional gauge must not end training
         return None
 
 

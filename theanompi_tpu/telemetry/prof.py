@@ -10,7 +10,7 @@ run::
     tmprof ./telemetry --json           # machine-readable
     tmprof ./telemetry --write          # also (re)publish ATTRIB.json
 
-Ledger mode drives ``PERF_LEDGER.jsonl`` (``telemetry/ledger.py``)::
+Ledger mode drives ``TMPROF_LEDGER.jsonl`` (``telemetry/ledger.py``)::
 
     tmprof --ledger update BENCH_r06.json SERVE.json
     tmprof --ledger check               # exit 1 on any regression
@@ -160,7 +160,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="tmprof",
         description="Step-time attribution tables from a telemetry dir, "
-                    "and the PERF_LEDGER.jsonl regression trajectory")
+                    "and the TMPROF_LEDGER.jsonl regression trajectory")
     p.add_argument("directory", nargs="?",
                    help="telemetry dir (attribution mode) or repo dir "
                         "(--ledger backfill)")

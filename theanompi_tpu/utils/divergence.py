@@ -5,7 +5,7 @@ that *replicated* values stay bit-identical across their device copies —
 BSP params after the fused all-reduce, EASGD's center, batch-norm state
 under sync-BN.  A divergence means a non-deterministic op, a wrong
 ``grad_reduce_axes``, or an exchange bug (exactly the class the round-1
-Megatron-gradient bug belonged to), and shard_map's ``check_rep=False``
+Megatron-gradient bug belonged to), and shard_map's ``check_vma=False``
 hides it silently.
 
 The check is host-side and collective-free: every device copy of a

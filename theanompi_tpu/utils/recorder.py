@@ -185,11 +185,15 @@ def write_history_snapshot(snapshot: dict, path: str) -> None:
     os.makedirs(path, exist_ok=True)
     for name in ("time", "train", "val"):
         hist = snapshot.get(name, {})
-        np.save(
-            os.path.join(path, f"{name}_history.npy"),
-            {k: np.asarray(v) for k, v in hist.items()},
-            allow_pickle=True,
-        )
+        # tmp + replace, like summary.json below: np.save straight to the
+        # final name truncates first, and a SIGKILL inside that window left
+        # an empty file every resume then died on (EOFError in
+        # Recorder.load — seen in the supervised-SIGKILL e2e)
+        npy = os.path.join(path, f"{name}_history.npy")
+        with open(npy + ".tmp", "wb") as f:
+            np.save(f, {k: np.asarray(v) for k, v in hist.items()},
+                    allow_pickle=True)
+        os.replace(npy + ".tmp", npy)
     spath = os.path.join(path, "summary.json")
     with open(spath + ".tmp", "w") as f:
         json.dump(

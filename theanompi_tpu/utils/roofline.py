@@ -65,37 +65,13 @@ def _text_bytes(text: str) -> int:
                for m in _SHAPE_RE.finditer(text))
 
 
-def _split_top_level(s: str) -> list[str]:
-    """Split on commas outside ``[]``/``{}`` (shape dims contain commas)."""
-    out, cur, depth = [], [], 0
-    for ch in s:
-        if ch in "[{":
-            depth += 1
-        elif ch in "]}":
-            depth -= 1
-        if ch == "," and depth == 0:
-            out.append("".join(cur))
-            cur = []
-        else:
-            cur.append(ch)
-    if cur:
-        out.append("".join(cur))
-    return out
-
-
 def _operand_names(line: str) -> list[str]:
-    """Operand instruction names from the op's first paren group.
-
-    Handles both HLO operand spellings: bare names (``dot(%a, %b)``) and
-    typed operands (``dot(f32[8,4]{1,0} %a, ...)``, jax <= 0.4.x) — the
-    instruction name is always the last whitespace-separated token of each
-    top-level comma-separated operand.
-    """
+    """Operand instruction names of an HLO ``dot``/``convolution``
+    (``dot(%a, %b)`` — XLA spells operands by bare name)."""
     m = re.search(r"\b(?:dot|convolution)\(([^)]*)\)", line)
     if not m:
         return []
-    return [t.strip().split()[-1].lstrip("%")
-            for t in _split_top_level(m.group(1)) if t.strip()]
+    return [t.strip().lstrip("%") for t in m.group(1).split(",") if t.strip()]
 
 
 def _dot_flops(line: str, shapes: dict[str, str]) -> int:
@@ -372,27 +348,26 @@ def main(argv=None):
     p.add_argument("--model", default="resnet50")
     p.add_argument("--steps", type=int, default=4)
     p.add_argument("--out", default="ROOFLINE.json")
-    p.add_argument("--peak-gbps", type=float, default=None,
-                   help="HBM GB/s (v5e: 819)")
     args = p.parse_args(argv)
 
     import jax
 
     import bench as benchmod  # repo-root bench.py: shared model builders
+    from theanompi_tpu.telemetry.metrics import device_peaks
 
-    platform = jax.devices()[0].platform
-    trainer, model = benchmod.build_trainer(args.model, platform)
+    device = benchmod.require_accelerator()
+    peaks = device_peaks(device["kind"])  # unknown device: an error
+    trainer, model = benchmod.build_trainer(args.model)
     batch = next(iter(model.data.train_batches(trainer.global_batch, 0, seed=0)))
     from theanompi_tpu.utils.helper_funcs import shard_batch
 
     placed = shard_batch(trainer.mesh, batch, spec=trainer.batch_spec)
     jax.block_until_ready(placed)
-    peak = benchmod.chip_peak_flops()
-    gbps = args.peak_gbps or (819.0 if platform == "tpu" else None)
     art = profile_step(trainer, placed, steps=args.steps,
-                       peak_flops=peak, peak_gbps=gbps)
+                       peak_flops=peaks["bf16_tflops"] * 1e12,
+                       peak_gbps=peaks["hbm_gbps"])
     art["model"] = args.model
-    art["platform"] = platform
+    art["device"] = device
     with open(args.out + ".tmp", "w") as f:
         json.dump(art, f, indent=1)
     os.replace(args.out + ".tmp", args.out)

@@ -434,11 +434,6 @@ def main(argv=None):
                    "other telemetry-on runs")
     p.add_argument("--virtual", type=int, default=0,
                    help="force N virtual host (CPU) devices first")
-    p.add_argument("--compile-cache-dir", default=None,
-                   help="persistent XLA compilation-cache directory (same "
-                   "knob as tmlauncher): a scaling sweep compiles one "
-                   "program per rung, and re-runs/later rungs sharing the "
-                   "dir skip recompiles")
     p.add_argument("--exchange-bench", action="store_true",
                    help="run the exchange-strategy microbenchmark instead "
                    "of the scaling ladder (HLO collective counts + static "
@@ -458,10 +453,10 @@ def main(argv=None):
         from theanompi_tpu.parallel.mesh import force_host_devices
 
         force_host_devices(args.virtual)
-    if args.compile_cache_dir:
-        from theanompi_tpu.parallel.mesh import setup_compile_cache
+    from theanompi_tpu.parallel.mesh import setup_compile_cache
 
-        setup_compile_cache(args.compile_cache_dir)
+    # a sweep compiles one program per rung; re-runs load them
+    setup_compile_cache()
     ns = tuple(int(x) for x in args.ns.split(","))
     cfg = {"batch_size": args.batch_size, "n_train": max(256, args.batch_size * 8),
            "n_val": 64, "n_epochs": 1, "augment": False, "verbose": False}
