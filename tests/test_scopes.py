@@ -1,0 +1,144 @@
+"""Device work is named by scope (ISSUE 25): in the lowered tiny train
+step, decode step and prefill program every ``dot_general``, every custom
+call and every all-reduce lies under one of the scope names, and each
+scope name occurs.  Metadata only: the lowering is read, nothing runs."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+TINY = {"batch_size": 2, "n_train": 16, "n_val": 8, "seq_len": 32,
+        "vocab": 61, "dim": 32, "heads": 2, "n_layers": 2, "dropout": 0.0,
+        "n_epochs": 1, "precision": "bf16", "grad_clip": 1.0}
+
+MODEL = ("embed", "block", "attn", "mlp")
+#: program -> the scopes it must show
+EXPECTED = {
+    "train": (*MODEL, "loss", "clip", "exchange", "optimizer"),
+    "train_plain_loss": (*MODEL, "head", "loss", "clip", "exchange",
+                         "optimizer"),
+    "decode": (*MODEL, "head", "recast", "sample", "paged_decode"),
+    "prefill": (*MODEL, "head", "recast", "sample"),
+}
+SCOPES = sorted({s for names in EXPECTED.values() for s in names}
+                | {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
+_WORD = re.compile(r"(?<![A-Za-z0-9_])(" + "|".join(SCOPES)
+                   + r")(?![A-Za-z0-9_])")
+_OPS = ("stablehlo.dot_general", "stablehlo.custom_call",
+        "stablehlo.all_reduce")
+_LOC = re.compile(r"loc\((#loc\d+)\)\s*$")
+
+
+def named_ops(text: str) -> tuple[list, set]:
+    """-> ([(op, its whole name stack)], every scope name in any stack).
+
+    ``as_text(debug_info=True)`` names an op by a ``#locN`` alias whose
+    string is the name stack relative to the function the op is in; the
+    stack of a private function (a scan body, a ``closed_call``) is that of
+    its ``call`` sites, prepended here."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    lines = text.splitlines()
+    callers: dict = {}
+    found, fn = [], None
+    for i, line in enumerate(lines):
+        m = re.search(r"func\.func (?:public |private )?@([\w.]+)\(", line)
+        if m:
+            fn = m.group(1)
+            continue
+        loc = _LOC.search(line)
+        op = next((o for o in _OPS if o in line), None)
+        if op and not loc:  # an op with a region: the name is on its last line
+            indent = " " * (len(line) - len(line.lstrip()))
+            loc = _LOC.search(next(x for x in lines[i + 1:]
+                                   if x.startswith(indent + "})")))
+        name = names.get(loc.group(1), "") if loc else ""
+        call = re.search(r"call @([\w.]+)\(", line)
+        if call:
+            callers.setdefault(call.group(1), []).append((fn, name))
+        if op:
+            found.append((op, fn, name))
+
+    def stacks(f, seen=()):
+        if f not in callers or f in seen:
+            return [""]
+        return [p + "/" + n for cf, n in callers[f]
+                for p in stacks(cf, seen + (f,))]
+
+    ops = [(op, p + "/" + name) for op, f, name in found for p in stacks(f)]
+    # argument names (params['head']...) are no name stacks: ops' names only
+    seen = {w for n in names.values() if "[" not in n for w in _WORD.findall(n)}
+    return ops, seen
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    from theanompi_tpu.models.transformer_lm import TransformerLM
+    from theanompi_tpu.parallel.bsp import BSPTrainer
+    from theanompi_tpu.parallel.mesh import make_mesh
+    from theanompi_tpu.serving.engine import InferenceEngine
+    from theanompi_tpu.utils.helper_funcs import shard_batch
+    from theanompi_tpu.utils.recorder import Recorder
+
+    out = {}
+    for key, fused in (("train", True), ("train_plain_loss", False)):
+        model = TransformerLM(dict(TINY, fused_loss=fused))
+        t = BSPTrainer(model, mesh=make_mesh(n_data=2, devices=jax.devices()[:2]),
+                       recorder=Recorder(verbose=False))
+        t.compile_iter_fns()
+        t.init_state()
+        batch = shard_batch(t.mesh, next(iter(model.data.train_batches(
+            t.global_batch, 0, seed=0))), spec=t.batch_spec)
+        out[key] = t._step_fn.lower(
+            t.params, t.state, t.opt_state, batch, jnp.float32(0.01),
+            jnp.int32(0)).as_text(debug_info=True)
+    params, _ = model.init_params(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, block_size=8, max_batch=2,
+                          decode_kernel="on")
+    b, i32 = eng.max_batch, jnp.int32
+    out["decode"] = eng._decode_fn.lower(
+        eng.params, eng._k, eng._v,
+        jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+        jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+        jnp.zeros((b,), i32), eng._base_key).as_text(debug_info=True)
+    out["prefill"] = jax.jit(eng._prefill_impl, donate_argnums=(1, 2)).lower(
+        eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
+        jnp.zeros((16,), i32), jnp.asarray(5, i32),
+        jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
+        eng._base_key).as_text(debug_info=True)
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(EXPECTED))
+def test_every_matmul_kernel_and_all_reduce_lies_under_a_scope(lowered, program):
+    ops, seen = named_ops(lowered[program])
+    kinds = {op for op, _ in ops}
+    assert "stablehlo.dot_general" in kinds
+    if program.startswith("train"):
+        assert "stablehlo.all_reduce" in kinds  # two workers: the exchange
+    outside = [(op, stack) for op, stack in ops if not _WORD.search(stack)]
+    assert not outside, outside[:5]
+    missing = set(EXPECTED[program]) - seen
+    assert not missing, (program, sorted(seen))
+
+
+def test_the_exchange_and_the_loss_own_their_ops(lowered):
+    """The gradient all-reduce reads ``exchange`` (or the optimizer's
+    ``clip`` norm), the fused loss's matmuls read ``loss``, on the way
+    forward and on the way back."""
+    ops, _ = named_ops(lowered["train"])
+    reduces = [s for op, s in ops if op == "stablehlo.all_reduce"]
+    assert reduces and all(re.search(r"exchange|clip", s) for s in reduces)
+    loss = [s for op, s in ops
+            if op == "stablehlo.dot_general" and "loss" in s]
+    assert any("transpose" in s for s in loss)
+    assert any("transpose" not in s for s in loss)
+    blocks = [s for op, s in ops if "block" in s]
+    assert all(re.search(r"attn|mlp", s) for s in blocks)
+
+
+def test_the_jitted_programs_keep_their_names(lowered):
+    """The benchmark's traffic files find the programs by these names."""
+    assert "module @jit_local_step" in lowered["train"]
+    assert "module @jit__decode_impl" in lowered["decode"]

@@ -75,13 +75,15 @@ LAYER_DAG: tuple[tuple[str, tuple[str, ...], tuple[str, ...]], ...] = (
                     f"{PKG}.parallel.pipeline"),
                    ("mesh", "kernels")),
     ("ops",        (f"{PKG}.ops",), ("mesh", "kernels", "sharding")),
+    # "telemetry" admitted in ISSUE 25: the Recorder's segments are spans
+    # of the process's ring (telemetry.spans: stdlib-only, always on)
     ("utils_base", (f"{PKG}.utils.helper_funcs", f"{PKG}.utils.recorder",
                     f"{PKG}.utils.divergence"),
-                   ("mesh",)),
+                   ("mesh", "telemetry")),
     ("exchange",   (f"{PKG}.parallel.exchanger", f"{PKG}.parallel.overlap"),
                    ("mesh", "kernels")),
     ("data",       (f"{PKG}.models.data",),
-                   ("codes", "resilience", "utils_base")),
+                   ("codes", "telemetry", "resilience", "utils_base")),
     ("models",     (f"{PKG}.models",),
                    ("mesh", "kernels", "sharding", "ops", "utils_base",
                     "exchange", "data")),

@@ -21,7 +21,11 @@ import queue
 import threading
 import time
 
+from theanompi_tpu.telemetry import spans
+from theanompi_tpu.telemetry.metrics import PREFETCH_SPANS
 from theanompi_tpu.utils.helper_funcs import shard_batch
+
+(_SPAN_DEQUEUE,) = PREFETCH_SPANS
 
 _END = object()
 
@@ -69,9 +73,10 @@ class Prefetcher:
                 f"got {stall_timeout}")
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._it = it
-        # optional telemetry: each dequeue emits a span with the residual
-        # queue depth, so a starving pipeline is visible in the trace as
-        # long prefetch.dequeue spans at qsize 0
+        # optional telemetry sink (the prefetch.stall instant).  Each
+        # dequeue is a span of the process's ring with the residual queue
+        # depth, so a starving pipeline is visible in the trace as long
+        # prefetch.dequeue spans at qsize 0
         self._telemetry = telemetry
         self._stall_timeout = stall_timeout
         self._err: BaseException | None = None
@@ -146,17 +151,19 @@ class Prefetcher:
                 continue
 
     def __next__(self):
-        tel = self._telemetry
-        t0 = time.perf_counter() if tel is not None else 0.0
-        item = self._get()
+        span = spans.begin(_SPAN_DEQUEUE)
+        try:
+            item = self._get()
+        except BaseException as e:  # a stall stays visible, as long as it was
+            span.end(error=type(e).__name__)
+            raise
         if item is _END:
+            span.cancel()  # the end of the epoch is not a batch
             self._thread.join()
             if self._err is not None:
                 raise self._err
             raise StopIteration
-        if tel is not None:
-            tel.emit_span("prefetch.dequeue", t0,
-                          time.perf_counter() - t0, qsize=self._q.qsize())
+        span.end(qsize=self._q.qsize())
         self._consumed += 1
         return item
 

@@ -94,14 +94,19 @@ class _Block(L.Layer):
         )
         drop = L.Dropout(self.dropout)
 
-        h, _ = subs["ln1"].apply(params["ln1"], {}, x)
-        h, _ = subs["attn"].apply(params["attn"], {}, h, train=train)
-        h, _ = drop.apply({}, {}, h, train=train, rng=rngs[0])
-        x = x + h
-        h, _ = subs["ln2"].apply(params["ln2"], {}, x)
-        h, ffn_state = self._apply_ffn(subs, params, state, h, train)
-        h, _ = drop.apply({}, {}, h, train=train, rng=rngs[1])
-        return x + h, ffn_state
+        # device scopes (ISSUE 25): every op of a block carries
+        # block/attn or block/mlp in its name, each half with its LayerNorm
+        with jax.named_scope("block"):
+            with jax.named_scope("attn"):
+                h, _ = subs["ln1"].apply(params["ln1"], {}, x)
+                h, _ = subs["attn"].apply(params["attn"], {}, h, train=train)
+                h, _ = drop.apply({}, {}, h, train=train, rng=rngs[0])
+                x = x + h
+            with jax.named_scope("mlp"):
+                h, _ = subs["ln2"].apply(params["ln2"], {}, x)
+                h, ffn_state = self._apply_ffn(subs, params, state, h, train)
+                h, _ = drop.apply({}, {}, h, train=train, rng=rngs[1])
+                return x + h, ffn_state
 
     # -- serving path (ISSUE 6) ----------------------------------------------
     # Both steps reuse the training block's exact sub-layers (same params,
@@ -117,16 +122,16 @@ class _Block(L.Layer):
         ``x`` ``[1, P_pad, D]`` -> (y, cache')."""
         subs = dict(self._subs())
         attn = subs["attn"]
-        h, _ = subs["ln1"].apply(params["ln1"], {}, x)
-        q, k, v = attn.project_qkv(params["attn"], h)
-        cache = cache.write_prefill(layer_idx, k, v, table_row)
-        ctx = attn.attend(q, k, v)
-        h = attn.project_out(
-            params["attn"], ctx.reshape(x.shape[0], x.shape[1], -1))
-        x = x + h
-        h, _ = subs["ln2"].apply(params["ln2"], {}, x)
-        h, _ = self._apply_ffn(subs, params, {}, h, False)
-        return x + h, cache
+        with jax.named_scope("block"):
+            with jax.named_scope("attn"):
+                h, _ = subs["ln1"].apply(params["ln1"], {}, x)
+                q, k, v = attn.project_qkv(params["attn"], h)
+                cache = cache.write_prefill(layer_idx, k, v, table_row)
+                ctx = attn.attend(q, k, v)
+                h = attn.project_out(
+                    params["attn"], ctx.reshape(x.shape[0], x.shape[1], -1))
+                x = x + h
+            return self._serve_mlp(subs, params, x), cache
 
     def prefill_suffix_step(self, params, x, cache, layer_idx, suffix_row,
                             full_row, prefix_len):
@@ -138,16 +143,17 @@ class _Block(L.Layer):
         paged gather.  -> (y, cache')."""
         subs = dict(self._subs())
         attn = subs["attn"]
-        h, _ = subs["ln1"].apply(params["ln1"], {}, x)
-        q, k, v = attn.project_qkv(params["attn"], h)
-        cache = cache.write_prefill(layer_idx, k, v, suffix_row)
-        ctx = cache.attend_prefill(layer_idx, q, full_row, prefix_len)
-        h = attn.project_out(
-            params["attn"], ctx.reshape(x.shape[0], x.shape[1], -1))
-        x = x + h
-        h, _ = subs["ln2"].apply(params["ln2"], {}, x)
-        h, _ = self._apply_ffn(subs, params, {}, h, False)
-        return x + h, cache
+        with jax.named_scope("block"):
+            with jax.named_scope("attn"):
+                h, _ = subs["ln1"].apply(params["ln1"], {}, x)
+                q, k, v = attn.project_qkv(params["attn"], h)
+                cache = cache.write_prefill(layer_idx, k, v, suffix_row)
+                ctx = cache.attend_prefill(layer_idx, q, full_row,
+                                           prefix_len)
+                h = attn.project_out(
+                    params["attn"], ctx.reshape(x.shape[0], x.shape[1], -1))
+                x = x + h
+            return self._serve_mlp(subs, params, x), cache
 
     def decode_step(self, params, x, cache, layer_idx, positions):
         """One-token incremental forward of one block: appends this layer's
@@ -155,16 +161,24 @@ class _Block(L.Layer):
         ``x`` ``[B, 1, D]``, ``positions`` ``[B]`` -> (y, cache')."""
         subs = dict(self._subs())
         attn = subs["attn"]
-        h, _ = subs["ln1"].apply(params["ln1"], {}, x)
-        q, k, v = attn.project_qkv(params["attn"], h)
-        cache = cache.write_decode(layer_idx, k[:, 0], v[:, 0], positions)
-        ctx = cache.attend_decode(layer_idx, q[:, 0], positions)
-        h = attn.project_out(
-            params["attn"], ctx.reshape(x.shape[0], 1, -1))
-        x = x + h
+        with jax.named_scope("block"):
+            with jax.named_scope("attn"):
+                h, _ = subs["ln1"].apply(params["ln1"], {}, x)
+                q, k, v = attn.project_qkv(params["attn"], h)
+                cache = cache.write_decode(layer_idx, k[:, 0], v[:, 0],
+                                           positions)
+                ctx = cache.attend_decode(layer_idx, q[:, 0], positions)
+                h = attn.project_out(
+                    params["attn"], ctx.reshape(x.shape[0], 1, -1))
+                x = x + h
+            return self._serve_mlp(subs, params, x), cache
+
+    @jax.named_scope("mlp")
+    def _serve_mlp(self, subs, params, x):
+        """The FFN half of a serving step: LN, FFN, residual."""
         h, _ = subs["ln2"].apply(params["ln2"], {}, x)
         h, _ = self._apply_ffn(subs, params, {}, h, False)
-        return x + h, cache
+        return x + h
 
 
 @dataclasses.dataclass(frozen=True)
@@ -324,6 +338,7 @@ class TransformerLM(SupervisedModel):
         return [(f"{i:02d}_{layer.name}", layer)
                 for i, layer in enumerate(self.net.layers)]
 
+    @jax.named_scope("head")
     def _head_logits(self, cp, h):
         y = quant.matmul_any(h, cp["head"]["w"])
         if "b" in cp["head"]:
@@ -346,12 +361,13 @@ class TransformerLM(SupervisedModel):
         sequence's block table.  -> (logits ``[1, P_pad, V]`` fp32, cache').
         """
         del state
-        cp = self.precision.cast_to_compute(params)
+        with jax.named_scope("recast"):
+            cp = self.precision.cast_to_compute(params)
         x, li = None, 0
         for name, layer in self._serving_layers():
             p = cp.get(name, {})
             if isinstance(layer, L.Embedding):
-                x = jnp.take(p["w"], tokens, axis=0)
+                x, _ = layer.apply(p, {}, tokens)
             elif isinstance(layer, PositionEmbedding):
                 x, _ = layer.apply(p, {}, x)
             elif isinstance(layer, _Block):
@@ -376,17 +392,19 @@ class TransformerLM(SupervisedModel):
         table for end-padding positions, whose lanes are masked garbage by
         the same causal contract as full prefill's end-padding)."""
         del state
-        cp = self.precision.cast_to_compute(params)
+        with jax.named_scope("recast"):
+            cp = self.precision.cast_to_compute(params)
         x, li = None, 0
         for name, layer in self._serving_layers():
             p = cp.get(name, {})
             if isinstance(layer, L.Embedding):
-                x = jnp.take(p["w"], tokens, axis=0)
+                x, _ = layer.apply(p, {}, tokens)
             elif isinstance(layer, PositionEmbedding):
-                idx = jnp.clip(prefix_len + jnp.arange(tokens.shape[1]),
-                               0, p["pos"].shape[0] - 1)
-                pos = jnp.take(p["pos"], idx, axis=0).astype(x.dtype)
-                x = x + pos[None]
+                with jax.named_scope("embed"):
+                    idx = jnp.clip(prefix_len + jnp.arange(tokens.shape[1]),
+                                   0, p["pos"].shape[0] - 1)
+                    pos = jnp.take(p["pos"], idx, axis=0).astype(x.dtype)
+                    x = x + pos[None]
             elif isinstance(layer, _Block):
                 x, kv_cache = layer.prefill_suffix_step(
                     p, x, kv_cache, li, suffix_row, full_row, prefix_len)
@@ -407,16 +425,21 @@ class TransformerLM(SupervisedModel):
         # QuantizedTensor leaves (their fp32 scales must not cast to the
         # compute dtype) — the serving fast path feeds them through here
         # to the fused matmul kernel (ISSUE 18)
-        cp = self.precision.cast_to_compute(
-            params, is_leaf=lambda x: isinstance(x, quant.QuantizedTensor))
+        with jax.named_scope("recast"):
+            cp = self.precision.cast_to_compute(
+                params,
+                is_leaf=lambda x: isinstance(x, quant.QuantizedTensor))
         x, li = None, 0
         for name, layer in self._serving_layers():
             p = cp.get(name, {})
             if isinstance(layer, L.Embedding):
-                x = jnp.take(p["w"], tokens, axis=0)[:, None, :]
+                x, _ = layer.apply(p, {}, tokens)
+                x = x[:, None, :]
             elif isinstance(layer, PositionEmbedding):
-                pos = jnp.take(p["pos"], positions, axis=0).astype(x.dtype)
-                x = x + pos[:, None, :]
+                with jax.named_scope("embed"):
+                    pos = jnp.take(p["pos"], positions,
+                                   axis=0).astype(x.dtype)
+                    x = x + pos[:, None, :]
             elif isinstance(layer, _Block):
                 x, kv_cache = layer.decode_step(p, x, kv_cache, li,
                                                 positions)
@@ -478,11 +501,14 @@ class TransformerLM(SupervisedModel):
                 loss, err1, err5 = fused_lm_xent(h, w, b, batch["y"],
                                                  unroll=unroll)
         else:
-            logits, _ = self._head.apply(cp["head"], {}, h)
-            loss = softmax_cross_entropy(logits, batch["y"])
-            err1 = top_k_error(logits, batch["y"], k=1)
-            err5 = (top_k_error(logits, batch["y"], k=5)
-                    if logits.shape[-1] >= 5 else jnp.zeros((), jnp.float32))
+            with jax.named_scope("head"):
+                logits, _ = self._head.apply(cp["head"], {}, h)
+            with jax.named_scope("loss"):
+                loss = softmax_cross_entropy(logits, batch["y"])
+                err1 = top_k_error(logits, batch["y"], k=1)
+                err5 = (top_k_error(logits, batch["y"], k=5)
+                        if logits.shape[-1] >= 5
+                        else jnp.zeros((), jnp.float32))
         if self.config.get("l2", 0.0):
             loss = loss + self.config["l2"] * self.l2_sq_norm(params)
         metrics = {"cost": loss, "error": err1, "error_top5": err5,

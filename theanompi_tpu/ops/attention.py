@@ -184,6 +184,7 @@ class PositionEmbedding(L.Layer):
         params = {"pos": init_lib.normal(0.02)(key, (self.max_len, self.dim))}
         return params, {}, tuple(in_shape)
 
+    @jax.named_scope("embed")
     def apply(self, params, state, x, *, train=False, rng=None):
         t = x.shape[1]
         start = 0

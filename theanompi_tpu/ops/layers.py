@@ -420,6 +420,7 @@ class Embedding(Layer):
         params = {"w": self.w_init(key, (self.vocab, self.dim))}
         return params, {}, (*in_shape, self.dim)
 
+    @jax.named_scope("embed")
     def apply(self, params, state, x, *, train=False, rng=None):
         return jnp.take(params["w"], x, axis=0), state
 

@@ -98,6 +98,7 @@ def _chunk_stats(hc, yc, w, b, v, axis):
     return lse, gold, rank
 
 
+@jax.named_scope("loss")
 def _lm_xent_scan(h3, w, b, y2, mask2, cfg, axis):
     n, v, unroll = cfg
 
@@ -123,6 +124,7 @@ def _lm_xent_fwd(h3, w, b, y2, mask2, cfg, axis):
     return (loss, e1, e5), (h3, w, b, y2, mask2, lse2)
 
 
+@jax.named_scope("loss")
 def _lm_xent_bwd(cfg, axis, res, cts):
     h3, w, b, y2, mask2, lse2 = res
     n, v, unroll = cfg

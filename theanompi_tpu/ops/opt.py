@@ -70,6 +70,7 @@ def global_sq_norm(grads, param_specs=None):
     return total
 
 
+@jax.named_scope("clip")
 def clip_by_global_norm(grads, max_norm: float, param_specs=None):
     """Scale the whole gradient pytree so its global L2 norm <= max_norm
     (the tutorial-era LSTM BPTT stabilizer; reference lstm.py lineage).
@@ -112,14 +113,17 @@ def sharded_update(opt, grads, opt_state, params, lr, axis_name=None,
     all-bucket sync point; the chain still pins the release order.
     """
     if opt.grad_clip:
-        sq = global_sq_norm(grads)
-        if axis_name is not None:
-            axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
-            for a in axes:
-                sq = jax.lax.psum(sq, a)
-        norm = jnp.sqrt(sq)
-        scale = jnp.minimum(1.0, opt.grad_clip / jnp.maximum(norm, 1e-12))
-        grads = _tmap(lambda g: (g * scale).astype(g.dtype), grads)
+        with jax.named_scope("clip"):
+            sq = global_sq_norm(grads)
+            if axis_name is not None:
+                axes = (axis_name if isinstance(axis_name, tuple)
+                        else (axis_name,))
+                for a in axes:
+                    sq = jax.lax.psum(sq, a)
+            norm = jnp.sqrt(sq)
+            scale = jnp.minimum(1.0,
+                                opt.grad_clip / jnp.maximum(norm, 1e-12))
+            grads = _tmap(lambda g: (g * scale).astype(g.dtype), grads)
         opt = dataclasses.replace(opt, grad_clip=None)
     new_params, new_opt_state = opt.update(grads, opt_state, params, lr)
     if chain is not None:

@@ -171,6 +171,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
             m_scr[:, 0] + jnp.log(l_safe), (8, block_q))
 
 
+@jax.named_scope("flash_fwd")  # names the custom call in a device trace
 def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret):
     """q/k/v: [B, H, T, D] -> (out [B,H,T,D], lse [B,H,nq,8,block_q]).
 
@@ -315,7 +316,7 @@ def _bwd_call(q, k, v, out, lse, g, *, causal, block_q, block_k, interpret):
     k_tile = pl.BlockSpec((1, 1, block_k, d), kv_map)
     row_q = pl.BlockSpec((1, 1, 1, 8, block_q),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0))
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(b, h, nq, nk),
@@ -324,7 +325,9 @@ def _bwd_call(q, k, v, out, lse, g, *, causal, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    )
+    with jax.named_scope("flash_bwd_dq"):
+        dq = dq_call(q, k, v, g, lse, delta)
 
     # grid transposed: k-tile outer, q-tile inner (the accumulated axis).
     # Causal clamp runs the OTHER way here: q-tiles before the diagonal
@@ -343,7 +346,7 @@ def _bwd_call(q, k, v, out, lse, g, *, causal, block_q, block_k, interpret):
     k_tile2 = pl.BlockSpec((1, 1, block_k, d),
                            lambda bi, hi, ki, qi: (bi, hi, ki, 0))
     row_q2 = pl.BlockSpec((1, 1, 1, 8, block_q), row_map)
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(b, h, nk, nq),
@@ -354,7 +357,9 @@ def _bwd_call(q, k, v, out, lse, g, *, causal, block_q, block_k, interpret):
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    )
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = dkv_call(q, k, v, g, lse, delta)
     return dq, dk, dv
 
 

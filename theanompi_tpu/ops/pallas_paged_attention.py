@@ -154,4 +154,6 @@ def paged_attend_decode(k_pool, v_pool, tables, block_size: int, q,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
     )
-    return fn(tables, jnp.asarray(positions, jnp.int32), q, k_pool, v_pool)
+    with jax.named_scope("paged_decode"):  # the custom call's name in a trace
+        return fn(tables, jnp.asarray(positions, jnp.int32), q, k_pool,
+                  v_pool)

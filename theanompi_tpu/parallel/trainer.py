@@ -45,9 +45,12 @@ from theanompi_tpu.parallel.mesh import (
     replica_rng,
     replicated,
 )
+from theanompi_tpu.telemetry import spans
+from theanompi_tpu.telemetry.metrics import TRAIN_SPANS
 from theanompi_tpu.utils.helper_funcs import import_model, shard_batch
 from theanompi_tpu.utils.recorder import Recorder
 
+(_SPAN_STEP,) = TRAIN_SPANS
 
 from theanompi_tpu.parallel.exchanger import (  # noqa: E402
     EXCHANGE_RNG_TAG as _EXCH_RNG_TAG,
@@ -172,22 +175,30 @@ def make_local_step(model, opt, base_key, exchanger=None, stacked=False,
                 # zero1: the exchange IS the update — reduce-scatter grad
                 # buckets, shard-local optimizer step, all-gather params
                 # (opt_state lives in the exchanger's sharded bucket layout)
-                new_params, new_opt_state = exchanger.exchange_and_update(
-                    grads, opt_state, params, lr, opt,
-                    rng=jax.random.fold_in(rng, _EXCH_RNG_TAG),
-                    step=step,
-                )
+                with jax.named_scope("exchange"):
+                    new_params, new_opt_state = (
+                        exchanger.exchange_and_update(
+                            grads, opt_state, params, lr, opt,
+                            rng=jax.random.fold_in(rng, _EXCH_RNG_TAG),
+                            step=step,
+                        ))
             else:
                 if exchanger is not None:
                     # a distinct stream from dropout's: ring_int8 seeds its
                     # stochastic rounding from this key.  step anchors the
                     # overlap fence chain (exch_overlap; unused otherwise)
-                    grads = exchanger.exchange(
-                        grads, rng=jax.random.fold_in(rng, _EXCH_RNG_TAG),
-                        step=step)
-                new_params, new_opt_state = opt.update(
-                    grads, opt_state, params, lr, param_specs=param_specs
-                )
+                    with jax.named_scope("exchange"):
+                        grads = exchanger.exchange(
+                            grads,
+                            rng=jax.random.fold_in(rng, _EXCH_RNG_TAG),
+                            step=step)
+                # gradient clipping is the optimizer's first act: its ops
+                # read optimizer/clip (ops/opt.py)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt_state = opt.update(
+                        grads, opt_state, params, lr,
+                        param_specs=param_specs
+                    )
             if ok is not None:
                 # skip_batch: a poisoned step costs one skipped update —
                 # keep the old params/state/opt state wholesale
@@ -211,10 +222,11 @@ def make_local_step(model, opt, base_key, exchanger=None, stacked=False,
                 jax.tree.map(lambda m: m[None], metrics),
             )
         axes = exchanger.axis_name if exchanger is not None else DATA_AXIS
-        metrics = pmean_floats(metrics, axes)
-        # keep non-learned state consistent across replicas (already
-        # identical under sync-BN; pmean repairs drift otherwise)
-        new_state = pmean_floats(new_state, axes)
+        with jax.named_scope("exchange"):
+            metrics = pmean_floats(metrics, axes)
+            # keep non-learned state consistent across replicas (already
+            # identical under sync-BN; pmean repairs drift otherwise)
+            new_state = pmean_floats(new_state, axes)
         if isinstance(metrics, dict):
             # the donated-device-step contract: train_iter pops this and
             # feeds it back as the next step argument, so the counter never
@@ -425,8 +437,10 @@ class BaseTrainer:
         self.profile_dir = profile_dir
         self.profile_window = profile_window
         self._profiling = False
-        # ISSUE 1 telemetry: None means OFF — every hot-path integration
-        # below guards on it, so a disabled run makes zero telemetry calls
+        # ISSUE 1 telemetry: None means no sink — the step's spans go to
+        # the process's ring either way (ISSUE 25); everything that writes
+        # (gauges, counters, flushes) guards on this, so a disabled run
+        # constructs no Telemetry and writes nothing
         self.telemetry = telemetry
         self.recorder.telemetry = telemetry
         # ISSUE 10: the data layer's read-retry telemetry and fault hooks
@@ -900,59 +914,69 @@ class BaseTrainer:
         self._profile_tick()
         r = recorder or self.recorder
         tel = self.telemetry
-        step_t0 = time.perf_counter() if tel is not None else 0.0
         step_idx, epoch_idx = self.iteration, self.epoch
-        r.start("wait")
-        # already-placed batches (prefetch path) pass through device_put free
-        batch = shard_batch(self.mesh, batch, spec=self.batch_spec)
-        r.end("wait")
-        r.start("calc")
-        # scalar-hoisting (ISSUE 2 satellite): jnp.float32(lr)/jnp.int32(i)
-        # here were one host->device transfer EACH per step; the lr is
-        # placed once per schedule change and the step counter is carried
-        # as a device scalar threaded through the step's `_next_step`
-        lr_f = float(lr)
-        if self._lr_dev is None or self._lr_host != lr_f:
-            self._lr_dev = jnp.float32(lr_f)
-            self._lr_host = lr_f
-        if self._step_dev is None or self._step_dev_iter != self.iteration:
-            # placed ON THE MESH like the `_next_step` the step hands back:
-            # jax types carry the mesh, so a bare jnp.int32 here made the
-            # second call (fed the returned counter) a different signature
-            # — a full second trace and compile of the train step
-            self._step_dev = jax.device_put(np.int32(self.iteration),
-                                            replicated(self.mesh))
-        self.params, self.state, self.opt_state, metrics = self._step_fn(
-            self.params,
-            self.state,
-            self.opt_state,
-            batch,
-            self._lr_dev,
-            self._step_dev,
-        )
-        self.iteration += 1
-        nxt = (metrics.pop("_next_step", None)
-               if isinstance(metrics, dict) else None)
-        if nxt is not None and getattr(nxt, "ndim", None) == 0:
-            self._step_dev, self._step_dev_iter = nxt, self.iteration
-        else:  # stacked/custom metrics carry no counter: re-place next call
-            self._step_dev = None
-        # the device guard's skip flag is sentinel bookkeeping, not a
-        # training metric — pop it before the recorder sees the dict
-        skipf = (metrics.pop("_sentinel_skip", None)
-                 if isinstance(metrics, dict) else None)
-        # fence only at print boundaries: per-iter blocking would serialize
-        # the dispatch pipeline (SURVEY.md §7 hard part 5)
-        fence = metrics["cost"] if self.iteration % r.print_freq == 0 else None
-        r.end("calc", fence=fence)
-        # no wrapping span here: the async rules' post_step brackets the
-        # rounds that actually exchange with recorder 'comm' segments, which
-        # the recorder already emits as spans — a per-step wrapper would
-        # write a no-op span line on every non-exchange step (tau-1 of tau)
-        self.post_step()
-        r.end_iteration()
-        r.train_metrics(**metrics)
-        r.print_train_info(self.iteration)
+        with spans.span(_SPAN_STEP, step=step_idx, epoch=epoch_idx) as step:
+            r.start("wait")
+            # already-placed batches (prefetch path) pass through
+            # device_put free
+            batch = shard_batch(self.mesh, batch, spec=self.batch_spec)
+            r.end("wait")
+            r.start("calc")
+            # scalar-hoisting (ISSUE 2 satellite): jnp.float32(lr)/jnp.int32(i)
+            # here were one host->device transfer EACH per step; the lr is
+            # placed once per schedule change and the step counter is carried
+            # as a device scalar threaded through the step's `_next_step`
+            # lint: host-sync-ok — lr is the schedule's host number
+            lr_f = float(lr)
+            if self._lr_dev is None or self._lr_host != lr_f:
+                self._lr_dev = jnp.float32(lr_f)
+                self._lr_host = lr_f
+            if self._step_dev is None or self._step_dev_iter != self.iteration:
+                # placed ON THE MESH like the `_next_step` the step hands back:
+                # jax types carry the mesh, so a bare jnp.int32 here made the
+                # second call (fed the returned counter) a different signature
+                # — a full second trace and compile of the train step
+                self._step_dev = jax.device_put(np.int32(self.iteration),
+                                                replicated(self.mesh))
+            self.params, self.state, self.opt_state, metrics = self._step_fn(
+                self.params,
+                self.state,
+                self.opt_state,
+                batch,
+                self._lr_dev,
+                self._step_dev,
+            )
+            self.iteration += 1
+            nxt = (metrics.pop("_next_step", None)
+                   if isinstance(metrics, dict) else None)
+            if nxt is not None and getattr(nxt, "ndim", None) == 0:
+                self._step_dev, self._step_dev_iter = nxt, self.iteration
+            else:  # stacked/custom metrics carry no counter: re-place next call
+                self._step_dev = None
+            # the device guard's skip flag is sentinel bookkeeping, not a
+            # training metric — pop it before the recorder sees the dict
+            skipf = (metrics.pop("_sentinel_skip", None)
+                     if isinstance(metrics, dict) else None)
+            # fence only at print boundaries: per-iter blocking would serialize
+            # the dispatch pipeline (SURVEY.md §7 hard part 5)
+            fence = metrics["cost"] if self.iteration % r.print_freq == 0 else None
+            r.end("calc", fence=fence)
+            if fence is not None:
+                # loss tag ONLY at fenced boundary steps (ISSUE 13): the
+                # cost is already materialized by the calc fence above, so
+                # float() is free here; tagging every step would add a
+                # per-step sync.  The health monitor's NaN/spike detector
+                # keys on this tag.
+                # lint: host-sync-ok — the calc fence just materialized it
+                step.tag(loss=float(fence))
+            # no wrapping span here: the async rules' post_step brackets the
+            # rounds that actually exchange with recorder 'comm' segments, which
+            # the recorder already emits as spans — a per-step wrapper would
+            # write a no-op span line on every non-exchange step (tau-1 of tau)
+            self.post_step()
+            r.end_iteration()
+            r.train_metrics(**metrics)
+            r.print_train_info(self.iteration)
         if tel is not None:
             # same async-dispatch honesty caveat as the calc split: between
             # print boundaries a span measures dispatch, and only the fenced
@@ -960,17 +984,7 @@ class BaseTrainer:
             # metrics below aggregate across a window, which is honest at
             # steady state because dispatched work must drain through the
             # donated-buffer chain
-            dur = time.perf_counter() - step_t0
-            # loss tag ONLY at fenced boundary steps (ISSUE 13): the cost
-            # is already materialized by the calc fence above, so float()
-            # is free here; tagging every step would add a per-step sync.
-            # The health monitor's NaN/spike detector keys on this tag.
-            if fence is not None:
-                tel.emit_span("train.step", step_t0, dur, step=step_idx,
-                              epoch=epoch_idx, loss=float(fence))
-            else:
-                tel.emit_span("train.step", step_t0, dur,
-                              step=step_idx, epoch=epoch_idx)
+            dur = step.dur
             tel.observe("train.step_s", dur)
             if not self._first_step_emitted:
                 # first-compile visibility (ISSUE 3): the first dispatch

@@ -19,6 +19,15 @@ the trainer checkpoints, see :mod:`theanompi_tpu.models.transformer_lm`):
 Sampling runs inside the step under explicit PRNG keys derived from
 ``(request id, position)`` only — so a preempted-and-recomputed sequence
 resamples identically, and greedy (``temperature=0``) is pure argmax.
+
+Each host call is a span of the process's ring
+(:mod:`theanompi_tpu.telemetry.spans`): ``serve.prefill`` around a whole
+prefill, ``serve.decode`` around a whole decode step with its four parts
+beneath it — ``.place`` (the host-to-device puts), ``.dispatch`` (the
+jitted call), ``.wait`` (the next tokens reach the host: the device step
+is over), ``.fetch`` (the logits' copy).  On the device, ``recast`` and
+``sample`` scopes name the weight cast and the sampler beside the model's
+own ``embed`` / ``block`` / ``attn`` / ``mlp`` / ``head``.
 """
 
 from __future__ import annotations
@@ -39,6 +48,11 @@ from theanompi_tpu.serving.quant import (
     is_quantized_tree,
     quantize_tree,
 )
+from theanompi_tpu.telemetry import spans
+from theanompi_tpu.telemetry.metrics import SERVE_DECODE_SPANS, SERVE_SPANS
+
+_SPAN_PREFILL, _SPAN_DECODE = SERVE_SPANS
+_SPAN_PLACE, _SPAN_DISPATCH, _SPAN_WAIT, _SPAN_FETCH = SERVE_DECODE_SPANS
 
 
 def _is_quantized(leaf) -> bool:
@@ -148,6 +162,8 @@ class InferenceEngine:
         # computed under the OLD weights, so the scheduler's prefix cache
         # stamps itself against this and invalidates on mismatch (ISSUE 17)
         self.params_version = 0
+        #: decode steps run so far: the ``step`` tag of ``serve.decode``
+        self.n_decodes = 0
 
     @property
     def quantized(self) -> bool:
@@ -211,29 +227,34 @@ class InferenceEngine:
         # fast path keeps kernel-consumable int8 leaves quantized; the
         # fallback dequantizes everything exactly as before (the PR 9
         # argmax-agreement lock rides on that path staying bit-stable)
-        params = dequantize_tree(params, keep=self._keep_quant)
+        with jax.named_scope("recast"):
+            params = dequantize_tree(params, keep=self._keep_quant)
         cache = PagedKVCache(k, v, tables, self.block_size,
                              decode_impl=self.decode_impl)
         # the incoming token's 0-based position == tokens already cached
         positions = lengths
         logits, cache = self.model.apply_decode(
             params, {}, cache, positions, tokens)
-        keys = jax.vmap(functools.partial(_sample_key, base_key))(
-            rids, positions + 1)
-        nxt = sample_tokens(logits, temps, keys, self.top_k)
+        with jax.named_scope("sample"):
+            keys = jax.vmap(functools.partial(_sample_key, base_key))(
+                rids, positions + 1)
+            nxt = sample_tokens(logits, temps, keys, self.top_k)
         return nxt, logits, cache.k, cache.v
 
     def _prefill_impl(self, params, k, v, table_row, tokens, true_len,
                       temp, rid, base_key):
-        params = dequantize_tree(params)
+        with jax.named_scope("recast"):
+            params = dequantize_tree(params)
         cache = PagedKVCache(
             k, v, jnp.zeros((1, self.max_blocks_per_seq), jnp.int32),
             self.block_size)
         logits, cache = self.model.apply_prefill(
             params, {}, cache, table_row, tokens[None, :])
-        last = jnp.take(logits[0], true_len - 1, axis=0)
-        key = _sample_key(base_key, rid, true_len)
-        nxt = sample_tokens(last[None], temp[None], key[None], self.top_k)
+        with jax.named_scope("sample"):
+            last = jnp.take(logits[0], true_len - 1, axis=0)
+            key = _sample_key(base_key, rid, true_len)
+            nxt = sample_tokens(last[None], temp[None], key[None],
+                                self.top_k)
         return nxt[0], last, cache.k, cache.v
 
     def _prefill_suffix_impl(self, params, k, v, full_row, suffix_row,
@@ -247,16 +268,19 @@ class InferenceEngine:
         stay absolute-position-derived — a partial prefill samples the
         identical stream a full prefill (or a decode at the same position)
         would."""
-        params = dequantize_tree(params)
+        with jax.named_scope("recast"):
+            params = dequantize_tree(params)
         cache = PagedKVCache(
             k, v, jnp.zeros((1, self.max_blocks_per_seq), jnp.int32),
             self.block_size)
         logits, cache = self.model.apply_prefill_partial(
             params, {}, cache, suffix_row, full_row, tokens[None, :],
             prefix_len)
-        last = jnp.take(logits[0], true_len - prefix_len - 1, axis=0)
-        key = _sample_key(base_key, rid, true_len)
-        nxt = sample_tokens(last[None], temp[None], key[None], self.top_k)
+        with jax.named_scope("sample"):
+            last = jnp.take(logits[0], true_len - prefix_len - 1, axis=0)
+            key = _sample_key(base_key, rid, true_len)
+            nxt = sample_tokens(last[None], temp[None], key[None],
+                                self.top_k)
         return nxt[0], last, cache.k, cache.v
 
     # -- host API (the scheduler's surface) ----------------------------------
@@ -284,33 +308,40 @@ class InferenceEngine:
         if p > self.max_context:
             raise ValueError(f"prompt of {p} tokens > max context "
                              f"{self.max_context}")
-        if prefix_len:
-            return self._prefill_suffix(table_row, tokens, temperature,
-                                        rid, prefix_len)
-        p_pad = self.pad_len(p)
-        if p_pad < p:
-            raise ValueError(f"prompt {p} > padded bucket {p_pad}")
-        row = list(table_row) + [PagedKVCache.NULL_BLOCK] * (
-            p_pad // self.block_size - len(table_row))
-        fn = self._prefill_fns.get(p_pad)
-        if fn is None:
-            fn = self._prefill_fns[p_pad] = jax.jit(
-                self._prefill_impl, donate_argnums=(1, 2))
-        toks = np.zeros((p_pad,), np.int32)
-        toks[:p] = tokens
-        nxt, last, self._k, self._v = fn(
-            self.params, self._k, self._v,
-            jnp.asarray(row, jnp.int32), jnp.asarray(toks),
-            jnp.asarray(p, jnp.int32),
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(rid, jnp.int32), self._base_key)
-        # lint: donated-escape-ok — prefill outputs are fresh XLA result
-        # buffers; only the k/v pools are donated, never sampled tokens
-        return int(nxt), np.asarray(last)
+        # the whole call, fenced by the host int it returns; ``bucket`` is
+        # the padded length (of the uncached part) that picks the program
+        with spans.span(_SPAN_PREFILL, request=rid, prompt=p,
+                        bucket=self.pad_len(p - prefix_len),
+                        prefix_len=prefix_len):
+            if prefix_len:
+                return self._prefill_suffix(table_row, tokens, temperature,
+                                            rid, prefix_len)
+            p_pad = self.pad_len(p)
+            if p_pad < p:
+                raise ValueError(f"prompt {p} > padded bucket {p_pad}")
+            row = list(table_row) + [PagedKVCache.NULL_BLOCK] * (
+                p_pad // self.block_size - len(table_row))
+            fn = self._prefill_fns.get(p_pad)
+            if fn is None:
+                fn = self._prefill_fns[p_pad] = jax.jit(
+                    self._prefill_impl, donate_argnums=(1, 2))
+            toks = np.zeros((p_pad,), np.int32)
+            toks[:p] = tokens
+            nxt, last, self._k, self._v = fn(
+                self.params, self._k, self._v,
+                jnp.asarray(row, jnp.int32), jnp.asarray(toks),
+                jnp.asarray(p, jnp.int32),
+                jnp.asarray(temperature, jnp.float32),
+                jnp.asarray(rid, jnp.int32), self._base_key)
+            # lint: donated-escape-ok — prefill outputs are fresh XLA result
+            # buffers; only the k/v pools are donated, never sampled tokens
+            # lint: host-sync-ok — the span closes over materialized results
+            return int(nxt), np.asarray(last)
 
     def _prefill_suffix(self, table_row, tokens, temperature, rid,
                         prefix_len):
-        """The ``prefix_len > 0`` half of :meth:`prefill`."""
+        """The ``prefix_len > 0`` half of :meth:`prefill`, inside its
+        ``serve.prefill`` span."""
         p = len(tokens)
         if prefix_len % self.block_size:
             raise ValueError(f"prefix_len {prefix_len} is not a whole "
@@ -349,16 +380,30 @@ class InferenceEngine:
         np.int32, logits ``[B, V]`` np).  All arguments are host arrays of
         length ``max_batch``; inactive slots pass table rows of nulls and
         length 0 (their outputs are garbage by contract)."""
-        nxt, logits, self._k, self._v = self._decode_fn(
-            self.params, self._k, self._v,
-            jnp.asarray(tables, jnp.int32),
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(temps, jnp.float32),
-            jnp.asarray(rids, jnp.int32), self._base_key)
-        # lint: donated-escape-ok — decode outputs are fresh XLA result
-        # buffers; only the k/v pools are donated, never tokens/logits
-        return np.asarray(nxt), np.asarray(logits)
+        lengths = np.asarray(lengths)
+        active = np.flatnonzero(lengths)
+        with spans.span(_SPAN_DECODE, step=self.n_decodes, batch=len(active),
+                        requests=np.asarray(rids)[active].tolist()):
+            self.n_decodes += 1
+            with spans.span(_SPAN_PLACE):
+                args = (jnp.asarray(tables, jnp.int32),
+                        jnp.asarray(lengths, jnp.int32),
+                        jnp.asarray(tokens, jnp.int32),
+                        jnp.asarray(temps, jnp.float32),
+                        jnp.asarray(rids, jnp.int32))
+            with spans.span(_SPAN_DISPATCH):
+                nxt, logits, self._k, self._v = self._decode_fn(
+                    self.params, self._k, self._v, *args, self._base_key)
+            with spans.span(_SPAN_WAIT):
+                # lint: host-sync-ok — this span IS the wait for the device
+                # lint: donated-escape-ok — decode outputs are fresh XLA
+                # result buffers; only the k/v pools are donated
+                nxt = np.asarray(nxt)
+            with spans.span(_SPAN_FETCH, bytes=logits.nbytes):
+                # lint: host-sync-ok — this span IS the copy to the host
+                # lint: donated-escape-ok — as above: never tokens/logits
+                logits = np.asarray(logits)
+            return nxt, logits
 
     def fence(self):
         """Block until the cache state is materialized (honest timing)."""

@@ -35,7 +35,10 @@ terminal state —
 
 All telemetry flows through the names registered in
 :mod:`theanompi_tpu.telemetry.metrics` (``SERVE_*``); latency percentiles
-are also tracked host-side so the SERVE report works with telemetry off.
+are also tracked host-side so the SERVE report works with telemetry off,
+and every step is a ``serve.step`` span of the process's ring
+(:mod:`theanompi_tpu.telemetry.spans`) with the admission pass and the
+engine's calls beneath it.
 """
 
 from __future__ import annotations
@@ -52,6 +55,7 @@ from theanompi_tpu.resilience.faults import FaultInjected, FaultPlan
 from theanompi_tpu.serving.kv_cache import BlockPool, PagedKVCache, blocks_for
 from theanompi_tpu.serving.lifecycle import DRAIN_OP, read_jsonl_since
 from theanompi_tpu.serving.prefix_cache import PrefixCache
+from theanompi_tpu.telemetry import spans
 from theanompi_tpu.telemetry.metrics import (  # registered names (ISSUE 6)
     SERVE_COUNTERS,
     SERVE_HISTOGRAMS,
@@ -60,10 +64,10 @@ from theanompi_tpu.telemetry.metrics import (  # registered names (ISSUE 6)
     SERVE_LIFECYCLE_INSTANTS,
     SERVE_PREFIX_COUNTERS,
     SERVE_PREFIX_INSTANTS,
-    SERVE_SPANS,
+    SERVE_STEP_SPANS,
 )
 
-_SPAN_PREFILL, _SPAN_DECODE = SERVE_SPANS
+_SPAN_STEP, _SPAN_ADMIT = SERVE_STEP_SPANS
 _INST_ADMIT, _INST_PREEMPT, _INST_FINISH = SERVE_INSTANTS
 _HIST_TOKEN_MS, _HIST_TTFT_MS = SERVE_HISTOGRAMS
 _CNT_TOKENS, _CNT_PREEMPTIONS, _CNT_REQUESTS = SERVE_COUNTERS
@@ -97,6 +101,7 @@ class Request:
     n_preemptions: int = 0
     t_submit: float | None = None
     t_first_token: float | None = None
+    t_last_token: float | None = None  # stamp of the newest token
     t_done: float | None = None
 
     @property
@@ -490,19 +495,10 @@ class Scheduler:
                 return
             row = matched + new
             self.queue.popleft()
-            span = (self.telemetry.span(_SPAN_PREFILL, request=req.rid,
-                                        prompt=len(prefix), slot=slot)
-                    if self.telemetry is not None else None)
-            if span is not None:
-                span.__enter__()
-            try:
-                # prefill returns a host int — already materialized, so the
-                # span close measures execution, not dispatch
-                tok, _ = self.engine.prefill(row, prefix, req.temperature,
-                                             req.rid, prefix_len=prefix_len)
-            finally:
-                if span is not None:
-                    span.__exit__(None, None, None)
+            # prefill returns a host int — already materialized, so the
+            # engine's serve.prefill span measures execution, not dispatch
+            tok, _ = self.engine.prefill(row, prefix, req.temperature,
+                                         req.rid, prefix_len=prefix_len)
             if prefix_len:
                 # exact accounting: tokens_saved is the sum of matched-
                 # prefix lengths — prefill K/V the engine did not recompute
@@ -518,6 +514,9 @@ class Scheduler:
                 self.ttft_ms.append(ttft)
                 if self.telemetry is not None:
                     self.telemetry.observe(_HIST_TTFT_MS, ttft)
+            # a prefill's token opens the request's series of token gaps
+            # (again after a preemption: the recompute is no gap)
+            req.t_last_token = now
             req.generated.append(tok)
             if self.telemetry is not None:
                 self.telemetry.count(_CNT_TOKENS)
@@ -585,55 +584,54 @@ class Scheduler:
         blocks, decode the fixed batch, account the new tokens; -> every
         request that reached a TERMINAL state this step (done + expired +
         failed — run loops key on ``req.state``)."""
-        finished: list[Request] = []
-        self._sweep_deadlines(finished)
-        self._admit(finished)
-        if self.n_active == 0:
-            return finished
-        self._ensure_capacity()
-        active = [s for s in range(self.engine.max_batch)
-                  if self.slots[s] is not None]
-        if not active:  # capacity pressure preempted everyone admitted
-            return finished
-        self._fire_faults()
-        span = None
-        if self.telemetry is not None:
-            span = self.telemetry.span(
-                _SPAN_DECODE, step=self.n_steps, batch=len(active),
-                requests=[int(self._rids[s]) for s in active])
-            span.__enter__()
-        t0 = time.perf_counter()
-        try:
+        with spans.span(_SPAN_STEP, step=self.n_steps) as step:
+            finished: list[Request] = []
+            self._sweep_deadlines(finished)
+            with spans.span(_SPAN_ADMIT, queued=len(self.queue)):
+                self._admit(finished)
+            if self.n_active == 0:
+                return finished
+            self._ensure_capacity()
+            active = [s for s in range(self.engine.max_batch)
+                      if self.slots[s] is not None]
+            if not active:  # capacity pressure preempted everyone admitted
+                return finished
+            self._fire_faults()
+            step.tag(batch=len(active))
+            t0 = time.perf_counter()
+            # decode() returns host arrays: its serve.decode span is fenced
             nxt, _ = self.engine.decode(self._tables, self._lengths,
                                         self._tokens, self._temps,
                                         self._rids)
-        finally:
-            if span is not None:  # decode() returned host arrays: fenced
-                span.__exit__(None, None, None)
-        t1 = time.perf_counter()
-        step_ms = (t1 - t0) * 1e3
-        self.step_ms.append(step_ms)
-        self.n_steps += 1
-        self._rate.append((t1, len(active)))
-        for slot in active:
-            req = self.slots[slot]
-            self._lengths[slot] += 1  # the fed token is now cached
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            self._tokens[slot] = tok
-            self.token_ms.append(step_ms)
-            if self.telemetry is not None:
-                self.telemetry.count(_CNT_TOKENS)
-                self.telemetry.observe(_HIST_TOKEN_MS, step_ms)
-            if self._done(req):
-                self._finish(slot, finished)
-        if self.telemetry is not None and self.n_steps % 16 == 0:
-            # periodic flush (ISSUE 13): the ttft/token histograms must
-            # reach the event stream while serving is LIVE — the health
-            # monitor's SLO detector reads p99 from ``metrics`` events,
-            # and a flush only at shutdown would blind it
-            self.telemetry.flush_metrics(step=self.n_steps)
-        return finished
+            t1 = time.perf_counter()
+            step_ms = (t1 - t0) * 1e3
+            self.step_ms.append(step_ms)
+            self.n_steps += 1
+            self._rate.append((t1, len(active)))
+            for slot in active:
+                req = self.slots[slot]
+                self._lengths[slot] += 1  # the fed token is now cached
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                self._tokens[slot] = tok
+                # the gap since this request's previous token: a stall for
+                # another request's prefill and the scheduler's own time are
+                # inside it, as the request's user feels them
+                gap_ms = (t1 - req.t_last_token) * 1e3
+                req.t_last_token = t1
+                self.token_ms.append(gap_ms)
+                if self.telemetry is not None:
+                    self.telemetry.count(_CNT_TOKENS)
+                    self.telemetry.observe(_HIST_TOKEN_MS, gap_ms)
+                if self._done(req):
+                    self._finish(slot, finished)
+            if self.telemetry is not None and self.n_steps % 16 == 0:
+                # periodic flush (ISSUE 13): the ttft/token histograms must
+                # reach the event stream while serving is LIVE — the health
+                # monitor's SLO detector reads p99 from ``metrics`` events,
+                # and a flush only at shutdown would blind it
+                self.telemetry.flush_metrics(step=self.n_steps)
+            return finished
 
     # -- graceful drain (ISSUE 14) -------------------------------------------
     def begin_drain(self) -> list[Request]:

@@ -5,9 +5,16 @@ Three fragments existed before this package — the Recorder's host splits,
 the bounded ``jax.profiler`` window, and per-round bench JSON — none of
 which emitted structured events.  This package is the common substrate:
 
+- :mod:`~theanompi_tpu.telemetry.spans` — the process's always-on span
+  ring (ISSUE 25): every hot loop opens its spans there, once; a record
+  has an id, a parent, ``perf_counter`` stamps and tags, and is a
+  ``jax.profiler.TraceAnnotation`` as well, so a profiler trace carries
+  the program's spans on the device's clock.  ``jit.build`` instants say
+  which span built a program;
 - :class:`~theanompi_tpu.telemetry.core.Telemetry` — per-rank JSONL event
   sink (spans / counters / gauges, monotonic timestamps, rank+host tags,
   bounded rotation) with a metrics registry flushed at ``print_freq``;
+  it subscribes to the ring and writes each closed span;
 - :mod:`~theanompi_tpu.telemetry.chrome_trace` — export to the Chrome
   trace-event format so host-side spans render in Perfetto alongside the
   ``profile_dir`` device traces;
@@ -30,13 +37,15 @@ which emitted structured events.  This package is the common substrate:
 - :mod:`~theanompi_tpu.telemetry.cli` / ``.prof`` — the ``tmhealth`` and
   ``tmprof`` CLIs (``python -m theanompi_tpu.telemetry``).
 
-Everything is off by default: the trainer holds ``telemetry=None`` unless
-a sink was configured (``telemetry_dir`` rule config / ``--telemetry-dir``
-launcher flag), and every integration point guards on that, so a disabled
-run makes zero telemetry calls on the hot path.
+The sink is off by default: the trainer holds ``telemetry=None`` unless a
+directory was configured (``telemetry_dir`` rule config /
+``--telemetry-dir`` launcher flag), and a disabled run constructs no
+``Telemetry`` and writes nothing: no sink, no file, no thread, no
+profiler.  The ring itself always records, as the ``Recorder``'s
+histories and the scheduler's latency lists always have.
 """
 
-from theanompi_tpu.telemetry.core import Span, Telemetry
+from theanompi_tpu.telemetry.core import Telemetry
 from theanompi_tpu.telemetry.flight_recorder import (
     FlightRecorder,
     read_blackbox,
@@ -73,6 +82,7 @@ from theanompi_tpu.telemetry.sink import (
     sink_files,
     tail_events,
 )
+from theanompi_tpu.telemetry.spans import Span
 
 __all__ = [
     "EventSink",

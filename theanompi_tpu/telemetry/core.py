@@ -1,20 +1,25 @@
-"""Telemetry front-end: spans, instants, counters, gauges, metric flushes.
+"""Telemetry front-end: the sink of the span ring, plus instants, counters,
+gauges and metric flushes.
 
-Design rules (ISSUE 1):
+Design rules (ISSUE 1, restated by ISSUE 25):
 
-- **Off means off.**  Nothing in this module runs on the hot path unless a
-  ``Telemetry`` was explicitly constructed and handed to the trainer; the
-  integration points all guard with ``if telemetry is not None`` so a
-  disabled run makes zero telemetry calls (asserted by the tests).
+- **Off means no sink.**  The hot loops record their spans in the
+  process's in-memory ring (:mod:`theanompi_tpu.telemetry.spans`) whether
+  or not a ``Telemetry`` exists, as the ``Recorder`` and the scheduler's
+  latency lists always have.  A run that configured no directory
+  constructs no ``Telemetry`` and writes nothing: no sink, no file, no
+  thread (asserted by the tests).  A ``Telemetry`` that is constructed
+  subscribes to the ring and writes each closed span as one ``span`` event.
 - **Honest under async dispatch.**  A span around jax work measures
   *dispatch* unless something fences.  Spans accept the same optional
   ``fence`` the Recorder uses: ``end(fence=x)`` blocks on the array before
-  stamping the close time.  The Recorder integration inherits its existing
-  fence discipline unchanged — the recorder blocks first, then reports the
-  segment here, so recorder spans and recorder histories are the same
-  numbers by construction.
+  stamping the close time.  The Recorder's segments ARE ring spans, so
+  recorder spans and recorder histories are the same numbers by
+  construction.
 - **Monotonic time.**  All timestamps are ``time.perf_counter()``; the one
-  wall-clock anchor is an ISO string in the session ``meta`` event.
+  wall-clock anchor is an ISO string in the session ``meta`` event.  While
+  a ``jax.profiler`` trace runs, every ring span is also a
+  ``TraceAnnotation`` on the profiler's clock.
 """
 
 from __future__ import annotations
@@ -23,55 +28,16 @@ import os
 import socket
 import threading
 import time
+import weakref
 from datetime import datetime, timezone
 
 # analysis.interleave is stdlib-only and sits at the bottom of the
 # import DAG — the one non-telemetry import the leaf wall permits
 from theanompi_tpu.analysis.interleave import sp
+from theanompi_tpu.telemetry import spans
 from theanompi_tpu.telemetry.metrics import MetricsRegistry
 from theanompi_tpu.telemetry.sink import EventSink
-
-
-class Span:
-    """Context manager stamping one complete span event on exit.
-
-    Emitted at close (Chrome ``ph: "X"`` style: start + duration), so
-    nesting in Perfetto comes from containment on the thread track — no
-    begin/end pairing to corrupt if a run dies mid-span.
-    """
-
-    __slots__ = ("_tel", "name", "tags", "t0", "_closed")
-
-    def __init__(self, tel: "Telemetry", name: str, tags: dict):
-        self._tel = tel
-        self.name = name
-        self.tags = tags
-        self.t0 = 0.0
-        self._closed = False
-
-    def __enter__(self) -> "Span":
-        self.t0 = time.perf_counter()
-        return self
-
-    def end(self, fence=None) -> float:
-        """Close + emit once; -> duration.  Idempotent, so a manual
-        fence-aware ``end(fence=x)`` inside a ``with`` block does not
-        double-emit when ``__exit__`` runs."""
-        if self._closed:
-            return 0.0
-        self._closed = True
-        if fence is not None:
-            import jax
-
-            jax.block_until_ready(fence)
-        dur = time.perf_counter() - self.t0
-        self._tel.emit_span(self.name, self.t0, dur, **self.tags)
-        return dur
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        if exc_type is not None and not self._closed:
-            self.tags = {**self.tags, "error": exc_type.__name__}
-        self.end()
+from theanompi_tpu.telemetry.spans import Span
 
 
 class Telemetry:
@@ -132,6 +98,10 @@ class Telemetry:
         self.emit("meta", "session",
                   wall_time=datetime.now(timezone.utc).isoformat(),
                   host=self.host, pid=os.getpid())
+        # weakly: a Telemetry dropped without close() must not keep
+        # writing (or living) because the ring remembers it
+        self._on_record = _weak_subscriber(self)
+        spans.subscribe(self._on_record)
 
     # -- raw emission --------------------------------------------------------
     def emit(self, kind: str, name: str, ts: float | None = None,
@@ -147,13 +117,26 @@ class Telemetry:
         if self.prof is not None:
             self.prof.observe(event)
 
-    def emit_span(self, name: str, t0: float, dur: float, **tags) -> None:
-        self.emit("span", name, ts=t0, dur=dur,
-                  tid=threading.get_ident(), **tags)
+    def _write_record(self, rec: Span) -> None:
+        """The ring's subscriber: one event per closed span or instant, on
+        the thread that closed it."""
+        if rec.instant:
+            self.emit("instant", rec.name, ts=rec.t0, id=rec.id,
+                      parent=rec.parent, **rec.tags)
+        else:
+            self.emit("span", rec.name, ts=rec.t0, dur=rec.t1 - rec.t0,
+                      tid=threading.get_ident(), id=rec.id,
+                      parent=rec.parent, **rec.tags)
 
     # -- user surface --------------------------------------------------------
+    # thin fronts of the ring for callers outside the hot loops
+    # (checkpoint, validate, resilience): the span reaches this sink, and
+    # any other, through the subscription
+    def emit_span(self, name: str, t0: float, dur: float, **tags) -> None:
+        spans.record(name, t0, t0 + dur, **tags)
+
     def span(self, name: str, **tags) -> Span:
-        return Span(self, name, tags)
+        return spans.span(name, **tags)
 
     def instant(self, name: str, **fields) -> None:
         self.emit("instant", name, **fields)
@@ -253,6 +236,7 @@ class Telemetry:
 
     def close(self) -> None:
         sp("health.close")
+        spans.unsubscribe(self._on_record)
         if self._health_thread is not None:
             self._health_stop.set()
             self._health_thread.join(timeout=5.0)
@@ -276,3 +260,16 @@ class Telemetry:
             except OSError:
                 pass  # lint: swallow-ok — advisory file at shutdown
         self.sink.close()
+
+
+def _weak_subscriber(tel: Telemetry):
+    ref = weakref.ref(tel)
+
+    def on_record(rec: Span) -> None:
+        live = ref()
+        if live is None:
+            spans.unsubscribe(on_record)
+        else:
+            live._write_record(rec)
+
+    return on_record

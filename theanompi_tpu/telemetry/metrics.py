@@ -56,13 +56,34 @@ def hlo_collective_counts(hlo_text: str) -> dict[str, int]:
 # imports them from here — one source of truth, so dashboards and the
 # Chrome-trace test can't drift from what the code emits).  Span semantics:
 # ``serve.prefill`` wraps one sequence's full-prompt forward (tags:
-# ``request``, ``prompt``, ``slot``); ``serve.decode`` wraps one fixed-batch
+# ``request``, ``prompt``, ...); ``serve.decode`` wraps one fixed-batch
 # decode step (tags: ``step``, ``batch``, ``requests`` — the per-request ids
 # threaded through the trace).  Both close over materialized host results,
 # so they measure execution, not dispatch; in the single-threaded serve loop
 # they are disjoint by construction (locked by test).
 
 SERVE_SPANS = ("serve.prefill", "serve.decode")
+#: ISSUE 25 — the spans the serving loops open in the span ring
+#: (``telemetry/spans.py``), beside the two above which the ENGINE opens
+#: now (``serve.prefill`` tags: ``request``, ``prompt``, ``bucket`` — the
+#: padded length that picks the program, ``prefix_len``; ``serve.decode``
+#: tags: ``step`` — the engine's decode ordinal, ``batch``, ``requests``).
+#: ``serve.step`` is one ``Scheduler.step`` (tags ``step``, ``batch``);
+#: ``serve.admit`` the admission pass inside it (tag ``queued``; the
+#: per-request ``serve.admit`` INSTANT keeps its name and kind).
+SERVE_STEP_SPANS = ("serve.step", "serve.admit")
+#: the decode call from inside, in order: the host-to-device puts, the
+#: jitted call's dispatch, the wait for the device step (the next tokens
+#: reach the host), the logits' copy to the host (tag ``bytes``)
+SERVE_DECODE_SPANS = ("serve.decode.place", "serve.decode.dispatch",
+                      "serve.decode.wait", "serve.decode.fetch")
+#: one ``train_iter`` (tags ``step``, ``epoch``; ``loss`` at fenced steps)
+TRAIN_SPANS = ("train.step",)
+#: the Recorder's segments: a ring span each
+RECORDER_SPANS = {"wait": "recorder.wait", "calc": "recorder.calc",
+                  "comm": "recorder.comm"}
+#: the prefetcher's dequeue (tag ``qsize``: what the queue still held)
+PREFETCH_SPANS = ("prefetch.dequeue",)
 SERVE_INSTANTS = ("serve.admit", "serve.preempt", "serve.finish")
 #: histograms: per-token decode latency and time-to-first-token, both ms
 SERVE_HISTOGRAMS = ("serve.token_ms", "serve.ttft_ms")

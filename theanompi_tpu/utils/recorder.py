@@ -19,6 +19,9 @@ from collections import defaultdict
 
 import numpy as np
 
+from theanompi_tpu.telemetry import spans
+from theanompi_tpu.telemetry.metrics import RECORDER_SPANS
+
 SEGMENTS = ("wait", "calc", "comm")
 
 
@@ -28,11 +31,12 @@ class Recorder:
         self.print_freq = print_freq
         self.save_dir = save_dir
         self.verbose = verbose and rank == 0
-        # optional telemetry sink: each closed segment is also emitted as a
-        # structured span (same start/duration the histories record, so the
-        # Perfetto view and the .npy splits are one set of numbers)
+        # optional telemetry sink (val_metrics instants).  The segments
+        # themselves are spans of the process's ring whether or not a sink
+        # exists: the same start/duration the histories record, so the
+        # Perfetto view and the .npy splits are one set of numbers
         self.telemetry = telemetry
-        self._t0: dict[str, float] = {}
+        self._open: dict[str, spans.Span] = {}
         self._iter_times: dict[str, float] = defaultdict(float)
         self.time_history: dict[str, list] = defaultdict(list)
         self.train_history: dict[str, list] = defaultdict(list)
@@ -42,34 +46,33 @@ class Recorder:
 
     # -- wall-clock segments ------------------------------------------------
     def start(self, what: str = "calc") -> None:
-        self._t0[what] = time.perf_counter()
+        stale = self._open.pop(what, None)
+        if stale is not None:  # restarted: the later start counts
+            stale.cancel()
+        self._open[what] = spans.begin(
+            RECORDER_SPANS.get(what) or f"recorder.{what}")
 
     def end(self, what: str = "calc", fence=None) -> None:
         """Close segment ``what``; pass a jax array as ``fence`` to block on
         device completion so the split reflects device time, not dispatch."""
-        if fence is not None:
-            import jax
-
-            # no exception guard: an async device error surfacing at the
-            # fence (the one deliberate sync point) must propagate here, not
-            # at some arbitrary later sync with a misleading stack
-            jax.block_until_ready(fence)
-        t0 = self._t0.pop(what, None)
-        if t0 is None:
+        span = self._open.pop(what, None)
+        if span is None:
             raise RuntimeError(
                 f"Recorder.end({what!r}): segment was never started "
-                f"(open segments: {sorted(self._t0) or 'none'}); "
+                f"(open segments: {sorted(self._open) or 'none'}); "
                 f"use cancel() to abandon a segment"
             )
-        dur = time.perf_counter() - t0
-        self._iter_times[what] += dur
-        if self.telemetry is not None:
-            self.telemetry.emit_span(f"recorder.{what}", t0, dur)
+        # an async device error surfacing at the fence (the one deliberate
+        # sync point) propagates from here, not from some arbitrary later
+        # sync with a misleading stack; the span closes tagged ``error``
+        self._iter_times[what] += span.end(fence=fence)
 
     def cancel(self, what: str) -> None:
         """Abandon an open segment without recording it (e.g. the wait
         opened before a ``next()`` that raised StopIteration)."""
-        self._t0.pop(what, None)
+        span = self._open.pop(what, None)
+        if span is not None:
+            span.cancel()
 
     def end_iteration(self) -> None:
         for seg in SEGMENTS:
