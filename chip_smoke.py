@@ -19,7 +19,9 @@ supports, with depth and run length cut and weights random from a seed:
                     the decode step must go to the fused matmul.
 5. ``decode_parity`` one decode-attention step at the served head geometry
                     through the kernel and through
-                    ``PagedKVCache.attend_decode``'s pure-JAX path.
+                    ``PagedKVCache.attend_decode``'s pure-JAX path, at
+                    layer 1 of a two-layer pool (the kernel's layer index
+                    is checked too).
 6. ``multichip``    only where jax reports >= 4 chips: tmlauncher
                     ``--devices 4`` on both training models and
                     ``__graft_entry__.py dryrun 4`` on the real devices;

@@ -150,6 +150,10 @@ def test_serve_decode_kernel_audit():
     assert r["ok"], r["violations"]
     assert r["custom_calls_on"] >= r["n_layers"]   # paged attn per layer
     assert r["custom_calls_off"] == 0              # negative proof
+    # ISSUE 26: every layer's call reads the whole 5-D pools, no slice
+    assert len(r["paged_calls"]) == r["n_layers"] >= 2
+    assert all(c == {"shapes": [r["pool_shape"]] * 2, "sliced": False}
+               for c in r["paged_calls"])
     assert r["custom_calls_int8"] >= 1             # fused int8 matmul
     assert r["alias_count"] >= 2                   # pools stay donated
     assert r["collectives"] == {}
@@ -195,6 +199,44 @@ def test_auditor_detects_missing_donation():
         .compile().as_text()
     assert hlo_audit.donation_alias_count(undonated) == 0
     assert hlo_audit.donation_alias_count(donated) >= 1
+
+
+@pytest.mark.parametrize("cut,sliced", [
+    (lambda pool: pool, False),
+    (lambda pool: pool[1:], True),                       # static slice
+    (lambda pool: jax.lax.dynamic_slice_in_dim(pool, 1, 1), True),
+])
+def test_auditor_detects_a_sliced_pool_before_the_kernel(cut, sliced,
+                                                         monkeypatch):
+    """ISSUE 26's guard has teeth: a slice of the K/V pool in front of
+    the paged-decode call (what ``self.k[layer]`` lowered to, a copy of a
+    layer per call on the chip) reads as ``sliced`` with a shape that is
+    not the pool's, and the kernel audit lists it as a violation."""
+    from theanompi_tpu.ops.pallas_paged_attention import paged_attend_decode
+
+    pool = jnp.zeros((3, 4, 8, 8, 128), jnp.float32)
+    tables = jnp.zeros((2, 2), jnp.int32)
+    q = jnp.zeros((2, 8, 128), jnp.float32)
+
+    def step(k, v, t, q, p):
+        return paged_attend_decode(cut(k), cut(v), 0, t, 8, q, p,
+                                   interpret=False)
+
+    text = jax.jit(step).trace(pool, pool, tables, q,
+                               jnp.zeros((2,), jnp.int32)) \
+        .lower(lowering_platforms=("tpu",)).as_text()
+    (call,) = hlo_audit.paged_call_operands(text)
+    assert call["sliced"] is sliced
+    assert (call["shapes"] == [list(pool.shape)] * 2) is (not sliced)
+
+    facts = dict(hlo_audit._serve_decode_kernel_artifact(),
+                 pool_shape=list(pool.shape))
+    facts["paged_calls"] = [call] * facts["n_layers"]
+    monkeypatch.setattr(hlo_audit, "_serve_decode_kernel_artifact",
+                        lambda: facts)
+    r = hlo_audit.audit_serve_decode_kernel()
+    assert r["ok"] is (not sliced)
+    assert any("not the whole" in v for v in r["violations"]) is sliced
 
 
 def test_budget_violation_surfaces_in_report(monkeypatch):

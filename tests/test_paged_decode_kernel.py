@@ -48,9 +48,9 @@ _NEG_INF = -1e30
 #: paths differ only in how their reductions associate
 _F32_TOL = dict(rtol=0, atol=4 * float(np.finfo(np.float32).eps) * 4)
 
-def _pools(key, nblocks, bs, h, d, dtype=jnp.float32):
+def _pools(key, nblocks, bs, h, d, dtype=jnp.float32, layers=1):
     kk, kv = jax.random.split(key)
-    shape = (1, nblocks, bs, h, d)
+    shape = (layers, nblocks, bs, h, d)
     return (jax.random.normal(kk, shape, jnp.float32).astype(dtype),
             jax.random.normal(kv, shape, jnp.float32).astype(dtype))
 
@@ -103,6 +103,33 @@ def test_kernel_matches_fallback_bf16():
     np.testing.assert_allclose(
         outs["kernel_interpret"], outs["fallback"], rtol=0,
         atol=float(jnp.finfo(jnp.bfloat16).eps) * 4)
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_kernel_reads_its_own_layer_of_the_pool(layer):
+    """ISSUE 26: the kernel takes the whole ``[L, ...]`` pools and finds
+    its layer in the K/V index_map.  Every layer of this pool holds other
+    values, so a wrong (or dropped) layer index reads O(1) away from the
+    fallback at that layer — which ``L = 1`` pools cannot show."""
+    n_layers, bs, nblocks, h, d = 5, 4, 6, 2, 16
+    kp, vp = _pools(jax.random.PRNGKey(26), nblocks, bs, h, d,
+                    layers=n_layers)
+    tbl = jnp.asarray([[1, 2, 0, 0], [3, 4, 5, 0]], jnp.int32)
+    pos = jnp.asarray([5, 11], jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(27), (2, h, d), jnp.float32)
+    outs = {}
+    for impl in ("kernel_interpret", "fallback"):
+        cache = PagedKVCache(kp, vp, tbl, bs, decode_impl=impl)
+        outs[impl] = np.asarray(cache.attend_decode(layer, q, pos))
+    np.testing.assert_allclose(outs["kernel_interpret"], outs["fallback"],
+                               **_F32_TOL)
+    # the lock has teeth: the same call one layer off is nowhere near
+    other = np.asarray(PagedKVCache(kp, vp, tbl, bs, "kernel_interpret")
+                       .attend_decode((layer + 1) % n_layers, q, pos))
+    assert np.abs(other - outs["fallback"]).max() > 1e-2
+    with pytest.raises(ValueError, match="outside the pool"):
+        paged_attend_decode(kp, vp, n_layers, tbl, bs, q, pos,
+                            interpret=True)
 
 
 def test_decode_parity_at_a_tileable_geometry():
@@ -165,7 +192,7 @@ def test_compiled_shape_gates_and_raise():
     bs, h, d = 4, 2, 16
     kp, vp = _pools(jax.random.PRNGKey(0), 3, bs, h, d)
     with pytest.raises(ValueError, match="unsupported shape"):
-        paged_attend_decode(kp[0], vp[0],
+        paged_attend_decode(kp, vp, 0,
                             jnp.asarray([[1, 2]], jnp.int32), bs,
                             jnp.zeros((1, h, d), jnp.float32),
                             jnp.asarray([3], jnp.int32), interpret=False)

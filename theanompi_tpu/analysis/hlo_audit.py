@@ -499,6 +499,48 @@ def audit_serve_prefill() -> dict:
 SERVE_KERNEL_CFG = {**SERVE_MODEL_CFG, "dim": 1024, "heads": 8}
 
 
+_SHLO_DEF = re.compile(r"^\s*(%[\w#]+)(?::\d+)? = \"?stablehlo\.(\w+)\"?[ (]"
+                       r"(%[\w#]+)?")
+_SHLO_PAGED_CALL = re.compile(
+    r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\).*"
+    r"kernel_name = \"_decode_kernel\".* : \(([^)]*)\) -> ")
+
+
+def paged_call_operands(stablehlo_text: str) -> list[dict]:
+    """The K and V operands of every paged-decode ``tpu_custom_call`` in a
+    TPU lowering's StableHLO text (ISSUE 26); -> one record per call:
+    ``{"shapes": [k, v], "sliced": bool}`` with each shape a list of ints
+    and ``sliced`` true when a ``slice`` / ``dynamic_slice`` (seen through
+    reshapes) produces either operand.
+
+    A custom call's operand has to be a whole buffer, so a sliced pool in
+    front of the kernel is a copy of that slice on the chip, per layer and
+    step (:mod:`theanompi_tpu.ops.pallas_paged_attention`)."""
+    calls, defs = [], {}
+    for line in stablehlo_text.splitlines():
+        if "func.func" in line:
+            defs = {}  # SSA names are per function
+        m = _SHLO_DEF.match(line)
+        if m:
+            defs[m.group(1)] = (m.group(2), m.group(3))
+        c = _SHLO_PAGED_CALL.search(line)
+        if not c:
+            continue
+        names = [n.strip() for n in c.group(1).split(",")][-2:]
+        types = [t.strip() for t in c.group(2).split(", ")][-2:]
+        sliced = False
+        for name in names:
+            op, src = defs.get(name, (None, None))
+            while op == "reshape":
+                op, src = defs.get(src, (None, None))
+            sliced |= op in ("slice", "dynamic_slice")
+        calls.append({
+            "shapes": [[int(n) for n in t[len("tensor<"):].split("x")[:-1]]
+                       for t in types],
+            "sliced": sliced})
+    return calls
+
+
 @functools.lru_cache(maxsize=None)
 def _serve_decode_kernel_artifact() -> dict:
     """Gather the decode-kernel dispatch facts (ISSUE 18).
@@ -511,7 +553,9 @@ def _serve_decode_kernel_artifact() -> dict:
       cache field, so pinning ``"kernel"`` lowers the COMPILED pallas
       call even on a CPU host (``lowering_platforms=("tpu",)``) — the
       positive proof is ``tpu_custom_call`` per layer, the negative proof
-      is zero custom calls in the ``"off"`` lowering.
+      is zero custom calls in the ``"off"`` lowering.  The ``"on"``
+      lowering also gives the paged calls' K/V operands
+      (:func:`paged_call_operands`): each must be the whole pool.
     - a **direct int8 lowering** of :func:`~theanompi_tpu.ops.quant.
       int8_matmul` over an actual quantized engine weight leaf (the
       engine-level lowering above keeps int8 in interpret mode off-TPU,
@@ -570,6 +614,9 @@ def _serve_decode_kernel_artifact() -> dict:
         text = jax.jit(eng._decode_impl, donate_argnums=(1, 2)) \
             .trace(*args).lower(lowering_platforms=("tpu",)).as_text()
         facts[f"custom_calls_{variant}"] = text.count("tpu_custom_call")
+        if variant == "on":
+            facts["pool_shape"] = list(eng._k.shape)
+            facts["paged_calls"] = paged_call_operands(text)
 
     # -- direct int8 kernel lowering over a real engine weight -----------
     x = jnp.zeros((8, int(int8_leaf.shape[0])), jnp.float32)
@@ -633,9 +680,11 @@ INT8_REL_TOL = 1e-5
 def audit_serve_decode_kernel() -> dict:
     """Audit the serving decode fast path (ISSUE 18): the pallas paged
     decode kernel and fused int8 matmul actually dispatch as TPU custom
-    calls (with the kernel-off lowering as the negative proof), the
-    kernel-on step keeps the donation / zero-collective contract, and
-    the kernel is bit-identical to the fallback on CPU."""
+    calls (with the kernel-off lowering as the negative proof), every
+    paged call reads the whole K/V pool (ISSUE 26: no per-layer slice, so
+    no per-layer copy), the kernel-on step keeps the donation /
+    zero-collective contract, and the kernel is bit-identical to the
+    fallback on CPU."""
     facts = _serve_decode_kernel_artifact()
     violations: list[str] = []
     if facts["custom_calls_on"] < facts["n_layers"]:
@@ -643,6 +692,15 @@ def audit_serve_decode_kernel() -> dict:
             f"kernel-on TPU lowering has {facts['custom_calls_on']} "
             f"tpu_custom_call(s) < n_layers={facts['n_layers']} — the "
             f"paged decode kernel is not dispatching per layer")
+    partial = [c for c in facts["paged_calls"]
+               if c["sliced"] or c["shapes"] != [facts["pool_shape"]] * 2]
+    if len(facts["paged_calls"]) < facts["n_layers"] or partial:
+        violations.append(
+            f"{len(partial)} of {len(facts['paged_calls'])} paged-decode "
+            f"call(s) (n_layers={facts['n_layers']}) take K/V operands "
+            f"that are not the whole {facts['pool_shape']} pool "
+            f"({partial[:1]}) — a sliced pool is copied on the chip "
+            f"before every call")
     if facts["custom_calls_off"] != 0:
         violations.append(
             f"kernel-off TPU lowering has {facts['custom_calls_off']} "

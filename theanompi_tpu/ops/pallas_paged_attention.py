@@ -1,10 +1,15 @@
 """Fused paged-attention decode kernel (the serving fast path, ISSUE 18).
 
-One Pallas kernel per (layer, decode step): grid ``(batch, blocks)`` with
-the **block table driving the KV index_map** — each grid step DMAs exactly
-the pool block the table names, so the gather that
-``serving/kv_cache.py`` does with a materialized ``[B, T_max, H, Dh]``
-``jnp.take`` never touches HBM here.  Online softmax carries
+One Pallas kernel per (layer, decode step) over the WHOLE pool: grid
+``(batch, blocks)`` with the **block table driving the KV index_map** —
+each grid step DMAs exactly the pool block the table names, out of the
+``[L, num_blocks, block_size, H, Dh]`` pool at the (static) layer, so the
+gather that ``serving/kv_cache.py`` does with a materialized
+``[B, T_max, H, Dh]`` ``jnp.take`` never touches HBM here.  Nothing may
+slice the pool before the call: a custom call's operand is a whole
+buffer, so XLA copies a ``k_pool[layer]`` operand ahead of every call
+(ISSUE 26: 134 MB each for K and V at the served size, a third of the
+step's device time over 24 layers).  Online softmax carries
 (running max, normalizer, accumulator) in VMEM scratch with the block
 index innermost, the same Mosaic accumulation layout as the flash train
 kernels (:mod:`theanompi_tpu.ops.pallas_attention`).
@@ -106,21 +111,28 @@ def paged_decode_supported(heads: int, head_dim: int,
     return heads % sublane == 0 and head_dim % 128 == 0
 
 
-def paged_attend_decode(k_pool, v_pool, tables, block_size: int, q,
-                        positions, interpret: bool | None = None):
-    """Paged decode attention over one layer's pools.
+def paged_attend_decode(k_pool, v_pool, layer: int, tables,
+                        block_size: int, q, positions,
+                        interpret: bool | None = None):
+    """Paged decode attention over layer ``layer`` of the whole pools.
 
-    ``k_pool``/``v_pool`` ``[num_blocks, block_size, H, Dh]``, ``tables``
-    ``[B, max_blocks_per_seq]`` int32, ``q`` ``[B, H, Dh]``, ``positions``
-    ``[B]`` (each query's own 0-based position, already written) ->
-    context ``[B, H, Dh]``.  ``interpret=None`` auto-selects: compiled on
-    TPU (gate with :func:`paged_decode_supported`), interpreter elsewhere.
+    ``k_pool``/``v_pool`` ``[L, num_blocks, block_size, H, Dh]`` (the
+    cache's own arrays, never a slice of them), ``layer`` a Python int,
+    ``tables`` ``[B, max_blocks_per_seq]`` int32, ``q`` ``[B, H, Dh]``,
+    ``positions`` ``[B]`` (each query's own 0-based position, already
+    written) -> context ``[B, H, Dh]``.  ``interpret=None`` auto-selects:
+    compiled on TPU (gate with :func:`paged_decode_supported`),
+    interpreter elsewhere.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
     nb = tables.shape[1]
     bs = block_size
+    layer = int(layer)
+    if not 0 <= layer < k_pool.shape[0]:
+        raise ValueError(f"paged_attend_decode: layer {layer} outside the "
+                         f"pool's {k_pool.shape[0]} layers")
     if not interpret and not paged_decode_supported(h, d, q.dtype):
         raise ValueError(
             f"paged_attend_decode: unsupported shape H={h} Dh={d} "
@@ -130,16 +142,17 @@ def paged_attend_decode(k_pool, v_pool, tables, block_size: int, q,
     def kv_map(i, j, t, p):
         # DMA elision: past-the-end (null-block) steps re-reference the
         # last needed block, so their copies never issue; compute stays
-        # gated on the REAL j, so numerics are untouched
-        return (t[i, jnp.minimum(j, p[i] // bs)], 0, 0, 0)
+        # gated on the REAL j, so numerics are untouched.  The layer is
+        # a constant of this call's index_map
+        return (layer, t[i, jnp.minimum(j, p[i] // bs)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, nb),
         in_specs=[
             pl.BlockSpec((None, h, d), lambda i, j, t, p: (i, 0, 0)),
-            pl.BlockSpec((None, bs, h, d), kv_map),
-            pl.BlockSpec((None, bs, h, d), kv_map),
+            pl.BlockSpec((None, None, bs, h, d), kv_map),
+            pl.BlockSpec((None, None, bs, h, d), kv_map),
         ],
         out_specs=pl.BlockSpec((None, h, d), lambda i, j, t, p: (i, 0, 0)),
         scratch_shapes=[

@@ -28,8 +28,10 @@ training.  The O(P) per-token decode has two implementations selected by
 the static ``decode_impl`` field (ISSUE 18): the pure-JAX blockwise gather
 below (``"fallback"``), and the fused pallas kernel of
 ``ops/pallas_paged_attention.py`` (``"kernel"``) whose block table drives
-the DMA index_map directly.  Both compute the SAME blockwise
-online-softmax recurrence in the same op order, so they are bit-identical
+the DMA index_map directly: ``paged_attend_decode(k, v, layer, tables,
+block_size, q, positions)``, one kernel per (layer, step) over the WHOLE
+``[L, ...]`` pools with the layer in the index_map.  Both compute the
+SAME blockwise online-softmax recurrence in the same op order, so they are bit-identical
 on CPU (`interpret=True`) — the parity lock the HLO audit and
 tests/test_paged_decode_kernel.py enforce.
 """
@@ -214,8 +216,10 @@ class PagedKVCache:
         of the normalizer differs), which test_paged_decode_kernel.py pins
         against the verbatim old formula."""
         if self.decode_impl != "fallback":
+            # the whole pools, never ``self.k[layer]``: XLA copies a
+            # sliced operand of a custom call (see the kernel's module)
             return paged_attend_decode(
-                self.k[layer], self.v[layer], self.block_tables,
+                self.k, self.v, layer, self.block_tables,
                 self.block_size, q, jnp.asarray(positions, jnp.int32),
                 interpret=(self.decode_impl == "kernel_interpret"))
         # [B, nb, bs, H, Dh]: gather each slot's blocks, then run the
@@ -269,7 +273,9 @@ def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,
 
     The slots sit at ragged positions (first token, a block boundary, the
     last position of the context) with scattered block tables, one slot
-    inactive.  The two paths run one online-softmax recurrence and differ
+    inactive.  The pool has two layers of different values and both paths
+    attend at layer 1, so the kernel's layer index is under the check too
+    (ISSUE 26).  The two paths run one online-softmax recurrence and differ
     by fp32 rounding only, so the bound is four rounding steps of the
     OUTPUT dtype at the output's magnitude — the check ``chip_smoke.py``
     runs on the chip at the served head geometry, and tier-1 runs under
@@ -280,7 +286,7 @@ def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,
     nb = blocks_for(max_context, block_size)
     num_blocks = max_batch * nb + 1
     kk, kv, kq = jax.random.split(jax.random.PRNGKey(seed), 3)
-    shape = (1, num_blocks, block_size, heads, head_dim)
+    shape = (2, num_blocks, block_size, heads, head_dim)
     k = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
     v = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
     q = jax.random.normal(kq, (max_batch, heads, head_dim),
@@ -299,7 +305,7 @@ def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,
 
     def attend(impl):
         fn = jax.jit(lambda k, v, t, q, p: PagedKVCache(
-            k, v, t, block_size, decode_impl=impl).attend_decode(0, q, p))
+            k, v, t, block_size, decode_impl=impl).attend_decode(1, q, p))
         return np.array(fn(k, v, jnp.asarray(tables), q,
                            jnp.asarray(positions)).astype(jnp.float32))
 
