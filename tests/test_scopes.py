@@ -14,6 +14,8 @@ TINY = {"batch_size": 2, "n_train": 16, "n_val": 8, "seq_len": 32,
         "n_epochs": 1, "precision": "bf16", "grad_clip": 1.0}
 
 MODEL = ("embed", "block", "attn", "mlp")
+HYBRID = ("embed", "mamba", "moe.route", "moe.experts", "moe.shared", "attn",
+          "head")
 #: program -> the scopes it must show
 EXPECTED = {
     "train": (*MODEL, "loss", "clip", "exchange", "optimizer"),
@@ -21,11 +23,14 @@ EXPECTED = {
                          "optimizer"),
     "decode": (*MODEL, "head", "recast", "sample", "paged_decode"),
     "prefill": (*MODEL, "head", "recast", "sample"),
+    # HybridLM (ISSUE 27): one mixer a layer, each under its own scope
+    "hybrid_decode": (*HYBRID, "sample"),
+    "hybrid_prefill": (*HYBRID, "sample"),
 }
 SCOPES = sorted({s for names in EXPECTED.values() for s in names}
                 | {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
-_WORD = re.compile(r"(?<![A-Za-z0-9_])(" + "|".join(SCOPES)
-                   + r")(?![A-Za-z0-9_])")
+_WORD = re.compile(r"(?<![A-Za-z0-9_])(" + "|".join(map(re.escape, SCOPES))
+                   + r")(?![A-Za-z0-9_.])")
 _OPS = ("stablehlo.dot_general", "stablehlo.custom_call",
         "stablehlo.all_reduce")
 _LOC = re.compile(r"loc\((#loc\d+)\)\s*$")
@@ -107,6 +112,30 @@ def lowered():
         jnp.zeros((16,), i32), jnp.asarray(5, i32),
         jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
         eng._base_key).as_text(debug_info=True)
+
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+
+    hybrid = HybridLM({"pattern": "ME*", "dim": 32, "vocab": 61, "seq_len": 32,
+                       "heads": 4, "kv_heads": 2, "head_dim": 8,
+                       "mamba_heads": 4, "mamba_head_dim": 16, "state_size": 8,
+                       "n_groups": 2, "chunk_size": 8, "n_experts": 8,
+                       "experts_held": (2, 6), "top_k": 2, "latent": 16,
+                       "expert_dim": 24, "shared_dim": 32})
+    eng = InferenceEngine(hybrid, hybrid.init_params(jax.random.PRNGKey(0))[0],
+                          block_size=8, max_batch=2)
+    out["hybrid_decode"] = eng._decode_fn.lower(
+        eng.params, eng._k, eng._v,
+        jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+        jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+        jnp.zeros((b,), i32), eng._base_key,
+        eng._state).as_text(debug_info=True)
+    out["hybrid_prefill"] = jax.jit(
+        eng._prefill_impl, donate_argnums=(1, 2, 9)).lower(
+        eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
+        jnp.zeros((16,), i32), jnp.asarray(5, i32),
+        jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
+        eng._base_key, eng._state,
+        jnp.asarray(1, i32)).as_text(debug_info=True)
     return out
 
 
@@ -142,3 +171,12 @@ def test_the_jitted_programs_keep_their_names(lowered):
     """The benchmark's traffic files find the programs by these names."""
     assert "module @jit_local_step" in lowered["train"]
     assert "module @jit__decode_impl" in lowered["decode"]
+    assert "module @jit__decode_impl" in lowered["hybrid_decode"]
+
+
+def test_the_documented_scopes_are_the_ones_the_programs_show(lowered):
+    from theanompi_tpu.telemetry.metrics import DEVICE_SCOPES
+
+    documented = {s for names in DEVICE_SCOPES.values() for s in names}
+    assert set(SCOPES) <= documented
+    assert set(DEVICE_SCOPES["HybridLM"]) == set(HYBRID)

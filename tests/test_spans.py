@@ -229,6 +229,34 @@ def test_a_sink_gets_the_serving_spans_with_id_and_parent(served_events, name,
         assert all(e["bytes"] == 2 * 61 * 4 for e in found)  # [B, V] fp32
 
 
+def test_a_model_that_counts_experts_tags_its_decode_spans(served_events):
+    """``HybridLM``'s two device counters ride on ``serve.decode``
+    (``SERVE_DECODE_MOE_TAGS``); a model without expert layers adds none."""
+    import jax
+
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.telemetry.metrics import SERVE_DECODE_MOE_TAGS
+
+    events, _ = served_events
+    assert not any(t in e for e in _spans_named(events, "serve.decode")
+                   for t in SERVE_DECODE_MOE_TAGS)
+    model = HybridLM({"pattern": "ME", "dim": 32, "vocab": 61, "seq_len": 32,
+                      "mamba_heads": 4, "mamba_head_dim": 16, "state_size": 8,
+                      "n_groups": 2, "chunk_size": 8, "n_experts": 4, "top_k": 2,
+                      "latent": 16, "expert_dim": 24, "shared_dim": 32})
+    engine = InferenceEngine(model, model.init_params(jax.random.PRNGKey(0))[0],
+                             block_size=4, max_batch=2)
+    sched = Scheduler(engine)
+    sched.submit(Request(rid=5, prompt=[1, 2, 3], max_new_tokens=3))
+    while not sched.idle:
+        sched.step()
+    ours = [r for r in spans.snapshot()
+            if r.name == "serve.decode" and r.tags["requests"] == [5]]
+    assert len(ours) == 2
+    for r in ours:  # every expert held, one E layer, one slot: top_k hits
+        assert r.tags["moe_local_hits"] == 2 and r.tags["moe_load_peak"] == 1
+
+
 def test_the_decode_parts_add_up_to_the_decode_span(served_events):
     events, _ = served_events
     parts: dict = {}

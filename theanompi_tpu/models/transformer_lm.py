@@ -338,6 +338,15 @@ class TransformerLM(SupervisedModel):
         return [(f"{i:02d}_{layer.name}", layer)
                 for i, layer in enumerate(self.net.layers)]
 
+    def cache_spec(self) -> dict:
+        """What a serving cache must hold for this model (the engine builds
+        its cache from this, for every model alike): paged K/V at every
+        layer, one K/V head per query head, no per-slot state."""
+        cfg = self.config
+        return {"kv": {"layers": cfg["n_layers"], "heads": cfg["heads"],
+                       "head_dim": cfg["dim"] // cfg["heads"]},
+                "state": {}, "state_layers": 0}
+
     @jax.named_scope("head")
     def _head_logits(self, cp, h):
         y = quant.matmul_any(h, cp["head"]["w"])

@@ -25,6 +25,7 @@ from theanompi_tpu.ops.layers import (
     LRN,
     LSTM,
     MaxPool,
+    RMSNorm,
     Sequential,
 )
 from theanompi_tpu.ops.losses import (
@@ -37,7 +38,7 @@ from theanompi_tpu.ops.opt import SGD, Adam, Optimizer, RMSProp
 __all__ = [
     "Activation", "AvgPool", "BatchNorm", "Conv2D", "ConvTranspose2D",
     "Dense", "Dropout", "Embedding", "Flatten", "GlobalAvgPool", "LayerNorm",
-    "LRN", "LSTM", "MaxPool", "Sequential", "initializers",
+    "LRN", "LSTM", "MaxPool", "RMSNorm", "Sequential", "initializers",
     "softmax_cross_entropy", "sigmoid_binary_cross_entropy", "top_k_error",
     "SGD", "Adam", "RMSProp", "Optimizer",
 ]

@@ -171,6 +171,59 @@ class MultiHeadAttention(L.Layer):
 
 
 @dataclasses.dataclass(frozen=True)
+class GroupedQueryAttention(L.Layer):
+    """Causal attention whose ``kv_heads`` K/V heads are each shared by
+    ``heads // kv_heads`` query heads (query head ``h`` reads K/V head
+    ``h // (heads // kv_heads)``); head size ``head_dim`` independent of
+    ``dim``, no bias, no positional term.  A cache holds the ``kv_heads``
+    only; :meth:`attend` repeats them to the query heads and takes
+    :meth:`MultiHeadAttention.attend`'s dispatch, so a TPU prefill rides the
+    flash kernel where its gate admits the shape."""
+
+    dim: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    impl: str = "auto"
+
+    def init(self, key, in_shape):
+        if in_shape[-1] != self.dim:
+            raise ValueError(f"GQA dim {self.dim} != input {in_shape[-1]}")
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads do not divide over "
+                             f"{self.kv_heads} K/V heads")
+        kq, kk, kv, ko = jax.random.split(key, 4)
+        w02 = init_lib.normal(0.02)
+        hq, hkv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        params = {"q": {"w": w02(kq, (self.dim, hq))},
+                  "k": {"w": w02(kk, (self.dim, hkv))},
+                  "v": {"w": w02(kv, (self.dim, hkv))},
+                  "o": {"w": w02(ko, (hq, self.dim))}}
+        return params, {}, tuple(in_shape)
+
+    def project_qkv(self, params, x):
+        """``[B, T, D]`` -> q ``[B, T, H, Dh]``, k, v ``[B, T, Hkv, Dh]``."""
+        b, t, _ = x.shape
+        w = jnp.concatenate([params[n]["w"] for n in "qkv"], axis=1)
+        qkv = x @ w.astype(x.dtype)
+        hq = self.heads * self.head_dim
+        hkv = self.kv_heads * self.head_dim
+        return (qkv[..., :hq].reshape(b, t, self.heads, self.head_dim),
+                qkv[..., hq:hq + hkv].reshape(b, t, self.kv_heads, self.head_dim),
+                qkv[..., hq + hkv:].reshape(b, t, self.kv_heads, self.head_dim))
+
+    def attend(self, q, k, v):
+        rep = self.heads // self.kv_heads
+        core = MultiHeadAttention(self.heads * self.head_dim, self.heads,
+                                  impl=self.impl)
+        return core.attend(q, jnp.repeat(k, rep, axis=2),
+                           jnp.repeat(v, rep, axis=2))
+
+    def project_out(self, params, out):
+        return out @ params["o"]["w"].astype(out.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
 class PositionEmbedding(L.Layer):
     """Learned absolute positions, offset-aware under sequence sharding."""
 

@@ -71,6 +71,8 @@ ACTIVATIONS: dict[str, Callable] = {
     "gelu": jax.nn.gelu,
     "elu": jax.nn.elu,
     "identity": lambda x: x,
+    # squared ReLU (the Nemotron-H family's ungated expert activation)
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
 }
 
 
@@ -378,6 +380,38 @@ class LayerNorm(Layer):
         y = (x - mean.astype(x.dtype)) * inv.astype(x.dtype)
         y = y * params["scale"].astype(x.dtype) + params["bias"].astype(x.dtype)
         return y, state
+
+
+@dataclasses.dataclass(frozen=True)
+class RMSNorm(Layer):
+    """Root-mean-square normalization over the trailing dim, learned scale,
+    no mean and no bias.  ``groups > 1`` normalizes each of ``groups`` equal
+    slices of the trailing dim by its own mean square (Mamba-2's grouped
+    norm); ``gated`` multiplies the input by ``silu(gate)`` first
+    (``apply(..., gate=z)``), the order the Mamba-2 mixer uses."""
+
+    eps: float = 1e-5
+    groups: int = 1
+    gated: bool = False
+
+    def init(self, key, in_shape):
+        del key
+        if in_shape[-1] % self.groups:
+            raise ValueError(f"RMSNorm: {in_shape[-1]} features do not "
+                             f"divide into {self.groups} groups")
+        return ({"scale": jnp.ones((in_shape[-1],), jnp.float32)}, {},
+                tuple(in_shape))
+
+    def apply(self, params, state, x, *, train=False, rng=None, gate=None):
+        # fp32 statistics, input-dtype result (LayerNorm's discipline)
+        xf = x.astype(jnp.float32)
+        if self.gated:
+            xf = xf * jax.nn.silu(gate.astype(jnp.float32))
+        g = xf.reshape(*xf.shape[:-1], self.groups, -1)
+        g = g * lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                          + self.eps)
+        y = g.reshape(xf.shape) * params["scale"].astype(jnp.float32)
+        return y.astype(x.dtype), state
 
 
 @dataclasses.dataclass(frozen=True)

@@ -205,3 +205,133 @@ class MoEFFN(L.Layer):
             yt = psum_fwd_identity_bwd(pad, self.axis_name)
         return (yt.reshape(b, t, d).astype(x.dtype),
                 {"aux": aux} if not state else {**state, "aux": aux})
+
+
+@dataclasses.dataclass(frozen=True)
+class DroplessMoE(L.Layer):
+    """Top-k sigmoid-routed latent experts with a shared expert, no capacity
+    and no dropped token (the ``E`` layer of the Nemotron-H family), over
+    tokens ``[N, D]``::
+
+        s = sigmoid(W_r u)                     float32, all ``n_experts``
+        selected = top_k(s + b_corr)           b_corr biases selection only
+        w_i = route_scale * s_i / sum_selected s_j
+        l = W_down u                           D -> latent
+        r = sum_i w_i W2_i relu(W1_i l)^2      latent -> expert_dim -> latent
+        out = W_up r + V2 relu(V1 u)^2         the shared expert sees every token
+
+    ``experts_held = (lo, hi)`` is this chip's share of an expert-parallel
+    deployment: the router stays ``n_experts`` wide and selects over all of
+    them, the stacked expert weights hold experts ``lo..hi-1`` only, and
+    ``r`` sums the selected experts that are held.  What the absent experts
+    would add is added by the chips that hold them; on one chip the layer
+    runs without that exchange.  The shared expert and the latent
+    projections are computed by every chip alike (``routed=False`` /
+    ``shared=False`` give the two parts apart, for tests that add shares up).
+
+    The expert products are grouped matrix products: the ``N * top_k``
+    assignments are sorted by expert and ``jax.lax.ragged_dot`` multiplies
+    each expert's rows by that expert's weights, so the work is that of the
+    rows there are and no ``[N, E, ...]`` mask is built.  Assignments to
+    absent experts sort last, past every group, and carry weight zero.
+    """
+
+    dim: int
+    n_experts: int
+    top_k: int
+    latent: int
+    expert_dim: int
+    shared_dim: int
+    route_scale: float = 1.0
+    experts_held: tuple[int, int] | None = None
+
+    @property
+    def held(self) -> tuple[int, int]:
+        lo, hi = self.experts_held or (0, self.n_experts)
+        if not 0 <= lo < hi <= self.n_experts:
+            raise ValueError(f"experts_held {(lo, hi)} outside 0..{self.n_experts}")
+        return int(lo), int(hi)
+
+    def init(self, key, in_shape):
+        if in_shape[-1] != self.dim:
+            raise ValueError(f"DroplessMoE dim {self.dim} != input {in_shape[-1]}")
+        lo, hi = self.held
+        ks = jax.random.split(key, 7)
+        w02 = init_lib.normal(0.02)
+        params = {
+            "router": {"w": w02(ks[0], (self.dim, self.n_experts)),
+                       "b_corr": jnp.zeros((self.n_experts,), jnp.float32)},
+            "down": {"w": w02(ks[1], (self.dim, self.latent))},
+            "w1": w02(ks[2], (hi - lo, self.latent, self.expert_dim)),
+            "w2": w02(ks[3], (hi - lo, self.expert_dim, self.latent)),
+            "up": {"w": w02(ks[4], (self.latent, self.dim))},
+            "shared": {"v1": w02(ks[5], (self.dim, self.shared_dim)),
+                       "v2": w02(ks[6], (self.shared_dim, self.dim))},
+        }
+        return params, {}, tuple(in_shape)
+
+    def route(self, params, u):
+        """-> (selected experts ``[N, top_k]`` int32, their weights
+        ``[N, top_k]`` float32)."""
+        with jax.named_scope("moe.route"):
+            s = jax.nn.sigmoid(u.astype(jnp.float32)
+                               @ params["router"]["w"].astype(jnp.float32))
+            _, idx = lax.top_k(
+                s + params["router"]["b_corr"].astype(jnp.float32), self.top_k)
+            w = jnp.take_along_axis(s, idx, axis=-1)
+            w = self.route_scale * w / jnp.sum(w, axis=-1, keepdims=True)
+            return idx.astype(jnp.int32), w
+
+    def apply_tokens(self, params, u, *, routed: bool = True,
+                     shared: bool = True, active=None):
+        """``u`` ``[N, D]`` -> (out ``[N, D]``, stats).  ``stats``:
+        ``local_hits`` (selected experts that are held, summed over the
+        tokens ``active`` marks; all where None) and ``load_peak`` (the
+        largest number of those assignments any held expert received)."""
+        n = u.shape[0]
+        lo, hi = self.held
+        e_held = hi - lo
+        out = jnp.zeros((n, self.dim), u.dtype)
+        stats = {"local_hits": jnp.int32(0), "load_peak": jnp.int32(0)}
+        if routed:
+            idx, w = self.route(params, u)
+            with jax.named_scope("moe.experts"):
+                lat = u @ params["down"]["w"].astype(u.dtype)
+                local = idx - lo
+                is_held = (local >= 0) & (local < e_held)
+                # absent experts sort past every group
+                eid = jnp.where(is_held, local, e_held).reshape(-1)
+                order = jnp.argsort(eid, stable=True)
+                sizes = jnp.bincount(eid, length=e_held + 1)[:e_held]
+                rows = jnp.take(lat, order // self.top_k, axis=0)
+                sizes = sizes.astype(jnp.int32)
+                h = lax.ragged_dot(rows, params["w1"].astype(u.dtype), sizes)
+                h = jnp.square(jax.nn.relu(h.astype(jnp.float32)))
+                y = lax.ragged_dot(h.astype(u.dtype),
+                                   params["w2"].astype(u.dtype), sizes,
+                                   preferred_element_type=jnp.float32)
+                # back to token order; rows past the last group belong to
+                # no expert and carry weight 0
+                y = jnp.take(y, jnp.argsort(order), axis=0).reshape(
+                    n, self.top_k, self.latent)
+                r = jnp.sum(jnp.where(is_held[..., None], y * w[..., None],
+                                      0.0), axis=1)
+                out = out + r.astype(u.dtype) @ params["up"]["w"].astype(u.dtype)
+                counted = is_held if active is None else (
+                    is_held & active[:, None])
+                stats = {
+                    "local_hits": jnp.sum(counted, dtype=jnp.int32),
+                    "load_peak": jnp.max(jnp.bincount(
+                        jnp.where(counted, local, e_held).reshape(-1),
+                        length=e_held + 1)[:e_held]).astype(jnp.int32)}
+        if shared:
+            with jax.named_scope("moe.shared"):
+                hs = u @ params["shared"]["v1"].astype(u.dtype)
+                hs = jnp.square(jax.nn.relu(hs))
+                out = out + hs @ params["shared"]["v2"].astype(u.dtype)
+        return out, stats
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        lead = x.shape[:-1]
+        y, _ = self.apply_tokens(params, x.reshape(-1, self.dim))
+        return y.reshape(*lead, self.dim), state

@@ -22,6 +22,12 @@ Example::
         --set dim=256 --set n_layers=4 --set seq_len=256 \
         --checkpoint-dir ./ckpt --requests 64 --arrival-rate 32 \
         --max-batch 8 --num-blocks 96 --quantize-int8 --out SERVE.json
+
+A ``HybridLM`` (serving-only; no ``--prefix-cache``)::
+
+    tmserve --modelfile theanompi_tpu.models.hybrid_lm --modelclass HybridLM \
+        --set "pattern='MEM*E'" --set dim=256 --set seq_len=256 \
+        --requests 16 --max-batch 8 --out SERVE.json
 """
 
 from __future__ import annotations
@@ -43,8 +49,16 @@ def build_parser() -> argparse.ArgumentParser:
         allow_abbrev=False,
     )
     p.add_argument("--modelfile",
-                   default="theanompi_tpu.models.transformer_lm")
-    p.add_argument("--modelclass", default="TransformerLM")
+                   default="theanompi_tpu.models.transformer_lm",
+                   help="module of the model class (default the "
+                   "transformer LM; theanompi_tpu.models.hybrid_lm for "
+                   "HybridLM)")
+    p.add_argument("--modelclass", default="TransformerLM",
+                   help="a model with the serving interface (apply_prefill, "
+                   "apply_decode, cache_spec): TransformerLM and its MoE "
+                   "variant, or HybridLM — a per-layer pattern of Mamba-2 "
+                   "(M), expert (E) and attention (*) mixers, serving-only, "
+                   "configured by --set pattern=... and its widths")
     p.add_argument("--set", dest="model_set", action="append", default=[],
                    metavar="K=V", help="model config entry (repeatable; "
                    "must reproduce the training config for the checkpoint "
@@ -82,7 +96,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="radix prefix cache over the KV block pool (ISSUE "
                    "17): admissions reuse cached full-block prompt-prefix "
                    "K/V via partial prefill; token streams are unchanged "
-                   "and the cache invalidates on live weight rollout")
+                   "and the cache invalidates on live weight rollout.  "
+                   "Refused (config error) for a model that keeps per-slot "
+                   "recurrent state, e.g. HybridLM with M layers: shared "
+                   "K/V blocks hold no state to resume from")
     # -- synthetic traffic -------------------------------------------------
     p.add_argument("--requests", type=int, default=16)
     p.add_argument("--prompt-len", type=int, default=16,

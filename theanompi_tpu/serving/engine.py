@@ -1,4 +1,5 @@
-"""Inference engine: compiled prefill/decode steps for ``TransformerLM``.
+"""Inference engine: compiled prefill/decode steps for a serving model
+(``TransformerLM``, ``HybridLM``).
 
 The engine is the pure-compute half of serving (the policy half — admission,
 preemption, batching — is :mod:`theanompi_tpu.serving.scheduler`): it owns
@@ -27,7 +28,17 @@ beneath it — ``.place`` (the host-to-device puts), ``.dispatch`` (the
 jitted call), ``.wait`` (the next tokens reach the host: the device step
 is over), ``.fetch`` (the logits' copy).  On the device, ``recast`` and
 ``sample`` scopes name the weight cast and the sampler beside the model's
-own ``embed`` / ``block`` / ``attn`` / ``mlp`` / ``head``.
+own ``embed`` / ``block`` / ``attn`` / ``mlp`` / ``head`` (``HybridLM``:
+``mamba`` / ``moe.route`` / ``moe.experts`` / ``moe.shared``).
+
+The cache is whatever the model's ``cache_spec()`` asks for: paged K/V
+pools over the layers that attend and, for layers that keep a recurrent
+state instead, a slot-indexed state pool ``[layers, max_batch, ...]``
+donated through the steps like the K/V pools.  A prefill writes its
+slot's state whole, taken at the prompt's TRUE length (padding to a bucket
+is invisible to causal attention but not to a recurrence); a decode step
+updates every slot's in place.  A model that states a ``weight_dtype``
+has its weights cast to it once, here, and held so.
 """
 
 from __future__ import annotations
@@ -113,11 +124,14 @@ class InferenceEngine:
             raise ValueError(f"decode_kernel={decode_kernel!r} not in "
                              f"('auto', 'on', 'off')")
         self.decode_kernel = decode_kernel
-        heads, dim = cfg["heads"], cfg["dim"]
+        spec = model.cache_spec()
+        heads, head_dim = spec["kv"]["heads"], spec["kv"]["head_dim"]
         on_tpu = jax.default_backend() == "tpu"
-        use_kernel = decode_kernel == "on" or (
+        # the kernel reads one K/V head per query head: a pool of fewer
+        # (grouped) K/V heads takes the fallback whatever was asked
+        use_kernel = heads == cfg["heads"] and (decode_kernel == "on" or (
             decode_kernel == "auto" and on_tpu and paged_decode_supported(
-                heads, dim // heads, model.precision.compute_dtype))
+                heads, head_dim, model.precision.compute_dtype)))
         #: resolved decode-attention variant — "kernel" (compiled pallas,
         #: TPU), "kernel_interpret" (same kernel through the pallas
         #: interpreter, the off-TPU "on" mode the parity locks run) or
@@ -141,18 +155,22 @@ class InferenceEngine:
         if quantize_int8:
             params, self.quant_stats = quantize_tree(
                 params, self._quant_key, quant_chunk)
-        self.params = params
-        cache = PagedKVCache.create(
-            n_layers=cfg["n_layers"], num_blocks=self.num_blocks,
-            block_size=block_size, heads=heads, head_dim=dim // heads,
+        self.params = self._held(params)
+        cache = PagedKVCache.from_spec(
+            spec, num_blocks=self.num_blocks, block_size=block_size,
             max_batch=max_batch, max_context=self.max_context,
             dtype=model.precision.compute_dtype,
             decode_impl=self.decode_impl)
-        self._k, self._v = cache.k, cache.v
+        self._k, self._v, self._state = cache.k, cache.v, cache.state
         # k/v pools are donated: the step's .at[].set() writes update the
         # pool buffers in place instead of copying two [L, blocks, bs, H,
-        # Dh] arrays per generated token (the cache docstring's contract)
-        self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1, 2))
+        # Dh] arrays per generated token (the cache docstring's contract).
+        # The state pool, where the model has one, rides as the trailing
+        # argument and is donated the same way; a model without one passes
+        # nothing there and compiles the programs it always did.
+        self._donate = (1, 2, 9) if self._state else (1, 2)
+        self._decode_fn = jax.jit(self._decode_impl,
+                                  donate_argnums=self._donate)
         self._prefill_fns: dict[int, object] = {}
         # partial-prefill programs, keyed on PADDED SUFFIX length (same
         # power-of-two bucketing as full prefill -> same log2 bound on
@@ -168,6 +186,23 @@ class InferenceEngine:
     @property
     def quantized(self) -> bool:
         return is_quantized_tree(self.params)
+
+    @property
+    def stateful(self) -> bool:
+        """The model keeps per-slot recurrent state (which no block-level
+        prefix cache can share)."""
+        return bool(self._state)
+
+    def _held(self, params):
+        """``params`` as the engine keeps them: cast once to the model's
+        stated ``weight_dtype`` (a model that states none, or float32,
+        keeps the tree as handed in)."""
+        dtype = getattr(self.model, "weight_dtype", None)
+        if dtype is None or dtype == jnp.float32:
+            return params
+        return jax.tree.map(
+            lambda x: x.astype(dtype)
+            if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
 
     def resolved_paths(self) -> dict:
         """Which implementation serves each hot op of this engine — the
@@ -208,7 +243,7 @@ class InferenceEngine:
         if self._quantize_int8:
             params, self.quant_stats = quantize_tree(
                 params, self._quant_key, self._quant_chunk)
-        self.params = params
+        self.params = self._held(params)
         self.params_version += 1
         return prev
 
@@ -223,39 +258,45 @@ class InferenceEngine:
 
     # -- compiled bodies -----------------------------------------------------
     def _decode_impl(self, params, k, v, tables, lengths, tokens, temps,
-                     rids, base_key):
+                     rids, base_key, state=None):
         # fast path keeps kernel-consumable int8 leaves quantized; the
         # fallback dequantizes everything exactly as before (the PR 9
         # argmax-agreement lock rides on that path staying bit-stable)
         with jax.named_scope("recast"):
             params = dequantize_tree(params, keep=self._keep_quant)
         cache = PagedKVCache(k, v, tables, self.block_size,
-                             decode_impl=self.decode_impl)
+                             decode_impl=self.decode_impl, state=state or {})
         # the incoming token's 0-based position == tokens already cached
         positions = lengths
-        logits, cache = self.model.apply_decode(
+        # (logits, cache) and, from a model that counts them, the step's
+        # small device counters (the ``serve.decode`` span's tags)
+        logits, cache, *stats = self.model.apply_decode(
             params, {}, cache, positions, tokens)
         with jax.named_scope("sample"):
             keys = jax.vmap(functools.partial(_sample_key, base_key))(
                 rids, positions + 1)
             nxt = sample_tokens(logits, temps, keys, self.top_k)
-        return nxt, logits, cache.k, cache.v
+        return (nxt, logits, cache.k, cache.v, cache.state,
+                stats[0] if stats else {})
 
     def _prefill_impl(self, params, k, v, table_row, tokens, true_len,
-                      temp, rid, base_key):
+                      temp, rid, base_key, state=None, slot=None):
         with jax.named_scope("recast"):
             params = dequantize_tree(params)
         cache = PagedKVCache(
             k, v, jnp.zeros((1, self.max_blocks_per_seq), jnp.int32),
-            self.block_size)
+            self.block_size, state=state or {})
+        # a model with per-slot state is told where the prompt really ends
+        # and whose state it leaves behind
+        own = {} if slot is None else {"true_len": true_len, "slot": slot}
         logits, cache = self.model.apply_prefill(
-            params, {}, cache, table_row, tokens[None, :])
+            params, {}, cache, table_row, tokens[None, :], **own)
         with jax.named_scope("sample"):
             last = jnp.take(logits[0], true_len - 1, axis=0)
             key = _sample_key(base_key, rid, true_len)
             nxt = sample_tokens(last[None], temp[None], key[None],
                                 self.top_k)
-        return nxt[0], last, cache.k, cache.v
+        return nxt[0], last, cache.k, cache.v, cache.state
 
     def _prefill_suffix_impl(self, params, k, v, full_row, suffix_row,
                              tokens, prefix_len, true_len, temp, rid,
@@ -293,10 +334,14 @@ class InferenceEngine:
         return min(nb, self.max_blocks_per_seq) * self.block_size
 
     def prefill(self, table_row, tokens, temperature: float = 0.0,
-                rid: int = 0, prefix_len: int = 0):
+                rid: int = 0, prefix_len: int = 0, slot: int = 0):
         """Prefill one sequence; -> (first generated token: int, last-
         position logits ``[V]`` np).  ``table_row``: the block ids backing
-        the prompt (padded internally with the null block).
+        the prompt (padded internally with the null block).  ``slot``: the
+        batch slot the sequence will decode in; a model with per-slot state
+        has that slot's state replaced by the prompt's (so a slot's
+        previous owner, or this request's own state from before a
+        preemption, leaves nothing behind).
 
         ``prefix_len > 0`` (ISSUE 17): the first ``prefix_len`` tokens'
         K/V already sit in ``table_row``'s leading blocks (a prefix-cache
@@ -314,6 +359,10 @@ class InferenceEngine:
                         bucket=self.pad_len(p - prefix_len),
                         prefix_len=prefix_len):
             if prefix_len:
+                if self._state:
+                    raise ValueError(
+                        "a cached prefix holds K/V blocks but no recurrent "
+                        "state: this model cannot prefill from one")
                 return self._prefill_suffix(table_row, tokens, temperature,
                                             rid, prefix_len)
             p_pad = self.pad_len(p)
@@ -324,15 +373,17 @@ class InferenceEngine:
             fn = self._prefill_fns.get(p_pad)
             if fn is None:
                 fn = self._prefill_fns[p_pad] = jax.jit(
-                    self._prefill_impl, donate_argnums=(1, 2))
+                    self._prefill_impl, donate_argnums=self._donate)
             toks = np.zeros((p_pad,), np.int32)
             toks[:p] = tokens
-            nxt, last, self._k, self._v = fn(
+            own = ((self._state, jnp.asarray(slot, jnp.int32))
+                   if self._state else ())
+            nxt, last, self._k, self._v, self._state = fn(
                 self.params, self._k, self._v,
                 jnp.asarray(row, jnp.int32), jnp.asarray(toks),
                 jnp.asarray(p, jnp.int32),
                 jnp.asarray(temperature, jnp.float32),
-                jnp.asarray(rid, jnp.int32), self._base_key)
+                jnp.asarray(rid, jnp.int32), self._base_key, *own)
             # lint: donated-escape-ok — prefill outputs are fresh XLA result
             # buffers; only the k/v pools are donated, never sampled tokens
             # lint: host-sync-ok — the span closes over materialized results
@@ -383,7 +434,7 @@ class InferenceEngine:
         lengths = np.asarray(lengths)
         active = np.flatnonzero(lengths)
         with spans.span(_SPAN_DECODE, step=self.n_decodes, batch=len(active),
-                        requests=np.asarray(rids)[active].tolist()):
+                        requests=np.asarray(rids)[active].tolist()) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):
                 args = (jnp.asarray(tables, jnp.int32),
@@ -392,13 +443,18 @@ class InferenceEngine:
                         jnp.asarray(temps, jnp.float32),
                         jnp.asarray(rids, jnp.int32))
             with spans.span(_SPAN_DISPATCH):
-                nxt, logits, self._k, self._v = self._decode_fn(
-                    self.params, self._k, self._v, *args, self._base_key)
+                own = (self._state,) if self._state else ()
+                nxt, logits, self._k, self._v, self._state, stats = \
+                    self._decode_fn(self.params, self._k, self._v, *args,
+                                    self._base_key, *own)
             with spans.span(_SPAN_WAIT):
                 # lint: host-sync-ok — this span IS the wait for the device
                 # lint: donated-escape-ok — decode outputs are fresh XLA
                 # result buffers; only the k/v pools are donated
                 nxt = np.asarray(nxt)
+                # the model's step counters (moe_local_hits, moe_load_peak):
+                # scalars that come with the tokens
+                span.tag(**{name: int(x) for name, x in stats.items()})
             with spans.span(_SPAN_FETCH, bytes=logits.nbytes):
                 # lint: host-sync-ok — this span IS the copy to the host
                 # lint: donated-escape-ok — as above: never tokens/logits
@@ -407,4 +463,4 @@ class InferenceEngine:
 
     def fence(self):
         """Block until the cache state is materialized (honest timing)."""
-        jax.block_until_ready((self._k, self._v))
+        jax.block_until_ready((self._k, self._v, self._state))

@@ -73,16 +73,24 @@ class PagedKVCache:
     #: from the backend at trace time so a CPU host can still lower the
     #: compiled variant for TPU (the HLO audit does exactly that).
     decode_impl: str = "fallback"
+    #: per-slot recurrent state of the layers that keep one (a model's
+    #: ``cache_spec()["state"]``): name -> ``[n_state_layers, max_batch,
+    #: ...]``.  Not paged: a slot owns one fixed-size entry per layer, a
+    #: prefill writes its slot's entry whole and a decode step updates
+    #: every slot's.  Empty for a model whose layers all cache K/V.
+    state: dict = dataclasses.field(default_factory=dict)
 
     NULL_BLOCK = 0
 
     def tree_flatten(self):
-        return ((self.k, self.v, self.block_tables),
+        return ((self.k, self.v, self.block_tables, self.state),
                 (self.block_size, self.decode_impl))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, block_size=aux[0], decode_impl=aux[1])
+        k, v, tables, state = children
+        return cls(k, v, tables, block_size=aux[0], decode_impl=aux[1],
+                   state=state)
 
     # -- shape properties ----------------------------------------------------
     @property
@@ -96,6 +104,22 @@ class PagedKVCache:
     @property
     def max_context(self) -> int:
         return self.block_tables.shape[1] * self.block_size
+
+    @classmethod
+    def from_spec(cls, spec: dict, *, num_blocks: int, block_size: int,
+                  max_batch: int, max_context: int, dtype=jnp.float32,
+                  decode_impl: str = "fallback") -> "PagedKVCache":
+        """The cache a model's ``cache_spec()`` asks for: K/V pools over its
+        ``kv`` layers (``layers``, ``heads``, ``head_dim``) and, per entry
+        of ``state`` (name -> (per-sequence shape, dtype)), a slot-indexed
+        pool ``[state_layers, max_batch, *shape]`` of zeros."""
+        kv = spec["kv"]
+        cache = cls.create(kv["layers"], num_blocks, block_size, kv["heads"],
+                           kv["head_dim"], max_batch, max_context, dtype,
+                           decode_impl)
+        return dataclasses.replace(cache, state={
+            name: jnp.zeros((spec["state_layers"], max_batch, *shape), dt)
+            for name, (shape, dt) in spec["state"].items()})
 
     @classmethod
     def create(cls, n_layers: int, num_blocks: int, block_size: int,
@@ -121,9 +145,8 @@ class PagedKVCache:
     def with_tables(self, tables) -> "PagedKVCache":
         """New cache view with the given ``[max_batch, max_blocks]`` tables
         (the scheduler re-materializes these from host state each step)."""
-        return PagedKVCache(self.k, self.v,
-                            jnp.asarray(tables, jnp.int32), self.block_size,
-                            decode_impl=self.decode_impl)
+        return dataclasses.replace(
+            self, block_tables=jnp.asarray(tables, jnp.int32))
 
     # -- writes --------------------------------------------------------------
     def write_prefill(self, layer: int, k, v, table_row) -> "PagedKVCache":
@@ -137,11 +160,9 @@ class PagedKVCache:
         blocks_k = k[0].reshape(p_pad // bs, bs, *k.shape[2:])
         blocks_v = v[0].reshape(p_pad // bs, bs, *v.shape[2:])
         idx = jnp.asarray(table_row, jnp.int32)
-        return PagedKVCache(
-            self.k.at[layer, idx].set(blocks_k.astype(self.k.dtype)),
-            self.v.at[layer, idx].set(blocks_v.astype(self.v.dtype)),
-            self.block_tables, self.block_size,
-            decode_impl=self.decode_impl)
+        return dataclasses.replace(
+            self, k=self.k.at[layer, idx].set(blocks_k.astype(self.k.dtype)),
+            v=self.v.at[layer, idx].set(blocks_v.astype(self.v.dtype)))
 
     def write_decode(self, layer: int, k, v, positions) -> "PagedKVCache":
         """Append one token's K/V per batch slot: ``k``/``v`` ``[B, H, Dh]``
@@ -152,11 +173,24 @@ class PagedKVCache:
         blk = jnp.take_along_axis(
             self.block_tables, blk_idx[:, None], axis=1)[:, 0]
         off = positions % self.block_size
-        return PagedKVCache(
-            self.k.at[layer, blk, off].set(k.astype(self.k.dtype)),
-            self.v.at[layer, blk, off].set(v.astype(self.v.dtype)),
-            self.block_tables, self.block_size,
-            decode_impl=self.decode_impl)
+        return dataclasses.replace(
+            self, k=self.k.at[layer, blk, off].set(k.astype(self.k.dtype)),
+            v=self.v.at[layer, blk, off].set(v.astype(self.v.dtype)))
+
+    # -- per-slot recurrent state ----------------------------------------------
+    def read_state(self, layer: int) -> dict:
+        """State layer ``layer`` of every slot: name -> ``[max_batch, ...]``."""
+        return {name: pool[layer] for name, pool in self.state.items()}
+
+    def write_state(self, layer: int, new: dict, slot=None) -> "PagedKVCache":
+        """Replace state layer ``layer``: of every slot (``new`` leaves
+        ``[max_batch, ...]``, a decode step) or, with ``slot``, of that one
+        slot whole (leaves without the batch axis, a prefill: whatever a
+        previous owner of the slot left there is gone)."""
+        at = (layer,) if slot is None else (layer, slot)
+        return dataclasses.replace(self, state={
+            name: pool.at[at].set(new[name].astype(pool.dtype))
+            for name, pool in self.state.items()})
 
     # -- paged attention (suffix prefill) --------------------------------------
     def attend_prefill(self, layer: int, q, table_row, prefix_len):
@@ -222,6 +256,8 @@ class PagedKVCache:
                 self.k, self.v, layer, self.block_tables,
                 self.block_size, q, jnp.asarray(positions, jnp.int32),
                 interpret=(self.decode_impl == "kernel_interpret"))
+        if q.shape[1] != self.k.shape[3]:
+            return self._attend_decode_grouped(layer, q, positions)
         # [B, nb, bs, H, Dh]: gather each slot's blocks, then run the
         # recurrence over the block axis
         kb = jnp.take(self.k[layer], self.block_tables, axis=0)
@@ -261,6 +297,32 @@ class PagedKVCache:
         a0 = jnp.zeros((b, h, d), jnp.float32)
         m, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, a0))
         return (acc / jnp.swapaxes(l, 1, 2)).astype(q.dtype)
+
+    def _attend_decode_grouped(self, layer: int, q, positions):
+        """:meth:`attend_decode`'s fallback where the pool holds fewer K/V
+        heads than ``q`` has query heads (query head ``h`` reads K/V head
+        ``h // (H // Hkv)``): one masked fp32 softmax over each slot's
+        gathered context, the K/V heads never repeated.  The kernel's gate
+        refuses such a pool, so no bit-parity is owed and the products are
+        plain einsums."""
+        b, h, d = q.shape
+        hkv = self.k.shape[3]
+        if h % hkv:
+            raise ValueError(f"{h} query heads over {hkv} K/V heads")
+        t_max = self.block_tables.shape[1] * self.block_size
+        kb = jnp.take(self.k[layer], self.block_tables, axis=0)
+        vb = jnp.take(self.v[layer], self.block_tables, axis=0)
+        kb = kb.reshape(b, t_max, hkv, d)
+        vb = vb.reshape(b, t_max, hkv, d)
+        qg = (q.astype(jnp.float32) * d ** -0.5).reshape(b, hkv, h // hkv, d)
+        s = jnp.einsum("bgrd,btgd->bgrt", qg.astype(kb.dtype), kb,
+                       preferred_element_type=jnp.float32)
+        valid = jnp.arange(t_max)[None, :] <= positions[:, None]
+        s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        ctx = jnp.einsum("bgrt,btgd->bgrd", p.astype(vb.dtype), vb,
+                         preferred_element_type=jnp.float32)
+        return ctx.reshape(b, h, d).astype(q.dtype)
 
 
 def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,

@@ -135,6 +135,17 @@ class Scheduler:
         self.shed = shed
         self.fault_plan = fault_plan
         self.pool = BlockPool(engine.num_blocks)
+        #: the engine's model keeps per-slot recurrent state
+        self._stateful = bool(getattr(engine, "stateful", False))
+        if prefix_cache and self._stateful:
+            # the cache shares K/V BLOCKS between sequences; a recurrent
+            # layer's state after the shared prefix lives in no block, so a
+            # hit would resume from a state that was never computed
+            raise ValueError(
+                "prefix_cache=True with a model that keeps per-slot "
+                "recurrent state (cache_spec()['state']): the prefix cache "
+                "shares K/V blocks and holds no state snapshot to resume "
+                "from; serve this model without it")
         # ISSUE 17: radix prefix cache over the pool — OFF by default (the
         # cache-OFF token streams are the bit-equality reference)
         self.prefix_cache = (PrefixCache(self.pool, engine.block_size)
@@ -497,8 +508,13 @@ class Scheduler:
             self.queue.popleft()
             # prefill returns a host int — already materialized, so the
             # engine's serve.prefill span measures execution, not dispatch
+            # the slot's recurrent state (a model that has one) is replaced
+            # whole by this prefill: a reused slot, and a preempted request
+            # recomputed over prompt + generated, both start clean
+            own = {"slot": slot} if self._stateful else {}
             tok, _ = self.engine.prefill(row, prefix, req.temperature,
-                                         req.rid, prefix_len=prefix_len)
+                                         req.rid, prefix_len=prefix_len,
+                                         **own)
             if prefix_len:
                 # exact accounting: tokens_saved is the sum of matched-
                 # prefix lengths — prefill K/V the engine did not recompute

@@ -67,7 +67,9 @@ SERVE_SPANS = ("serve.prefill", "serve.decode")
 #: (``telemetry/spans.py``), beside the two above which the ENGINE opens
 #: now (``serve.prefill`` tags: ``request``, ``prompt``, ``bucket`` — the
 #: padded length that picks the program, ``prefix_len``; ``serve.decode``
-#: tags: ``step`` — the engine's decode ordinal, ``batch``, ``requests``).
+#: tags: ``step`` — the engine's decode ordinal, ``batch``, ``requests``;
+#: from a model that counts them on the device (``HybridLM``) also the two
+#: of :data:`SERVE_DECODE_MOE_TAGS`, fetched with the step's tokens).
 #: ``serve.step`` is one ``Scheduler.step`` (tags ``step``, ``batch``);
 #: ``serve.admit`` the admission pass inside it (tag ``queued``; the
 #: per-request ``serve.admit`` INSTANT keeps its name and kind).
@@ -77,6 +79,31 @@ SERVE_STEP_SPANS = ("serve.step", "serve.admit")
 #: reach the host), the logits' copy to the host (tag ``bytes``)
 SERVE_DECODE_SPANS = ("serve.decode.place", "serve.decode.dispatch",
                       "serve.decode.wait", "serve.decode.fetch")
+#: ``serve.decode`` tags of a model with expert layers, one value a step:
+#: ``moe_local_hits`` — selected experts that this process holds, summed
+#: over the expert layers and the active slots (over ``batch`` and the
+#: layers: ``top_k x held / n_experts`` a token under an even router);
+#: ``moe_load_peak`` — the most assignments any held expert received in
+#: one expert layer of the step (the longest group of the grouped product)
+SERVE_DECODE_MOE_TAGS = ("moe_local_hits", "moe_load_peak")
+#: ``jax.named_scope`` names on the DEVICE (op metadata: they name rows of a
+#: profiler trace, not ring records), by who opens them.  The engine:
+#: ``recast`` (weight casts / dequantize), ``sample``; ``TransformerLM``:
+#: ``embed``, ``block`` > ``attn`` / ``mlp``, ``head``, ``loss``; the
+#: trainer: ``clip``, ``exchange``, ``optimizer``; the kernels name
+#: themselves (``paged_decode``, ``flash_fwd``, ``flash_bwd_dq``,
+#: ``flash_bwd_dkv``); ``HybridLM``: ``embed``, ``mamba`` (a Mamba-2
+#: mixer, projections and state update), ``moe.route`` (router, top-k),
+#: ``moe.experts`` (latent projections, sort, grouped products),
+#: ``moe.shared`` (the shared expert), ``attn``, ``head``
+DEVICE_SCOPES = {
+    "engine": ("recast", "sample"),
+    "TransformerLM": ("embed", "block", "attn", "mlp", "head", "loss"),
+    "trainer": ("clip", "exchange", "optimizer"),
+    "kernels": ("paged_decode", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
+                 "attn", "head"),
+}
 #: one ``train_iter`` (tags ``step``, ``epoch``; ``loss`` at fenced steps)
 TRAIN_SPANS = ("train.step",)
 #: the Recorder's segments: a ring span each
