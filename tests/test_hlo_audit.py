@@ -161,12 +161,28 @@ def test_serve_decode_kernel_audit():
     assert r["int8_rel_err"] <= hlo_audit.INT8_REL_TOL
 
 
+def test_expert_products_audit():
+    """ISSUE 28: with the kernel on, every grouped product of a small
+    ``HybridLM``'s decode program is a ``grouped_matmul`` custom call fed
+    by the ``[E, K, N]`` parameter leaf itself and no ``ragged_dot`` is
+    left; with it off, the ``ragged_dot``s are all there."""
+    r = hlo_audit.audit_expert_products()
+    assert r["ok"], r["violations"]
+    n = r["expected"]
+    assert n == 4 and len(r["products_on"]) == len(r["products_off"]) == n
+    assert all(c["via"] == "kernel" and c["weight_from"] == "parameter"
+               and c["weight"] in r["stacks"] for c in r["products_on"])
+    assert r["stacks"] == [[4, 128, 256], [4, 256, 128]]
+    assert all(c["via"] == "ragged_dot" for c in r["products_off"])
+
+
 def test_run_default_audits_is_green():
     reports = hlo_audit.run_default_audits()
     assert [(r["kind"], r.get("strategy")) for r in reports] == [
         ("train", "psum_bucket"), ("train", "zero1"),
         ("train-overlap", "psum_bucket"), ("train-overlap", "zero1"),
-        ("serve", None), ("serve-prefill", None), ("serve-kernel", None)]
+        ("serve", None), ("serve-prefill", None), ("serve-kernel", None),
+        ("serve-experts", None)]
     assert all(r["ok"] for r in reports)
 
 
@@ -239,6 +255,53 @@ def test_auditor_detects_a_sliced_pool_before_the_kernel(cut, sliced,
     assert any("not the whole" in v for v in r["violations"]) is sliced
 
 
+@pytest.mark.parametrize("cut,made_by", [
+    (lambda w: w, "parameter"),
+    (lambda w: w.astype(jnp.bfloat16), "convert"),       # a leaf held in fp32
+    (lambda w: w[1:], "slice"),
+    (lambda w: jax.lax.dynamic_slice_in_dim(w, 1, 3), "dynamic_slice"),
+])
+def test_auditor_detects_a_copied_expert_stack_before_the_kernel(
+        cut, made_by, monkeypatch):
+    """ISSUE 28's guard has teeth: a re-cast or a slice of the stacked
+    expert weights in front of the grouped product reads as made by that
+    op, not as the parameter, and the audit lists it as a violation."""
+    from theanompi_tpu.ops.pallas_grouped_matmul import grouped_matmul
+
+    dtype = jnp.float32 if made_by == "convert" else jnp.bfloat16
+    w = jnp.zeros((4, 128, 256), dtype)
+    rows = jnp.zeros((16, 128), jnp.bfloat16)
+
+    def step(rows, w, sizes):
+        w = cut(w)
+        return grouped_matmul(rows, w, sizes[:w.shape[0]], tm=16,
+                              interpret=False)
+
+    text = jax.jit(step).trace(rows, w, jnp.zeros((4,), jnp.int32)) \
+        .lower(lowering_platforms=("tpu",)).as_text()
+    (call,) = hlo_audit.grouped_product_calls(text)
+    assert call["via"] == "kernel" and call["weight_from"] == made_by
+
+    facts = dict(hlo_audit._expert_products_artifact())
+    facts["products_on"] = [dict(c, weight_from=made_by)
+                            for c in facts["products_on"]]
+    monkeypatch.setattr(hlo_audit, "_expert_products_artifact", lambda: facts)
+    r = hlo_audit.audit_expert_products()
+    assert r["ok"] is (made_by == "parameter")
+    assert any("not a whole parameter leaf" in v
+               for v in r["violations"]) is (made_by != "parameter")
+
+
+def test_a_ragged_dot_left_in_the_kernel_on_program_is_a_violation(
+        monkeypatch):
+    facts = dict(hlo_audit._expert_products_artifact())
+    facts["products_on"] = (facts["products_on"][:-1]
+                            + facts["products_off"][-1:])
+    monkeypatch.setattr(hlo_audit, "_expert_products_artifact", lambda: facts)
+    r = hlo_audit.audit_expert_products()
+    assert not r["ok"] and any("1 ragged_dot(s)" in v for v in r["violations"])
+
+
 def test_budget_violation_surfaces_in_report(monkeypatch):
     """Tighten the psum_bucket lock to an impossible bound: the audit
     must report the violation (and run_default_audits must raise)."""
@@ -255,7 +318,7 @@ def test_budget_violation_surfaces_in_report(monkeypatch):
     # the tightened psum_bucket TRAIN lock fails — the overlap audits
     # have their own invariants and stay green
     assert [rep["ok"] for rep in ei.value.reports] == [
-        False, True, True, True, True, True, True]
+        False, True, True, True, True, True, True, True]
 
 
 def test_train_cfg_matches_the_locked_fixture():

@@ -4,6 +4,7 @@ grouped K/V heads) against the plain reference
 with seeded weights: the ops alone, the model through the engine's cache,
 the scheduler's slots, and the programs the other model must keep."""
 
+import dataclasses
 import hashlib
 import os
 import re
@@ -96,11 +97,18 @@ def _skew(leaves, p):
     return leaves, p
 
 
-def test_expert_layer_is_the_reference_and_drops_nothing(tiny):
+#: what multiplies in the expert layer: the default, and the kernel of
+#: ``ops/pallas_grouped_matmul.py`` through the Pallas interpreter
+PRODUCTS = ["ragged_dot", "kernel_interpret"]
+
+
+@pytest.mark.parametrize("products", PRODUCTS)
+def test_expert_layer_is_the_reference_and_drops_nothing(tiny, products):
     """Top-k of 8 over 32 experts, 8 held, under a skewed router: every
     token's every held expert is computed, whatever the load."""
     cfg = tiny[0]
     leaves, p, mixer = _layer(tiny, "moe")
+    mixer = dataclasses.replace(mixer, products=products)
     leaves, p = _skew(leaves, p)
     u = _inputs(cfg, 40)
     want = ref.experts(cfg, ref._ein("fp32"), leaves, u)
@@ -113,7 +121,9 @@ def test_expert_layer_is_the_reference_and_drops_nothing(tiny):
     assert int(stats["load_peak"]) > 2 * held.sum() / held.shape[1]
 
 
-def test_the_four_shares_and_the_shared_expert_once_make_the_uncut_layer(tiny):
+@pytest.mark.parametrize("products", PRODUCTS)
+def test_the_four_shares_and_the_shared_expert_once_make_the_uncut_layer(
+        tiny, products):
     """Each of four chips holds 8 of the 32 experts and routes over all 32;
     their routed parts, plus the shared expert counted once, add up to the
     reference's layer with every expert held."""
@@ -131,7 +141,8 @@ def test_the_four_shares_and_the_shared_expert_once_make_the_uncut_layer(tiny):
         layer = DroplessMoE(cfg["hidden_size"], n, cfg["num_experts_per_tok"],
                             cfg["moe_latent_size"], cfg["moe_intermediate_size"],
                             cfg["moe_shared_expert_intermediate_size"],
-                            float(cfg["routed_scaling_factor"]), (lo, hi))
+                            float(cfg["routed_scaling_factor"]), (lo, hi),
+                            products)
         p = {"router": {"w": leaves["router_w"], "b_corr": leaves["b_corr"]},
              "down": {"w": leaves["down"]}, "up": {"w": leaves["up"]},
              "w1": leaves["w1"][lo:hi], "w2": leaves["w2"][lo:hi],
@@ -309,6 +320,55 @@ def test_decode_tags_count_held_experts_on_the_device(tiny):
         assert 0 <= t["moe_local_hits"] <= t["batch"] * n_e * cfg["num_experts_per_tok"]
         assert 0 <= t["moe_load_peak"] <= t["batch"]
         assert (t["moe_local_hits"] > 0) == (t["moe_load_peak"] > 0)
+
+
+def test_the_grouped_product_kernel_decodes_the_tokens_ragged_dot_does(tiny):
+    """``decode_kernel`` resolves the expert products where it resolves the
+    decode attention: "on" runs the kernel (the interpreter here), "off"
+    and, off the TPU, "auto" run ``lax.ragged_dot``; the tokens are the
+    same and ``serve.decode`` / ``serve.prefill`` say which ran."""
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+    from theanompi_tpu.telemetry import spans
+
+    cfg, _, params = tiny
+    n_e = cfg["hybrid_override_pattern"].count("E")
+    rng = np.random.RandomState(28)
+    work = [(i, rng.randint(0, cfg["vocab_rows_held"], size=n).tolist(), m)
+            for i, (n, m) in enumerate([(9, 6), (21, 5), (5, 7)])]
+    got = {}
+    for variant, impl, ran in [("on", "kernel_interpret", 2 * n_e),
+                               ("off", "ragged_dot", 0),
+                               ("auto", "ragged_dot", 0)]:
+        model = HybridLM(arch.model_config(cfg))  # an engine sets its model
+        eng = InferenceEngine(model, params, block_size=8, max_batch=2,
+                              decode_kernel=variant)
+        assert eng.expert_impl == impl == model.expert_layer.products
+        assert eng.resolved_paths()["expert_products"] == impl
+        assert model.resolved_paths()["experts"] == impl
+        seq0 = max((r.seq for r in spans.snapshot()), default=-1)
+        got[variant] = _serve(eng, work)[0]
+        mine = [r for r in spans.snapshot() if r.seq > seq0
+                and r.name in ("serve.decode", "serve.prefill")]
+        assert {r.name for r in mine} == {"serve.decode", "serve.prefill"}
+        for r in mine:
+            assert r.tags["moe_products"] == 2 * n_e
+            assert r.tags["moe_kernel_products"] == ran
+    assert got["on"] == got["off"] == got["auto"]
+    assert all(len(toks) == m for (_, _, m), toks
+               in zip(work, got["on"].values()))
+
+
+def test_a_model_without_an_expert_layer_gains_no_tag():
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    model = HybridLM({"pattern": "M*", "dim": 32, "vocab": 50, "seq_len": 16})
+    assert model.expert_layer is None and model.expert_products == 0
+    eng = InferenceEngine(model, model.init_params(jax.random.PRNGKey(0))[0],
+                          block_size=8, max_batch=2, decode_kernel="on")
+    assert eng.expert_impl is None and eng._moe_tags == {}
+    assert "expert_products" not in eng.resolved_paths()
 
 
 def test_device_scopes_name_the_new_layers(tiny):

@@ -235,11 +235,15 @@ def test_a_model_that_counts_experts_tags_its_decode_spans(served_events):
     import jax
 
     from theanompi_tpu.models.hybrid_lm import HybridLM
-    from theanompi_tpu.telemetry.metrics import SERVE_DECODE_MOE_TAGS
+    from theanompi_tpu.telemetry.metrics import (
+        SERVE_DECODE_MOE_TAGS,
+        SERVE_MOE_PRODUCT_TAGS,
+    )
 
     events, _ = served_events
-    assert not any(t in e for e in _spans_named(events, "serve.decode")
-                   for t in SERVE_DECODE_MOE_TAGS)
+    assert not any(t in e for name in ("serve.decode", "serve.prefill")
+                   for e in _spans_named(events, name)
+                   for t in SERVE_DECODE_MOE_TAGS + SERVE_MOE_PRODUCT_TAGS)
     model = HybridLM({"pattern": "ME", "dim": 32, "vocab": 61, "seq_len": 32,
                       "mamba_heads": 4, "mamba_head_dim": 16, "state_size": 8,
                       "n_groups": 2, "chunk_size": 8, "n_experts": 4, "top_k": 2,
@@ -255,6 +259,8 @@ def test_a_model_that_counts_experts_tags_its_decode_spans(served_events):
     assert len(ours) == 2
     for r in ours:  # every expert held, one E layer, one slot: top_k hits
         assert r.tags["moe_local_hits"] == 2 and r.tags["moe_load_peak"] == 1
+        # two grouped products; off the TPU "auto" leaves them to ragged_dot
+        assert [r.tags[t] for t in SERVE_MOE_PRODUCT_TAGS] == [2, 0]
 
 
 def test_the_decode_parts_add_up_to_the_decode_span(served_events):

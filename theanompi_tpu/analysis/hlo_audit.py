@@ -735,13 +735,138 @@ def audit_serve_decode_kernel() -> dict:
             "violations": violations, **facts}
 
 
+# -- expert layer: the grouped products (ISSUE 28) ---------------------------
+
+#: a small ``HybridLM`` whose expert widths the grouped-product kernel's
+#: gate admits (latent and expert width whole numbers of 128, bf16):
+#: traced and lowered for the TPU, never compiled or run
+SERVE_EXPERT_CFG = {
+    "pattern": "MEM*E", "dim": 128, "vocab": 256, "seq_len": 64,
+    "heads": 4, "kv_heads": 2, "head_dim": 32, "mamba_heads": 4,
+    "mamba_head_dim": 32, "state_size": 16, "n_groups": 2, "chunk_size": 8,
+    "n_experts": 16, "experts_held": (4, 8), "top_k": 4, "latent": 128,
+    "expert_dim": 256, "shared_dim": 128, "weights": "bf16",
+    "precision": "bf16"}
+
+_SHLO_GROUPED_CALL = re.compile(
+    r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\).*"
+    r"kernel_name = \"grouped_matmul\".* : \(([^)]*)\) -> ")
+_SHLO_RAGGED_DOT = re.compile(
+    r"\"chlo\.ragged_dot\"\(([^)]*)\).* : \(([^)]*)\) -> ")
+
+
+def grouped_product_calls(stablehlo_text: str) -> list[dict]:
+    """Every grouped product in a TPU lowering's StableHLO text; -> one
+    record per product: ``{"via": "kernel" | "ragged_dot", "weight": the
+    ``[E, K, N]`` operand's shape, "weight_from": "parameter" when that
+    operand is an argument of the program itself, else the op that made
+    it}``.
+
+    The kernel's weight operand has to be the parameter leaf: a custom
+    call's operand is a whole buffer, so a ``convert`` (a leaf held in
+    another dtype), a ``slice`` or any other op in front of it is a copy of
+    the whole stack of experts on the chip ahead of every call
+    (:mod:`theanompi_tpu.ops.pallas_grouped_matmul`)."""
+    calls, defs = [], {}
+    for line in stablehlo_text.splitlines():
+        if "func.func" in line:
+            defs = {}  # SSA names are per function
+        m = _SHLO_DEF.match(line)
+        if m:
+            defs[m.group(1)] = m.group(2)
+        for via, pattern, at in (("kernel", _SHLO_GROUPED_CALL, -1),
+                                 ("ragged_dot", _SHLO_RAGGED_DOT, 1)):
+            c = pattern.search(line)
+            if not c:
+                continue
+            name = [n.strip() for n in c.group(1).split(",")][at]
+            kind = [t.strip() for t in c.group(2).split(", ")][at]
+            calls.append({
+                "via": via,
+                "weight": [int(n) for n in
+                           kind[len("tensor<"):].split("x")[:-1]],
+                "weight_from": ("parameter" if name.startswith("%arg")
+                                else defs.get(name, "unknown"))})
+    return calls
+
+
+@functools.lru_cache(maxsize=None)
+def _expert_products_artifact() -> dict:
+    """The decode program of a small ``HybridLM`` lowered for the TPU with
+    the grouped-product kernel pinned on and with it off."""
+    import jax
+    import jax.numpy as jnp
+
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    facts: dict = {}
+    for variant in ("on", "off"):
+        model = HybridLM(dict(SERVE_EXPERT_CFG))
+        params, _ = model.init_params(jax.random.PRNGKey(0))
+        eng = InferenceEngine(model, params, block_size=8, max_batch=2,
+                              decode_kernel=variant)
+        if variant == "on":
+            # pin the COMPILED kernel (off-TPU "on" resolves to the
+            # interpreter): what a TPU host would lower
+            model.set_expert_products("kernel")
+        b = eng.max_batch
+        args = (eng.params, eng._k, eng._v,
+                jnp.zeros((b, eng.max_blocks_per_seq), jnp.int32),
+                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
+                eng._base_key, eng._state)
+        text = jax.jit(eng._decode_impl, donate_argnums=eng._donate) \
+            .trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        facts[f"products_{variant}"] = grouped_product_calls(text)
+    layer = model.expert_layer
+    e = layer.held[1] - layer.held[0]
+    facts["expected"] = model.expert_products
+    facts["stacks"] = sorted([[e, layer.latent, layer.expert_dim],
+                              [e, layer.expert_dim, layer.latent]])
+    return facts
+
+
+def audit_expert_products() -> dict:
+    """Audit the expert layer's grouped products (ISSUE 28): with the
+    kernel on, every product of the decode program is a ``grouped_matmul``
+    custom call whose weight operand is the ``[E, K, N]`` parameter leaf
+    itself (nothing sliced, converted or copied in front of it) and no
+    ``ragged_dot`` is left; with it off, every product is a
+    ``ragged_dot`` and there is no such call."""
+    facts = _expert_products_artifact()
+    violations: list[str] = []
+    on, off, n = facts["products_on"], facts["products_off"], facts["expected"]
+    left = [c for c in on if c["via"] != "kernel"]
+    if len(on) != n or left:
+        violations.append(
+            f"kernel-on decode program has {len(on) - len(left)} "
+            f"grouped_matmul call(s) and {len(left)} ragged_dot(s) for "
+            f"{n} grouped products — the kernel is not dispatching for "
+            f"every product")
+    copied = [c for c in on if c["via"] == "kernel" and (
+        c["weight_from"] != "parameter" or c["weight"] not in facts["stacks"])]
+    if copied:
+        violations.append(
+            f"{len(copied)} grouped_matmul call(s) take a weight operand "
+            f"that is not a whole parameter leaf ({copied[:1]}) — the "
+            f"stack of experts is copied on the chip before every call")
+    if [c["via"] for c in off] != ["ragged_dot"] * n:
+        violations.append(
+            f"kernel-off decode program has {[c['via'] for c in off]} for "
+            f"{n} grouped products — the negative proof failed")
+    return {"kind": "serve-experts", "ok": not violations,
+            "violations": violations, **facts}
+
+
 # -- entry point -------------------------------------------------------------
 
 #: what ``tmlint --hlo-audit`` (and the tier-1 test) audits: the two
 #: strategies the acceptance criteria name, their overlapped-schedule
 #: locks (ISSUE 12 — the BASELINE step-7 gate), plus the serve decode,
 #: partial-prefill (prefix-cache hit, ISSUE 17) and decode-kernel
-#: dispatch (ISSUE 18) steps
+#: dispatch (ISSUE 18) steps, and the expert layer's grouped products
+#: (ISSUE 28)
 DEFAULT_TRAIN_STRATEGIES = ("psum_bucket", "zero1")
 
 
@@ -779,6 +904,7 @@ def run_default_audits(n_data: int = 4) -> list[dict]:
     reports.append(audit_serve_step())
     reports.append(audit_serve_prefill())
     reports.append(audit_serve_decode_kernel())
+    reports.append(audit_expert_products())
     bad = [r for r in reports if not r["ok"]]
     if bad:
         err = HLOAuditError("; ".join(

@@ -69,7 +69,8 @@ LAYER_DAG: tuple[tuple[str, tuple[str, ...], tuple[str, ...]], ...] = (
     ("kernels",    (f"{PKG}.ops.initializers", f"{PKG}.ops.layers",
                     f"{PKG}.ops.losses", f"{PKG}.ops.quant",
                     f"{PKG}.ops.pallas_attention",
-                    f"{PKG}.ops.pallas_paged_attention"),
+                    f"{PKG}.ops.pallas_paged_attention",
+                    f"{PKG}.ops.pallas_grouped_matmul"),
                    ("mesh",)),
     ("sharding",   (f"{PKG}.parallel.tensor", f"{PKG}.parallel.ring_attention",
                     f"{PKG}.parallel.pipeline"),

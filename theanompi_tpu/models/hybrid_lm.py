@@ -8,7 +8,9 @@ layer's letter in ``pattern`` (the Nemotron-H family's
   recurrent state per sequence, no K/V;
 - ``E``: dropless top-k latent experts with a shared expert
   (:class:`theanompi_tpu.ops.moe.DroplessMoE`), of which this process may
-  hold a share (``experts_held``);
+  hold a share (``experts_held``); its grouped products go through
+  ``lax.ragged_dot`` or, where a serving engine resolves so, the kernel of
+  :mod:`theanompi_tpu.ops.pallas_grouped_matmul`;
 - ``*``: causal attention with grouped K/V heads and no positional term
   (:class:`theanompi_tpu.ops.attention.GroupedQueryAttention`): paged K/V.
 
@@ -29,6 +31,7 @@ has no backward here.  Device scopes: ``embed``, ``mamba``, ``moe.route``,
 
 from __future__ import annotations
 
+import dataclasses
 import types
 
 import jax
@@ -105,6 +108,24 @@ class HybridLM(Model):
         #: (param-tree name, kind) per layer, in order
         self.layers = [(f"{i:02d}_{_KINDS[c]}", _KINDS[c])
                        for i, c in enumerate(cfg["pattern"])]
+
+    @property
+    def expert_layer(self):
+        """The ``E`` layers' mixer (they share one), None without an ``E``."""
+        kinds = [k for _, k in self.layers]
+        return self._mixers["moe"] if "moe" in kinds else None
+
+    @property
+    def expert_products(self) -> int:
+        """Grouped products in one forward pass: two an ``E`` layer."""
+        return 2 * [k for _, k in self.layers].count("moe")
+
+    def set_expert_products(self, products: str) -> None:
+        """What multiplies in the expert layers from now on
+        (:class:`DroplessMoE`'s ``products``).  A serving engine calls this
+        once, before it builds a program."""
+        self._mixers["moe"] = dataclasses.replace(self._mixers["moe"],
+                                                  products=products)
 
     def build_data(self):
         # no dataset: serving draws its own ids; the CLI reads ``vocab``
@@ -257,4 +278,4 @@ class HybridLM(Model):
 
     def resolved_paths(self) -> dict:
         return {"attention": self.attention_impl(self.config["seq_len"]),
-                "experts": "ragged_dot", "mamba": "jax"}
+                "experts": self._mixers["moe"].products, "mamba": "jax"}

@@ -36,6 +36,7 @@ from jax import lax
 
 from theanompi_tpu.ops import initializers as init_lib
 from theanompi_tpu.ops import layers as L
+from theanompi_tpu.ops import pallas_grouped_matmul as gmm
 from theanompi_tpu.parallel.mesh import MODEL_AXIS
 from theanompi_tpu.parallel.tensor import axis_bound
 
@@ -230,10 +231,23 @@ class DroplessMoE(L.Layer):
     ``shared=False`` give the two parts apart, for tests that add shares up).
 
     The expert products are grouped matrix products: the ``N * top_k``
-    assignments are sorted by expert and ``jax.lax.ragged_dot`` multiplies
-    each expert's rows by that expert's weights, so the work is that of the
-    rows there are and no ``[N, E, ...]`` mask is built.  Assignments to
-    absent experts sort last, past every group, and carry weight zero.
+    assignments are sorted by expert and each expert's rows are multiplied
+    by that expert's weights, so the work is that of the rows there are and
+    no ``[N, E, ...]`` mask is built.  Assignments to absent experts sort
+    last, past every group, and are discarded by selection.
+
+    ``products`` says what multiplies: ``"ragged_dot"``
+    (``jax.lax.ragged_dot``: the default, what every CPU run and every shape
+    the kernel's gate refuses takes), ``"kernel"``
+    (:func:`theanompi_tpu.ops.pallas_grouped_matmul.grouped_matmul` compiled
+    for the TPU: each held expert's weights read once, no read for an expert
+    without a row) or ``"kernel_interpret"`` (the same kernel through the
+    Pallas interpreter: the parity tests).  A serving engine resolves it
+    once from its ``decode_kernel`` argument and
+    :func:`~theanompi_tpu.ops.pallas_grouped_matmul.grouped_matmul_supported`
+    (latent and expert widths whole numbers of 128, bf16), where it resolves
+    the decode-attention path.  The kernel leaves rows past the last group
+    unwritten, so nothing here may multiply them by zero to drop them.
     """
 
     dim: int
@@ -244,6 +258,7 @@ class DroplessMoE(L.Layer):
     shared_dim: int
     route_scale: float = 1.0
     experts_held: tuple[int, int] | None = None
+    products: str = "ragged_dot"
 
     @property
     def held(self) -> tuple[int, int]:
@@ -282,6 +297,22 @@ class DroplessMoE(L.Layer):
             w = self.route_scale * w / jnp.sum(w, axis=-1, keepdims=True)
             return idx.astype(jnp.int32), w
 
+    def _grouped_dot(self, sizes, m: int, dtype):
+        """-> ``dot(rows [m, K], w [E, K, N], out dtype)`` over the groups
+        ``sizes`` by the path ``products`` names; the kernel's schedule is
+        made once for the layer's two products."""
+        if self.products == "ragged_dot":
+            return lambda rows, w, out: lax.ragged_dot(
+                rows, w, sizes, preferred_element_type=out)
+        if self.products not in ("kernel", "kernel_interpret"):
+            raise ValueError(f"DroplessMoE products={self.products!r} not in "
+                             f"('ragged_dot', 'kernel', 'kernel_interpret')")
+        tm = gmm.row_tile(m, dtype)
+        visits = gmm.group_visits(sizes, m, tm)
+        return lambda rows, w, out: gmm.grouped_matmul(
+            rows, w, sizes, tm=tm, out_dtype=out, visits=visits,
+            interpret=self.products == "kernel_interpret")
+
     def apply_tokens(self, params, u, *, routed: bool = True,
                      shared: bool = True, active=None):
         """``u`` ``[N, D]`` -> (out ``[N, D]``, stats).  ``stats``:
@@ -304,14 +335,15 @@ class DroplessMoE(L.Layer):
                 order = jnp.argsort(eid, stable=True)
                 sizes = jnp.bincount(eid, length=e_held + 1)[:e_held]
                 rows = jnp.take(lat, order // self.top_k, axis=0)
-                sizes = sizes.astype(jnp.int32)
-                h = lax.ragged_dot(rows, params["w1"].astype(u.dtype), sizes)
+                dot = self._grouped_dot(sizes.astype(jnp.int32),
+                                        rows.shape[0], u.dtype)
+                h = dot(rows, params["w1"].astype(u.dtype), u.dtype)
                 h = jnp.square(jax.nn.relu(h.astype(jnp.float32)))
-                y = lax.ragged_dot(h.astype(u.dtype),
-                                   params["w2"].astype(u.dtype), sizes,
-                                   preferred_element_type=jnp.float32)
+                y = dot(h.astype(u.dtype), params["w2"].astype(u.dtype),
+                        jnp.float32)
                 # back to token order; rows past the last group belong to
-                # no expert and carry weight 0
+                # no expert (the kernel never writes them) and are selected
+                # away below, not multiplied away
                 y = jnp.take(y, jnp.argsort(order), axis=0).reshape(
                     n, self.top_k, self.latent)
                 r = jnp.sum(jnp.where(is_held[..., None], y * w[..., None],

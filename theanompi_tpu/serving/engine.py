@@ -50,6 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from theanompi_tpu.ops.pallas_grouped_matmul import grouped_matmul_supported
 from theanompi_tpu.ops.pallas_paged_attention import paged_decode_supported
 from theanompi_tpu.ops.quant import int8_matmul_supported
 from theanompi_tpu.serving.kv_cache import PagedKVCache, blocks_for
@@ -137,9 +138,31 @@ class InferenceEngine:
         #: interpreter, the off-TPU "on" mode the parity locks run) or
         #: "fallback".  SERVE.json and the serve.decode_kernel gauge
         #: report whether the kernel tier is active.
-        self.decode_impl = (
-            ("kernel" if on_tpu else "kernel_interpret")
-            if use_kernel else "fallback")
+        kernel = "kernel" if on_tpu else "kernel_interpret"
+        self.decode_impl = kernel if use_kernel else "fallback"
+        #: resolved expert-product variant — "kernel", "kernel_interpret",
+        #: "ragged_dot", or None for a model without an expert layer
+        self.expert_impl = None
+        #: the ``moe_products`` / ``moe_kernel_products`` tags of
+        #: ``serve.decode`` and ``serve.prefill``: grouped products in a
+        #: program, and those of them the kernel runs
+        self._moe_tags: dict = {}
+        # the expert layer's grouped products, where the model has such a
+        # layer, by the same argument and a gate of their own (ISSUE 28);
+        # the engine sets its model's path, once, before it builds a program
+        experts = getattr(model, "expert_layer", None)
+        if experts is not None:
+            widths = (experts.latent, experts.expert_dim)
+            use_grouped = decode_kernel == "on" or (
+                decode_kernel == "auto" and on_tpu and all(
+                    grouped_matmul_supported(k, n,
+                                             model.precision.compute_dtype)
+                    for k, n in (widths, widths[::-1])))
+            self.expert_impl = kernel if use_grouped else "ragged_dot"
+            model.set_expert_products(self.expert_impl)
+            n = model.expert_products
+            self._moe_tags = {"moe_products": n,
+                              "moe_kernel_products": n if use_grouped else 0}
         # int8 leaves the fused matmul can consume stay quantized inside
         # the decode step; the rest (odd-vocab head, MoE stacks)
         # dequantize as before.  None = dequantize everything.
@@ -212,6 +235,8 @@ class InferenceEngine:
         always dequantizes), and the attention path of every prefill
         bucket compiled so far."""
         out: dict = {"decode_attention": self.decode_impl}
+        if self.expert_impl is not None:
+            out["expert_products"] = self.expert_impl
         if self.quantized:
             leaves = [leaf for leaf in jax.tree.leaves(
                 self.params, is_leaf=_is_quantized) if _is_quantized(leaf)]
@@ -357,7 +382,7 @@ class InferenceEngine:
         # the padded length (of the uncached part) that picks the program
         with spans.span(_SPAN_PREFILL, request=rid, prompt=p,
                         bucket=self.pad_len(p - prefix_len),
-                        prefix_len=prefix_len):
+                        prefix_len=prefix_len, **self._moe_tags):
             if prefix_len:
                 if self._state:
                     raise ValueError(
@@ -434,7 +459,8 @@ class InferenceEngine:
         lengths = np.asarray(lengths)
         active = np.flatnonzero(lengths)
         with spans.span(_SPAN_DECODE, step=self.n_decodes, batch=len(active),
-                        requests=np.asarray(rids)[active].tolist()) as span:
+                        requests=np.asarray(rids)[active].tolist(),
+                        **self._moe_tags) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):
                 args = (jnp.asarray(tables, jnp.int32),

@@ -69,7 +69,9 @@ SERVE_SPANS = ("serve.prefill", "serve.decode")
 #: padded length that picks the program, ``prefix_len``; ``serve.decode``
 #: tags: ``step`` — the engine's decode ordinal, ``batch``, ``requests``;
 #: from a model that counts them on the device (``HybridLM``) also the two
-#: of :data:`SERVE_DECODE_MOE_TAGS`, fetched with the step's tokens).
+#: of :data:`SERVE_DECODE_MOE_TAGS`, fetched with the step's tokens; both
+#: spans carry :data:`SERVE_MOE_PRODUCT_TAGS` where the model has an expert
+#: layer).
 #: ``serve.step`` is one ``Scheduler.step`` (tags ``step``, ``batch``);
 #: ``serve.admit`` the admission pass inside it (tag ``queued``; the
 #: per-request ``serve.admit`` INSTANT keeps its name and kind).
@@ -86,21 +88,33 @@ SERVE_DECODE_SPANS = ("serve.decode.place", "serve.decode.dispatch",
 #: ``moe_load_peak`` — the most assignments any held expert received in
 #: one expert layer of the step (the longest group of the grouped product)
 SERVE_DECODE_MOE_TAGS = ("moe_local_hits", "moe_load_peak")
+#: tags of ``serve.decode`` AND ``serve.prefill`` from an engine whose model
+#: has an expert layer (ISSUE 28), the same in every call since the path is
+#: resolved once: ``moe_products`` — grouped products in the program (two
+#: an ``E`` layer); ``moe_kernel_products`` — those of them the Pallas
+#: kernel runs (all of them, or 0 where ``lax.ragged_dot`` does)
+SERVE_MOE_PRODUCT_TAGS = ("moe_products", "moe_kernel_products")
 #: ``jax.named_scope`` names on the DEVICE (op metadata: they name rows of a
 #: profiler trace, not ring records), by who opens them.  The engine:
 #: ``recast`` (weight casts / dequantize), ``sample``; ``TransformerLM``:
 #: ``embed``, ``block`` > ``attn`` / ``mlp``, ``head``, ``loss``; the
 #: trainer: ``clip``, ``exchange``, ``optimizer``; the kernels name
 #: themselves (``paged_decode``, ``flash_fwd``, ``flash_bwd_dq``,
-#: ``flash_bwd_dkv``); ``HybridLM``: ``embed``, ``mamba`` (a Mamba-2
-#: mixer, projections and state update), ``moe.route`` (router, top-k),
-#: ``moe.experts`` (latent projections, sort, grouped products),
-#: ``moe.shared`` (the shared expert), ``attn``, ``head``
+#: ``flash_bwd_dkv``, and ``grouped_matmul``: the expert layer's grouped
+#: product, ``ops/pallas_grouped_matmul.py``, whose custom call reads
+#: ``custom-call/grouped_matmul`` under ``moe.experts`` in a trace — the
+#: ``lax.ragged_dot`` it replaces carries no ``op_name`` and reads as
+#: unscoped ``custom-call/ragged-dot-none``); ``HybridLM``: ``embed``,
+#: ``mamba`` (a Mamba-2 mixer, projections and state update),
+#: ``moe.route`` (router, top-k), ``moe.experts`` (latent projections,
+#: sort, the kernel's schedule, grouped products), ``moe.shared`` (the
+#: shared expert), ``attn``, ``head``
 DEVICE_SCOPES = {
     "engine": ("recast", "sample"),
     "TransformerLM": ("embed", "block", "attn", "mlp", "head", "loss"),
     "trainer": ("clip", "exchange", "optimizer"),
-    "kernels": ("paged_decode", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+    "kernels": ("paged_decode", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                "grouped_matmul"),
     "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
                  "attn", "head"),
 }
