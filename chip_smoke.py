@@ -68,7 +68,7 @@ LM = "theanompi_tpu.models.transformer_lm"
 RESNET = "theanompi_tpu.models.resnet50"
 
 #: model widths per mode: (lm train, resnet train, served lm, serve flags,
-#: parity geometry).  The chip widths are the r4 bench's trainer configs
+#: parity geometry).  The chip widths are round r4's trainer configs
 #: and the narrowest server the paged-decode gate admits in bf16
 #: (heads % 16 == 0, head_dim % 128 == 0).
 WIDTHS = {

@@ -4,7 +4,7 @@ The smoke's real run needs the chip and goes through the chip tool; what
 tier-1 can lock on the CPU is the contract around it:
 
 - with no accelerator (this sandbox) it exits non-zero, says why in one
-  line and prints no result — and so does ``bench.py``;
+  line and prints no result;
 - alone in a directory it exits non-zero;
 - its parent process is stdlib-only (a parent that has touched jax holds
   the chip and its children cannot get it);
@@ -64,15 +64,6 @@ def test_smoke_parent_is_stdlib_only():
     assert roots <= set(sys.stdlib_module_names) | {"__future__"}, roots
 
 
-def test_bench_without_a_chip_fails_with_one_line():
-    out = _run([sys.executable, os.path.join(REPO, "bench.py")],
-               JAX_PLATFORMS="cpu")
-    assert out.returncode != 0
-    assert out.stdout.strip() == ""  # no number of any kind
-    lines = [ln for ln in out.stderr.splitlines() if ln.startswith("bench:")]
-    assert len(lines) == 1 and "platform=cpu" in lines[0]
-
-
 def test_smoke_cpu_rehearsal_runs_every_phase(tmp_path):
     """The one explicit CPU rehearsal: tmlauncher (LM with the flash
     kernels interpreted, ResNet-50), tmserve plain and int8 with the
@@ -104,7 +95,7 @@ def test_parents_and_workers_import_without_opening_a_backend():
     code = (
         "import theanompi_tpu.launcher, theanompi_tpu.serving.cli\n"
         "import theanompi_tpu.resilience.replica, theanompi_tpu.fleet.jobs\n"
-        "import theanompi_tpu.router.cli, bench\n"
+        "import theanompi_tpu.router.cli\n"
         # what a spawned loader worker imports (shm_loader._worker)
         "import theanompi_tpu.models.data.shm_loader\n"
         "import theanompi_tpu.models.data.imagenet\n"

@@ -22,7 +22,7 @@ from theanompi_tpu.parallel.mesh import DATA_AXIS
 
 #: every strategy whose plain exchange() computes a mean (zero1 fuses the
 #: exchange into the optimizer update — covered by the train-step matrix;
-#: 'none' deliberately skips the mean, see test_scaling.py)
+#: 'none' deliberately skips the mean, see test_none_strategy_skips_exchange)
 MEAN_STRATEGIES = sorted(
     (set(STRATEGIES) | set(BUCKETED_STRATEGIES)) - {"none", "zero1"}
 )
@@ -70,6 +70,13 @@ def test_strategy_computes_mean(mesh4, strategy):
         np.testing.assert_allclose(out[i], expect, rtol=tol, atol=tol)
     for i in range(1, 4):
         np.testing.assert_array_equal(out[i], out[0])
+
+
+def test_none_strategy_skips_exchange(mesh8):
+    """'none' must leave per-worker grads unreduced (replicas diverge)."""
+    vals = np.arange(8 * 3, dtype=np.float32).reshape(8, 3)
+    out = _run_exchange(mesh8, "none", jnp.asarray(vals))
+    np.testing.assert_array_equal(out, vals)  # untouched, NOT the mean
 
 
 @pytest.mark.parametrize("strategy", ["ring", "psum", "ring_int8"])

@@ -3,11 +3,11 @@
 Pure-python on synthetic records plus the repo's own committed
 artifacts: classification of every known artifact shape, fingerprint
 idempotence, the torn-tail crash contract, trailing-median regression
-verdicts in both metric directions, stub-run exclusion from baselines,
-the ``tmprof --ledger`` exit contract (0 clean / 1 regression / 2
-usage), and the bench.py append hook.  The acceptance fixture seeds a
-throughput collapse and must exit 1; the repo's real backfilled
-artifacts must exit 0.
+verdicts in both metric directions, value-less records kept out of
+baselines, and the ``tmprof --ledger`` exit contract (0 clean / 1
+regression / 2 usage).  The acceptance fixture seeds a throughput
+collapse and must exit 1; the repo's real backfilled artifacts must
+exit 0.
 """
 
 import json
@@ -53,38 +53,13 @@ def test_lower_is_better_inference():
     assert lower_is_better("serve.ttft_p99_ms", "ms")
     assert lower_is_better("attrib.train.step_ms", "ms")
     assert not lower_is_better("bench.imgs_per_sec", "images/sec")
-    assert not lower_is_better("mfu_ladder.d256xL4.mfu", "mfu")
-    assert not lower_is_better("scaling.wrn.psum.n8.efficiency",
-                               "efficiency")
+    assert not lower_is_better("train.mfu", "mfu")
+    assert not lower_is_better("converge.wrn.margin", "margin")
 
 
 # -- artifact classification --------------------------------------------------
 
-def test_classify_bench_wrapper_and_stub():
-    ok = {"n": 1, "cmd": "x", "rc": 0,
-          "parsed": {"metric": "imgs_per_sec", "value": 2481.0,
-                     "unit": "images/sec", "run_id": "r03",
-                     "step_ms": 103.2, "mfu": 0.299}}
-    recs = classify_artifact("BENCH_r03.json", ok)
-    by_metric = {r["metric"]: r for r in recs}
-    assert by_metric["imgs_per_sec"]["value"] == 2481.0
-    assert by_metric["imgs_per_sec.step_ms"]["unit"] == "ms"
-    assert by_metric["imgs_per_sec.mfu"]["value"] == 0.299
-    # rc!=0 / unparsed rounds become stub records, never baselines
-    bad = {"n": 4, "cmd": "x", "rc": 1, "tail": "boom", "parsed": None}
-    (rec,) = classify_artifact("BENCH_r04.json", bad)
-    assert rec["kind"] == "backend_unavailable" and rec["value"] is None
-
-
-def test_classify_scaling_and_attrib():
-    scaling = {"model": "wrn", "strategy": "psum",
-               "per_n": {"2": {"imgs_per_sec": 100.0, "step_ms": 20.0,
-                               "efficiency": 0.9},
-                         "1": {"imgs_per_sec": 55.0}}}
-    recs = classify_artifact("SCALING.json", scaling)
-    metrics = [r["metric"] for r in recs]
-    assert "scaling.wrn.psum.n1.imgs_per_sec" in metrics
-    assert "scaling.wrn.psum.n2.efficiency" in metrics
+def test_classify_attrib():
     attrib = {"pid": 7, "per_rank": {"0": {
         "mode": "train", "wall_step": {"p50_ms": 12.5},
         "segments": {"compute": {"share": 0.8},
@@ -97,9 +72,8 @@ def test_classify_scaling_and_attrib():
 
 
 def test_classify_serve_report():
-    """SERVE.json carries top-level metric/value like a bare bench line —
-    the SERVE branch must win (basename precedence) so the nested latency
-    percentiles and the ISSUE 17 prefix-cache accounting are kept."""
+    """SERVE.json: throughput, the nested latency percentiles and the
+    ISSUE 17 prefix-cache accounting all enter the trajectory."""
     serve = {"metric": "serve_tokens_per_sec", "value": 812.5,
              "unit": "tokens/sec", "run_id": "r9",
              "ttft_ms": {"p50": 11.0, "p99": 30.5},
@@ -135,44 +109,6 @@ def test_classify_serve_report():
     # direction inference: hit rate and tokens saved improve upward
     assert not lower_is_better("serve.prefix_hit_rate", "rate")
     assert not lower_is_better("serve.prefill_tokens_saved", "tokens")
-
-
-def test_classify_roofline_report():
-    """ROOFLINE*.json (utils/roofline.py): whole-step aggregates enter
-    the trajectory; per-op rows stay out (fusion boundaries rename them
-    every compiler bump).  Label prefers the payload's ``model``, falling
-    back to the filename stem — ROOFLINE_transformer_32k.json ships
-    without a model key."""
-    roof = {"steps_profiled": 4, "device_step_ms": 97.8,
-            "time_share_at_half_roof": 0.97,
-            "time_share_at_80pct_roof": 0.85,
-            "model": "resnet50", "platform": "tpu",
-            "ops": [{"op": "fusion.1", "time_ms_per_step": 3.2}]}
-    by_metric = {r["metric"]: r for r in
-                 classify_artifact("ROOFLINE.json", roof)}
-    assert set(by_metric) == {
-        "roofline.resnet50.device_step_ms",
-        "roofline.resnet50.time_share_at_half_roof",
-        "roofline.resnet50.time_share_at_80pct_roof"}
-    assert by_metric["roofline.resnet50.device_step_ms"]["value"] == 97.8
-    assert by_metric["roofline.resnet50.device_step_ms"]["unit"] == "ms"
-    assert all(r["kind"] == "roofline" for r in by_metric.values())
-    # no per-op records ever
-    assert not any("fusion" in m for m in by_metric)
-    # model-less artifact: the filename stem names the trajectory
-    no_model = {k: v for k, v in roof.items() if k != "model"}
-    stems = {r["metric"] for r in classify_artifact(
-        "ROOFLINE_transformer_32k.json", no_model)}
-    assert "roofline.transformer_32k.device_step_ms" in stems
-    assert {r["metric"] for r in classify_artifact(
-        "ROOFLINE.json", no_model)} == {
-        "roofline.default.device_step_ms",
-        "roofline.default.time_share_at_half_roof",
-        "roofline.default.time_share_at_80pct_roof"}
-    # direction inference: step time down, roof-proximity shares up
-    assert lower_is_better("roofline.resnet50.device_step_ms", "ms")
-    assert not lower_is_better(
-        "roofline.resnet50.time_share_at_half_roof", "share")
 
 
 def test_classify_unknown_shape_yields_nothing():
@@ -262,20 +198,19 @@ def test_single_point_insufficient_history(tmp_path):
     assert v["baseline"] is None and v["delta_pct"] is None
 
 
-def test_stub_runs_never_enter_baselines(tmp_path):
+def test_valueless_records_never_enter_baselines(tmp_path):
     path = str(tmp_path / LEDGER_FILENAME)
     led = _seed(path, "m", [100.0, 100.0])
-    led.append([make_record("BENCH_r04.json", "backend_unavailable",
-                            None, None, run_id="r04")])
+    led.append([make_record("SERVE.json", "serve", "m", None,
+                            run_id="r04")])
     led.append([make_record("s", "bench", "m", 99.0, "images/sec",
                             run_id="r5")])
     traj = trajectories(led.records())
     assert list(traj) == ["m"] and len(traj["m"]) == 3
     (v,) = led.check()
-    assert v["verdict"] == "ok"  # the stub is not a 0-valued baseline
-    # but the log keeps the stub as the gap's witness
-    assert sum(1 for r in led.records()
-               if r["kind"] == "backend_unavailable") == 1
+    assert v["verdict"] == "ok"  # the gap is not a 0-valued baseline
+    # but the log keeps the record as the gap's witness
+    assert sum(1 for r in led.records() if r["value"] is None) == 1
 
 
 def test_trailing_window_bounds_baseline(tmp_path):
@@ -298,7 +233,9 @@ def test_check_records_empty():
 def test_backfill_repo_artifacts_idempotent(tmp_path):
     led = PerfLedger(str(tmp_path / LEDGER_FILENAME))
     written = led.backfill(REPO)
-    assert len(written) >= 10, "repo artifacts did not classify"
+    # CONVERGE.json is the one committed report the ledger reads
+    assert {r["kind"] for r in written} == {"converge"}
+    assert len(written) >= 6, "repo artifacts did not classify"
     assert led.backfill(REPO) == []  # fingerprint-idempotent
     assert not regressions(led.check()), \
         "repo's own artifacts must not read as a regression"
@@ -338,11 +275,10 @@ def test_tmprof_check_json(tmp_path, capsys):
 
 
 def test_tmprof_update_and_show(tmp_path, capsys):
-    art = tmp_path / "BENCH_r01.json"
+    art = tmp_path / "SERVE.json"
     art.write_text(json.dumps(
-        {"n": 1, "cmd": "x", "rc": 0,
-         "parsed": {"metric": "imgs_per_sec", "value": 100.0,
-                    "unit": "images/sec", "run_id": "r1"}}))
+        {"metric": "serve_tokens_per_sec", "value": 100.0,
+         "unit": "tokens/sec", "run_id": "r1"}))
     path = str(tmp_path / "l.jsonl")
     rc = prof.main(["--ledger", "update", str(art), "--ledger-path", path])
     assert rc == 0
@@ -350,7 +286,7 @@ def test_tmprof_update_and_show(tmp_path, capsys):
     assert os.path.exists(str(tmp_path / "TMPROF_LEDGER.json"))
     rc = prof.main(["--ledger", "show", "--ledger-path", path])
     assert rc == 0
-    assert "imgs_per_sec" in capsys.readouterr().out
+    assert "serve.tokens_per_sec" in capsys.readouterr().out
 
 
 def test_tmprof_ledger_usage_errors(tmp_path, capsys):
@@ -367,10 +303,10 @@ def test_tmprof_ledger_usage_errors(tmp_path, capsys):
 
 
 def test_tmprof_backfill_cli(tmp_path, capsys):
-    art = tmp_path / "SCALING.json"
+    art = tmp_path / "CONVERGE.json"
     art.write_text(json.dumps(
-        {"model": "wrn", "strategy": "psum",
-         "per_n": {"1": {"imgs_per_sec": 50.0}}}))
+        {"results": [{"model": "wrn", "target_error": 0.5,
+                      "best_val_error": 0.4}]}))
     path = str(tmp_path / "l.jsonl")
     rc = prof.main(["--ledger", "backfill", str(tmp_path),
                     "--ledger-path", path])

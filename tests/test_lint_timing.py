@@ -5,7 +5,7 @@ The ad-hoc regex walker that lived here moved into the rule registry as
 original test name green (bisectability) and proves the ported rule
 still catches the negative case it was born from.  Coverage is the rule
 engine's default path set: the whole package (serving/ and resilience/
-included) plus ``bench.py``.
+included).
 """
 
 from theanompi_tpu.analysis import core

@@ -222,7 +222,7 @@ def test_fused_lm_xent_vocab_parallel_matches_unsharded(unroll):
 def test_fused_lm_xent_unroll_exact_match():
     """unroll>1 is a scheduling hint, not a numerics change: loss, metrics,
     and all grads must be bit-comparable to the unroll=1 scan (r5 knob for
-    the while-self-time share in ROOFLINE_transformer_32k.json).  Also
+    the fused-loss scans' while-self-time share).  Also
     covers the non-divisible case (4 chunks, unroll=3)."""
     from theanompi_tpu.ops.losses import fused_lm_xent
 

@@ -183,7 +183,7 @@ def test_pp2_tp2_with_fused_vocab_parallel_loss():
 def test_scan_unroll_matches_rolled():
     """layers_unroll/loss_unroll are scheduling hints: multi-step training
     must track the rolled (unroll=1) run on identical inits (r5 knobs for
-    the while-self-time share in ROOFLINE_transformer_32k.json).
+    the scans' while-self-time share).
 
     loss_unroll is exercised on the base TransformerLM (its only scans are
     the fused-loss chunk scans); layers_unroll on PipelineTransformerLM —

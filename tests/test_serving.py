@@ -452,7 +452,7 @@ def test_tmserve_cli_end_to_end(tmp_path, capsys):
     assert rc == 0
     report = json.load(open(out))
     assert report["requests"] == 3 and report["value"] > 0
-    # the one-JSON-line stdout contract (same as bench.py)
+    # the one-JSON-line stdout contract
     line = [ln for ln in capsys.readouterr().out.splitlines()
             if ln.startswith("{")][-1]
     assert json.loads(line)["metric"] == "serve_tokens_per_sec"

@@ -10,6 +10,7 @@ guards above a rebinding, lazy cycle-breaking imports).
 """
 
 import json
+import os
 
 import pytest
 
@@ -37,7 +38,7 @@ def run_src(tmp_path, source, rules=None, rel="fx.py"):
 
 
 def test_package_runs_clean_under_the_full_rule_set():
-    """Zero unsuppressed findings over theanompi_tpu/ + bench.py with
+    """Zero unsuppressed findings over theanompi_tpu/ with
     every registered rule on — the ISSUE 7 acceptance criterion.  Every
     suppression in the tree must carry its justification (the meta rule
     fires otherwise and shows up right here)."""
@@ -46,6 +47,21 @@ def test_package_runs_clean_under_the_full_rule_set():
     assert n_files > 70, f"suspiciously small scan: {n_files}"
     assert not offenders, "tmlint findings in the tree:\n" + \
         "\n".join(offenders)
+
+
+def test_default_path_set_is_the_package(tmp_path):
+    """With no path arguments tmlint scans the package and nothing beside
+    it: a root script is linted only when named."""
+    pkg = tmp_path / "theanompi_tpu" / "sub"
+    pkg.mkdir(parents=True)
+    (pkg / "m.py").write_text("x = 1\n")
+    (tmp_path / "theanompi_tpu" / "__init__.py").write_text("")
+    (tmp_path / "root_script.py").write_text("x = 1\n")
+    rels = [os.path.relpath(p, str(tmp_path))
+            for p in core.default_paths(str(tmp_path))]
+    assert rels == ["theanompi_tpu/__init__.py", "theanompi_tpu/sub/m.py"]
+    assert all(os.path.relpath(p, core.REPO_ROOT).startswith("theanompi_tpu/")
+               for p in core.default_paths())
 
 
 def test_registry_has_the_advertised_rules():

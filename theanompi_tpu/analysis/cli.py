@@ -32,7 +32,7 @@ def build_parser() -> argparse.ArgumentParser:
                     "(rule registry + compiled-artifact auditor)",
         allow_abbrev=False)
     p.add_argument("paths", nargs="*",
-                   help="files to lint (default: the package + bench.py)")
+                   help="files to lint (default: the package)")
     p.add_argument("--rules", default=None,
                    help="comma-separated rule subset (default: all)")
     p.add_argument("--list-rules", action="store_true",

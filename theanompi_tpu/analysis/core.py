@@ -30,7 +30,7 @@ import os
 import re
 from typing import Callable, Iterable, Iterator
 
-#: repository root (the directory holding ``theanompi_tpu/`` and bench.py)
+#: repository root (the directory holding ``theanompi_tpu/``)
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -191,8 +191,7 @@ def all_rules() -> dict[str, type[Rule]]:
 
 
 def default_paths(root: str = REPO_ROOT) -> list[str]:
-    """What ``tmlint`` scans with no path arguments: the package and the
-    bench entrypoint — the exact coverage the legacy test lints had."""
+    """What ``tmlint`` scans with no path arguments: the package."""
     paths = []
     pkg = os.path.join(root, "theanompi_tpu")
     for dirpath, dirnames, filenames in os.walk(pkg):
@@ -200,9 +199,6 @@ def default_paths(root: str = REPO_ROOT) -> list[str]:
         for f in sorted(filenames):
             if f.endswith(".py"):
                 paths.append(os.path.join(dirpath, f))
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        paths.append(bench)
     return paths
 
 

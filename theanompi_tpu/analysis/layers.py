@@ -282,7 +282,7 @@ def _package_modules(root: str) -> set[str]:
 
 def _file_module(rel: str) -> str | None:
     """Dotted module name of a repo-relative path, None outside the
-    package (bench.py etc. carry no layer)."""
+    package (root scripts carry no layer)."""
     if not rel.startswith(PKG + "/") or not rel.endswith(".py"):
         return None
     mod = rel[:-3].replace("/", ".")

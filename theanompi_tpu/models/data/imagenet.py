@@ -241,8 +241,9 @@ class ImageNetData(Dataset):
         config = config or {}
         self.image_size = config.get("image_size", 224)
         # host-side parallelism: one process cannot feed a v5e chip
-        # (LOADER.json: single-thread load+crop ~1.2k img/s vs ~2.5k
-        # demand), so train shards fan out over a fork pool.  0 = inline.
+        # (single-thread load+crop ~1.2k img/s vs ~2.5k demand, measured
+        # in round r3; not re-measured), so train shards fan out over a
+        # fork pool.  0 = inline.
         self.loader_workers = int(config.get("loader_workers", 0))
         path = config.get("data_path") or os.environ.get("IMAGENET_PATH")
         if path and os.path.isdir(os.path.join(path, "train")):

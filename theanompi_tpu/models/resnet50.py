@@ -34,9 +34,10 @@ class _Bottleneck(L.Layer):
     save-only-conv-outputs policy: the backward recomputes the elementwise
     BN-normalize/ReLU chain from the saved conv outputs instead of reading
     stored post-activation tensors.  On a bandwidth-bound step (ResNet-50
-    at batch 256 — ROOFLINE.json proves 85% of time at ≥80% of the HBM
-    roof) stored-activation traffic is throughput, and the recompute is
-    elementwise work that fuses into reads the backward performs anyway.
+    at batch 256 — 85% of time at ≥80% of the HBM roof, measured in round
+    r3 under jax 0.4.3x; not re-measured) stored-activation traffic is
+    throughput, and the recompute is elementwise work that fuses into
+    reads the backward performs anyway.
     This is a BYTES lever, not a memory-capacity lever — full-block remat
     (recompute convs too) would re-materialize intermediates to HBM twice
     and lose."""
@@ -140,9 +141,10 @@ class _SpaceToDepthStem(L.Layer):
     """The 7×7/2 stem conv, math-identical but MXU-shaped (MLPerf trick).
 
     A 7×7 stride-2 conv on ``[H, W, 3]`` runs the MXU at 3 input channels
-    — measured 16% utilization, 0.59 of the HBM roof (ROOFLINE.json
-    fusion.903).  Rearranging 2×2 pixel blocks into channels
-    (space-to-depth) and the zero-padded 8×8 kernel into ``[4, 4, 12, F]``
+    — 16% utilization, 0.59 of the HBM roof (measured in round r3 under
+    jax 0.4.3x; not re-measured).  Rearranging 2×2 pixel blocks into
+    channels (space-to-depth) and the zero-padded 8×8 kernel into
+    ``[4, 4, 12, F]``
     gives the SAME linear map as a stride-1 conv with asymmetric padding
     (2, 1): output[i,j] = Σ_a,b xpad[2i-4+a, 2j-4+b]·Kpad[a,b] with
     Kpad[0,·]=Kpad[·,0]=0 reproduces the original Σ x[2i-3+a']·K[a']

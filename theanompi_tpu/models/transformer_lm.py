@@ -218,10 +218,11 @@ class TransformerLM(SupervisedModel):
         # "auto": pallas flash attention when shapes allow (TPU-compiled,
         # interpreted on CPU); "blockwise"/"pallas" force a path
         "attn_impl": "auto",
-        # lax.scan unroll factors — the V=32k roofline attributes ~27 % of
-        # the step to while self-time (ROOFLINE_transformer_32k.json), all
-        # of it the fused-loss chunk scans in this base model (the trunk
-        # is a Python-loop Sequential, not a scan).  loss_unroll lets XLA
+        # lax.scan unroll factors — a per-op profile at V=32k attributed
+        # ~27 % of the step to while self-time (measured in round r4 under
+        # jax 0.4.3x; not re-measured), all of it the fused-loss chunk
+        # scans in this base model (the trunk is a Python-loop
+        # Sequential, not a scan).  loss_unroll lets XLA
         # software-pipeline the loss chunks; layers_unroll applies ONLY to
         # PipelineTransformerLM's stacked-layer scan.  1 = the r4 behavior.
         "layers_unroll": 1,

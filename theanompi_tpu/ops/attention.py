@@ -34,8 +34,8 @@ def resolve_attn_impl(impl: str, t: int, head_dim: int) -> str:
 
     ``'auto'`` = pallas flash kernels on TPU when the shape gate admits
     them (elsewhere interpret mode would be pure slowdown).  Shared with
-    bench.py's artifact reporting so the recorded ``attention_impl`` can't
-    drift from the gate the model actually applies (code-review r5).
+    the models' ``attention_impl`` report so the recorded path can't drift
+    from the gate the model actually applies (code-review r5).
     """
     if impl == "auto":
         from theanompi_tpu.ops.pallas_attention import (

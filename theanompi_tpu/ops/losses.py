@@ -203,10 +203,11 @@ def fused_lm_xent(h: jax.Array, w: jax.Array, b: jax.Array | None,
     within ~4% of the naive [N, V]-materializing path's speed while
     keeping O(N) memory).  N is zero-padded to the chunk and masked, so
     no divisibility is required of the caller.  ``unroll`` feeds the
-    chunk scans (fwd + custom bwd) — the V=32k profile attributes ~27 %
+    chunk scans (fwd + custom bwd) — a V=32k profile attributed ~27 %
     of the LM step to ``while`` self-time (carry/slice overhead and
-    inter-iteration stalls, ROOFLINE_transformer_32k.json), which
-    unrolling lets XLA software-pipeline away at the cost of code size.
+    inter-iteration stalls; measured in round r4 under jax 0.4.3x, not
+    re-measured), which unrolling lets XLA software-pipeline away at the
+    cost of code size.
     """
     v = w.shape[-1]
     h3, y2, mask2, n = _chunk_and_pad(h, labels, v, chunk_tokens)

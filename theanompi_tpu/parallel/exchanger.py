@@ -151,10 +151,10 @@ def register_strategy(name: str):
 def _no_exchange(x: jax.Array, axis_name: str, axis_size: int) -> jax.Array:
     """No-op strategy: skip the collective entirely.
 
-    Replicas diverge — NOT for training.  Exists for the scaling harness's
-    differential comm measurement (step time with vs. without the exchange
-    is the honest comm-share proxy when the collective is fused into one
-    XLA program and invisible to host-side timers).
+    Replicas diverge — NOT for training.  Exists for a differential comm
+    measurement (step time with vs. without the exchange is the honest
+    comm-share proxy when the collective is fused into one XLA program and
+    invisible to host-side timers).
     """
     return x
 

@@ -72,7 +72,7 @@ DEFAULT_COMPILE_CACHE = os.path.join(
 def setup_compile_cache() -> str:
     """Place JAX's persistent compilation cache; -> its directory.
 
-    One rule for every entry point (launcher, tmserve, bench, scaling):
+    One rule for every entry point (launcher, tmserve, chip_smoke):
     where ``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and
     this sets no other directory; where it is not, the cache lives in
     :data:`DEFAULT_COMPILE_CACHE`.  Call before the first compile — jax

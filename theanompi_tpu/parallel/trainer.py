@@ -479,14 +479,12 @@ class BaseTrainer:
 
     def compiled_step(self, batch):
         """The compiled train-step executable (serves ``.cost_analysis()``
-        and ``.as_text()`` for bench/roofline tooling without each caller
-        re-deriving the argument tuple).
+        to the ``train.mfu`` gauge and ``.as_text()`` to ``tmlint
+        --hlo-audit`` without each caller re-deriving the argument tuple).
 
         Memoized on the batch's shapes/dtypes (lowering is shape-based):
         ``lower().compile()`` is a full second XLA compile, which the
-        telemetry MFU probe must not pay inside the train loop — and
-        roofline's compiled_step + compiled_step_text pair now compiles
-        once instead of twice."""
+        telemetry MFU probe must not pay inside the train loop."""
         import jax.numpy as jnp
 
         key = jax.tree.map(lambda x: (tuple(x.shape), str(x.dtype)), batch)
@@ -500,7 +498,7 @@ class BaseTrainer:
         return exe
 
     def compiled_step_text(self, batch) -> str:
-        """HLO text of the compiled train step (roofline/bench tooling)."""
+        """HLO text of the compiled train step (``tmlint --hlo-audit``)."""
         return self.compiled_step(batch).as_text()
 
     def post_step(self) -> None:
@@ -513,7 +511,7 @@ class BaseTrainer:
     def warmup(self) -> None:
         """Run every compiled path once, then reset to a fresh init.
 
-        Timing harnesses (bench, rulecomp) call this so their measured
+        Timing harnesses (rulecomp) call this so their measured
         window excludes XLA compilation: jit compiles at first call, not at
         ``compile_iter_fns`` (which only builds the jit wrappers).
         """

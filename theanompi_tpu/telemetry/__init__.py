@@ -1,9 +1,9 @@
 """Unified telemetry layer (ISSUE 1): structured spans, collective byte
 accounting, live training metrics.
 
-Three fragments existed before this package — the Recorder's host splits,
-the bounded ``jax.profiler`` window, and per-round bench JSON — none of
-which emitted structured events.  This package is the common substrate:
+Two fragments existed before this package — the Recorder's host splits
+and the bounded ``jax.profiler`` window — neither of which emitted
+structured events.  This package is the common substrate:
 
 - :mod:`~theanompi_tpu.telemetry.spans` — the process's always-on span
   ring (ISSUE 25): every hot loop opens its spans there, once; a record

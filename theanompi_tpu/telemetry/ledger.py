@@ -1,10 +1,11 @@
 """Durable perf-regression ledger (ISSUE 16).
 
-Every perf artifact this repo produces — the ``BENCH_*.json`` rounds,
-``SCALING.json``, ``EXCHANGE*``/``SERVE.json`` reports, each run's
-``ATTRIB.json`` — is a write-once snapshot: round 3's 2481 images/sec
-says nothing about whether round 6 regressed.  :class:`PerfLedger` turns
-them into one append-only trajectory:
+Every report a run of this program leaves behind — ``tmserve``'s
+``SERVE.json``, ``tmrouter``'s ``ROUTER.json``, each telemetry run's
+``ATTRIB.json``, the convergence gate's ``CONVERGE.json`` — is a
+write-once snapshot: one run's number says nothing about whether the next
+one regressed.  :class:`PerfLedger` turns them into one append-only
+trajectory:
 
 - ``TMPROF_LEDGER.jsonl`` — one normalized record per measurement,
   appended (never rewritten) with a line-granular crash contract: a torn
@@ -17,8 +18,7 @@ them into one append-only trajectory:
   vs the trailing median of the previous ``window`` points, with the
   tolerance stated in the verdict.  Direction is inferred from the unit
   (``ms`` down is good, ``/sec``/``mfu``/``efficiency`` up is good).
-  ``backend_unavailable`` stub runs are *recorded* (the trajectory shows
-  the gap) but never enter a baseline and never regress.
+  A record without a metric or a value never enters a baseline.
 
 Consumers: ``tmprof --ledger`` drives update/check/backfill from the
 CLI, and the HealthMonitor's ``perf`` detector surfaces regressions as
@@ -45,13 +45,9 @@ SNAPSHOT_FILENAME = "TMPROF_LEDGER.json"
 DEFAULT_WINDOW = 5
 DEFAULT_TOLERANCE = 0.10
 
-#: artifact glob patterns backfill() ingests, in trajectory order —
-#: sorted() within a pattern keeps BENCH_r01..r05 chronological
-BACKFILL_PATTERNS = ("BENCH_r*.json", "BENCH_mfu_ladder.json",
-                     "BENCH_transformer.json",
-                     "SCALING*.json", "EXCHANGE*.json", "SERVE*.json",
-                     "ROUTER*.json",
-                     "ROOFLINE*.json", "ATTRIB.json", "CONVERGE*.json")
+#: artifact glob patterns backfill() ingests, in trajectory order
+BACKFILL_PATTERNS = ("SERVE*.json", "ROUTER*.json", "ATTRIB.json",
+                     "CONVERGE*.json")
 
 #: unit substrings that mean lower-is-better; everything else (rates,
 #: mfu, efficiency, shares) improves upward
@@ -96,29 +92,6 @@ def lower_is_better(metric: str, unit: str) -> bool:
 
 # -- artifact classifiers ----------------------------------------------------
 
-def _bench_line_records(source: str, line: dict,
-                        prefix: str = "") -> list[dict]:
-    """Records out of one bench primary-output dict (the ``{"metric":
-    ..., "value": ...}`` line bench.py prints and re-publishes)."""
-    metric = line.get("metric")
-    if metric is None:
-        return []
-    recs = [make_record(source, "bench", prefix + metric,
-                        line.get("value"), line.get("unit", ""),
-                        run_id=line.get("run_id"),
-                        vs_baseline=line.get("vs_baseline"))]
-    if line.get("step_ms") is not None:
-        recs.append(make_record(source, "bench",
-                                f"{prefix}{metric}.step_ms",
-                                line["step_ms"], "ms",
-                                run_id=line.get("run_id")))
-    if line.get("mfu") is not None:
-        recs.append(make_record(source, "bench", f"{prefix}{metric}.mfu",
-                                line["mfu"], "mfu",
-                                run_id=line.get("run_id")))
-    return recs
-
-
 def classify_artifact(name: str, payload: dict) -> list[dict]:
     """Normalize one known artifact into ledger records.
 
@@ -129,17 +102,7 @@ def classify_artifact(name: str, payload: dict) -> list[dict]:
         return []
     base = os.path.basename(name)
     run_id = payload.get("run_id")
-    # BENCH_rNN.json: a driver wrapper {n, cmd, rc, tail, parsed}
-    if "parsed" in payload and "rc" in payload:
-        parsed = payload.get("parsed")
-        if not parsed or payload.get("rc"):
-            return [make_record(base, "backend_unavailable", None, None,
-                                run_id=run_id, rc=payload.get("rc"))]
-        return _bench_line_records(base, parsed)
-    # SERVE.json: bench.py serve-mode report.  MUST precede the bare
-    # bench-line branch — serve_report() also carries top-level
-    # ``metric``/``value``, and the generic branch would swallow it,
-    # dropping the latency percentiles and prefix-cache accounting.
+    # SERVE.json: the tmserve report (serve_report()).
     if base.startswith("SERVE"):
         recs = []
         tps = payload.get("value", payload.get("tokens_per_sec"))
@@ -177,10 +140,7 @@ def classify_artifact(name: str, payload: dict) -> list[dict]:
                                             payload[field], unit,
                                             run_id=run_id))
         return recs
-    # ROUTER.json: the tmrouter multi-replica report (ISSUE 19).  Same
-    # trap as SERVE — it carries top-level ``metric``/``value``, so it
-    # MUST precede the bare bench-line branch or the TTFT percentiles
-    # and replica-count trajectory would be dropped.
+    # ROUTER.json: the tmrouter multi-replica report (ISSUE 19).
     if base.startswith("ROUTER"):
         recs = []
         tps = payload.get("value", payload.get("tokens_per_sec"))
@@ -222,85 +182,6 @@ def classify_artifact(name: str, payload: dict) -> list[dict]:
                 float(target) - float(best), "margin", run_id=run_id,
                 rule=row.get("rule"), passed=row.get("passed"),
                 epochs_to_target=row.get("epochs_to_target")))
-        return recs
-    # BENCH_transformer.json / a bare bench line
-    if "metric" in payload and "value" in payload:
-        return _bench_line_records(base, payload)
-    # BENCH_mfu_ladder.json: {what, rows: [{dim, n_layers, batch, ...}]}
-    if base.startswith("BENCH_") and isinstance(payload.get("rows"), list):
-        recs = []
-        for row in payload["rows"]:
-            if not isinstance(row, dict):
-                continue
-            key = f"mfu_ladder.d{row.get('dim')}xL{row.get('n_layers')}"
-            if row.get("tokens_per_sec") is not None:
-                recs.append(make_record(base, "bench",
-                                        f"{key}.tokens_per_sec",
-                                        row["tokens_per_sec"], "tokens/sec",
-                                        run_id=run_id))
-            if row.get("mfu") is not None:
-                recs.append(make_record(base, "bench", f"{key}.mfu",
-                                        row["mfu"], "mfu", run_id=run_id))
-        return recs
-    # SCALING.json: {model, strategy, per_n: {n: {...}}}
-    if "per_n" in payload:
-        recs = []
-        model = payload.get("model", "model")
-        strat = payload.get("strategy", "")
-        for n, row in sorted(payload["per_n"].items(),
-                             key=lambda kv: int(kv[0])):
-            if not isinstance(row, dict):
-                continue
-            key = f"scaling.{model}.{strat}.n{n}"
-            for field, unit in (("imgs_per_sec", "images/sec"),
-                                ("efficiency", "efficiency"),
-                                ("step_ms", "ms")):
-                if row.get(field) is not None:
-                    recs.append(make_record(base, "scaling",
-                                            f"{key}.{field}", row[field],
-                                            unit, run_id=run_id))
-        return recs
-    # EXCHANGE*.json: {strategy -> {ms_per_exchange, ...}} or rows
-    if base.startswith("EXCHANGE"):
-        recs = []
-        rows = payload.get("rows")
-        items = (enumerate(rows) if isinstance(rows, list)
-                 else payload.items())
-        for key, row in items:
-            if not isinstance(row, dict):
-                continue
-            label = row.get("strategy", key)
-            for field in ("ms_per_exchange", "ms", "gbps"):
-                if row.get(field) is not None:
-                    unit = "ms" if "ms" in field else "gbps"
-                    recs.append(make_record(base, "exchange",
-                                            f"exchange.{label}.{field}",
-                                            row[field], unit,
-                                            run_id=run_id))
-        return recs
-    # ROOFLINE*.json: utils/roofline.py per-op roofline report.  Only the
-    # whole-step aggregates enter the trajectory — per-op rows churn with
-    # every fusion-boundary change and would drown check() in renames.
-    if isinstance(payload.get("ops"), list) and "device_step_ms" in payload:
-        label = payload.get("model")
-        if not label:
-            stem = base[:-5] if base.endswith(".json") else base
-            label = (stem[len("ROOFLINE_"):]
-                     if stem.startswith("ROOFLINE_") else "default")
-        recs = []
-        if payload.get("device_step_ms") is not None:
-            recs.append(make_record(base, "roofline",
-                                    f"roofline.{label}.device_step_ms",
-                                    payload["device_step_ms"], "ms",
-                                    run_id=run_id))
-        # roof-proximity shares: the fraction of step time spent at
-        # >= half / >= 80% of the relevant roof — up is good
-        for field in ("time_share_at_half_roof", "time_share_at_80pct_roof"):
-            if payload.get(field) is not None:
-                recs.append(make_record(base, "roofline",
-                                        f"roofline.{label}.{field}",
-                                        payload[field], "share",
-                                        run_id=run_id))
         return recs
     # ATTRIB.json: per-run attribution summary (telemetry/profile.py)
     if "per_rank" in payload:
@@ -348,13 +229,10 @@ def read_ledger(path: str) -> list[dict]:
 
 
 def trajectories(records: list[dict]) -> dict[str, list[dict]]:
-    """metric -> append-ordered measurable points.  Stub runs
-    (``backend_unavailable``) carry no metric and drop out here — they
-    stay in the log as the gap's witness but never enter a baseline."""
+    """metric -> append-ordered measurable points.  A record without a
+    metric or a value stays in the log but never enters a baseline."""
     out: dict[str, list[dict]] = {}
     for rec in records:
-        if rec.get("kind") == "backend_unavailable":
-            continue
         metric, value = rec.get("metric"), rec.get("value")
         if metric is None or value is None:
             continue
@@ -482,10 +360,6 @@ class PerfLedger:
             return []
         return self.append(classify_artifact(path, payload))
 
-    def ingest(self, source: str, payload: dict) -> list[dict]:
-        """Classify + append an in-memory artifact."""
-        return self.append(classify_artifact(source, payload))
-
     def check(self, tolerance: float = DEFAULT_TOLERANCE,
               window: int = DEFAULT_WINDOW) -> list[dict]:
         return check_records(self.records(), tolerance, window)
@@ -502,8 +376,6 @@ class PerfLedger:
             "updated": time.time(),  # lint: wall-ok — cross-process stamp
             "ledger": os.path.basename(self.path),
             "n_records": len(records),
-            "n_stub_runs": sum(1 for r in records
-                               if r.get("kind") == "backend_unavailable"),
             "verdicts": verdicts,
         }
         tmp = f"{path}.tmp.{os.getpid()}"

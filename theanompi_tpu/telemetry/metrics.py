@@ -5,11 +5,10 @@ observing a histogram sample is a dict update, never device work or I/O.
 ``snapshot()`` is called at flush boundaries (``print_freq`` in the
 trainer) and its dict rides one ``metrics`` event through the sink.
 
-MFU reuses the repo's existing FLOP accounting rather than re-deriving it:
-``step_flops_estimate`` asks XLA's cost analysis through the trainer's
-``compiled_step`` hook (the same source ``bench.py`` uses for conv nets)
-and ``peak_flops`` reads :data:`DEVICE_PEAKS`, the one table of published
-chip peaks (bench.py and utils/roofline.py read it too).
+The ``train.mfu`` gauge derives nothing itself: ``step_flops_estimate``
+asks XLA's cost analysis through the trainer's ``compiled_step`` hook and
+``peak_flops`` reads :data:`DEVICE_PEAKS`, this package's table of
+published chip peaks.
 """
 
 from __future__ import annotations
@@ -35,9 +34,9 @@ def hlo_collective_counts(hlo_text: str) -> dict[str, int]:
     step must compile to O(buckets) ``all-reduce`` ops, not O(leaves) — and
     ``zero1`` must show its ``reduce-scatter``/``all-gather`` pair.  Works
     on any backend, so CPU-mesh tests lint collective counts without TPU
-    hardware (``tests/test_lint_collectives.py``); the exchange
-    microbenchmark (``utils/scaling.py --exchange-bench``) reports the same
-    numbers per strategy.  Async ``-start``/``-done`` pairs count once;
+    hardware (``tests/test_lint_collectives.py``); ``tmlint --hlo-audit``
+    reports the same numbers per compiled step.  Async ``-start``/``-done``
+    pairs count once;
     operand references never carry parens, so only definitions match.
     """
     counts: dict[str, int] = {}
@@ -388,7 +387,7 @@ class MetricsRegistry:
 #: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s
 #: int8, 16 GB of HBM at 819 GB/s.  It lists the devices this repo has run
 #: on and nothing else: a kind that is not here is an error, not a default
-#: peak (bench.py, utils/roofline.py and the train.mfu gauge all read it).
+#: peak (the train.mfu gauge reads it).
 DEVICE_PEAKS = {
     "TPU v5 lite": {"bf16_tflops": 197.0, "int8_tops": 393.0,
                     "hbm_gbps": 819.0, "hbm_gb": 16.0},
@@ -424,10 +423,10 @@ def peak_flops() -> float | None:
 def step_flops_estimate(trainer, batch) -> float | None:
     """FLOPs per train step from XLA's cost analysis of the compiled step.
 
-    Same accounting (and same caveats — Pallas custom-calls count zero,
-    scan bodies count once) as ``bench.step_flops``; scaled by ``n_subb``
-    for gradient accumulation exactly as bench does.  Returns None when
-    cost analysis is unavailable; callers then simply omit MFU.
+    Caveats of that accounting: Pallas custom-calls count zero and scan
+    bodies count once.  Scaled by ``n_subb`` for gradient accumulation.
+    Returns None when cost analysis is unavailable; callers then simply
+    omit MFU.
     """
     try:
         fl = float(trainer.compiled_step(batch).cost_analysis()

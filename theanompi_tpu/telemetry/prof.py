@@ -12,7 +12,7 @@ run::
 
 Ledger mode drives ``TMPROF_LEDGER.jsonl`` (``telemetry/ledger.py``)::
 
-    tmprof --ledger update BENCH_r06.json SERVE.json
+    tmprof --ledger update SERVE.json ./telemetry/ATTRIB.json
     tmprof --ledger check               # exit 1 on any regression
     tmprof --ledger backfill .          # one-shot ingest of repo artifacts
     tmprof --ledger show                # per-metric trajectories

@@ -1,4 +1,5 @@
-"""Utilities: recorder, checkpointing, helper functions.
+"""Utilities: recorder, checkpointing, helper functions, the cross-replica
+divergence check and the convergence gates (``converge``, ``rulecomp``).
 
 Reference (unverified — SURVEY.md §2.1): ``theanompi/lib/recorder.py`` and
 ``theanompi/lib/helper_funcs.py``.
