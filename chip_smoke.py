@@ -22,7 +22,12 @@ supports, with depth and run length cut and weights random from a seed:
                     ``PagedKVCache.attend_decode``'s pure-JAX path, at
                     layer 1 of a two-layer pool (the kernel's layer index
                     is checked too).
-6. ``multichip``    only where jax reports >= 4 chips: tmlauncher
+6. ``state_update_parity`` one decode step of a Mamba-2 layer at the served
+                    state geometry (128 heads in 8 groups, 64 x 128 float32
+                    a head) through the state-update kernel and through
+                    ``Mamba2.decode``'s plain lines, at layer 1 of a
+                    two-layer pool.
+7. ``multichip``    only where jax reports >= 4 chips: tmlauncher
                     ``--devices 4`` on both training models and
                     ``__graft_entry__.py dryrun 4`` on the real devices;
                     all four must hold shards.
@@ -68,7 +73,7 @@ LM = "theanompi_tpu.models.transformer_lm"
 RESNET = "theanompi_tpu.models.resnet50"
 
 #: model widths per mode: (lm train, resnet train, served lm, serve flags,
-#: parity geometry).  The chip widths are round r4's trainer configs
+#: parity geometries).  The chip widths are round r4's trainer configs
 #: and the narrowest server the paged-decode gate admits in bf16
 #: (heads % 16 == 0, head_dim % 128 == 0).
 WIDTHS = {
@@ -85,6 +90,8 @@ WIDTHS = {
         "parity": dict(heads=16, head_dim=128, block_size=16, max_batch=8,
                        max_context=2048, dtype="bfloat16",
                        decode_impl="kernel"),
+        "state_parity": dict(heads=128, groups=8, head_dim=64, state=128,
+                             max_batch=4, impl="kernel"),
         "expect": {"platform": "tpu", "attention": "pallas",
                    "decode": "kernel"},
     },
@@ -104,6 +111,8 @@ WIDTHS = {
         "parity": dict(heads=2, head_dim=32, block_size=4, max_batch=2,
                        max_context=32, dtype="float32",
                        decode_impl="kernel_interpret"),
+        "state_parity": dict(heads=8, groups=2, head_dim=8, state=128,
+                             dim=32, max_batch=2, impl="kernel_interpret"),
         "expect": {"platform": "cpu", "attention": "pallas_interpret",
                    "decode": "kernel_interpret"},
     },
@@ -333,26 +342,55 @@ def serve_phase(name: str, mode: str, int8: bool, device: dict,
     }
 
 
-def parity_phase(name: str, mode: str, device: dict,
-                 deadline: float) -> dict:
-    kw = dict(WIDTHS[mode]["parity"])
-    dtype = kw.pop("dtype")
+def _parity_child(name: str, imports: str, call: str, device: dict,
+                  deadline: float) -> tuple[dict, float]:
+    """Run one kernel-against-plain-lines check in a fresh child: ``call``
+    (an expression, after ``imports``) gives the check's report, a dict
+    with ``ok``; -> (the report, wall seconds), on the expected device."""
     code = (
         "import json, jax, jax.numpy as jnp\n"
         "from theanompi_tpu.parallel.mesh import device_summary, "
         "setup_compile_cache\n"
-        "from theanompi_tpu.serving.kv_cache import decode_parity\n"
+        f"{imports}\n"
         "setup_compile_cache()\n"
-        f"res = decode_parity(dtype=jnp.{dtype}, **{kw!r})\n"
+        f"res = {call}\n"
         "print(json.dumps({'device': device_summary(), **res}))\n")
     out, wall = run_child(name, [sys.executable, "-c", code], deadline)
     res = json.loads(out.strip().splitlines()[-1])
     check_device(name, res.pop("device"), device)
+    return res, wall
+
+
+def parity_phase(name: str, mode: str, device: dict,
+                 deadline: float) -> dict:
+    kw = dict(WIDTHS[mode]["parity"])
+    dtype = kw.pop("dtype")
+    res, wall = _parity_child(
+        name, "from theanompi_tpu.serving.kv_cache import decode_parity",
+        f"decode_parity(dtype=jnp.{dtype}, **{kw!r})", device, deadline)
     if not res["ok"]:
         raise PhaseFailed(
             f"{name}: kernel vs PagedKVCache.attend_decode fallback: max "
             f"abs err {res['max_abs_err']:.3g} > tolerance "
             f"{res['tolerance']:.3g} (finite={res['finite']})")
+    return {**res, "child_wall_s": round(wall, 1)}
+
+
+def state_parity_phase(name: str, mode: str, device: dict,
+                       deadline: float) -> dict:
+    res, wall = _parity_child(
+        name, "from theanompi_tpu.ops.mamba2 import state_update_parity",
+        f"state_update_parity(**{WIDTHS[mode]['state_parity']!r})",
+        device, deadline)
+    if not res["ok"]:
+        raise PhaseFailed(
+            f"{name}: state update through {res['state_update']!r} vs "
+            f"Mamba2.decode's plain lines: output max abs err "
+            f"{res['max_abs_err_out']:.3g} (tolerance "
+            f"{res['tolerance_out']:.3g}), state "
+            f"{res['max_abs_err_state']:.3g} (tolerance "
+            f"{res['tolerance_state']:.3g}), finite={res['finite']}, other "
+            f"layer unchanged={res['other_layer_unchanged']}")
     return {**res, "child_wall_s": round(wall, 1)}
 
 
@@ -402,6 +440,7 @@ def main(argv: list[str] | None = None) -> int:
             ("serve_bf16", serve_phase, (mode, False)),
             ("serve_int8", serve_phase, (mode, True)),
             ("decode_parity", parity_phase, (mode,)),
+            ("state_update_parity", state_parity_phase, (mode,)),
         ]
         if device["count"] >= 4:
             phases += [

@@ -67,8 +67,9 @@ def test_smoke_parent_is_stdlib_only():
 def test_smoke_cpu_rehearsal_runs_every_phase(tmp_path):
     """The one explicit CPU rehearsal: tmlauncher (LM with the flash
     kernels interpreted, ResNet-50), tmserve plain and int8 with the
-    paged-decode and fused-int8 kernels interpreted, and the decode
-    parity check — each a fresh child, none of it a result for the chip."""
+    paged-decode and fused-int8 kernels interpreted, and the decode and
+    state-update parity checks — each a fresh child, none of it a result
+    for the chip."""
     # one CPU device: the session's 8 virtual devices would add the
     # multichip phases (they run on the four-chip host, not in tier-1)
     out = _run([sys.executable, SMOKE, "--rehearse-cpu"], timeout=900,
@@ -78,12 +79,13 @@ def test_smoke_cpu_rehearsal_runs_every_phase(tmp_path):
     assert last == {"rehearsal": True, "ok": True,
                     "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
     for phase in ("lm_train", "resnet_train", "serve_bf16", "serve_int8",
-                  "decode_parity"):
+                  "decode_parity", "state_update_parity"):
         assert f"chip_smoke: {phase} ok on platform=cpu" in out.stdout
     assert "multichip phases skipped" in out.stdout
     assert '"attention": "pallas_interpret"' in out.stdout
     assert '"decode_attention": "kernel_interpret"' in out.stdout
     assert '"decode_dequantized": 0' in out.stdout
+    assert '"state_update": "kernel_interpret"' in out.stdout
 
 
 #: a platform name no backend answers to: ``jax.devices()`` — any backend

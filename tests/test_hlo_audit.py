@@ -9,6 +9,8 @@ pure_callback IS detected, an undonated step IS detected — the auditor
 must be falsifiable, not a rubber stamp.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -176,13 +178,29 @@ def test_expert_products_audit():
     assert all(c["via"] == "ragged_dot" for c in r["products_off"])
 
 
+def test_state_update_audit():
+    """ISSUE 30: with the kernel resolved, both state layers of a small
+    ``HybridLM``'s decode program are ``mamba_state_update`` calls on the
+    whole float32 pool — the donated argument, then the previous call's
+    pool — aliased to their output; the plain lines leave no such call."""
+    r = hlo_audit.audit_state_update()
+    assert r["ok"], r["violations"]
+    assert r["expected"] == 2 and r["pool_shape"] == [2, 2, 4, 32, 128]
+    assert r["resolved_kernel"] == "kernel" and r["resolved_plain"] == "plain"
+    assert r["calls_kernel"] == [
+        {"pool": r["pool_shape"], "pool_from": "parameter", "aliased": True},
+        {"pool": r["pool_shape"], "pool_from": "state_update",
+         "aliased": True}]
+    assert r["calls_plain"] == []
+
+
 def test_run_default_audits_is_green():
     reports = hlo_audit.run_default_audits()
     assert [(r["kind"], r.get("strategy")) for r in reports] == [
         ("train", "psum_bucket"), ("train", "zero1"),
         ("train-overlap", "psum_bucket"), ("train-overlap", "zero1"),
         ("serve", None), ("serve-prefill", None), ("serve-kernel", None),
-        ("serve-experts", None)]
+        ("serve-experts", None), ("serve-state", None)]
     assert all(r["ok"] for r in reports)
 
 
@@ -292,6 +310,54 @@ def test_auditor_detects_a_copied_expert_stack_before_the_kernel(
                for v in r["violations"]) is (made_by != "parameter")
 
 
+@pytest.mark.parametrize("cut,made_by,aliased", [
+    (lambda pool: pool, "parameter", True),
+    (lambda pool: pool[1:], "slice", True),
+    (lambda pool: pool * 1.0, "multiply", True),
+    (lambda pool: pool, "parameter", False),
+])
+def test_auditor_detects_a_copied_or_unaliased_state_pool(
+        cut, made_by, aliased, monkeypatch):
+    """ISSUE 30's guard has teeth: a slice or a copy of the pool in front
+    of the state update reads as made by that op, a call without the alias
+    reads as not aliased, and the audit lists either as a violation."""
+    from theanompi_tpu.ops import pallas_state_update as psu
+
+    z = functools.partial(jnp.zeros, dtype=jnp.float32)
+    pool, a, dtx, bc = z((3, 2, 4, 8, 128)), z((2, 4)), z((2, 4, 8)), \
+        z((2, 2, 128))
+
+    def step(pool, a, dtx, bc):
+        return psu.state_update(cut(pool), 0, a, dtx, bc, bc, interpret=False)
+
+    text = jax.jit(step).trace(pool, a, dtx, bc) \
+        .lower(lowering_platforms=("tpu",)).as_text()
+    if not aliased:
+        text = text.replace("output_tuple_indices = [0], operand_index = 4",
+                            "output_tuple_indices = [1], operand_index = 4")
+    (call,) = hlo_audit.state_update_calls(text)
+    assert call["pool_from"] == made_by and call["aliased"] is aliased
+
+    facts = dict(hlo_audit._state_update_artifact())
+    facts["calls_kernel"] = [dict(facts["calls_kernel"][0], pool_from=made_by,
+                                  aliased=aliased)] + facts["calls_kernel"][1:]
+    monkeypatch.setattr(hlo_audit, "_state_update_artifact", lambda: facts)
+    r = hlo_audit.audit_state_update()
+    assert r["ok"] is (made_by == "parameter" and aliased)
+    assert any("copied on the chip" in v
+               for v in r["violations"]) is (made_by != "parameter")
+    assert any("second pool" in v for v in r["violations"]) is (not aliased)
+
+
+def test_a_state_layer_left_to_the_plain_lines_is_a_violation(monkeypatch):
+    facts = dict(hlo_audit._state_update_artifact())
+    facts["calls_kernel"] = facts["calls_kernel"][:1]
+    monkeypatch.setattr(hlo_audit, "_state_update_artifact", lambda: facts)
+    r = hlo_audit.audit_state_update()
+    assert not r["ok"] and any("1 mamba_state_update call(s) for 2" in v
+                               for v in r["violations"])
+
+
 def test_a_ragged_dot_left_in_the_kernel_on_program_is_a_violation(
         monkeypatch):
     facts = dict(hlo_audit._expert_products_artifact())
@@ -318,7 +384,7 @@ def test_budget_violation_surfaces_in_report(monkeypatch):
     # the tightened psum_bucket TRAIN lock fails — the overlap audits
     # have their own invariants and stay green
     assert [rep["ok"] for rep in ei.value.reports] == [
-        False, True, True, True, True, True, True, True]
+        False, True, True, True, True, True, True, True, True]
 
 
 def test_train_cfg_matches_the_locked_fixture():

@@ -83,7 +83,9 @@ def test_a_padded_bucket_leaves_the_unpadded_prompts_state(tiny, t, bucket):
         np.testing.assert_allclose(np.asarray(s_pad[name], np.float32),
                                    np.asarray(s[name], np.float32),
                                    atol=1e-6, rtol=1e-6)
-    step, _ = mixer.decode(p, u[t:], jax.tree.map(lambda a: a[None], s_pad))
+    # one state layer, one slot: the pools whole, as a server hands them over
+    step, _ = mixer.decode(p, u[t:],
+                           jax.tree.map(lambda a: a[None, None], s_pad), 0)
     want = ref.mamba(cfg, ref._ein("fp32"), leaves, u)[t]
     # the convolution state is kept in bf16
     np.testing.assert_allclose(step[0], want, atol=3e-3, rtol=3e-2)

@@ -790,12 +790,27 @@ def grouped_product_calls(stablehlo_text: str) -> list[dict]:
     return calls
 
 
+def _hybrid_decode_tpu_text(eng) -> str:
+    """The StableHLO text of a ``HybridLM`` engine's decode program, traced
+    here and lowered for the TPU (never compiled or run)."""
+    import jax
+    import jax.numpy as jnp
+
+    b = eng.max_batch
+    args = (eng.params, eng._k, eng._v,
+            jnp.zeros((b, eng.max_blocks_per_seq), jnp.int32),
+            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+            jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
+            eng._base_key, eng._state)
+    return jax.jit(eng._decode_impl, donate_argnums=eng._donate) \
+        .trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+
 @functools.lru_cache(maxsize=None)
 def _expert_products_artifact() -> dict:
     """The decode program of a small ``HybridLM`` lowered for the TPU with
     the grouped-product kernel pinned on and with it off."""
     import jax
-    import jax.numpy as jnp
 
     from theanompi_tpu.models.hybrid_lm import HybridLM
     from theanompi_tpu.serving.engine import InferenceEngine
@@ -810,15 +825,8 @@ def _expert_products_artifact() -> dict:
             # pin the COMPILED kernel (off-TPU "on" resolves to the
             # interpreter): what a TPU host would lower
             model.set_expert_products("kernel")
-        b = eng.max_batch
-        args = (eng.params, eng._k, eng._v,
-                jnp.zeros((b, eng.max_blocks_per_seq), jnp.int32),
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b,), jnp.float32), jnp.zeros((b,), jnp.int32),
-                eng._base_key, eng._state)
-        text = jax.jit(eng._decode_impl, donate_argnums=eng._donate) \
-            .trace(*args).lower(lowering_platforms=("tpu",)).as_text()
-        facts[f"products_{variant}"] = grouped_product_calls(text)
+        facts[f"products_{variant}"] = grouped_product_calls(
+            _hybrid_decode_tpu_text(eng))
     layer = model.expert_layer
     e = layer.held[1] - layer.held[0]
     facts["expected"] = model.expert_products
@@ -859,14 +867,127 @@ def audit_expert_products() -> dict:
             "violations": violations, **facts}
 
 
+# -- recurrent-state layer: the decode state update (ISSUE 30) ----------------
+
+#: :data:`SERVE_EXPERT_CFG` with a state the state-update kernel's gate
+#: admits (``state_size`` a whole number of 128 lanes)
+SERVE_STATE_CFG = {**SERVE_EXPERT_CFG, "state_size": 128}
+
+_SHLO_STATE_CALL = re.compile(
+    r"(%[\w#]+)(?::\d+)? = stablehlo\.custom_call @tpu_custom_call"
+    r"\(([^)]*)\).*kernel_name = \"mamba_state_update\".* : \(([^)]*)\) -> ")
+_SHLO_ALIAS = re.compile(r"output_operand_alias<output_tuple_indices = "
+                         r"\[(\d*)\], operand_index = (\d+)")
+
+
+def state_update_calls(stablehlo_text: str) -> list[dict]:
+    """Every ``mamba_state_update`` call in a TPU lowering's StableHLO
+    text; -> one record per call: ``{"pool": the state-pool operand's shape
+    (the call's last operand), "pool_from": "parameter" when that operand
+    is an argument of the program itself, "state_update" when it is the
+    pool an earlier such call returned, else the op that made it,
+    "aliased": the call's first output is that operand's buffer}``.
+
+    The pool has to reach the kernel whole: a ``slice`` of one layer (or
+    anything else) in front of the call is a copy of it on the chip ahead
+    of every call, and a call whose output does not alias the pool writes a
+    second pool (:mod:`theanompi_tpu.ops.pallas_state_update`)."""
+    calls, defs, pools = [], {}, set()
+    for line in stablehlo_text.splitlines():
+        if "func.func" in line:
+            defs, pools = {}, set()  # SSA names are per function
+        m = _SHLO_DEF.match(line)
+        if m:
+            defs[m.group(1)] = m.group(2)
+        c = _SHLO_STATE_CALL.search(line)
+        if not c:
+            continue
+        name = [n.strip() for n in c.group(2).split(",")][-1]
+        kinds = [t.strip() for t in c.group(3).split(", ")]
+        pools.add(f"{c.group(1)}#0")
+        calls.append({
+            "pool": [int(n) for n in
+                     kinds[-1][len("tensor<"):].split("x")[:-1]],
+            "pool_from": ("parameter" if name.startswith("%arg")
+                          else "state_update" if name in pools
+                          else defs.get(name.split("#")[0], "unknown")),
+            "aliased": ("0", str(len(kinds) - 1))
+            in _SHLO_ALIAS.findall(line)})
+    return calls
+
+
+@functools.lru_cache(maxsize=None)
+def _state_update_artifact() -> dict:
+    """The decode program of a small ``HybridLM`` lowered for the TPU with
+    the state-update kernel pinned on (a CPU host resolves the plain lines)
+    and as this host resolves it."""
+    import jax
+
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.ops.mamba2 import pin_state_update
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    facts: dict = {}
+    for variant in ("kernel", "plain"):
+        with pin_state_update(variant):
+            model = HybridLM(dict(SERVE_STATE_CFG))
+            params, _ = model.init_params(jax.random.PRNGKey(0))
+            eng = InferenceEngine(model, params, block_size=8, max_batch=2,
+                                  decode_kernel="off")
+            facts[f"calls_{variant}"] = state_update_calls(
+                _hybrid_decode_tpu_text(eng))
+        facts[f"resolved_{variant}"] = eng.state_update_impl
+    facts["expected"] = model.cache_spec()["state_layers"]
+    facts["pool_shape"] = list(eng._state["ssm"].shape)
+    return facts
+
+
+def audit_state_update() -> dict:
+    """Audit the decode step's recurrent-state update (ISSUE 30): with the
+    kernel resolved, every state layer of the decode program is one
+    ``mamba_state_update`` custom call whose pool operand is the whole
+    ``[L, B, H, P, N]`` float32 pool — the program's own donated argument
+    for the first layer, the pool the previous call returned for the next,
+    nothing sliced or copied in front — and whose output aliases it; with
+    the plain lines resolved there is no such call."""
+    facts = _state_update_artifact()
+    violations: list[str] = []
+    on, n = facts["calls_kernel"], facts["expected"]
+    if len(on) != n:
+        violations.append(
+            f"kernel-on decode program has {len(on)} mamba_state_update "
+            f"call(s) for {n} state layer(s) — the kernel is not "
+            f"dispatching for every layer")
+    copied = [c for c in on
+              if c["pool"] != facts["pool_shape"]
+              or c["pool_from"] not in ("parameter", "state_update")]
+    if copied or (on and on[0]["pool_from"] != "parameter"):
+        violations.append(
+            f"{len(copied)} mamba_state_update call(s) take a pool operand "
+            f"that is not the whole {facts['pool_shape']} pool handed from "
+            f"the program's argument ({(copied or on)[:1]}) — a layer of "
+            f"the pool is copied on the chip before every call")
+    loose = [c for c in on if not c["aliased"]]
+    if loose:
+        violations.append(
+            f"{len(loose)} mamba_state_update call(s) do not alias the "
+            f"pool to their output — each writes a second pool")
+    if facts["calls_plain"]:
+        violations.append(
+            f"plain decode program has {len(facts['calls_plain'])} "
+            f"mamba_state_update call(s) — the negative proof failed")
+    return {"kind": "serve-state", "ok": not violations,
+            "violations": violations, **facts}
+
+
 # -- entry point -------------------------------------------------------------
 
 #: what ``tmlint --hlo-audit`` (and the tier-1 test) audits: the two
 #: strategies the acceptance criteria name, their overlapped-schedule
 #: locks (ISSUE 12 — the BASELINE step-7 gate), plus the serve decode,
 #: partial-prefill (prefix-cache hit, ISSUE 17) and decode-kernel
-#: dispatch (ISSUE 18) steps, and the expert layer's grouped products
-#: (ISSUE 28)
+#: dispatch (ISSUE 18) steps, the expert layer's grouped products
+#: (ISSUE 28) and the recurrent-state layer's decode update (ISSUE 30)
 DEFAULT_TRAIN_STRATEGIES = ("psum_bucket", "zero1")
 
 
@@ -905,6 +1026,7 @@ def run_default_audits(n_data: int = 4) -> list[dict]:
     reports.append(audit_serve_prefill())
     reports.append(audit_serve_decode_kernel())
     reports.append(audit_expert_products())
+    reports.append(audit_state_update())
     bad = [r for r in reports if not r["ok"]]
     if bad:
         err = HLOAuditError("; ".join(

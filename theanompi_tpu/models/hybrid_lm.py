@@ -246,10 +246,9 @@ class HybridLM(Model):
             p = cp[name]
             u = self._normed(p, x)
             if kind == "mamba":
-                y, s = self._mixers[kind].decode(
-                    p["mixer"], u, kv_cache.read_state(n_state))
-                with jax.named_scope("mamba"):  # the state's write-back
-                    kv_cache = kv_cache.write_state(n_state, s)
+                y, pools = self._mixers[kind].decode(p["mixer"], u,
+                                                     kv_cache.state, n_state)
+                kv_cache = dataclasses.replace(kv_cache, state=pools)
                 n_state += 1
             elif kind == "moe":
                 y, st = self._mixers[kind].apply_tokens(p["mixer"], u,
@@ -277,5 +276,10 @@ class HybridLM(Model):
                                  self.config["head_dim"])
 
     def resolved_paths(self) -> dict:
-        return {"attention": self.attention_impl(self.config["seq_len"]),
-                "experts": self._mixers["moe"].products, "mamba": "jax"}
+        """``state_update``: what runs an ``M`` layer's decode state update
+        (``kernel`` | ``plain``), where the pattern has such a layer."""
+        paths = {"attention": self.attention_impl(self.config["seq_len"]),
+                 "experts": self._mixers["moe"].products}
+        if "mamba" in [k for _, k in self.layers]:
+            paths["state_update"] = self._mixers["mamba"].state_update_impl()
+        return paths

@@ -24,15 +24,20 @@ Two evaluations of the same recurrence:
   ``true_len`` get ``dt = 0`` (the state passes them unchanged) and the
   convolution state is read at ``true_len``.
 - :meth:`Mamba2.decode`: one token for every slot of a batch, in place on
-  the slots' states.
+  the slots' states in a server's pools.
 
-Plain ``jax.numpy`` / ``lax``: the state update is memory-bound and a Pallas
-kernel for it is later work.  The state is float32; products take the
-input's dtype and accumulate in float32.
+Plain ``jax.numpy`` / ``lax``, but for the decode step's state update,
+which is memory-bound: on a TPU, for a state the gate admits, it is the
+kernel of :mod:`theanompi_tpu.ops.pallas_state_update`
+(:func:`resolve_state_update` chooses from platform and shape, as
+``ops/attention.py::resolve_attn_impl`` does for flash attention).  The
+state is float32; products take the input's dtype and accumulate in
+float32.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -41,6 +46,42 @@ from jax import lax
 
 from theanompi_tpu.ops import initializers as init_lib
 from theanompi_tpu.ops import layers as L
+from theanompi_tpu.ops.pallas_state_update import (
+    state_update,
+    state_update_supported,
+)
+
+#: what :func:`pin_state_update` holds the resolver to, None outside one
+_pinned: str | None = None
+
+
+def resolve_state_update(head_dim: int, state: int, dtype) -> str:
+    """What runs a decode step's state update: ``"kernel"`` (the compiled
+    Pallas kernel: on a TPU, for a state the gate admits) or ``"plain"``
+    (the ``jax.numpy`` lines: everywhere else); under
+    :func:`pin_state_update`, what was pinned where the gate admits it."""
+    if not state_update_supported(head_dim, state, dtype):
+        return "plain"
+    if _pinned is not None:
+        return _pinned
+    return "kernel" if jax.default_backend() == "tpu" else "plain"
+
+
+@contextlib.contextmanager
+def pin_state_update(impl: str):
+    """Hold :func:`resolve_state_update` to ``impl`` — ``"kernel"``,
+    ``"kernel_interpret"`` (the kernel through the Pallas interpreter) or
+    ``"plain"`` — whatever the platform, while a program is traced.  For
+    the HLO audit, which lowers for the chip from a CPU host, and for the
+    tests; nothing a server sets."""
+    global _pinned
+    if impl not in ("kernel", "kernel_interpret", "plain"):
+        raise ValueError(f"pin_state_update({impl!r})")
+    before, _pinned = _pinned, impl
+    try:
+        yield
+    finally:
+        _pinned = before
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,27 +222,91 @@ class Mamba2(L.Layer):
         return out[:t_in], state
 
     # -- decode: one token per slot --------------------------------------------
+    def state_update_impl(self) -> str:
+        """:func:`resolve_state_update` for this layer's state."""
+        return resolve_state_update(self.head_dim, self.state,
+                                    self.state_shapes()["ssm"][1])
+
     @jax.named_scope("mamba")
-    def decode(self, params, u, state):
-        """``u`` ``[B, D]``, ``state`` the slots' states with a leading
-        ``[B]`` -> (out ``[B, D]``, the states after this token)."""
+    def decode(self, params, u, pools, layer: int):
+        """``u`` ``[B, D]``; ``pools`` the states of every state layer and
+        slot, whole: name -> ``[L, B, ...]`` as :meth:`state_shapes` lays a
+        sequence's out; ``layer`` this layer's (static) index in them ->
+        (out ``[B, D]``, the pools with this layer's states after the
+        token)."""
         bsz = u.shape[0]
         z, xbc, dt = self._project(params, u)
         window = jnp.concatenate(
-            [state["conv"].astype(xbc.dtype), xbc[:, None]], axis=1)
+            [pools["conv"][layer].astype(xbc.dtype), xbc[:, None]], axis=1)
         w = params["conv"]["w"].astype(jnp.float32)
         conv = jnp.einsum("bkc,kc->bc", window.astype(jnp.float32), w)
         conv = jax.nn.silu(conv + params["conv"]["b"].astype(jnp.float32))
         x, b, c = self._split(conv)              # [B,G,R,P], [B,G,N], [B,G,N]
         dt = dt.reshape(bsz, self.groups, -1)                     # [B, G, R]
         a = jnp.exp(dt * -jnp.exp(self._heads(params["A_log"])))
-        s = state["ssm"].reshape(bsz, self.groups, -1, self.head_dim,
-                                 self.state)
-        s = (a[..., None, None] * s
-             + (dt[..., None] * x)[..., None] * b[:, :, None, None, :])
-        y = jnp.sum(s * c[:, :, None, None, :], axis=-1)          # [B,G,R,P]
+        dtx = dt[..., None] * x
+        impl = self.state_update_impl()
+        if impl == "plain":
+            s = pools["ssm"][layer].reshape(bsz, self.groups, -1,
+                                            self.head_dim, self.state)
+            s = a[..., None, None] * s + dtx[..., None] * b[:, :, None, None, :]
+            y = jnp.sum(s * c[:, :, None, None, :], axis=-1)      # [B,G,R,P]
+            ssm = pools["ssm"].at[layer].set(
+                s.reshape(pools["ssm"].shape[1:]))
+        else:
+            ssm, y = state_update(
+                pools["ssm"], layer, a.reshape(bsz, self.heads),
+                dtx.reshape(bsz, self.heads, self.head_dim), b, c,
+                interpret=impl == "kernel_interpret")
+            y = y.reshape(x.shape)
         y = y + self._heads(params["D"])[..., None] * x
         out = self._finish(params, y.reshape(bsz, self.d_inner), z)
-        new = {"ssm": s.reshape(state["ssm"].shape),
-               "conv": window[:, 1:].astype(state["conv"].dtype)}
-        return out, new
+        conv = pools["conv"].at[layer].set(
+            window[:, 1:].astype(pools["conv"].dtype))
+        return out, {"ssm": ssm, "conv": conv}
+
+
+def state_update_parity(heads: int, groups: int, head_dim: int, state: int,
+                        *, dim: int = 256, max_batch: int = 4,
+                        impl: str = "kernel", seed: int = 0) -> dict:
+    """One decode step of a :class:`Mamba2` layer through ``impl`` and
+    through the plain lines, from the same random weights, inputs and
+    pools, at layer 1 of a two-layer pool (the kernel's layer index is
+    under the check too); -> the largest absolute differences of the
+    layer's output and of the states it leaves, and the tolerances they
+    are held to.  ``S'`` is the same three products and one sum per
+    element either way; ``y`` sums 128 float32 terms in another order, so
+    the output is held to 64 rounding steps at its magnitude.  The check
+    ``chip_smoke.py`` runs on the chip at the served geometry."""
+    import numpy as np
+
+    mixer = Mamba2(dim, heads, head_dim, state, groups)
+    k_p, k_u, k_s, k_c = jax.random.split(jax.random.PRNGKey(seed), 4)
+    params, _, _ = mixer.init(k_p, (dim,))
+    u = jax.random.normal(k_u, (max_batch, dim), jnp.float32)
+    shapes = mixer.state_shapes()
+    pools = {name: jax.random.normal(k, (2, max_batch, *shapes[name][0]),
+                                     jnp.float32).astype(shapes[name][1])
+             for name, k in (("ssm", k_s), ("conv", k_c))}
+    got = {}
+    for which in (impl, "plain"):
+        with pin_state_update(which):
+            resolved = mixer.state_update_impl()
+            out, new = jax.jit(lambda p, u, s: mixer.decode(p, u, s, 1))(
+                params, u, pools)
+        got[which] = (resolved, np.asarray(out), np.asarray(new["ssm"]))
+    (resolved, out, ssm), (_, want_out, want_ssm) = got[impl], got["plain"]
+    eps = float(jnp.finfo(jnp.float32).eps)
+    tol_out = 64 * eps * max(1.0, float(np.abs(want_out).max()))
+    tol_ssm = 4 * eps * max(1.0, float(np.abs(want_ssm).max()))
+    err_out = float(np.abs(out - want_out).max())
+    err_ssm = float(np.abs(ssm - want_ssm).max())
+    finite = bool(np.isfinite(out).all() and np.isfinite(ssm).all())
+    untouched = bool(np.array_equal(ssm[0], np.asarray(pools["ssm"][0])))
+    return {"state_update": resolved, "heads": heads, "groups": groups,
+            "head_dim": head_dim, "state": state, "slots": max_batch,
+            "finite": finite, "max_abs_err_out": err_out,
+            "tolerance_out": tol_out, "max_abs_err_state": err_ssm,
+            "tolerance_state": tol_ssm, "other_layer_unchanged": untouched,
+            "ok": bool(finite and untouched and resolved == impl
+                       and err_out <= tol_out and err_ssm <= tol_ssm)}

@@ -163,6 +163,18 @@ class InferenceEngine:
             n = model.expert_products
             self._moe_tags = {"moe_products": n,
                               "moe_kernel_products": n if use_grouped else 0}
+        #: what runs the decode step's recurrent-state update, as the model's
+        #: op resolved it from platform and shape — "kernel",
+        #: "kernel_interpret", "plain", or None for a model without such a
+        #: layer — and ``serve.decode``'s tags for it: state layers in the
+        #: program, and those of them the kernel runs (ISSUE 30)
+        self.state_update_impl = model.resolved_paths().get("state_update")
+        self._state_tags: dict = {}
+        if self.state_update_impl is not None:
+            n = spec["state_layers"]
+            self._state_tags = {
+                "state_updates": n, "state_kernel_updates":
+                    0 if self.state_update_impl == "plain" else n}
         # int8 leaves the fused matmul can consume stay quantized inside
         # the decode step; the rest (odd-vocab head, MoE stacks)
         # dequantize as before.  None = dequantize everything.
@@ -237,6 +249,8 @@ class InferenceEngine:
         out: dict = {"decode_attention": self.decode_impl}
         if self.expert_impl is not None:
             out["expert_products"] = self.expert_impl
+        if self.state_update_impl is not None:
+            out["state_update"] = self.state_update_impl
         if self.quantized:
             leaves = [leaf for leaf in jax.tree.leaves(
                 self.params, is_leaf=_is_quantized) if _is_quantized(leaf)]
@@ -460,7 +474,7 @@ class InferenceEngine:
         active = np.flatnonzero(lengths)
         with spans.span(_SPAN_DECODE, step=self.n_decodes, batch=len(active),
                         requests=np.asarray(rids)[active].tolist(),
-                        **self._moe_tags) as span:
+                        **self._moe_tags, **self._state_tags) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):
                 args = (jnp.asarray(tables, jnp.int32),

@@ -178,18 +178,14 @@ class PagedKVCache:
             v=self.v.at[layer, blk, off].set(v.astype(self.v.dtype)))
 
     # -- per-slot recurrent state ----------------------------------------------
-    def read_state(self, layer: int) -> dict:
-        """State layer ``layer`` of every slot: name -> ``[max_batch, ...]``."""
-        return {name: pool[layer] for name, pool in self.state.items()}
-
-    def write_state(self, layer: int, new: dict, slot=None) -> "PagedKVCache":
-        """Replace state layer ``layer``: of every slot (``new`` leaves
-        ``[max_batch, ...]``, a decode step) or, with ``slot``, of that one
-        slot whole (leaves without the batch axis, a prefill: whatever a
-        previous owner of the slot left there is gone)."""
-        at = (layer,) if slot is None else (layer, slot)
+    def write_state(self, layer: int, new: dict, slot) -> "PagedKVCache":
+        """Replace state layer ``layer`` of ``slot`` whole (``new`` leaves
+        as a sequence's state is laid out, a prefill: whatever a previous
+        owner of the slot left there is gone).  A decode step hands the
+        layer ``state`` itself, every layer and slot of it, and takes it
+        back (``Mamba2.decode``)."""
         return dataclasses.replace(self, state={
-            name: pool.at[at].set(new[name].astype(pool.dtype))
+            name: pool.at[layer, slot].set(new[name].astype(pool.dtype))
             for name, pool in self.state.items()})
 
     # -- paged attention (suffix prefill) --------------------------------------

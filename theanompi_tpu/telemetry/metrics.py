@@ -93,6 +93,11 @@ SERVE_DECODE_MOE_TAGS = ("moe_local_hits", "moe_load_peak")
 #: an ``E`` layer); ``moe_kernel_products`` — those of them the Pallas
 #: kernel runs (all of them, or 0 where ``lax.ragged_dot`` does)
 SERVE_MOE_PRODUCT_TAGS = ("moe_products", "moe_kernel_products")
+#: tags of ``serve.decode`` from an engine whose model keeps recurrent state
+#: (ISSUE 30), the same in every call: ``state_updates`` — state layers in
+#: the decode program; ``state_kernel_updates`` — those of them whose state
+#: update the Pallas kernel runs (all of them, or 0 where the plain lines do)
+SERVE_STATE_UPDATE_TAGS = ("state_updates", "state_kernel_updates")
 #: ``jax.named_scope`` names on the DEVICE (op metadata: they name rows of a
 #: profiler trace, not ring records), by who opens them.  The engine:
 #: ``recast`` (weight casts / dequantize), ``sample``; ``TransformerLM``:
@@ -103,8 +108,10 @@ SERVE_MOE_PRODUCT_TAGS = ("moe_products", "moe_kernel_products")
 #: product, ``ops/pallas_grouped_matmul.py``, whose custom call reads
 #: ``custom-call/grouped_matmul`` under ``moe.experts`` in a trace — the
 #: ``lax.ragged_dot`` it replaces carries no ``op_name`` and reads as
-#: unscoped ``custom-call/ragged-dot-none``); ``HybridLM``: ``embed``,
-#: ``mamba`` (a Mamba-2 mixer, projections and state update),
+#: unscoped ``custom-call/ragged-dot-none`` — and ``mamba_state_update``:
+#: the decode step's recurrent-state update, ``ops/pallas_state_update.py``,
+#: ``custom-call/mamba_state_update`` under ``mamba``); ``HybridLM``:
+#: ``embed``, ``mamba`` (a Mamba-2 mixer, projections and state update),
 #: ``moe.route`` (router, top-k), ``moe.experts`` (latent projections,
 #: sort, the kernel's schedule, grouped products), ``moe.shared`` (the
 #: shared expert), ``attn``, ``head``
@@ -113,7 +120,7 @@ DEVICE_SCOPES = {
     "TransformerLM": ("embed", "block", "attn", "mlp", "head", "loss"),
     "trainer": ("clip", "exchange", "optimizer"),
     "kernels": ("paged_decode", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                "grouped_matmul"),
+                "grouped_matmul", "mamba_state_update"),
     "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
                  "attn", "head"),
 }
