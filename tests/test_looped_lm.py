@@ -1,0 +1,488 @@
+"""``HybridLM`` as a looped stack (the pattern applied ``loops`` times with
+shared weights, K/V per (loop step, layer), an exit gate, rotary positions,
+sandwich RMSNorm, a gated FFN) against the plain reference
+(``benchmarks/arch/ouro_reference.py``) on the CPU at small widths, with
+seeded weights: the ops alone, the model through the engine's cache and the
+scheduler's slots, and the programs the default configuration must keep."""
+
+import hashlib
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "benchmarks"))
+
+from ouro_tiny import tiny_cell  # noqa: E402
+
+from benchmarks.arch import ouro as arch  # noqa: E402
+from benchmarks.arch import ouro_reference as ref  # noqa: E402
+
+SEED = 2**31 + 5
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """The tiny configuration (dim 64, 4 heads of 16, FFN 96, pattern
+    ``*-*-``, 3 loops) computed in float32 (the weights are bf16 values
+    either way), its program model and that model's weights."""
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+
+    cfg = tiny_cell()["cfg"]
+    cfg["run"].update(precision="fp32", weights="fp32")
+    model = HybridLM(arch.model_config(cfg))
+    params = jax.tree.map(lambda x: x.astype(jnp.float32),
+                          arch.seeded_params(model, cfg, SEED))
+    return cfg, model, params
+
+
+def _tokens(cfg, shape, seed=0):
+    return np.random.RandomState(seed).randint(0, cfg["vocab_size"], size=shape)
+
+
+# -- the ops alone ---------------------------------------------------------------
+
+def test_rotary_is_the_complex_rotation():
+    """Pair ``(i, i + Dh/2)`` as one complex number, turned by ``position *
+    theta ** (-2 i / Dh)``; position 0 is the identity; q.k depends on the
+    distance alone."""
+    from theanompi_tpu.ops.attention import rotary
+
+    theta, hd = 1e4, 16
+    rng = np.random.RandomState(0)
+    q = rng.randn(2, 5, 3, hd).astype(np.float32)
+    k = rng.randn(2, 5, 3, hd).astype(np.float32)
+    pos = np.array([[0, 1, 2, 7, 300], [5, 6, 7, 8, 9]])
+    gq, gk = rotary(jnp.asarray(q), jnp.asarray(k), jnp.asarray(pos), theta)
+    turn = np.exp(1j * pos[..., None, None]
+                  * theta ** (-2.0 * np.arange(hd // 2) / hd))
+    for got, x in ((gq, q), (gk, k)):
+        want = (x[..., :hd // 2] + 1j * x[..., hd // 2:]) * turn
+        np.testing.assert_allclose(got[..., :hd // 2], want.real, atol=2e-5)
+        np.testing.assert_allclose(got[..., hd // 2:], want.imag, atol=2e-5)
+    np.testing.assert_array_equal(gq[0, 0], q[0, 0])
+    a = np.einsum("hd,hd->h", gq[1, 0], gk[1, 3])      # positions 5 and 8
+    fq, fk = rotary(jnp.asarray(q[1, :1]), jnp.asarray(k[1, 3:4]),
+                    jnp.asarray([100]), theta)
+    _, fk = rotary(fq, jnp.asarray(k[1, 3:4]), jnp.asarray([103]), theta)
+    np.testing.assert_allclose(a, np.einsum("hd,hd->h", fq[0], fk[0]),
+                               atol=1e-4)
+    assert gq.dtype == jnp.float32
+    assert rotary(jnp.asarray(q, jnp.bfloat16), jnp.asarray(k, jnp.bfloat16),
+                  jnp.asarray(pos), theta)[1].dtype == jnp.bfloat16
+
+
+def test_gated_ffn_and_the_sandwich_layer_are_the_references(tiny):
+    cfg, model, params = tiny
+    leaves = {n: x.astype(jnp.float32) for n, x in
+              ref.leaves(cfg, ref.seed_key(SEED), "layer", 1).items()}
+    u = jax.random.normal(jax.random.PRNGKey(1), (9, cfg["hidden_size"]))
+    got = model._mlp(params["03_mlp"]["mixer"], u)
+    np.testing.assert_allclose(got, ref.ffn(ref._ein("fp32"), leaves, u),
+                               atol=2e-6, rtol=2e-5)
+    assert set(params["03_mlp"]["mixer"]) == {"gate", "up", "down"}
+    assert set(params["03_mlp"]) == {"norm", "mixer", "post_norm"}
+    # x + RMSNorm(mixer(RMSNorm(x))): the FFN half of the reference's block
+    eps = cfg["rms_norm_eps"]
+    want = u + ref.rms(ref.ffn(ref._ein("fp32"), leaves,
+                               ref.rms(u, leaves["g3"], eps)), leaves["g4"], eps)
+    p = params["03_mlp"]
+    got = model._residual(p, u, model._mlp(p["mixer"], model._normed(p, u)))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_letters_loops_and_the_state_pool():
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+
+    with pytest.raises(ValueError, match="gated FFN"):
+        HybridLM({"pattern": "*x"})
+    with pytest.raises(ValueError, match="no M layer"):
+        HybridLM({"pattern": "M*-", "loops": 2})
+    with pytest.raises(ValueError, match="loops >= 1"):
+        HybridLM({"pattern": "*-", "loops": 0})
+    model = HybridLM({"pattern": "*-*-*", "loops": 4, "kv_heads": 2,
+                      "head_dim": 16})
+    assert model.cache_spec() == {
+        "kv": {"layers": 12, "heads": 2, "head_dim": 16},
+        "state": {}, "state_layers": 0}
+    params, _ = model.init_params(jax.random.PRNGKey(0))
+    assert params["exit_gate"]["w"].shape == (256,)
+    assert params["exit_gate"]["b"].shape == ()
+    assert "post_norm" not in params["00_attn"]
+    assert "exit_gate" not in HybridLM({"pattern": "*-"}).init_params(
+        jax.random.PRNGKey(0))[0]
+    with pytest.raises(NotImplementedError, match="multi-exit loss"):
+        model.loss_fn(params, {}, None, None, True)
+
+
+# -- the whole forward -------------------------------------------------------------
+
+def test_apply_logits_is_the_references_forward_at_every_position(tiny):
+    cfg, model, params = tiny
+    toks = _tokens(cfg, (2, 23))
+    got, t_star = model.apply_logits(params, {}, jnp.asarray(toks),
+                                     exit_steps=True)
+    np.testing.assert_allclose(got, ref.logits(cfg, SEED, toks),
+                               atol=2e-5, rtol=0)
+    # the published threshold: every token reads the last step's state
+    assert np.asarray(t_star).tolist() == [[cfg["total_ut_steps"]] * 23] * 2
+    assert (np.asarray(ref.forward(cfg, SEED, toks)["t_star"]) == 3).all()
+
+
+@pytest.mark.parametrize("tau", [0.51, 0.6, 0.77])
+def test_the_exit_rule_below_the_published_threshold(tiny, tau):
+    """``t*`` and the state read out, against the reference, wherever the
+    reference's cumulated exit probability keeps a margin from ``tau``."""
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+
+    cfg, _, params = tiny
+    toks = _tokens(cfg, (3, 19), seed=4)
+    out = ref.forward(cfg, SEED, toks, tau=tau)
+    lam = np.asarray(out["lam"])
+    cdf = np.cumsum(lam * np.cumprod(np.concatenate(
+        [np.ones_like(lam[:1]), 1.0 - lam[:-1]]), axis=0), axis=0)[:-1]
+    clear = (np.abs(cdf - tau) > 1e-4).all(axis=0)
+    assert clear.mean() > 0.8
+    model = HybridLM(dict(arch.model_config(cfg), exit_threshold=tau))
+    got, t_star = model.apply_logits(params, {}, jnp.asarray(toks),
+                                     exit_steps=True)
+    want_t = np.asarray(out["t_star"])
+    assert (np.asarray(t_star) == want_t)[clear].all()
+    want = np.asarray(ref.logits(cfg, SEED, toks, tau=tau))
+    np.testing.assert_allclose(np.asarray(got)[clear], want[clear],
+                               atol=2e-5, rtol=0)
+    # the three thresholds between them read every step out somewhere
+    assert set(np.unique(want_t)) <= {1, 2, 3}
+    assert set(np.unique(want_t[clear])) == {0.51: {1, 2}, 0.6: {2},
+                                             0.77: {2, 3}}[tau]
+
+
+# -- the model through the engine's cache and the scheduler's slots ----------------
+
+def _engine(tiny, **kw):
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    _, model, params = tiny
+    return InferenceEngine(model, params, block_size=8, **kw)
+
+
+@pytest.mark.parametrize("decode_kernel", ["off", "on"])
+def test_prefill_then_decode_through_the_cache_is_the_references_forward(
+        tiny, decode_kernel):
+    """Logits, not tokens: a prompt padded to its bucket, then 9 decode
+    steps, against the reference's one full forward over the same tokens;
+    by the gather and by the interpreted kernel under a traced entry."""
+    cfg = tiny[0]
+    eng = _engine(tiny, max_batch=2, decode_kernel=decode_kernel)
+    assert eng.decode_impl == {"off": "fallback",
+                               "on": "kernel_interpret"}[decode_kernel]
+    assert eng._k.shape == (6, 2 * 8 + 1, 8, 4, 16)   # 3 loops x 2 * layers
+    prompt = _tokens(cfg, 13, seed=3).tolist()
+    table = np.zeros((2, eng.max_blocks_per_seq), np.int32)
+    table[1, :4] = [3, 1, 4, 2]                    # slot 1; slot 0 inactive
+    tok, last = eng.prefill([3, 1], prompt, rid=7, slot=1)
+    rows, toks = [last], list(prompt) + [tok]
+    for _ in range(9):
+        lengths = np.array([0, len(toks) - 1], np.int32)
+        nxt, logits = eng.decode(table, lengths, np.array([0, toks[-1]]),
+                                 np.zeros(2, np.float32), np.array([0, 7]))
+        rows.append(logits[1])
+        toks.append(int(nxt[1]))
+    padded = np.zeros((1, cfg["run"]["max_context"]), np.int32)
+    padded[0, :len(toks) - 1] = toks[:-1]
+    want = ref.logits(cfg, SEED, padded)[0, len(prompt) - 1:len(toks) - 1]
+    np.testing.assert_allclose(np.stack(rows), want, atol=2e-5, rtol=0)
+    assert toks[len(prompt):] == np.argmax(want, axis=-1).tolist()
+
+
+def test_each_loop_step_and_layer_writes_its_own_entry(tiny):
+    """A prefill of 13 tokens into blocks 3 and 1 fills those blocks of all
+    six entries with six different K's and touches no other block; a decode
+    step then changes one token's row of each entry and nothing else."""
+    cfg = tiny[0]
+    eng = _engine(tiny, max_batch=2)
+    prompt = _tokens(cfg, 13, seed=3).tolist()
+    tok, _ = eng.prefill([3, 1], prompt, rid=7, slot=1)
+    k = np.asarray(eng._k)
+    assert k.shape[0] == 6
+    written = np.abs(k).sum(axis=(2, 3, 4)) > 0          # [entry, block]
+    assert written[:, [1, 3]].all() and not written[:, [2, 4, 5, 6]].any()
+    firsts = [k[e, 3, 0].ravel() for e in range(6)]      # position 0's key
+    for a in range(6):
+        for b in range(a):
+            assert np.abs(firsts[a] - firsts[b]).max() > 1e-3, (a, b)
+    # position 0 is unrotated: entry 0 holds layer 0's own W_k row of step 1
+    leaves = ref.leaves(cfg, ref.seed_key(SEED), "layer", 0)
+    top = ref.leaves(cfg, ref.seed_key(SEED), "top")
+    a = ref.rms(top["embed"][prompt[0]].astype(jnp.float32),
+                leaves["g1"].astype(jnp.float32), cfg["rms_norm_eps"])
+    np.testing.assert_allclose(firsts[0], a @ leaves["wk"].astype(jnp.float32),
+                               atol=2e-6)
+    table = np.zeros((2, eng.max_blocks_per_seq), np.int32)
+    table[1, :2] = [3, 1]
+    eng.decode(table, np.array([0, 13], np.int32), np.array([0, tok]),
+               np.zeros(2, np.float32), np.array([0, 7]))
+    changed = np.abs(np.asarray(eng._k) - k).sum(axis=(3, 4)) > 0
+    want = np.zeros_like(changed)
+    want[:, 1, 13 - 8] = True        # position 13: block 1, row 5, every entry
+    want[:, 0, 0] = True             # the inactive slot's write: the null block
+    np.testing.assert_array_equal(changed, want)
+
+
+@pytest.mark.parametrize("entry", [0, 2, 5])
+def test_a_traced_entry_writes_and_attends_at_that_entry_alone(entry):
+    """The cache's writes and both decode attentions under a traced entry
+    (a loop's): the entry's blocks change and no other's; the interpreted
+    kernel is bit-equal to the gather and to the Python-int call."""
+    from theanompi_tpu.serving.kv_cache import PagedKVCache
+
+    rng = np.random.RandomState(entry)
+    shape = (6, 9, 8, 4, 16)
+    k0, v0 = (jnp.asarray(rng.randn(*shape), jnp.float32) for _ in range(2))
+    tables = jnp.asarray([[3, 1, 0, 0], [5, 0, 0, 0], [0, 0, 0, 0]], jnp.int32)
+    positions = jnp.asarray([9, 4, 0], jnp.int32)
+    new_k, new_v, q = (jnp.asarray(rng.randn(3, 4, 16), jnp.float32)
+                       for _ in range(3))
+
+    def step(impl, e, k, v):
+        cache = PagedKVCache(k, v, tables, 8, decode_impl=impl)
+        cache = cache.write_decode(e, new_k, new_v, positions)
+        return cache.k, cache.v, cache.attend_decode(e, q, positions)
+
+    outs = {}
+    for impl in ("fallback", "kernel_interpret"):
+        outs[impl] = jax.jit(step, static_argnums=0)(
+            impl, jnp.int32(entry), k0, v0)
+    fixed = jax.jit(step, static_argnums=(0, 1))("fallback", entry, k0, v0)
+    for a, b, c in zip(outs["fallback"], outs["kernel_interpret"], fixed):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, c)
+    changed = np.abs(np.asarray(outs["fallback"][0]) - np.asarray(k0)
+                     ).sum(axis=(1, 2, 3, 4)) > 0
+    assert changed.tolist() == [e == entry for e in range(6)]
+    # a whole prompt's blocks under a traced entry too
+    blocks = jnp.asarray(rng.randn(1, 16, 4, 16), jnp.float32)
+    wrote = jax.jit(lambda e, k, v: PagedKVCache(k, v, tables, 8).write_prefill(
+        e, blocks, blocks, jnp.asarray([7, 2])).k)(jnp.int32(entry), k0, v0)
+    np.testing.assert_array_equal(wrote[entry, 7], blocks[0, :8])
+    np.testing.assert_array_equal(wrote[entry, 2], blocks[0, 8:])
+    assert float(jnp.abs(wrote - k0).sum(axis=(1, 2, 3, 4))[entry - 1]) == 0.0
+
+
+def _serve(eng_or_sched, requests):
+    from theanompi_tpu.serving.scheduler import Request, Scheduler
+
+    sched = (eng_or_sched if isinstance(eng_or_sched, Scheduler)
+             else Scheduler(eng_or_sched))
+    reqs = [Request(rid=rid, prompt=list(prompt), max_new_tokens=n,
+                    temperature=0.0) for rid, prompt, n in requests]
+    for r in reqs:
+        sched.submit(r)
+    while not sched.idle:
+        sched.step()
+    return {r.rid: list(r.generated) for r in reqs}, sched
+
+
+@pytest.fixture(scope="module")
+def work(tiny):
+    cfg = tiny[0]
+    rng = np.random.RandomState(11)
+    work = [(i, rng.randint(0, cfg["vocab_size"], size=n).tolist(), m)
+            for i, (n, m) in enumerate([(9, 12), (21, 7), (5, 16), (14, 10),
+                                        (30, 9)])]
+    fresh = {}
+    for w in work:  # each alone, in slot 0 of a new engine
+        fresh.update(_serve(_engine(tiny, max_batch=1), [w])[0])
+    return work, fresh
+
+
+def test_a_reused_slot_matches_a_fresh_run(tiny, work):
+    # two slots for five requests: every later one lands in a used slot,
+    # over blocks whose six entries an earlier request filled
+    got, sched = _serve(_engine(tiny, max_batch=2), work[0])
+    assert got == work[1] and sched.n_preemptions == 0
+
+
+def test_a_pool_too_small_preempts_and_finishes_every_request_whole(tiny, work):
+    """8 blocks under 3 slots: the longest is preempted, queued again and
+    recomputed over prompt + generated, through every entry of the pool."""
+    requests, fresh = work
+    got, sched = _serve(_engine(tiny, max_batch=3, num_blocks=8), requests)
+    assert sched.n_preemptions > 0
+    assert got == fresh
+    assert [len(got[i]) for i, _, _ in requests] == [m for _, _, m in requests]
+
+
+def test_the_prefix_cache_refuses_a_model_without_partial_prefill(tiny):
+    from theanompi_tpu.serving.scheduler import Scheduler
+
+    eng = _engine(tiny, max_batch=2)
+    assert not eng.stateful and not eng.partial_prefill
+    with pytest.raises(ValueError, match="no partial prefill"):
+        Scheduler(eng, prefix_cache=True)
+    with pytest.raises(ValueError, match="no partial prefill"):
+        eng.prefill([1, 2], list(range(12)), prefix_len=8)
+    Scheduler(eng)  # without the prefix cache it is served
+
+
+def test_decode_tags_count_the_context_and_the_exit_steps(tiny):
+    from theanompi_tpu.telemetry import spans
+
+    work = [(i, list(range(3 + i, 9 + 2 * i)), 4) for i in range(2)]
+    _serve(_engine(tiny, max_batch=3), work)   # prompts of 6 and 7 tokens
+    tags = [r.tags for r in spans.snapshot() if r.name == "serve.decode"][-3:]
+    assert [t["batch"] for t in tags] == [2, 2, 2]
+    # each slot's context with the token the step writes: 7 + 8, 8 + 9, ...
+    assert [t["kv_tokens"] for t in tags] == [15, 17, 19]
+    assert [t["loop_exit_steps"] for t in tags] == [6, 6, 6]   # 2 slots x 3
+
+
+# -- programs ------------------------------------------------------------------------
+
+def _decode_text(eng, debug_info=False):
+    b, i32 = eng.max_batch, jnp.int32
+    return eng._decode_fn.lower(
+        eng.params, eng._k, eng._v, jnp.zeros((b, eng.max_blocks_per_seq), i32),
+        jnp.zeros((b,), i32), jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+        jnp.zeros((b,), i32), eng._base_key).as_text(debug_info=debug_info)
+
+
+def test_the_loop_is_a_loop_of_the_program(tiny):
+    """The decode program holds the pattern once whatever ``loops`` is: as
+    many weight products at 3 loops as at 2, inside one ``while``."""
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    cfg, _, params = tiny
+    texts = {}
+    for loops in (2, 3):
+        model = HybridLM(dict(arch.model_config(cfg), loops=loops))
+        texts[loops] = _decode_text(InferenceEngine(
+            model, params, block_size=8, max_batch=2), debug_info=True)
+    n = {k: t.count("stablehlo.dot_general") for k, t in texts.items()}
+    # two layers of 7 products (q, k, v, o, gate, up, down) + the head
+    assert n[2] == n[3] == 2 * 7 + 1
+    assert all(t.count("stablehlo.while") >= 1 for t in texts.values())
+    for scope in ("embed", "attn", "mlp", "loop.exit", "head", "sample"):
+        assert re.search(rf'loc\("[^"]*\b{re.escape(scope)}[/"]', texts[3]), scope
+
+
+#: sha256 (first 16 hex) of the StableHLO text a default-configured
+#: ``HybridLM`` (``loops`` 1, ``post_norm`` False, ``rope_theta`` None, no
+#: ``-``) lowered its serving steps to at the parent of PR 31 (commit
+#: 4bffab6, jax 0.9.0).  A PR that means to change these programs replaces
+#: the hashes.
+GOLDEN_HYBRID = {"decode": "09e39c4d17dbe50b", "prefill": "e93a8e1b2fbe9012"}
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_the_default_spine_lowers_to_the_programs_it_had(name):
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    model = HybridLM({
+        "pattern": "MEM*E", "dim": 64, "vocab": 211, "seq_len": 64,
+        "heads": 4, "kv_heads": 2, "head_dim": 16, "mamba_heads": 8,
+        "mamba_head_dim": 16, "state_size": 16, "n_groups": 2,
+        "chunk_size": 8, "n_experts": 32, "experts_held": (8, 16),
+        "top_k": 8, "latent": 32, "expert_dim": 48, "shared_dim": 64})
+    params, _ = model.init_params(jax.random.PRNGKey(0))
+    eng = InferenceEngine(model, params, block_size=8, max_batch=2,
+                          decode_kernel="off")
+    b, i32 = eng.max_batch, jnp.int32
+    fn, args = {
+        "decode": (eng._decode_impl, (
+            eng.params, eng._k, eng._v,
+            jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+            jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+            jnp.zeros((b,), i32), eng._base_key, eng._state)),
+        "prefill": (eng._prefill_impl, (
+            eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
+            jnp.zeros((16,), i32), jnp.asarray(5, i32),
+            jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32), eng._base_key,
+            eng._state, jnp.asarray(1, i32))),
+    }[name]
+    text = jax.jit(fn, donate_argnums=(1, 2, 9)).trace(*args).lower().as_text()
+    text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', 'backend_config = ""',
+                  text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == GOLDEN_HYBRID[name]
+
+
+# -- compiled for the chip without the chip -----------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill16", "prefill128"])
+def test_the_served_widths_compile_for_a_v5e_around_one_pool(
+        one_chip, monkeypatch, program):
+    """What the interpreter cannot show, at the served widths, 16 slots and
+    pool geometry with 4 of the 48 layers (16 entries of 321 blocks): the
+    pools go through the program's loop as ONE buffer each — aliased in and
+    out, and no temporary the size of a pool, which is what a loop-carried
+    pool re-laid out for the prefill's scatter cost (two copies of both
+    pools) — and nothing loop-invariant is hoisted out for every layer at
+    once (a second layout of each of q, k, v's weights: 8 MB a weight)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "ouro-2.6b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=4)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    model = HybridLM(arch.model_config(cfg))
+
+    def shape(s, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    class Engine(InferenceEngine):
+        def _held(self, params):
+            return jax.tree.map(lambda x: shape(x.shape, jnp.bfloat16), params)
+
+    eng = Engine(model, jax.eval_shape(model.init_params,
+                                       jax.random.PRNGKey(0))[0],
+                 block_size=16, num_blocks=2, max_batch=16)
+    assert eng.decode_impl == "kernel"
+    pool = shape((16, 321, 16, 16, 128), jnp.bfloat16)
+    key, b = shape((2,), jnp.uint32), eng.max_batch
+    if program == "decode":
+        fn, args = eng._decode_impl, (
+            shape((b, eng.max_blocks_per_seq)), shape((b,)), shape((b,)),
+            shape((b,), jnp.float32), shape((b,)), key)
+    else:
+        p = int(program[len("prefill"):])
+        fn, args = eng._prefill_impl, (
+            shape((p // 16,)), shape((p,)), shape(()), shape((), jnp.float32),
+            shape(()), key)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+            eng.params, pool, pool, *args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    pool_bytes = 16 * 321 * 16 * 16 * 128 * 2
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < 64 * 2**20 < pool_bytes, mem
+    calls = text.count("tpu_custom_call")
+    # 4 layers inside one loop: the paged-decode kernel, the flash prefill
+    # kernel from 128 tokens on, the plain blockwise attention below
+    assert calls == {"decode": 4, "prefill16": 0, "prefill128": 4}[program]
+    assert len(re.findall(r"\bwhile\(", text)) >= 1

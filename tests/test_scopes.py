@@ -16,6 +16,7 @@ TINY = {"batch_size": 2, "n_train": 16, "n_val": 8, "seq_len": 32,
 MODEL = ("embed", "block", "attn", "mlp")
 HYBRID = ("embed", "mamba", "moe.route", "moe.experts", "moe.shared", "attn",
           "head")
+LOOPED = ("embed", "attn", "mlp", "loop.exit", "head", "sample")
 #: program -> the scopes it must show
 EXPECTED = {
     "train": (*MODEL, "loss", "clip", "exchange", "optimizer"),
@@ -26,6 +27,9 @@ EXPECTED = {
     # HybridLM (ISSUE 27): one mixer a layer, each under its own scope
     "hybrid_decode": (*HYBRID, "sample"),
     "hybrid_prefill": (*HYBRID, "sample"),
+    # a looped stack (ISSUE 31): the ``-`` mixer and the end of a loop step
+    "looped_decode": LOOPED,
+    "looped_prefill": LOOPED,
 }
 SCOPES = sorted({s for names in EXPECTED.values() for s in names}
                 | {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
@@ -136,6 +140,23 @@ def lowered():
         jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
         eng._base_key, eng._state,
         jnp.asarray(1, i32)).as_text(debug_info=True)
+
+    looped = HybridLM({"pattern": "*-*-", "dim": 32, "vocab": 61, "seq_len": 32,
+                       "heads": 4, "kv_heads": 4, "head_dim": 8, "ffn_dim": 48,
+                       "loops": 3, "post_norm": True, "rope_theta": 1e4})
+    eng = InferenceEngine(looped, looped.init_params(jax.random.PRNGKey(0))[0],
+                          block_size=8, max_batch=2)
+    out["looped_decode"] = eng._decode_fn.lower(
+        eng.params, eng._k, eng._v,
+        jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+        jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+        jnp.zeros((b,), i32), eng._base_key).as_text(debug_info=True)
+    out["looped_prefill"] = jax.jit(
+        eng._prefill_impl, donate_argnums=(1, 2)).lower(
+        eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
+        jnp.zeros((16,), i32), jnp.asarray(5, i32),
+        jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
+        eng._base_key).as_text(debug_info=True)
     return out
 
 
@@ -172,6 +193,7 @@ def test_the_jitted_programs_keep_their_names(lowered):
     assert "module @jit_local_step" in lowered["train"]
     assert "module @jit__decode_impl" in lowered["decode"]
     assert "module @jit__decode_impl" in lowered["hybrid_decode"]
+    assert "module @jit__decode_impl" in lowered["looped_decode"]
 
 
 def test_the_documented_scopes_are_the_ones_the_programs_show(lowered):
@@ -179,4 +201,5 @@ def test_the_documented_scopes_are_the_ones_the_programs_show(lowered):
 
     documented = {s for names in DEVICE_SCOPES.values() for s in names}
     assert set(SCOPES) <= documented
-    assert set(DEVICE_SCOPES["HybridLM"]) == set(HYBRID)
+    assert set(DEVICE_SCOPES["HybridLM"]) == set(HYBRID) | set(LOOPED) - {
+        "sample"}

@@ -263,6 +263,36 @@ def test_a_model_that_counts_experts_tags_its_decode_spans(served_events):
         assert [r.tags[t] for t in SERVE_MOE_PRODUCT_TAGS] == [2, 0]
 
 
+def test_decode_spans_count_the_context_and_a_looped_stacks_exit_steps(
+        served_events):
+    """``kv_tokens`` rides on every model's ``serve.decode`` (each active
+    slot's context with the token it writes); ``loop_exit_steps``
+    (``SERVE_DECODE_LOOP_TAGS``) only on a looped ``HybridLM``'s."""
+    import jax
+
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.telemetry.metrics import SERVE_DECODE_LOOP_TAGS
+
+    events, _ = served_events
+    decodes = _spans_named(events, "serve.decode")
+    assert decodes and all(e["kv_tokens"] >= 2 * e["batch"] for e in decodes)
+    assert not any(t in e for e in decodes for t in SERVE_DECODE_LOOP_TAGS)
+    model = HybridLM({"pattern": "*-", "dim": 32, "vocab": 61, "seq_len": 32,
+                      "heads": 4, "kv_heads": 4, "head_dim": 8, "ffn_dim": 48,
+                      "loops": 2, "post_norm": True, "rope_theta": 1e4})
+    engine = InferenceEngine(model, model.init_params(jax.random.PRNGKey(0))[0],
+                             block_size=4, max_batch=2)
+    sched = Scheduler(engine)
+    sched.submit(Request(rid=6, prompt=[1, 2, 3], max_new_tokens=3))
+    while not sched.idle:
+        sched.step()
+    ours = [r for r in spans.snapshot()
+            if r.name == "serve.decode" and r.tags["requests"] == [6]]
+    assert [r.tags["kv_tokens"] for r in ours] == [4, 5]
+    # one slot, exit_threshold 1: the head read the last of the two steps
+    assert [r.tags["loop_exit_steps"] for r in ours] == [2, 2]
+
+
 def test_the_decode_parts_add_up_to_the_decode_span(served_events):
     events, _ = served_events
     parts: dict = {}

@@ -11,22 +11,50 @@ layer's letter in ``pattern`` (the Nemotron-H family's
   hold a share (``experts_held``); its grouped products go through
   ``lax.ragged_dot`` or, where a serving engine resolves so, the kernel of
   :mod:`theanompi_tpu.ops.pallas_grouped_matmul`;
-- ``*``: causal attention with grouped K/V heads and no positional term
-  (:class:`theanompi_tpu.ops.attention.GroupedQueryAttention`): paged K/V.
+- ``*``: causal attention with grouped K/V heads
+  (:class:`theanompi_tpu.ops.attention.GroupedQueryAttention`): paged K/V;
+  no positional term, or with ``rope_theta`` rotary positions at that
+  base (the pool then holds rotated keys);
+- ``-``: a bias-free gated feed-forward of ``ffn_dim``
+  (:class:`theanompi_tpu.ops.layers.GatedFFN`).
 
-then a final RMSNorm and an untied head; no bias except the convolution's.
-``vocab`` is the number of vocabulary rows held (embedding and head alike).
+then a final RMSNorm and an untied head; no bias except the convolution's
+and the exit gate's.  ``vocab`` is the number of vocabulary rows held
+(embedding and head alike).  ``post_norm`` gives every layer a second scale
+and norms the mixer's output too: ``x + RMSNorm(mixer(RMSNorm(x)))``.
+
+**A looped stack** (``loops`` > 1): the whole pattern is applied ``loops``
+times with the SAME parameters; the final norm closes every step — its
+output ``u_t`` is the step's result and the next step's input — and an exit
+gate ``lam_t = sigmoid(u_t . w + b)`` (``exit_gate``, float32) gives the
+step's exit probability ``p_t = lam_t prod_{j<t} (1 - lam_j)`` (the last
+step takes what is left).  The head reads ``u_t*`` at the first step whose
+cumulated ``p`` reaches ``exit_threshold`` (at 1.0: the last step, unless a
+gate saturates); every step is computed whatever ``t*`` is.  The loop is a
+loop of the program (``lax.fori_loop``: a program holds the pattern once),
+and each (step, ``*`` layer) has K/V of its own: ``cache_spec()`` asks for
+``loops x attention layers`` entries, entry ``t * attention layers + l``
+for layer ``l`` of step ``t`` — ``2 x loops x attention layers x kv_heads
+x head_dim x itemsize`` bytes of cache a token, which is what
+``--num-blocks`` x ``--block-size`` tokens must be sized by.  A pattern with
+``M`` refuses ``loops`` > 1 (its state pool has one entry a layer).
 
 **Serving only.**  The model exposes what
 :class:`theanompi_tpu.serving.engine.InferenceEngine` calls —
 ``apply_prefill``, ``apply_decode``, ``apply_logits`` and ``cache_spec()``,
 which says which layers hold paged K/V at how many heads and which hold
 per-slot state of what shapes — and ``loss_fn`` refuses: the chunked scan
-has no backward here.  Device scopes: ``embed``, ``mamba``, ``moe.route``,
-``moe.experts``, ``moe.shared``, ``attn``, ``head``.
+has no backward here, nor has the loop a multi-exit loss.  It has no
+partial prefill, so the scheduler's prefix cache refuses it.  Device
+scopes: ``embed``, ``mamba``, ``moe.route``, ``moe.experts``,
+``moe.shared``, ``attn``, ``mlp``, ``loop.exit`` (a step's final norm, gate
+and read-out), ``head``.
 
     tmserve --modelfile theanompi_tpu.models.hybrid_lm --modelclass HybridLM \\
         --set pattern="'MEM*E'" --set dim=256 ...
+    # a looped stack of attention + gated FFN, rotary, sandwich norm:
+    tmserve ... --set pattern="'*-*-'" --set loops=4 --set post_norm=True \\
+        --set rope_theta=1e6 --set ffn_dim=704 --num-blocks <tokens / block>
 """
 
 from __future__ import annotations
@@ -36,6 +64,7 @@ import types
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from theanompi_tpu.models.contract import Model
 from theanompi_tpu.ops import initializers as init_lib
@@ -44,7 +73,20 @@ from theanompi_tpu.ops.attention import GroupedQueryAttention
 from theanompi_tpu.ops.mamba2 import Mamba2
 from theanompi_tpu.ops.moe import DroplessMoE
 
-_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "-": "mlp"}
+
+
+def _row_major(cache):
+    """``cache`` with its K/V pools held to the layout they arrive in.  A
+    loop-carried array's layout is the compiler's to choose, and for a
+    prefill's scatter it chose the keys' own head-major order: two copies
+    of the whole pools around the loop, 7.5 GB at the served size, where a
+    program without a loop is held to its parameters' layout (ISSUE 31)."""
+    if cache is None:
+        return None
+    order = Layout(major_to_minor=tuple(range(cache.k.ndim)))
+    return dataclasses.replace(cache, k=with_layout_constraint(cache.k, order),
+                               v=with_layout_constraint(cache.v, order))
 
 
 class HybridLM(Model):
@@ -59,6 +101,9 @@ class HybridLM(Model):
         "kv_heads": 2,
         "head_dim": 32,
         "attn_impl": "auto",
+        "rope_theta": None,
+        # ``-``
+        "ffn_dim": 512,
         # ``M``
         "mamba_heads": 16,
         "mamba_head_dim": 32,
@@ -74,6 +119,11 @@ class HybridLM(Model):
         "expert_dim": 128,
         "shared_dim": 256,
         "route_scale": 1.0,
+        # the spine: a second norm a layer, after the mixer; the pattern
+        # applied ``loops`` times (``exit_threshold`` is read when > 1)
+        "post_norm": False,
+        "loops": 1,
+        "exit_threshold": 1.0,
         # the dtype the engine keeps the weights in ("bf16" or "fp32")
         "weights": "bf16",
         "verbose": False,
@@ -86,7 +136,12 @@ class HybridLM(Model):
         if bad or not cfg["pattern"]:
             raise ValueError(f"pattern {cfg['pattern']!r}: letters are "
                              f"{sorted(_KINDS)} (M Mamba-2, E experts, "
-                             f"* attention)")
+                             f"* attention, - gated FFN)")
+        if cfg["loops"] < 1 or (cfg["loops"] > 1 and "M" in cfg["pattern"]):
+            raise ValueError(
+                f"loops={cfg['loops']} with pattern {cfg['pattern']!r}: a "
+                f"looped stack needs loops >= 1 and no M layer (the state "
+                f"pool holds one entry a layer, not one a loop step)")
         #: the dtype a serving engine holds the weights in
         self.weight_dtype = (jnp.bfloat16 if cfg["weights"] == "bf16"
                              else jnp.float32)
@@ -103,7 +158,9 @@ class HybridLM(Model):
                                tuple(held) if held is not None else None),
             "attn": GroupedQueryAttention(cfg["dim"], cfg["heads"],
                                           cfg["kv_heads"], cfg["head_dim"],
-                                          cfg["attn_impl"]),
+                                          cfg["attn_impl"], cfg["rope_theta"],
+                                          fused_qkv=cfg["loops"] == 1),
+            "mlp": L.GatedFFN(cfg["ffn_dim"]),
         }
         #: (param-tree name, kind) per layer, in order
         self.layers = [(f"{i:02d}_{_KINDS[c]}", _KINDS[c])
@@ -133,11 +190,12 @@ class HybridLM(Model):
 
     def cache_spec(self) -> dict:
         """What a serving cache must hold for this model: ``kv`` — paged
-        K/V over the attention layers only; ``state`` — per slot and per
-        ``M`` layer, name -> (shape, dtype); ``state_layers`` their count."""
+        K/V, an entry for each attention layer of each loop step; ``state``
+        — per slot and per ``M`` layer, name -> (shape, dtype);
+        ``state_layers`` their count."""
         cfg = self.config
         kinds = [k for _, k in self.layers]
-        return {"kv": {"layers": max(kinds.count("attn"), 1),
+        return {"kv": {"layers": max(cfg["loops"] * kinds.count("attn"), 1),
                        "heads": cfg["kv_heads"], "head_dim": cfg["head_dim"]},
                 "state": (self._mixers["mamba"].state_shapes()
                           if "mamba" in kinds else {}),
@@ -155,22 +213,40 @@ class HybridLM(Model):
         for (name, kind), k in zip(self.layers, keys[2:]):
             params[name] = {"norm": self._norm.init(None, (d,))[0],
                             "mixer": self._mixers[kind].init(k, (d,))[0]}
+            if cfg["post_norm"]:
+                params[name]["post_norm"] = self._norm.init(None, (d,))[0]
+        if cfg["loops"] > 1:
+            params["exit_gate"] = {
+                "w": w02(jax.random.fold_in(rng, 1), (d,)),
+                "b": jnp.zeros((), jnp.float32)}
         return params, {}
 
     def loss_fn(self, params, state, batch, rng, train: bool):
         raise NotImplementedError(
             "HybridLM is serving-only: the Mamba-2 chunked scan has no "
-            "backward path here and the dropless expert layer no balance "
-            "loss; train the plain TransformerLM, or serve this model "
-            "through tmserve / InferenceEngine")
+            "backward path here, the dropless expert layer no balance "
+            "loss and a looped stack no multi-exit loss; train the plain "
+            "TransformerLM, or serve this model through tmserve / "
+            "InferenceEngine")
 
     # -- the spine ---------------------------------------------------------------
     def _normed(self, p, x):
         return self._norm.apply(p["norm"], {}, x)[0]
 
+    def _residual(self, p, x, y):
+        """``x + y``, ``y`` a mixer's output: normed first under ``post_norm``."""
+        if self.config["post_norm"]:
+            y = self._norm.apply(p["post_norm"], {}, y)[0]
+        return x + y
+
+    @jax.named_scope("mlp")
+    def _mlp(self, p, u):
+        return self._mixers["mlp"].apply(p, {}, u)[0]
+
     @jax.named_scope("head")
     def _head_logits(self, cp, x):
-        x, _ = self._norm.apply(cp["norm_f"], {}, x)
+        if self.config["loops"] == 1:  # a looped stack norms inside its loop
+            x, _ = self._norm.apply(cp["norm_f"], {}, x)
         return (x @ cp["head"]["w"].astype(x.dtype)).astype(jnp.float32)
 
     @jax.named_scope("embed")
@@ -178,14 +254,96 @@ class HybridLM(Model):
         return jnp.take(cp["embed"]["w"], tokens, axis=0).astype(
             self.precision.compute_dtype)
 
-    def _attn_prefill(self, p, u, cache, li, table_row):
+    @jax.named_scope("loop.exit")
+    def _close_step(self, cp, x, ex, t):
+        """The end of loop step ``t``: -> (``u_t``, the exit state with this
+        step's gate counted).  ``ex``: ``chosen`` the state the head will
+        read, ``cdf`` the exit distribution so far, ``survive`` ``prod (1 -
+        lam)``, ``t_star`` the 1-based exit step (0 = none yet)."""
+        last = t == self.config["loops"] - 1
+        u, _ = self._norm.apply(cp["norm_f"], {}, x)
+        g = cp["exit_gate"]
+        lam = jax.nn.sigmoid(
+            jnp.sum(u.astype(jnp.float32) * g["w"].astype(jnp.float32), -1)
+            + g["b"].astype(jnp.float32))
+        cdf = ex["cdf"] + jnp.where(last, ex["survive"], lam * ex["survive"])
+        hit = (ex["t_star"] == 0) & (
+            (cdf >= float(self.config["exit_threshold"])) | last)
+        return u, {"chosen": jnp.where(hit[..., None], u, ex["chosen"]),
+                   "cdf": cdf, "survive": ex["survive"] * (1.0 - lam),
+                   "t_star": jnp.where(hit, t + 1, ex["t_star"])}
+
+    def _stack(self, cp, x, cache, acc, once):
+        """The pattern over ``x``, ``loops`` times: ``once(x, cache, acc,
+        t) -> (x, cache, acc)`` is one pass, ``t`` its loop step (the
+        Python int 0 without a loop, a traced int32 inside one).  -> (the
+        state the head reads, cache, acc, each token's exit step or None)."""
+        loops = self.config["loops"]
+        if loops == 1:
+            return (*once(x, cache, acc, 0), None)
+
+        def body(t, carry):
+            x, cache, acc, ex = carry
+            x, cache, acc = once(x, cache, acc, t)
+            u, ex = self._close_step(cp, x, ex, t)
+            with jax.named_scope("loop.exit"):
+                cache = _row_major(cache)
+            return u, cache, acc, ex
+
+        rows = x.shape[:-1]
+        ex = {"chosen": jnp.zeros_like(x), "cdf": jnp.zeros(rows, jnp.float32),
+              "survive": jnp.ones(rows, jnp.float32),
+              "t_star": jnp.zeros(rows, jnp.int32)}
+        _, cache, acc, ex = jax.lax.fori_loop(0, loops, body,
+                                              (x, cache, acc, ex))
+        return ex["chosen"], cache, acc, ex["t_star"]
+
+    def _entry(self, t, n_kv: int):
+        """The K/V pool entry of attention layer ``n_kv`` at loop step ``t``."""
+        return t * self.config["pattern"].count("*") + n_kv
+
+    def _prefill(self, params, kv_cache, table_row, tokens, true_len, slot):
+        """:meth:`apply_prefill` and each position's exit step beside it."""
+        cp = self.precision.cast_to_compute(params)
+        toks = tokens[0]
+        if true_len is None:
+            true_len = jnp.int32(toks.shape[0])
         attn = self._mixers["attn"]
-        with jax.named_scope("attn"):
-            q, k, v = attn.project_qkv(p, u[None])
-            if cache is not None:
-                cache = cache.write_prefill(li, k, v, table_row)
-            ctx = attn.attend(q, k, v)
-            return attn.project_out(p, ctx.reshape(u.shape[0], -1)), cache
+        positions = (None if attn.rope_theta is None else
+                     jnp.arange(toks.shape[0], dtype=jnp.int32)[None])
+
+        def once(x, kv_cache, acc, t):
+            n_kv = n_state = 0
+            for name, kind in self.layers:
+                p = cp[name]
+                u = self._normed(p, x)
+                if kind == "mamba":
+                    y, s = self._mixers[kind].prefill(p["mixer"], u, true_len)
+                    if kv_cache is not None:
+                        with jax.named_scope("mamba"):  # the state's write-back
+                            kv_cache = kv_cache.write_state(n_state, s, slot)
+                    n_state += 1
+                elif kind == "moe":
+                    y, _ = self._mixers[kind].apply_tokens(p["mixer"], u)
+                elif kind == "mlp":
+                    y = self._mlp(p["mixer"], u)
+                else:
+                    with jax.named_scope("attn"):
+                        q, k, v = attn.project_qkv(p["mixer"], u[None],
+                                                   positions)
+                        if kv_cache is not None:
+                            kv_cache = kv_cache.write_prefill(
+                                self._entry(t, n_kv), k, v, table_row)
+                        ctx = attn.attend(q, k, v)
+                        y = attn.project_out(p["mixer"],
+                                             ctx.reshape(u.shape[0], -1))
+                    n_kv += 1
+                x = self._residual(p, x, y)
+            return x, kv_cache, acc
+
+        x, kv_cache, _, t_star = self._stack(cp, self._embed(cp, toks),
+                                             kv_cache, (), once)
+        return self._head_logits(cp, x)[None], kv_cache, t_star
 
     def apply_prefill(self, params, state, kv_cache, table_row, tokens,
                       true_len=None, slot=None):
@@ -197,75 +355,73 @@ class HybridLM(Model):
         ``true_len``.  ``kv_cache=None`` runs the same spine with nothing
         kept (:meth:`apply_logits`)."""
         del state
-        cp = self.precision.cast_to_compute(params)
-        toks = tokens[0]
-        if true_len is None:
-            true_len = jnp.int32(toks.shape[0])
-        x = self._embed(cp, toks)
-        n_kv = n_state = 0
-        for name, kind in self.layers:
-            p = cp[name]
-            u = self._normed(p, x)
-            if kind == "mamba":
-                y, s = self._mixers[kind].prefill(p["mixer"], u, true_len)
-                if kv_cache is not None:
-                    with jax.named_scope("mamba"):  # the state's write-back
-                        kv_cache = kv_cache.write_state(n_state, s, slot)
-                n_state += 1
-            elif kind == "moe":
-                y, _ = self._mixers[kind].apply_tokens(p["mixer"], u)
-            else:
-                y, kv_cache = self._attn_prefill(p["mixer"], u, kv_cache,
-                                                 n_kv, table_row)
-                n_kv += 1
-            x = x + y
-        return self._head_logits(cp, x)[None], kv_cache
+        return self._prefill(params, kv_cache, table_row, tokens, true_len,
+                             slot)[:2]
 
-    def apply_logits(self, params, state, tokens):
+    def apply_logits(self, params, state, tokens, exit_steps: bool = False):
         """Full-sequence forward, ``tokens`` ``[B, T]`` -> logits
         ``[B, T, V]``, nothing cached: what incremental decoding is
-        compared against."""
-        rows = [self.apply_prefill(params, state, None, None, t[None])[0][0]
+        compared against.  ``exit_steps``: -> (logits, each position's
+        1-based exit step ``[B, T]``) of a looped stack."""
+        del state
+        rows = [self._prefill(params, None, None, t[None], None, None)
                 for t in tokens]
-        return jnp.stack(rows)
+        logits = jnp.stack([r[0][0] for r in rows])
+        if exit_steps:
+            return logits, jnp.stack([r[2] for r in rows])
+        return logits
 
     def apply_decode(self, params, state, kv_cache, positions, tokens):
         """One token for each slot of the fixed batch: ``tokens`` ``[B]`` at
         ``positions`` ``[B]``.  -> (logits ``[B, V]`` fp32, cache', stats);
         ``stats`` sums the expert layers' ``local_hits`` and takes the
         largest ``load_peak`` over them, counting slots at ``positions > 0``
-        (an inactive slot rides along at position 0 on the null block)."""
+        (an inactive slot rides along at position 0 on the null block); a
+        looped stack adds ``loop_exit_steps``, the sum of those slots' exit
+        steps."""
         del state
         cp = self.precision.cast_to_compute(params)
         attn = self._mixers["attn"]
         active = positions > 0
-        hits, peak = jnp.int32(0), jnp.int32(0)
-        x = self._embed(cp, tokens)
-        n_kv = n_state = 0
-        for name, kind in self.layers:
-            p = cp[name]
-            u = self._normed(p, x)
-            if kind == "mamba":
-                y, pools = self._mixers[kind].decode(p["mixer"], u,
-                                                     kv_cache.state, n_state)
-                kv_cache = dataclasses.replace(kv_cache, state=pools)
-                n_state += 1
-            elif kind == "moe":
-                y, st = self._mixers[kind].apply_tokens(p["mixer"], u,
-                                                        active=active)
-                hits = hits + st["local_hits"]
-                peak = jnp.maximum(peak, st["load_peak"])
-            else:
-                with jax.named_scope("attn"):
-                    q, k, v = attn.project_qkv(p["mixer"], u[:, None])
-                    kv_cache = kv_cache.write_decode(n_kv, k[:, 0], v[:, 0],
-                                                     positions)
-                    ctx = kv_cache.attend_decode(n_kv, q[:, 0], positions)
-                    y = attn.project_out(p["mixer"],
-                                         ctx.reshape(ctx.shape[0], -1))
-                n_kv += 1
-            x = x + y
+
+        def once(x, kv_cache, acc, t):
+            hits, peak = acc
+            n_kv = n_state = 0
+            for name, kind in self.layers:
+                p = cp[name]
+                u = self._normed(p, x)
+                if kind == "mamba":
+                    y, pools = self._mixers[kind].decode(
+                        p["mixer"], u, kv_cache.state, n_state)
+                    kv_cache = dataclasses.replace(kv_cache, state=pools)
+                    n_state += 1
+                elif kind == "moe":
+                    y, st = self._mixers[kind].apply_tokens(p["mixer"], u,
+                                                            active=active)
+                    hits = hits + st["local_hits"]
+                    peak = jnp.maximum(peak, st["load_peak"])
+                elif kind == "mlp":
+                    y = self._mlp(p["mixer"], u)
+                else:
+                    with jax.named_scope("attn"):
+                        entry = self._entry(t, n_kv)
+                        q, k, v = attn.project_qkv(p["mixer"], u[:, None],
+                                                   positions[:, None])
+                        kv_cache = kv_cache.write_decode(entry, k[:, 0],
+                                                         v[:, 0], positions)
+                        ctx = kv_cache.attend_decode(entry, q[:, 0], positions)
+                        y = attn.project_out(p["mixer"],
+                                             ctx.reshape(ctx.shape[0], -1))
+                    n_kv += 1
+                x = self._residual(p, x, y)
+            return x, kv_cache, (hits, peak)
+
+        x, kv_cache, (hits, peak), t_star = self._stack(
+            cp, self._embed(cp, tokens), kv_cache,
+            (jnp.int32(0), jnp.int32(0)), once)
         stats = {"moe_local_hits": hits, "moe_load_peak": peak}
+        if t_star is not None:
+            stats["loop_exit_steps"] = jnp.sum(jnp.where(active, t_star, 0))
         return self._head_logits(cp, x), kv_cache, stats
 
     # -- what the serving entry points ask of a model ------------------------------

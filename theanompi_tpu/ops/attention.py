@@ -170,21 +170,53 @@ class MultiHeadAttention(L.Layer):
         return self.project_out(params, out), state
 
 
+def rotary(q, k, positions, theta: float):
+    """Rotary position embedding over the whole head, rotate-half pairing
+    (dim ``i`` with ``i + Dh // 2``): ``q`` ``[..., T, H, Dh]`` and ``k``
+    ``[..., T, Hkv, Dh]`` at ``positions`` ``[..., T]`` -> the rotated pair
+    in their own dtypes.  Angles ``position * theta ** (-2 i / Dh)`` and the
+    rotation itself are float32.  Applied before a cache write, so a K pool
+    holds rotated keys and a decode step rotates its own token only."""
+    half = q.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.asarray(positions, jnp.float32)[..., None, None] * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+
+    def turn(x):
+        xf = x.astype(jnp.float32)
+        a, b = xf[..., :half], xf[..., half:]
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                               axis=-1).astype(x.dtype)
+
+    return turn(q), turn(k)
+
+
 @dataclasses.dataclass(frozen=True)
 class GroupedQueryAttention(L.Layer):
     """Causal attention whose ``kv_heads`` K/V heads are each shared by
     ``heads // kv_heads`` query heads (query head ``h`` reads K/V head
     ``h // (heads // kv_heads)``); head size ``head_dim`` independent of
-    ``dim``, no bias, no positional term.  A cache holds the ``kv_heads``
-    only; :meth:`attend` repeats them to the query heads and takes
-    :meth:`MultiHeadAttention.attend`'s dispatch, so a TPU prefill rides the
-    flash kernel where its gate admits the shape."""
+    ``dim``, no bias.  ``rope_theta=None``: no positional term;
+    a number: :func:`rotary` positions at that base, for which
+    :meth:`project_qkv` is told the tokens' ``positions``.  A cache holds
+    the ``kv_heads`` only; :meth:`attend` repeats them to the query heads
+    and takes :meth:`MultiHeadAttention.attend`'s dispatch, so a TPU
+    prefill rides the flash kernel where its gate admits the shape."""
 
     dim: int
     heads: int
     kv_heads: int
     head_dim: int
     impl: str = "auto"
+    rope_theta: float | None = None
+    #: q, k and v as ONE product over the concatenated weights.  False:
+    #: three products, fenced from the reshape to heads — what a stack
+    #: inside a loop of the program takes.  There XLA hoists whatever is
+    #: loop-invariant out of the loop and holds it for every layer at once:
+    #: the concatenations, or, unfenced, a second layout of each weight
+    #: that a product folded with its reshape wants (1.2 GB either way for
+    #: 48 layers of 2048, compiled for a v5e, ISSUE 31)
+    fused_qkv: bool = True
 
     def init(self, key, in_shape):
         if in_shape[-1] != self.dim:
@@ -201,16 +233,25 @@ class GroupedQueryAttention(L.Layer):
                   "o": {"w": w02(ko, (hq, self.dim))}}
         return params, {}, tuple(in_shape)
 
-    def project_qkv(self, params, x):
-        """``[B, T, D]`` -> q ``[B, T, H, Dh]``, k, v ``[B, T, Hkv, Dh]``."""
+    def project_qkv(self, params, x, positions=None):
+        """``[B, T, D]`` -> q ``[B, T, H, Dh]``, k, v ``[B, T, Hkv, Dh]``;
+        with a ``rope_theta``, q and k rotated to ``positions`` ``[B, T]``."""
         b, t, _ = x.shape
-        w = jnp.concatenate([params[n]["w"] for n in "qkv"], axis=1)
-        qkv = x @ w.astype(x.dtype)
         hq = self.heads * self.head_dim
         hkv = self.kv_heads * self.head_dim
-        return (qkv[..., :hq].reshape(b, t, self.heads, self.head_dim),
-                qkv[..., hq:hq + hkv].reshape(b, t, self.kv_heads, self.head_dim),
-                qkv[..., hq + hkv:].reshape(b, t, self.kv_heads, self.head_dim))
+        if self.fused_qkv:
+            w = jnp.concatenate([params[n]["w"] for n in "qkv"], axis=1)
+            qkv = x @ w.astype(x.dtype)
+            cuts = (0, hq, hq + hkv, hq + 2 * hkv)
+            q, k, v = (qkv[..., a:z].reshape(b, t, -1, self.head_dim)
+                       for a, z in zip(cuts, cuts[1:]))
+        else:
+            q, k, v = (y.reshape(b, t, -1, self.head_dim)
+                       for y in jax.lax.optimization_barrier(tuple(
+                           x @ params[n]["w"].astype(x.dtype) for n in "qkv")))
+        if self.rope_theta is not None:
+            q, k = rotary(q, k, positions, float(self.rope_theta))
+        return q, k, v
 
     def attend(self, q, k, v):
         rep = self.heads // self.kv_heads

@@ -415,6 +415,30 @@ class RMSNorm(Layer):
 
 
 @dataclasses.dataclass(frozen=True)
+class GatedFFN(Layer):
+    """Bias-free gated feed-forward over the trailing dim:
+    ``(silu(x W_gate) * (x W_up)) W_down``, ``dim -> hidden -> dim``; the
+    activation in float32, the products in ``x.dtype``."""
+
+    hidden: int
+
+    def init(self, key, in_shape):
+        d = in_shape[-1]
+        kg, ku, kd = jax.random.split(key, 3)
+        w02 = init_lib.normal(0.02)
+        params = {"gate": {"w": w02(kg, (d, self.hidden))},
+                  "up": {"w": w02(ku, (d, self.hidden))},
+                  "down": {"w": w02(kd, (self.hidden, d))}}
+        return params, {}, tuple(in_shape)
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        g = (x @ params["gate"]["w"].astype(x.dtype)).astype(jnp.float32)
+        u = (x @ params["up"]["w"].astype(x.dtype)).astype(jnp.float32)
+        y = (jax.nn.silu(g) * u).astype(x.dtype)
+        return y @ params["down"]["w"].astype(x.dtype), state
+
+
+@dataclasses.dataclass(frozen=True)
 class LRN(Layer):
     """Across-channel local response normalization (AlexNet/GoogLeNet).
 

@@ -3,7 +3,8 @@
 One Pallas kernel per (layer, decode step) over the WHOLE pool: grid
 ``(batch, blocks)`` with the **block table driving the KV index_map** —
 each grid step DMAs exactly the pool block the table names, out of the
-``[L, num_blocks, block_size, H, Dh]`` pool at the (static) layer, so the
+``[L, num_blocks, block_size, H, Dh]`` pool at the layer (a constant of
+the call, or a traced entry of a looped stack: ISSUE 31), so the
 gather that ``serving/kv_cache.py`` does with a materialized
 ``[B, T_max, H, Dh]`` ``jnp.take`` never touches HBM here.  Nothing may
 slice the pool before the call: a custom call's operand is a whole
@@ -101,6 +102,13 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                        / l_scr[:, :][:, :1]).astype(o_ref.dtype)
 
 
+def _decode_kernel_at(tables_ref, pos_ref, layer_ref, *refs, **static):
+    """:func:`_decode_kernel` under a third scalar-prefetch operand: a
+    traced layer, which the K/V ``index_map`` alone reads."""
+    del layer_ref
+    _decode_kernel(tables_ref, pos_ref, *refs, **static)
+
+
 def paged_decode_supported(heads: int, head_dim: int,
                            dtype=jnp.float32) -> bool:
     """Shape gate for the COMPILED kernel: the KV block's trailing
@@ -111,50 +119,56 @@ def paged_decode_supported(heads: int, head_dim: int,
     return heads % sublane == 0 and head_dim % 128 == 0
 
 
-def paged_attend_decode(k_pool, v_pool, layer: int, tables,
+def paged_attend_decode(k_pool, v_pool, layer, tables,
                         block_size: int, q, positions,
                         interpret: bool | None = None):
     """Paged decode attention over layer ``layer`` of the whole pools.
 
     ``k_pool``/``v_pool`` ``[L, num_blocks, block_size, H, Dh]`` (the
-    cache's own arrays, never a slice of them), ``layer`` a Python int,
-    ``tables`` ``[B, max_blocks_per_seq]`` int32, ``q`` ``[B, H, Dh]``,
-    ``positions`` ``[B]`` (each query's own 0-based position, already
-    written) -> context ``[B, H, Dh]``.  ``interpret=None`` auto-selects:
-    compiled on TPU (gate with :func:`paged_decode_supported`),
-    interpreter elsewhere.
+    cache's own arrays, never a slice of them), ``layer`` a Python int (a
+    constant of the ``index_map``) or a traced int32 scalar (a loop's
+    entry: it rides as a third scalar-prefetch operand of the same kernel
+    and must lie inside the pool), ``tables`` ``[B, max_blocks_per_seq]``
+    int32, ``q`` ``[B, H, Dh]``, ``positions`` ``[B]`` (each query's own
+    0-based position, already written) -> context ``[B, H, Dh]``.
+    ``interpret=None`` auto-selects: compiled on TPU (gate with
+    :func:`paged_decode_supported`), interpreter elsewhere.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
     nb = tables.shape[1]
     bs = block_size
-    layer = int(layer)
-    if not 0 <= layer < k_pool.shape[0]:
-        raise ValueError(f"paged_attend_decode: layer {layer} outside the "
-                         f"pool's {k_pool.shape[0]} layers")
+    traced = isinstance(layer, jax.Array)
+    if not traced:
+        layer = int(layer)
+        if not 0 <= layer < k_pool.shape[0]:
+            raise ValueError(f"paged_attend_decode: layer {layer} outside "
+                             f"the pool's {k_pool.shape[0]} layers")
     if not interpret and not paged_decode_supported(h, d, q.dtype):
         raise ValueError(
             f"paged_attend_decode: unsupported shape H={h} Dh={d} "
             f"({q.dtype}) for compiled Mosaic tiling; gate with "
             "paged_decode_supported()")
 
-    def kv_map(i, j, t, p):
+    def kv_map(i, j, t, p, *at):
         # DMA elision: past-the-end (null-block) steps re-reference the
         # last needed block, so their copies never issue; compute stays
         # gated on the REAL j, so numerics are untouched.  The layer is
-        # a constant of this call's index_map
-        return (layer, t[i, jnp.minimum(j, p[i] // bs)], 0, 0, 0)
+        # a constant of this call's index_map, or its third prefetched
+        # scalar
+        return (at[0][0] if at else layer,
+                t[i, jnp.minimum(j, p[i] // bs)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3 if traced else 2,
         grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((None, h, d), lambda i, j, t, p: (i, 0, 0)),
+            pl.BlockSpec((None, h, d), lambda i, j, *_: (i, 0, 0)),
             pl.BlockSpec((None, None, bs, h, d), kv_map),
             pl.BlockSpec((None, None, bs, h, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, h, d), lambda i, j, t, p: (i, 0, 0)),
+        out_specs=pl.BlockSpec((None, h, d), lambda i, j, *_: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 128), jnp.float32),   # running max (lane-bcast)
             pltpu.VMEM((h, 128), jnp.float32),   # normalizer (lane-bcast)
@@ -162,11 +176,13 @@ def paged_attend_decode(k_pool, v_pool, layer: int, tables,
         ],
     )
     fn = pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=bs, nb=nb, heads=h),
+        functools.partial(_decode_kernel_at if traced else _decode_kernel,
+                          block_size=bs, nb=nb, heads=h),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
     )
+    at = (jnp.asarray(layer, jnp.int32).reshape(1),) if traced else ()
     with jax.named_scope("paged_decode"):  # the custom call's name in a trace
-        return fn(tables, jnp.asarray(positions, jnp.int32), q, k_pool,
+        return fn(tables, jnp.asarray(positions, jnp.int32), *at, q, k_pool,
                   v_pool)

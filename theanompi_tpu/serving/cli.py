@@ -28,6 +28,16 @@ A ``HybridLM`` (serving-only; no ``--prefix-cache``)::
     tmserve --modelfile theanompi_tpu.models.hybrid_lm --modelclass HybridLM \
         --set "pattern='MEM*E'" --set dim=256 --set seq_len=256 \
         --requests 16 --max-batch 8 --out SERVE.json
+
+A looped stack — the pattern applied ``loops`` times with shared weights,
+``-`` a gated FFN, rotary positions, a norm after each mixer; the cache
+holds ``loops`` entries a ``*`` layer, so size ``--num-blocks`` by ``2 x
+loops x n_attn x kv_heads x head_dim x itemsize`` bytes a token::
+
+    tmserve --modelfile theanompi_tpu.models.hybrid_lm --modelclass HybridLM \
+        --set "pattern='*-*-'" --set loops=4 --set post_norm=True \
+        --set rope_theta=1e6 --set ffn_dim=704 --set kv_heads=8 \
+        --requests 16 --max-batch 8 --num-blocks 129 --out SERVE.json
 """
 
 from __future__ import annotations
@@ -57,8 +67,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="a model with the serving interface (apply_prefill, "
                    "apply_decode, cache_spec): TransformerLM and its MoE "
                    "variant, or HybridLM — a per-layer pattern of Mamba-2 "
-                   "(M), expert (E) and attention (*) mixers, serving-only, "
-                   "configured by --set pattern=... and its widths")
+                   "(M), expert (E), attention (*) and gated-FFN (-) mixers, "
+                   "serving-only, configured by --set pattern=... and its "
+                   "widths; --set loops=T applies the pattern T times with "
+                   "shared weights and an exit gate (exit_threshold), "
+                   "rope_theta=<base> gives rotary positions, post_norm=True "
+                   "a norm after each mixer")
     p.add_argument("--set", dest="model_set", action="append", default=[],
                    metavar="K=V", help="model config entry (repeatable; "
                    "must reproduce the training config for the checkpoint "
@@ -79,7 +93,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="KV-cache tokens per block")
     p.add_argument("--num-blocks", type=int, default=None,
                    help="KV block pool size (default: worst case; smaller "
-                   "values oversubscribe and rely on preemption)")
+                   "values oversubscribe and rely on preemption); a block "
+                   "holds --block-size tokens over every entry the model's "
+                   "cache_spec() asks for — loops x attention layers for a "
+                   "looped HybridLM")
     p.add_argument("--quantize-int8", action="store_true",
                    help="int8 weight-only quantization of matmul weights "
                    "(ring_int8 per-chunk-scale format)")
@@ -99,7 +116,8 @@ def build_parser() -> argparse.ArgumentParser:
                    "and the cache invalidates on live weight rollout.  "
                    "Refused (config error) for a model that keeps per-slot "
                    "recurrent state, e.g. HybridLM with M layers: shared "
-                   "K/V blocks hold no state to resume from")
+                   "K/V blocks hold no state to resume from; and for any "
+                   "HybridLM, which has no partial prefill")
     # -- synthetic traffic -------------------------------------------------
     p.add_argument("--requests", type=int, default=16)
     p.add_argument("--prompt-len", type=int, default=16,

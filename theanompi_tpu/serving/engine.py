@@ -29,10 +29,13 @@ jitted call), ``.wait`` (the next tokens reach the host: the device step
 is over), ``.fetch`` (the logits' copy).  On the device, ``recast`` and
 ``sample`` scopes name the weight cast and the sampler beside the model's
 own ``embed`` / ``block`` / ``attn`` / ``mlp`` / ``head`` (``HybridLM``:
-``mamba`` / ``moe.route`` / ``moe.experts`` / ``moe.shared``).
+``mamba`` / ``moe.route`` / ``moe.experts`` / ``moe.shared`` /
+``loop.exit``).
 
 The cache is whatever the model's ``cache_spec()`` asks for: paged K/V
-pools over the layers that attend and, for layers that keep a recurrent
+pools over the layers that attend (an entry per loop step and layer for a
+stack that is applied several times: the pools ride through the
+program's loop in place, donated as ever) and, for layers that keep a recurrent
 state instead, a slot-indexed state pool ``[layers, max_batch, ...]``
 donated through the steps like the K/V pools.  A prefill writes its
 slot's state whole, taken at the prompt's TRUE length (padding to a bucket
@@ -228,6 +231,12 @@ class InferenceEngine:
         prefix cache can share)."""
         return bool(self._state)
 
+    @property
+    def partial_prefill(self) -> bool:
+        """The model can prefill a suffix over cached K/V
+        (``apply_prefill_partial``): what a prefix-cache hit runs."""
+        return hasattr(self.model, "apply_prefill_partial")
+
     def _held(self, params):
         """``params`` as the engine keeps them: cast once to the model's
         stated ``weight_dtype`` (a model that states none, or float32,
@@ -402,6 +411,11 @@ class InferenceEngine:
                     raise ValueError(
                         "a cached prefix holds K/V blocks but no recurrent "
                         "state: this model cannot prefill from one")
+                if not self.partial_prefill:
+                    raise ValueError(
+                        "this model has no partial prefill "
+                        "(apply_prefill_partial): it cannot prefill from a "
+                        "cached prefix")
                 return self._prefill_suffix(table_row, tokens, temperature,
                                             rid, prefix_len)
             p_pad = self.pad_len(p)
@@ -472,7 +486,10 @@ class InferenceEngine:
         length 0 (their outputs are garbage by contract)."""
         lengths = np.asarray(lengths)
         active = np.flatnonzero(lengths)
+        # ``kv_tokens``: the tokens this step's attention reads, each
+        # active slot's context with the token it writes
         with spans.span(_SPAN_DECODE, step=self.n_decodes, batch=len(active),
+                        kv_tokens=int(lengths[active].sum()) + len(active),
                         requests=np.asarray(rids)[active].tolist(),
                         **self._moe_tags, **self._state_tags) as span:
             self.n_decodes += 1
@@ -492,8 +509,8 @@ class InferenceEngine:
                 # lint: donated-escape-ok — decode outputs are fresh XLA
                 # result buffers; only the k/v pools are donated
                 nxt = np.asarray(nxt)
-                # the model's step counters (moe_local_hits, moe_load_peak):
-                # scalars that come with the tokens
+                # the model's step counters (moe_local_hits, moe_load_peak,
+                # loop_exit_steps): scalars that come with the tokens
                 span.tag(**{name: int(x) for name, x in stats.items()})
             with spans.span(_SPAN_FETCH, bytes=logits.nbytes):
                 # lint: host-sync-ok — this span IS the copy to the host

@@ -61,6 +61,10 @@ class PagedKVCache:
     the scheduler, never on device.
     """
 
+    # ``L`` counts the pool's ENTRIES: one a layer that attends, or, for a
+    # stack that is applied ``loops`` times, one per (loop step, layer).
+    # Every method's ``layer`` is an entry: a Python int, or a traced int32
+    # scalar inside a loop of the program (ISSUE 31)
     k: jax.Array             # [L, num_blocks, block_size, H, Dh]
     v: jax.Array             # [L, num_blocks, block_size, H, Dh]
     block_tables: jax.Array  # [max_batch, max_blocks_per_seq] int32
@@ -149,7 +153,7 @@ class PagedKVCache:
             self, block_tables=jnp.asarray(tables, jnp.int32))
 
     # -- writes --------------------------------------------------------------
-    def write_prefill(self, layer: int, k, v, table_row) -> "PagedKVCache":
+    def write_prefill(self, layer, k, v, table_row) -> "PagedKVCache":
         """Write a whole prompt's K/V for one layer: ``k``/``v``
         ``[1, P_pad, H, Dh]`` with ``P_pad`` a multiple of ``block_size``;
         ``table_row`` ``[P_pad // block_size]`` block ids (padding entries
@@ -164,7 +168,7 @@ class PagedKVCache:
             self, k=self.k.at[layer, idx].set(blocks_k.astype(self.k.dtype)),
             v=self.v.at[layer, idx].set(blocks_v.astype(self.v.dtype)))
 
-    def write_decode(self, layer: int, k, v, positions) -> "PagedKVCache":
+    def write_decode(self, layer, k, v, positions) -> "PagedKVCache":
         """Append one token's K/V per batch slot: ``k``/``v`` ``[B, H, Dh]``
         at ``positions`` ``[B]`` (inactive slots' tables point at the null
         block, so their writes land in reserved garbage)."""
@@ -222,7 +226,7 @@ class PagedKVCache:
         return ctx[None].astype(q.dtype)
 
     # -- paged attention (decode) --------------------------------------------
-    def attend_decode(self, layer: int, q, positions):
+    def attend_decode(self, layer, q, positions):
         """Masked attention of one query token per slot over its cached
         context: ``q`` ``[B, H, Dh]``, ``positions`` ``[B]`` (the query's
         own 0-based position, already written) -> context ``[B, H, Dh]``.
@@ -294,7 +298,7 @@ class PagedKVCache:
         m, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, a0))
         return (acc / jnp.swapaxes(l, 1, 2)).astype(q.dtype)
 
-    def _attend_decode_grouped(self, layer: int, q, positions):
+    def _attend_decode_grouped(self, layer, q, positions):
         """:meth:`attend_decode`'s fallback where the pool holds fewer K/V
         heads than ``q`` has query heads (query head ``h`` reads K/V head
         ``h // (H // Hkv)``): one masked fp32 softmax over each slot's

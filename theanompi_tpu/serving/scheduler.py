@@ -122,7 +122,8 @@ class Scheduler:
     blocks back, and the whole tree invalidates when the engine's
     ``params_version`` moves (live rollout).  Token streams are unchanged
     by the cache — bit-equal to ``prefix_cache=False`` — only the prefill
-    work is.
+    work is.  A model that keeps per-slot recurrent state, or has no
+    partial prefill (``HybridLM``), is refused with the reason.
     """
 
     def __init__(self, engine, telemetry=None, eos_token: int | None = None,
@@ -146,6 +147,12 @@ class Scheduler:
                 "recurrent state (cache_spec()['state']): the prefix cache "
                 "shares K/V blocks and holds no state snapshot to resume "
                 "from; serve this model without it")
+        if prefix_cache and not getattr(engine, "partial_prefill", True):
+            raise ValueError(
+                "prefix_cache=True with a model that has no partial prefill "
+                "(apply_prefill_partial): a hit prefills only the suffix "
+                "over the cached blocks, which this model cannot; serve it "
+                "without the prefix cache")
         # ISSUE 17: radix prefix cache over the pool — OFF by default (the
         # cache-OFF token streams are the bit-equality reference)
         self.prefix_cache = (PrefixCache(self.pool, engine.block_size)

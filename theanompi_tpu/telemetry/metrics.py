@@ -66,9 +66,13 @@ SERVE_SPANS = ("serve.prefill", "serve.decode")
 #: (``telemetry/spans.py``), beside the two above which the ENGINE opens
 #: now (``serve.prefill`` tags: ``request``, ``prompt``, ``bucket`` — the
 #: padded length that picks the program, ``prefix_len``; ``serve.decode``
-#: tags: ``step`` — the engine's decode ordinal, ``batch``, ``requests``;
+#: tags: ``step`` — the engine's decode ordinal, ``batch``, ``requests``,
+#: ``kv_tokens`` — the tokens the step's attention reads: each active
+#: slot's context with the token it writes, counted on the host, for every
+#: model (ISSUE 31);
 #: from a model that counts them on the device (``HybridLM``) also the two
-#: of :data:`SERVE_DECODE_MOE_TAGS`, fetched with the step's tokens; both
+#: of :data:`SERVE_DECODE_MOE_TAGS` and, from a looped stack, the one of
+#: :data:`SERVE_DECODE_LOOP_TAGS`, fetched with the step's tokens; both
 #: spans carry :data:`SERVE_MOE_PRODUCT_TAGS` where the model has an expert
 #: layer).
 #: ``serve.step`` is one ``Scheduler.step`` (tags ``step``, ``batch``);
@@ -98,6 +102,11 @@ SERVE_MOE_PRODUCT_TAGS = ("moe_products", "moe_kernel_products")
 #: the decode program; ``state_kernel_updates`` — those of them whose state
 #: update the Pallas kernel runs (all of them, or 0 where the plain lines do)
 SERVE_STATE_UPDATE_TAGS = ("state_updates", "state_kernel_updates")
+#: ``serve.decode`` tag of a ``HybridLM`` whose pattern is applied ``loops``
+#: > 1 times (ISSUE 31): ``loop_exit_steps`` — the sum over the active slots
+#: of the 1-based loop step whose state the head read (over ``batch``:
+#: ``loops`` at ``exit_threshold`` 1, where every token reads the last step)
+SERVE_DECODE_LOOP_TAGS = ("loop_exit_steps",)
 #: ``jax.named_scope`` names on the DEVICE (op metadata: they name rows of a
 #: profiler trace, not ring records), by who opens them.  The engine:
 #: ``recast`` (weight casts / dequantize), ``sample``; ``TransformerLM``:
@@ -114,7 +123,9 @@ SERVE_STATE_UPDATE_TAGS = ("state_updates", "state_kernel_updates")
 #: ``embed``, ``mamba`` (a Mamba-2 mixer, projections and state update),
 #: ``moe.route`` (router, top-k), ``moe.experts`` (latent projections,
 #: sort, the kernel's schedule, grouped products), ``moe.shared`` (the
-#: shared expert), ``attn``, ``head``
+#: shared expert), ``attn``, ``mlp`` (the ``-`` letter's gated FFN),
+#: ``loop.exit`` (a looped stack's end of step: final norm, exit gate,
+#: read-out), ``head``
 DEVICE_SCOPES = {
     "engine": ("recast", "sample"),
     "TransformerLM": ("embed", "block", "attn", "mlp", "head", "loss"),
@@ -122,7 +133,7 @@ DEVICE_SCOPES = {
     "kernels": ("paged_decode", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "grouped_matmul", "mamba_state_update"),
     "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
-                 "attn", "head"),
+                 "attn", "mlp", "loop.exit", "head"),
 }
 #: one ``train_iter`` (tags ``step``, ``epoch``; ``loss`` at fenced steps)
 TRAIN_SPANS = ("train.step",)
