@@ -23,10 +23,14 @@ resamples identically, and greedy (``temperature=0``) is pure argmax.
 
 Each host call is a span of the process's ring
 (:mod:`theanompi_tpu.telemetry.spans`): ``serve.prefill`` around a whole
-prefill, ``serve.decode`` around a whole decode step with its four parts
+prefill, ``serve.decode`` around a whole decode call with its four parts
 beneath it — ``.place`` (the host-to-device puts), ``.dispatch`` (the
-jitted call), ``.wait`` (the next tokens reach the host: the device step
-is over), ``.fetch`` (the logits' copy).  On the device, ``recast`` and
+jitted call), ``.wait`` (a launch's tokens reach the host: that device
+step is over), ``.fetch`` (what else comes with them: the step's device
+counters and, for a direct caller, the logits).  Under a scheduler
+(``run_ahead_for``) the launch a call reads is the PREVIOUS call's, so
+the wait is for a step the device was given a whole call earlier.  On the
+device, ``recast`` and
 ``sample`` scopes name the weight cast and the sampler beside the model's
 own ``embed`` / ``block`` / ``attn`` / ``mlp`` / ``head`` (``HybridLM``:
 ``mamba`` / ``moe.route`` / ``moe.experts`` / ``moe.shared`` /
@@ -64,7 +68,11 @@ from theanompi_tpu.serving.quant import (
     quantize_tree,
 )
 from theanompi_tpu.telemetry import spans
-from theanompi_tpu.telemetry.metrics import SERVE_DECODE_SPANS, SERVE_SPANS
+from theanompi_tpu.telemetry.metrics import (
+    SERVE_COLLECT_SPAN,
+    SERVE_DECODE_SPANS,
+    SERVE_SPANS,
+)
 
 _SPAN_PREFILL, _SPAN_DECODE = SERVE_SPANS
 _SPAN_PLACE, _SPAN_DISPATCH, _SPAN_WAIT, _SPAN_FETCH = SERVE_DECODE_SPANS
@@ -72,6 +80,25 @@ _SPAN_PLACE, _SPAN_DISPATCH, _SPAN_WAIT, _SPAN_FETCH = SERVE_DECODE_SPANS
 
 def _is_quantized(leaf) -> bool:
     return isinstance(leaf, QuantizedTensor)
+
+
+class _Launch:
+    """One decode step as launched: its outputs, still on the device
+    (``logits`` None where nobody will read them)."""
+
+    __slots__ = ("nxt", "logits", "stats", "owner", "overrun")
+
+    def __init__(self, nxt, logits, stats, owner):
+        self.nxt, self.logits, self.stats = nxt, logits, stats
+        #: who left it unread (``InferenceEngine.run_ahead_for``)
+        self.owner = owner
+        #: slots of this step that ran for a request which had already
+        #: ended (the scheduler finds out one step late: ``overrun``)
+        self.overrun = 0
+
+
+#: what a call reads where no launch was unread: no tokens, no counters
+_NO_LAUNCH = _Launch(np.zeros((0,), np.int32), None, {}, None)
 
 
 def sample_tokens(logits, temps, keys, top_k: int = 0):
@@ -205,7 +232,7 @@ class InferenceEngine:
         # Dh] arrays per generated token (the cache docstring's contract).
         # The state pool, where the model has one, rides as the trailing
         # argument and is donated the same way; a model without one passes
-        # nothing there and compiles the programs it always did.
+        # nothing there (a decode step: the empty dict) and donates nothing.
         self._donate = (1, 2, 9) if self._state else (1, 2)
         self._decode_fn = jax.jit(self._decode_impl,
                                   donate_argnums=self._donate)
@@ -220,6 +247,14 @@ class InferenceEngine:
         self.params_version = 0
         #: decode steps run so far: the ``step`` tag of ``serve.decode``
         self.n_decodes = 0
+        #: set by a scheduler around its decode call, to itself: the call
+        #: then leaves its launch unread and returns the PREVIOUS launch's
+        #: tokens (see :meth:`decode`).  None: a direct caller, whose call
+        #: reads its own launch.
+        self.run_ahead_for = None
+        self._unread: _Launch | None = None
+        # what a step with no launch before it takes for ``carry``
+        self._no_carry = jax.device_put(np.zeros((self.max_batch,), np.int32))
 
     @property
     def quantized(self) -> bool:
@@ -306,7 +341,14 @@ class InferenceEngine:
 
     # -- compiled bodies -----------------------------------------------------
     def _decode_impl(self, params, k, v, tables, lengths, tokens, temps,
-                     rids, base_key, state=None):
+                     rids, base_key, state=None, carry=None):
+        # ``carry``: the previous step's ``nxt``, as it left the device; a
+        # slot whose ``tokens`` entry is negative is fed from it, so a
+        # sequence's next step needs nothing from the host.  (The engine
+        # always passes one: it builds ONE decode program.  Lowered without,
+        # as the audits do, the step is the same less this select.)
+        if carry is not None:
+            tokens = jnp.where(tokens < 0, carry, tokens)
         # fast path keeps kernel-consumable int8 leaves quantized; the
         # fallback dequantizes everything exactly as before (the PR 9
         # argmax-agreement lock rides on that path staying bit-stable)
@@ -483,40 +525,104 @@ class InferenceEngine:
         """One decode step over the fixed batch; -> (next tokens ``[B]``
         np.int32, logits ``[B, V]`` np).  All arguments are host arrays of
         length ``max_batch``; inactive slots pass table rows of nulls and
-        length 0 (their outputs are garbage by contract)."""
+        length 0 (their outputs are garbage by contract).  The arrays are
+        copied on the way in: the caller may change them once this returns.
+
+        A direct caller gets "launch, then read that same launch".  With
+        ``run_ahead_for`` set (a :class:`~theanompi_tpu.serving.scheduler
+        .Scheduler` sets it, to itself, around its call) the call launches
+        step n and then reads step n-1, which the device finished while the
+        host prepared this one: -> (step n-1's tokens, None) — the tokens
+        reach the host one step after they are computed, the logits never
+        do — or an empty token array where no launch was unread.  A slot
+        that continues passes a NEGATIVE token: step n takes its input on
+        the device from step n-1's output, the same program either way.
+        :meth:`collect` reads the launch still out without launching
+        another (a drain).  A launch is read by whoever left it unread:
+        anyone else's call drops it (a loop that was abandoned)."""
         lengths = np.asarray(lengths)
         active = np.flatnonzero(lengths)
+        owner = self.run_ahead_for
+        prev = self._unread
+        if prev is not None and prev.owner is not owner:
+            prev = None
+        if prev is None and (np.asarray(tokens)[active] < 0).any():
+            raise ValueError("a slot asks for the previous step's token and "
+                             "no launch of this caller's is unread")
         # ``kv_tokens``: the tokens this step's attention reads, each
-        # active slot's context with the token it writes
+        # active slot's context with the token it writes.  ``step``,
+        # ``batch``, ``kv_tokens`` and ``requests`` are the launched
+        # step's; the device counters the span is tagged with below are
+        # those of the step it READ, one behind where the call runs ahead
         with spans.span(_SPAN_DECODE, step=self.n_decodes, batch=len(active),
                         kv_tokens=int(lengths[active].sum()) + len(active),
                         requests=np.asarray(rids)[active].tolist(),
+                        launched=1, ran_ahead=int(prev is not None),
                         **self._moe_tags, **self._state_tags) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):
-                args = (jnp.asarray(tables, jnp.int32),
-                        jnp.asarray(lengths, jnp.int32),
-                        jnp.asarray(tokens, jnp.int32),
-                        jnp.asarray(temps, jnp.float32),
-                        jnp.asarray(rids, jnp.int32))
+                args = jax.device_put((np.array(tables, np.int32),
+                                       np.array(lengths, np.int32),
+                                       np.array(tokens, np.int32),
+                                       np.array(temps, np.float32),
+                                       np.array(rids, np.int32)))
             with spans.span(_SPAN_DISPATCH):
-                own = (self._state,) if self._state else ()
                 nxt, logits, self._k, self._v, self._state, stats = \
-                    self._decode_fn(self.params, self._k, self._v, *args,
-                                    self._base_key, *own)
-            with spans.span(_SPAN_WAIT):
-                # lint: host-sync-ok — this span IS the wait for the device
-                # lint: donated-escape-ok — decode outputs are fresh XLA
-                # result buffers; only the k/v pools are donated
-                nxt = np.asarray(nxt)
-                # the model's step counters (moe_local_hits, moe_load_peak,
-                # loop_exit_steps): scalars that come with the tokens
-                span.tag(**{name: int(x) for name, x in stats.items()})
-            with spans.span(_SPAN_FETCH, bytes=logits.nbytes):
-                # lint: host-sync-ok — this span IS the copy to the host
-                # lint: donated-escape-ok — as above: never tokens/logits
-                logits = np.asarray(logits)
-            return nxt, logits
+                    self._decode_fn(
+                        self.params, self._k, self._v, *args, self._base_key,
+                        self._state,
+                        self._no_carry if prev is None else prev.nxt)
+                # the tokens and counters start for the host as soon as the
+                # step ends, ahead of whatever is launched after it
+                for out in (nxt, *stats.values()):
+                    out.copy_to_host_async()
+            if owner is None:  # a direct caller: this launch, logits and all
+                self._unread = None
+                return self._read(_Launch(nxt, logits, stats, None), span)
+            self._unread = _Launch(nxt, None, stats, owner)
+            return self._read(prev or _NO_LAUNCH, span)
+
+    def _read(self, launch, span):
+        """The ``.wait`` and ``.fetch`` of one call: ``launch``'s tokens,
+        its device counters (tags of ``span``) and, where it kept them,
+        its logits reach the host."""
+        with spans.span(_SPAN_WAIT):
+            # lint: host-sync-ok — this span IS the wait for the device
+            # lint: donated-escape-ok — decode outputs are fresh XLA
+            # result buffers; only the k/v pools are donated
+            nxt = np.asarray(launch.nxt)
+        rest = [*launch.stats.values()]
+        if launch.logits is not None:
+            rest.append(launch.logits)
+        with spans.span(_SPAN_FETCH, bytes=sum(x.nbytes for x in rest)):
+            # the model's step counters (moe_local_hits, moe_load_peak,
+            # loop_exit_steps): scalars that come with the tokens
+            span.tag(overrun_slots=launch.overrun,
+                     **{name: int(x) for name, x in launch.stats.items()})
+            if launch.logits is None:
+                return nxt, None
+            # lint: host-sync-ok — this span IS the copy to the host
+            # lint: donated-escape-ok — as above: never tokens/logits
+            return nxt, np.asarray(launch.logits)
+
+    def collect(self):
+        """Read the launch a decode call left unread, launching
+        nothing (the scheduler's drain); -> its tokens ``[B]``, or None
+        where no launch is unread.  A ``serve.collect`` span (tag
+        ``overrun_slots``); the step's device counters go unreported."""
+        launch, self._unread = self._unread, None
+        if launch is None:
+            return None
+        with spans.span(SERVE_COLLECT_SPAN, overrun_slots=launch.overrun):
+            # lint: host-sync-ok — this span IS the wait for the device
+            # lint: donated-escape-ok — a decode's sampled tokens, as above
+            return np.asarray(launch.nxt)
+
+    def overrun(self) -> None:
+        """A slot of the unread launch ran for a request that had already
+        ended (its stop token was read after the launch went out): counted
+        in the ``overrun_slots`` tag of the span that reads the launch."""
+        self._unread.overrun += 1
 
     def fence(self):
         """Block until the cache state is materialized (honest timing)."""

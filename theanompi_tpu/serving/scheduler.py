@@ -17,6 +17,21 @@ sampling keys derive from ``(request id, position)`` only
 (:mod:`theanompi_tpu.serving.engine`), the replayed sequence continues
 exactly where it left off — greedy or sampled.
 
+The decode loop is a pipeline one step deep (ISSUE 32): ``step`` launches
+decode step n and only then reads step n-1's tokens, so the device always
+has its next program queued and the host's own work hides under a device
+step.  What a launch needs is known by counting — a continuing slot's
+input token is the previous step's output, taken on the device; its length
+is the old one plus one; a request that ends by ``max_new_tokens`` gives
+its slot up when its last step is launched.  Tokens therefore reach
+``req.generated`` one step after they are computed; a request is returned
+``done`` by the step that reads its last token; a request that ends on a
+stop token is found one step late and has then run one step for nothing
+(``n_overrun_slots``).  Whatever needs token values — preemption, a
+deadline's expiry, ``preempt_all``, ``expire_all_active``, an injected
+fault — first reads the launch still out (``_drain``), and the next launch
+starts the pipeline anew.
+
 Request lifecycle (ISSUE 14): every request ends in exactly one typed
 terminal state —
 
@@ -124,6 +139,12 @@ class Scheduler:
     by the cache — bit-equal to ``prefix_cache=False`` — only the prefill
     work is.  A model that keeps per-slot recurrent state, or has no
     partial prefill (``HybridLM``), is refused with the reason.
+
+    The scheduler sets ``engine.run_ahead_for`` around its decode calls:
+    they return the PREVIOUS launch's tokens (module docstring).  An engine
+    that reads its own launch all the same (a test double) is served as it
+    answers: tokens a call returns with nothing unread before it are that
+    call's own.
     """
 
     def __init__(self, engine, telemetry=None, eos_token: int | None = None,
@@ -169,7 +190,16 @@ class Scheduler:
         self._tokens = np.zeros((b,), np.int32)
         self._temps = np.zeros((b,), np.float32)
         self._rids = np.zeros((b,), np.int32)
+        #: the launch whose tokens are still on the device: slot -> the
+        #: request it ran for (None: the host holds every token)
+        self._unread: dict[int, Request] | None = None
+        #: requests a drain outside ``step`` found done: the next step's
+        self._early: list[Request] = []
         self.n_steps = 0
+        #: launches that went out with the one before them unread
+        self.n_ran_ahead = 0
+        #: slot-steps run for a request that had ended on a stop token
+        self.n_overrun_slots = 0
         self.token_ms: list[float] = []
         self.step_ms: list[float] = []  # one entry per decode step
         self.ttft_ms: list[float] = []
@@ -190,7 +220,10 @@ class Scheduler:
 
     @property
     def idle(self) -> bool:
-        return self.n_active == 0 and not self.queue
+        """Nothing queued, active, launched and unread, or waiting to be
+        returned."""
+        return (self.n_active == 0 and not self.queue
+                and self._unread is None and not self._early)
 
     def recent_token_rate(self) -> float | None:
         """Decoded tokens/sec over the recent window; None until at least
@@ -322,7 +355,10 @@ class Scheduler:
         return req
 
     def _finish(self, slot: int, finished: list[Request]) -> None:
-        req = self._evict(slot)
+        self._complete(self._evict(slot), finished)
+
+    def _complete(self, req: Request, finished: list[Request]) -> None:
+        """Typed terminal ``done``; the request's slot is given up already."""
         req.state = "done"
         req.t_done = time.perf_counter()
         self.n_done += 1
@@ -400,6 +436,9 @@ class Scheduler:
         active requests (active ones free their blocks — an expired
         request must stop consuming decode slots immediately)."""
         now = time.perf_counter()
+        if any(req is not None and self._deadline_overrun(req, now)
+               for req in self.slots):
+            self._drain(finished)  # an expired request keeps all its tokens
         kept: deque[Request] = deque()
         while self.queue:
             req = self.queue.popleft()
@@ -433,7 +472,10 @@ class Scheduler:
         """Evict every active request back to the queue front (recompute
         preemption) — the rollout watcher's weight-swap barrier: the KV
         cache was computed under the OLD weights, so active sequences
-        re-prefill under the new ones.  -> number preempted."""
+        re-prefill under the new ones.  The launch still out is read first
+        (a request it finishes is returned by the next ``step``).
+        -> number preempted."""
+        self._drain(self._early)
         n = 0
         for slot in range(self.engine.max_batch):
             if self.slots[slot] is not None:
@@ -566,10 +608,11 @@ class Scheduler:
                 and req.generated
                 and req.generated[-1] == self.eos_token)
 
-    def _ensure_capacity(self) -> None:
+    def _ensure_capacity(self, finished: list[Request]) -> None:
         """Every active slot whose NEXT token starts a new cache block must
-        get one before the decode step; exhaustion preempts the longest
-        active sequence and retries."""
+        get one before the decode step; exhaustion reads the launch still
+        out (the victim is re-queued with every token it has), preempts the
+        longest active sequence and retries."""
         for slot in range(self.engine.max_batch):
             if self.slots[slot] is None:
                 continue
@@ -583,71 +626,136 @@ class Scheduler:
                     self._blocks[slot].extend(got)
                     self._tables[slot, n_used] = got[0]
                     break
+                if self._unread is not None:
+                    self._drain(finished)
+                    continue
                 victim = max(
                     (s for s in range(self.engine.max_batch)
                      if self.slots[s] is not None),
                     key=lambda s: int(self._lengths[s]))
                 self._preempt(victim)
 
-    def _fire_faults(self) -> None:
+    def _fire_faults(self, finished: list[Request]) -> None:
         """serve:raise / serve:stall chaos sites, indexed by decode-step
         ordinal.  Action-narrowed fires: the rollout watcher counts a
-        DIFFERENT ordinal (candidates) for serve:rollout_corrupt."""
+        DIFFERENT ordinal (candidates) for serve:rollout_corrupt.  A fault
+        that fires finds the host holding every token launched so far."""
         if self.fault_plan is None:
             return
         if self.fault_plan.fire("serve", self.n_steps, "stall"):
+            self._drain(finished)
             time.sleep(float(os.environ.get("THEANOMPI_SERVE_STALL_S",
                                             "2.0")))
         if self.fault_plan.fire("serve", self.n_steps, "raise"):
+            self._drain(finished)
             raise FaultInjected(
                 f"serve:raise at decode step {self.n_steps}")
 
+    def _account(self, launch: dict[int, Request], nxt, now: float,
+                 finished: list[Request]) -> int:
+        """One launch's tokens have reached the host: append and stamp
+        them, finish what they end; -> tokens appended.  ``self._unread``
+        is the launch after it, where one is out."""
+        ahead = self._unread or {}
+        n = 0
+        for slot, req in launch.items():
+            if req.state != "active":
+                continue  # ended on a stop token a step ago: an overrun
+            tok = int(nxt[slot])
+            req.generated.append(tok)
+            n += 1
+            # the gap since this request's previous token: a stall for
+            # another request's prefill and the scheduler's own time are
+            # inside it, as the request's user feels them
+            gap_ms = (now - req.t_last_token) * 1e3
+            req.t_last_token = now
+            self.token_ms.append(gap_ms)
+            if self.telemetry is not None:
+                self.telemetry.count(_CNT_TOKENS)
+                self.telemetry.observe(_HIST_TOKEN_MS, gap_ms)
+            holds = self.slots[slot] is req
+            if holds and not ahead:
+                self._tokens[slot] = tok  # the next launch takes it from here
+            if not self._done(req):
+                continue
+            if ahead.get(slot) is req:
+                # found a step late: the launch that is out runs this slot
+                # once more, into a block the request still owned when it
+                # went; its token will be dropped
+                self.n_overrun_slots += 1
+                self.engine.overrun()
+            if holds:
+                self._finish(slot, finished)
+            else:  # ended by length: the slot went when this was launched
+                self._complete(req, finished)
+        return n
+
+    def _drain(self, finished: list[Request]) -> None:
+        """Read the launch still out, if one is: the host then holds every
+        token, as after a step of a loop that never ran ahead."""
+        if self._unread is None:
+            return
+        launch, self._unread = self._unread, None
+        nxt = self.engine.collect()
+        self._account(launch, nxt, time.perf_counter(), finished)
+
     def step(self) -> list[Request]:
         """One scheduler iteration: enforce deadlines, admit, secure
-        blocks, decode the fixed batch, account the new tokens; -> every
-        request that reached a TERMINAL state this step (done + expired +
-        failed — run loops key on ``req.state``)."""
+        blocks, LAUNCH decode step n over the fixed batch, then read and
+        account step n-1's tokens; -> every request that reached a TERMINAL
+        state this step (done + expired + failed — run loops key on
+        ``req.state``).
+
+        Tokens reach ``req.generated`` one step after the device computes
+        them, and a request is returned ``done`` by the step that reads its
+        last token (its slot and blocks went a step earlier, when that
+        token's step was launched).  A stop token is seen one step late:
+        the request has then run one more step, whose token is dropped
+        (``n_overrun_slots``).  Preemption, a deadline's expiry and an
+        injected fault first read the launch that is out (``_drain``); so
+        do ``preempt_all`` and ``expire_all_active``."""
         with spans.span(_SPAN_STEP, step=self.n_steps) as step:
-            finished: list[Request] = []
+            finished, self._early = self._early, []
             self._sweep_deadlines(finished)
             with spans.span(_SPAN_ADMIT, queued=len(self.queue)):
                 self._admit(finished)
-            if self.n_active == 0:
+            if self.n_active:
+                self._ensure_capacity(finished)
+            launch = {s: r for s, r in enumerate(self.slots) if r is not None}
+            if not launch:  # nothing to run (or pressure preempted it all)
+                self._drain(finished)
                 return finished
-            self._ensure_capacity()
-            active = [s for s in range(self.engine.max_batch)
-                      if self.slots[s] is not None]
-            if not active:  # capacity pressure preempted everyone admitted
-                return finished
-            self._fire_faults()
-            step.tag(batch=len(active))
+            self._fire_faults(finished)
+            step.tag(batch=len(launch))
+            self.n_ran_ahead += self._unread is not None
             t0 = time.perf_counter()
-            # decode() returns host arrays: its serve.decode span is fenced
-            nxt, _ = self.engine.decode(self._tables, self._lengths,
-                                        self._tokens, self._temps,
-                                        self._rids)
+            # host arrays, lengths not yet advanced; what comes back is the
+            # PREVIOUS launch's tokens: this one's stay on the device
+            self.engine.run_ahead_for = self
+            try:
+                nxt, _ = self.engine.decode(self._tables, self._lengths,
+                                            self._tokens, self._temps,
+                                            self._rids)
+            finally:
+                self.engine.run_ahead_for = None
             t1 = time.perf_counter()
-            step_ms = (t1 - t0) * 1e3
-            self.step_ms.append(step_ms)
+            self.step_ms.append((t1 - t0) * 1e3)
             self.n_steps += 1
-            self._rate.append((t1, len(active)))
-            for slot in active:
-                req = self.slots[slot]
+            for slot in launch:
                 self._lengths[slot] += 1  # the fed token is now cached
-                tok = int(nxt[slot])
-                req.generated.append(tok)
-                self._tokens[slot] = tok
-                # the gap since this request's previous token: a stall for
-                # another request's prefill and the scheduler's own time are
-                # inside it, as the request's user feels them
-                gap_ms = (t1 - req.t_last_token) * 1e3
-                req.t_last_token = t1
-                self.token_ms.append(gap_ms)
-                if self.telemetry is not None:
-                    self.telemetry.count(_CNT_TOKENS)
-                    self.telemetry.observe(_HIST_TOKEN_MS, gap_ms)
-                if self._done(req):
-                    self._finish(slot, finished)
+                self._tokens[slot] = -1   # the next one is on the device
+            read, self._unread = self._unread, launch
+            if read is None and len(nxt):
+                # an engine that read its own launch: nothing stays out
+                read, self._unread = launch, None
+            self._rate.append(
+                (t1, self._account(read, nxt, t1, finished) if read else 0))
+            for slot, req in (self._unread or {}).items():
+                # the launch that is out is the last of a request that ends
+                # by length: its slot and blocks are free for the next step
+                if (self.slots[slot] is req
+                        and len(req.generated) + 1 >= req.max_new_tokens):
+                    self._evict(slot)
             if self.telemetry is not None and self.n_steps % 16 == 0:
                 # periodic flush (ISSUE 13): the ttft/token histograms must
                 # reach the event stream while serving is LIVE — the health
@@ -673,9 +781,11 @@ class Scheduler:
         return shed
 
     def expire_all_active(self, reason: str) -> list[Request]:
-        """Force every in-flight request terminal (drain deadline): evict
-        and expire with ``reason``.  -> the expired requests."""
+        """Force every in-flight request terminal (drain deadline): read
+        the launch still out, then evict and expire with ``reason``.
+        -> the expired requests (and those that launch finished)."""
         out: list[Request] = []
+        self._drain(out)
         for slot in range(self.engine.max_batch):
             if self.slots[slot] is None:
                 continue
@@ -890,6 +1000,13 @@ def serve_report(results: dict[int, Request], wall_s: float,
         # per-step wall percentiles — the variant key the ledger trends
         "decode_kernel": eng.decode_impl,
         "decode_step_ms": pct(scheduler.step_ms),
+        # ISSUE 32: the decode pipeline — launches, those that went out
+        # with the one before them unread, and slot-steps run for a request
+        # whose stop token was read a step late (the serve.decode tags'
+        # sums)
+        "run_ahead": {"launched": scheduler.n_steps,
+                      "ran_ahead": scheduler.n_ran_ahead,
+                      "overrun_slots": scheduler.n_overrun_slots},
         "terminal_states": states,
         "drained": scheduler.draining,
         "quantized_int8": eng.quantized,

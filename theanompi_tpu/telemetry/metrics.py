@@ -80,8 +80,11 @@ SERVE_SPANS = ("serve.prefill", "serve.decode")
 #: per-request ``serve.admit`` INSTANT keeps its name and kind).
 SERVE_STEP_SPANS = ("serve.step", "serve.admit")
 #: the decode call from inside, in order: the host-to-device puts, the
-#: jitted call's dispatch, the wait for the device step (the next tokens
-#: reach the host), the logits' copy to the host (tag ``bytes``)
+#: jitted call's dispatch, the wait for a launch's tokens (under a
+#: scheduler the PREVIOUS call's launch, ISSUE 32: the device was given this
+#: call's step before the wait began), and what else comes with them (tag
+#: ``bytes``: the step's device counters; the logits too for a direct caller
+#: of ``InferenceEngine.decode``, never on the scheduler's path)
 SERVE_DECODE_SPANS = ("serve.decode.place", "serve.decode.dispatch",
                       "serve.decode.wait", "serve.decode.fetch")
 #: ``serve.decode`` tags of a model with expert layers, one value a step:
@@ -107,6 +110,21 @@ SERVE_STATE_UPDATE_TAGS = ("state_updates", "state_kernel_updates")
 #: of the 1-based loop step whose state the head read (over ``batch``:
 #: ``loops`` at ``exit_threshold`` 1, where every token reads the last step)
 SERVE_DECODE_LOOP_TAGS = ("loop_exit_steps",)
+#: ``serve.decode`` tags of the decode pipeline (ISSUE 32), on every call:
+#: ``launched`` — 1, the step this call gave the device; ``ran_ahead`` — 1
+#: where it went out with the previous launch unread, 0 where the pipeline
+#: was empty (the first step, the one after a drain, a direct caller's);
+#: ``overrun_slots`` — slots of the step this call READ that ran for a
+#: request which had already ended on a stop token (their tokens are
+#: dropped).  ``step`` / ``batch`` / ``kv_tokens`` / ``requests`` are the
+#: launched step's; the device counters above are those of the step read,
+#: one behind
+SERVE_DECODE_AHEAD_TAGS = ("launched", "ran_ahead", "overrun_slots")
+#: ``InferenceEngine.collect``: the wait for the launch a drain reads,
+#: with no launch of its own (under ``serve.step``, or under nothing where
+#: ``preempt_all`` / ``expire_all_active`` drained between steps); tag
+#: ``overrun_slots`` as on a ``serve.decode`` that reads a launch
+SERVE_COLLECT_SPAN = "serve.collect"
 #: ``jax.named_scope`` names on the DEVICE (op metadata: they name rows of a
 #: profiler trace, not ring records), by who opens them.  The engine:
 #: ``recast`` (weight casts / dequantize), ``sample``; ``TransformerLM``:
