@@ -197,13 +197,18 @@ def one_chip():
     (128 * 22, 2688, 1024, "float32"),     # decode, second product
     (4096 * 22, 1024, 2688, "bfloat16"),   # the longest prefill bucket
     (4 * 22, 128, 256, "bfloat16"),        # fewer rows than one tile of 128
+    # experts at model width, gate and up in one product (ISSUE 33): 256
+    # experts, weight tiles of 4 MB and 2 MB
+    (32 * 8, 2048, 1024, "bfloat16"),      # decode, first product
+    (32 * 8, 512, 2048, "float32"),        # decode, second product
+    (16384 * 8, 2048, 1024, "bfloat16"),   # the longest prefill bucket
 ])
 def test_the_served_widths_compile_for_a_v5e(one_chip, m, k, n, out):
     """What the interpreter cannot show: Mosaic takes the tiles, the
     dynamic number of visits and the VMEM the call asks for."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    e, bf16 = 128, jnp.bfloat16
+    e, bf16 = (256 if 2048 in (k, n) else 128), jnp.bfloat16
     tm = gmm.row_tile(m, bf16)
 
     def shape(s, dt):

@@ -101,8 +101,9 @@ def test_letters_loops_and_the_state_pool():
 
     with pytest.raises(ValueError, match="gated FFN"):
         HybridLM({"pattern": "*x"})
-    with pytest.raises(ValueError, match="no M layer"):
-        HybridLM({"pattern": "M*-", "loops": 2})
+    for pattern in ("M*-", "w-"):  # slot-owned pools: one entry a layer
+        with pytest.raises(ValueError, match="no M or w layer"):
+            HybridLM({"pattern": pattern, "loops": 2})
     with pytest.raises(ValueError, match="loops >= 1"):
         HybridLM({"pattern": "*-", "loops": 0})
     model = HybridLM({"pattern": "*-*-*", "loops": 4, "kv_heads": 2,
@@ -379,7 +380,9 @@ def test_the_loop_is_a_loop_of_the_program(tiny):
 #: ``-``) lowered its serving steps to at the parent of PR 31 (commit
 #: 4bffab6, jax 0.9.0).  A PR that means to change these programs replaces
 #: the hashes.
-GOLDEN_HYBRID = {"decode": "09e39c4d17dbe50b", "prefill": "e93a8e1b2fbe9012"}
+#: PR 33 replaced ``prefill``: the head reads the one position that is
+#: sampled (``head_at``), not the bucket's every row; ``decode`` is PR 31's.
+GOLDEN_HYBRID = {"decode": "09e39c4d17dbe50b", "prefill": "474deaf959a5d613"}
 
 
 @pytest.mark.parametrize("name", ["decode", "prefill"])
@@ -488,3 +491,72 @@ def test_the_served_widths_compile_for_a_v5e_around_one_pool(
     # kernel from 128 tokens on, the plain blockwise attention below
     assert calls == {"decode": 4, "prefill16": 0, "prefill128": 4}[program]
     assert len(re.findall(r"\bwhile\(", text)) >= 1
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill16384"])
+def test_the_window_models_served_widths_compile_for_a_v5e_and_fit(
+        one_chip, monkeypatch, program):
+    """The other architecture of this spine at ITS served widths
+    (``benchmarks/configs/laguna-xs2-pp8.json``: five layers, 32 slots,
+    contexts to 16 384): the programs compile, the pool and the window
+    rings are aliased in and out, the grouped products and (prefill) the
+    flash kernel with its band are custom calls, and arguments +
+    temporaries leave the chip room — the decode step's gather is held a
+    piece at a time (4.3 GB whole), the longest bucket's head is one row
+    (6.6 GB of float32 logits whole)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from benchmarks.arch import laguna
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "laguna-xs2-pp8.json")) as f:
+        cfg = json.load(f)
+    run = cfg["run"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    model = HybridLM(laguna.model_config(cfg))
+
+    def shape(s, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    class Engine(InferenceEngine):
+        def _held(self, params):
+            return jax.tree.map(lambda x: shape(x.shape, jnp.bfloat16), params)
+
+    eng = Engine(model, jax.eval_shape(model.init_params,
+                                       jax.random.PRNGKey(0))[0],
+                 block_size=16, num_blocks=2, max_batch=1)
+    assert (eng.decode_impl, eng.expert_impl) == ("fallback", "kernel")
+    b = run["max_batch"]
+    pool = shape((2, run["num_blocks"], 16, 8, 128), jnp.bfloat16)
+    ring = shape((3, b, 512, 8, 128), jnp.bfloat16)
+    state = {"window_k": ring, "window_v": ring}
+    key = shape((2,), jnp.uint32)
+    if program == "decode":
+        fn, args = eng._decode_impl, (
+            shape((b, eng.max_blocks_per_seq)), shape((b,)), shape((b,)),
+            shape((b,), jnp.float32), shape((b,)), key, state, shape((b,)))
+    else:
+        p = int(program[len("prefill"):])
+        fn, args = eng._prefill_impl, (
+            shape((p // 16,)), shape((p,)), shape(()), shape((), jnp.float32),
+            shape(()), key, state, shape(()))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(fn, donate_argnums=(1, 2, 9)).lower(
+            eng.params, pool, pool, *args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    held = 2 * (2 * run["num_blocks"] * 16 + 3 * b * 512) * 8 * 128 * 2
+    assert mem.alias_size_in_bytes >= held
+    assert 9.6e9 < mem.argument_size_in_bytes < 9.8e9   # 7.74 GB of weights
+    # decode 1.4 GB (one 4096-token piece of the gather and its copy),
+    # prefill 2.5 GB (131 072 expert rows at model width)
+    assert mem.temp_size_in_bytes < {"decode": 1.6e9, "prefill16384": 2.8e9}[
+        program], mem
+    calls = text.count("tpu_custom_call")
+    # eight grouped products; in prefill also five flash kernels, three of
+    # them over the band
+    assert calls == {"decode": 8, "prefill16384": 13}[program]

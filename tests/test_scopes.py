@@ -17,6 +17,8 @@ MODEL = ("embed", "block", "attn", "mlp")
 HYBRID = ("embed", "mamba", "moe.route", "moe.experts", "moe.shared", "attn",
           "head")
 LOOPED = ("embed", "attn", "mlp", "loop.exit", "head", "sample")
+WINDOWED = ("embed", "attn", "attn.window", "mlp", "moe.route", "moe.experts",
+            "moe.shared", "head", "sample")
 #: program -> the scopes it must show
 EXPECTED = {
     "train": (*MODEL, "loss", "clip", "exchange", "optimizer"),
@@ -30,6 +32,9 @@ EXPECTED = {
     # a looped stack (ISSUE 31): the ``-`` mixer and the end of a loop step
     "looped_decode": LOOPED,
     "looped_prefill": LOOPED,
+    # window and full attention layers in one model (ISSUE 33)
+    "window_decode": WINDOWED,
+    "window_prefill": WINDOWED,
 }
 SCOPES = sorted({s for names in EXPECTED.values() for s in names}
                 | {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
@@ -157,6 +162,30 @@ def lowered():
         jnp.zeros((16,), i32), jnp.asarray(5, i32),
         jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
         eng._base_key).as_text(debug_info=True)
+    window = HybridLM({"pattern": "*-wE", "dim": 32, "vocab": 61, "seq_len": 32,
+                       "heads": 4, "window_heads": 8, "kv_heads": 2,
+                       "head_dim": 8, "ffn_dim": 48, "window": 8,
+                       "attn_gate": True, "rope_theta": 5e5, "rope_share": 0.5,
+                       "rope_yarn": {"factor": 8, "original_max_position": 16,
+                                     "beta_fast": 4, "beta_slow": 1},
+                       "window_rope_theta": 1e4, "n_experts": 8, "top_k": 2,
+                       "latent": None, "expert_dim": 16, "shared_dim": 16,
+                       "expert_act": "silu_gated"})
+    eng = InferenceEngine(window, window.init_params(jax.random.PRNGKey(0))[0],
+                          block_size=8, max_batch=2)
+    out["window_decode"] = eng._decode_fn.lower(
+        eng.params, eng._k, eng._v,
+        jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+        jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+        jnp.zeros((b,), i32), eng._base_key,
+        eng._state).as_text(debug_info=True)
+    out["window_prefill"] = jax.jit(
+        eng._prefill_impl, donate_argnums=(1, 2, 9)).lower(
+        eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
+        jnp.zeros((16,), i32), jnp.asarray(5, i32),
+        jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
+        eng._base_key, eng._state,
+        jnp.asarray(1, i32)).as_text(debug_info=True)
     return out
 
 
@@ -188,6 +217,24 @@ def test_the_exchange_and_the_loss_own_their_ops(lowered):
     assert all(re.search(r"attn|mlp", s) for s in blocks)
 
 
+@pytest.mark.parametrize("program", ["window_decode", "window_prefill"])
+def test_each_attention_kind_owns_its_products_and_its_gate(lowered, program):
+    """A ``*`` layer's products read ``attn``, a ``w`` layer's
+    ``attn.window``; each kind's five weight products (q|k|v fused, the
+    gate, o, and the two of the attention itself) lie inside its scope, the
+    gate's ``[dim, heads]`` among them."""
+    ops, _ = named_ops(lowered[program])
+    dots = [s for op, s in ops if op == "stablehlo.dot_general"]
+    full = [s for s in dots if re.search(r"attn(?![.\w])", s)]
+    band = [s for s in dots if "attn.window" in s]
+    assert len(full) == len(band) == 5, (len(full), len(band))
+    text = lowered[program]
+    # the gates: 32 -> 4 heads under attn, 32 -> 8 heads under attn.window
+    for heads in (4, 8):
+        assert re.search(rf"tensor<32x{heads}xbf16>\) -> tensor<[0-9x]*x{heads}xbf16>",
+                         text), heads
+
+
 def test_the_jitted_programs_keep_their_names(lowered):
     """The benchmark's traffic files find the programs by these names."""
     assert "module @jit_local_step" in lowered["train"]
@@ -201,5 +248,5 @@ def test_the_documented_scopes_are_the_ones_the_programs_show(lowered):
 
     documented = {s for names in DEVICE_SCOPES.values() for s in names}
     assert set(SCOPES) <= documented
-    assert set(DEVICE_SCOPES["HybridLM"]) == set(HYBRID) | set(LOOPED) - {
-        "sample"}
+    assert set(DEVICE_SCOPES["HybridLM"]) == (
+        set(HYBRID) | set(LOOPED) | set(WINDOWED)) - {"sample"}

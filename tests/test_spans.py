@@ -302,6 +302,42 @@ def test_decode_spans_count_the_context_and_a_looped_stacks_exit_steps(
     assert [r.tags.get("loop_exit_steps") for r in ours] == [None, 2]
 
 
+def test_a_model_with_window_layers_tags_the_keys_each_kind_attends(
+        served_events):
+    """``kv_full_tokens`` and ``kv_window_tokens`` (``SERVE_DECODE_WINDOW_TAGS``)
+    ride on the ``serve.decode`` of a ``HybridLM`` with a ``w`` layer: each
+    active slot's context, and the same capped at the window; no other
+    model's span carries them."""
+    import jax
+
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.telemetry.metrics import SERVE_DECODE_WINDOW_TAGS
+
+    events, _ = served_events
+    assert not any(t in e for e in _spans_named(events, "serve.decode")
+                   for t in SERVE_DECODE_WINDOW_TAGS)
+    model = HybridLM({"pattern": "*-w-", "dim": 32, "vocab": 61, "seq_len": 32,
+                      "heads": 4, "window_heads": 8, "kv_heads": 2,
+                      "head_dim": 8, "ffn_dim": 48, "window": 6,
+                      "attn_gate": True, "rope_theta": 1e4, "rope_share": 0.5,
+                      "window_rope_theta": 1e4})
+    engine = InferenceEngine(model, model.init_params(jax.random.PRNGKey(0))[0],
+                             block_size=4, max_batch=2)
+    sched = Scheduler(engine)
+    sched.submit(Request(rid=7, prompt=[1, 2, 3, 4], max_new_tokens=5))
+    sched.submit(Request(rid=8, prompt=[5, 6], max_new_tokens=5))
+    while not sched.idle:
+        sched.step()
+    ours = [r for r in spans.snapshot()
+            if r.name == "serve.decode" and r.tags["requests"] == [7, 8]]
+    assert [r.tags["kv_tokens"] for r in ours] == [8, 10, 12, 14]
+    assert [r.tags["kv_full_tokens"] for r in ours] == [8, 10, 12, 14]
+    # contexts (5, 3), (6, 4), (7, 5), (8, 6) against a window of 6
+    assert [r.tags["kv_window_tokens"] for r in ours] == [8, 10, 11, 12]
+    assert engine.resolved_paths()["window_attention"] == (
+        "slot ring of 6 tokens, masked grouped softmax")
+
+
 def test_the_decode_parts_add_up_to_the_decode_span(served_events):
     events, _ = served_events
     parts: dict = {}

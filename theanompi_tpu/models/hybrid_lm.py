@@ -6,15 +6,26 @@ layer's letter in ``pattern`` (the Nemotron-H family's
 
 - ``M``: a Mamba-2 mixer (:mod:`theanompi_tpu.ops.mamba2`): a fixed-size
   recurrent state per sequence, no K/V;
-- ``E``: dropless top-k latent experts with a shared expert
+- ``E``: dropless top-k experts with a shared expert
   (:class:`theanompi_tpu.ops.moe.DroplessMoE`), of which this process may
-  hold a share (``experts_held``); its grouped products go through
-  ``lax.ragged_dot`` or, where a serving engine resolves so, the kernel of
+  hold a share (``experts_held``): behind a ``latent`` projection or
+  (``latent=None``) at model width, ``expert_act`` ``relu2`` or
+  ``silu_gated``; its grouped products go through ``lax.ragged_dot`` or,
+  where a serving engine resolves so, the kernel of
   :mod:`theanompi_tpu.ops.pallas_grouped_matmul`;
 - ``*``: causal attention with grouped K/V heads
   (:class:`theanompi_tpu.ops.attention.GroupedQueryAttention`): paged K/V;
   no positional term, or with ``rope_theta`` rotary positions at that
-  base (the pool then holds rotated keys);
+  base (the pool then holds rotated keys) over ``rope_share`` of the head,
+  YaRN-scaled with ``rope_yarn``;
+- ``w``: the same layer over a band — position ``i`` attends ``i - window <
+  j <= i`` — with query heads (``window_heads``) and a rotary rule
+  (``window_rope_theta``, ``window_rope_share``, ``window_rope_yarn``) of
+  its own and the ``*`` layers' K/V heads.  Its K/V is NOT paged: a ring
+  of ``window`` tokens a slot, whatever the context (``cache_spec()``'s
+  ``window``), so admission and preemption count the ``*`` layers' blocks
+  only.  ``attn_gate`` gives both kinds a sigmoid gate a head on the
+  context, read from the layer's input;
 - ``-``: a bias-free gated feed-forward of ``ffn_dim``
   (:class:`theanompi_tpu.ops.layers.GatedFFN`).
 
@@ -37,7 +48,8 @@ and each (step, ``*`` layer) has K/V of its own: ``cache_spec()`` asks for
 for layer ``l`` of step ``t`` — ``2 x loops x attention layers x kv_heads
 x head_dim x itemsize`` bytes of cache a token, which is what
 ``--num-blocks`` x ``--block-size`` tokens must be sized by.  A pattern with
-``M`` refuses ``loops`` > 1 (its state pool has one entry a layer).
+``M`` or ``w`` refuses ``loops`` > 1 (their slot-owned pools have one entry
+a layer).
 
 **Serving only.**  The model exposes what
 :class:`theanompi_tpu.serving.engine.InferenceEngine` calls —
@@ -45,16 +57,26 @@ x head_dim x itemsize`` bytes of cache a token, which is what
 which says which layers hold paged K/V at how many heads and which hold
 per-slot state of what shapes — and ``loss_fn`` refuses: the chunked scan
 has no backward here, nor has the loop a multi-exit loss.  It has no
-partial prefill, so the scheduler's prefix cache refuses it.  Device
+partial prefill, so the scheduler's prefix cache refuses it.  A prefill's
+head reads ONE position (``head_at``: the one that is sampled), not the
+bucket's every row; ``apply_logits`` reads them all.  Device
 scopes: ``embed``, ``mamba``, ``moe.route``, ``moe.experts``,
-``moe.shared``, ``attn``, ``mlp``, ``loop.exit`` (a step's final norm, gate
-and read-out), ``head``.
+``moe.shared``, ``attn``, ``attn.window`` (the gate inside either),
+``mlp``, ``loop.exit`` (a step's final norm, gate and read-out), ``head``.
 
     tmserve --modelfile theanompi_tpu.models.hybrid_lm --modelclass HybridLM \\
         --set pattern="'MEM*E'" --set dim=256 ...
     # a looped stack of attention + gated FFN, rotary, sandwich norm:
     tmserve ... --set pattern="'*-*-'" --set loops=4 --set post_norm=True \\
         --set rope_theta=1e6 --set ffn_dim=704 --num-blocks <tokens / block>
+    # window and full attention of different head counts over one cache, a
+    # gate a head, partial YaRN rotary, gated experts at model width:
+    tmserve ... --set pattern="'*-wEwEwE*E'" --set heads=48 \
+        --set window_heads=64 --set window=512 --set attn_gate=True \
+        --set rope_theta=5e5 --set rope_share=0.5 \
+        --set rope_yarn="{'factor': 64, 'original_max_position': 4096, \
+            'beta_fast': 64, 'beta_slow': 1}" --set window_rope_theta=1e4 \
+        --set latent=None --set expert_act="'silu_gated'"
 """
 
 from __future__ import annotations
@@ -73,7 +95,9 @@ from theanompi_tpu.ops.attention import GroupedQueryAttention
 from theanompi_tpu.ops.mamba2 import Mamba2
 from theanompi_tpu.ops.moe import DroplessMoE
 
-_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "-": "mlp"}
+_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "w": "attn_w", "-": "mlp"}
+#: the device scope of each attention kind
+_ATTN_SCOPES = {"attn": "attn", "attn_w": "attn.window"}
 
 
 def _row_major(cache):
@@ -102,6 +126,16 @@ class HybridLM(Model):
         "head_dim": 32,
         "attn_impl": "auto",
         "rope_theta": None,
+        "rope_share": 1.0,
+        "rope_yarn": None,
+        # ``*`` and ``w``: a sigmoid gate a head on the context
+        "attn_gate": False,
+        # ``w``: the band, its query heads (None: ``heads``), its rotary rule
+        "window": 512,
+        "window_heads": None,
+        "window_rope_theta": None,
+        "window_rope_share": 1.0,
+        "window_rope_yarn": None,
         # ``-``
         "ffn_dim": 512,
         # ``M``
@@ -119,6 +153,7 @@ class HybridLM(Model):
         "expert_dim": 128,
         "shared_dim": 256,
         "route_scale": 1.0,
+        "expert_act": "relu2",
         # the spine: a second norm a layer, after the mixer; the pattern
         # applied ``loops`` times (``exit_threshold`` is read when > 1)
         "post_norm": False,
@@ -136,12 +171,15 @@ class HybridLM(Model):
         if bad or not cfg["pattern"]:
             raise ValueError(f"pattern {cfg['pattern']!r}: letters are "
                              f"{sorted(_KINDS)} (M Mamba-2, E experts, "
-                             f"* attention, - gated FFN)")
-        if cfg["loops"] < 1 or (cfg["loops"] > 1 and "M" in cfg["pattern"]):
+                             f"* attention, w window attention, - gated "
+                             f"FFN)")
+        if cfg["loops"] < 1 or (cfg["loops"] > 1
+                                and set(cfg["pattern"]) & set("Mw")):
             raise ValueError(
                 f"loops={cfg['loops']} with pattern {cfg['pattern']!r}: a "
-                f"looped stack needs loops >= 1 and no M layer (the state "
-                f"pool holds one entry a layer, not one a loop step)")
+                f"looped stack needs loops >= 1 and no M or w layer (a "
+                f"slot-owned pool holds one entry a layer, not one a loop "
+                f"step)")
         #: the dtype a serving engine holds the weights in
         self.weight_dtype = (jnp.bfloat16 if cfg["weights"] == "bf16"
                              else jnp.float32)
@@ -155,11 +193,20 @@ class HybridLM(Model):
             "moe": DroplessMoE(cfg["dim"], cfg["n_experts"], cfg["top_k"],
                                cfg["latent"], cfg["expert_dim"],
                                cfg["shared_dim"], float(cfg["route_scale"]),
-                               tuple(held) if held is not None else None),
-            "attn": GroupedQueryAttention(cfg["dim"], cfg["heads"],
-                                          cfg["kv_heads"], cfg["head_dim"],
-                                          cfg["attn_impl"], cfg["rope_theta"],
-                                          fused_qkv=cfg["loops"] == 1),
+                               tuple(held) if held is not None else None,
+                               activation=cfg["expert_act"]),
+            "attn": GroupedQueryAttention(
+                cfg["dim"], cfg["heads"], cfg["kv_heads"], cfg["head_dim"],
+                cfg["attn_impl"], cfg["rope_theta"],
+                fused_qkv=cfg["loops"] == 1, rope_share=cfg["rope_share"],
+                rope_yarn=cfg["rope_yarn"], gate=cfg["attn_gate"]),
+            "attn_w": GroupedQueryAttention(
+                cfg["dim"], cfg["window_heads"] or cfg["heads"],
+                cfg["kv_heads"], cfg["head_dim"], cfg["attn_impl"],
+                cfg["window_rope_theta"],
+                rope_share=cfg["window_rope_share"],
+                rope_yarn=cfg["window_rope_yarn"], window=int(cfg["window"]),
+                gate=cfg["attn_gate"]),
             "mlp": L.GatedFFN(cfg["ffn_dim"]),
         }
         #: (param-tree name, kind) per layer, in order
@@ -190,16 +237,24 @@ class HybridLM(Model):
 
     def cache_spec(self) -> dict:
         """What a serving cache must hold for this model: ``kv`` — paged
-        K/V, an entry for each attention layer of each loop step; ``state``
+        K/V, an entry for each ``*`` layer of each loop step; ``state``
         — per slot and per ``M`` layer, name -> (shape, dtype);
-        ``state_layers`` their count."""
+        ``state_layers`` their count; ``window`` (where the pattern has a
+        ``w``) — per slot and per ``w`` layer a ring of ``size`` tokens of
+        K/V, apart from the paged pool."""
         cfg = self.config
         kinds = [k for _, k in self.layers]
-        return {"kv": {"layers": max(cfg["loops"] * kinds.count("attn"), 1),
+        spec = {"kv": {"layers": max(cfg["loops"] * kinds.count("attn"), 1),
                        "heads": cfg["kv_heads"], "head_dim": cfg["head_dim"]},
                 "state": (self._mixers["mamba"].state_shapes()
                           if "mamba" in kinds else {}),
                 "state_layers": kinds.count("mamba")}
+        if "attn_w" in kinds:
+            spec["window"] = {"layers": kinds.count("attn_w"),
+                              "size": int(cfg["window"]),
+                              "heads": cfg["kv_heads"],
+                              "head_dim": cfg["head_dim"]}
+        return spec
 
     # -- parameters ------------------------------------------------------------
     def init_params(self, rng):
@@ -302,18 +357,17 @@ class HybridLM(Model):
         """The K/V pool entry of attention layer ``n_kv`` at loop step ``t``."""
         return t * self.config["pattern"].count("*") + n_kv
 
-    def _prefill(self, params, kv_cache, table_row, tokens, true_len, slot):
+    def _prefill(self, params, kv_cache, table_row, tokens, true_len, slot,
+                 head_at=None):
         """:meth:`apply_prefill` and each position's exit step beside it."""
         cp = self.precision.cast_to_compute(params)
         toks = tokens[0]
         if true_len is None:
             true_len = jnp.int32(toks.shape[0])
-        attn = self._mixers["attn"]
-        positions = (None if attn.rope_theta is None else
-                     jnp.arange(toks.shape[0], dtype=jnp.int32)[None])
+        positions = jnp.arange(toks.shape[0], dtype=jnp.int32)[None]
 
         def once(x, kv_cache, acc, t):
-            n_kv = n_state = 0
+            n_kv = n_win = n_state = 0
             for name, kind in self.layers:
                 p = cp[name]
                 u = self._normed(p, x)
@@ -328,35 +382,49 @@ class HybridLM(Model):
                 elif kind == "mlp":
                     y = self._mlp(p["mixer"], u)
                 else:
-                    with jax.named_scope("attn"):
+                    attn = self._mixers[kind]
+                    with jax.named_scope(_ATTN_SCOPES[kind]):
                         q, k, v = attn.project_qkv(p["mixer"], u[None],
                                                    positions)
                         if kv_cache is not None:
-                            kv_cache = kv_cache.write_prefill(
-                                self._entry(t, n_kv), k, v, table_row)
-                        ctx = attn.attend(q, k, v)
+                            kv_cache = (
+                                kv_cache.write_prefill(self._entry(t, n_kv),
+                                                       k, v, table_row)
+                                if kind == "attn" else
+                                kv_cache.write_window_prefill(
+                                    n_win, k, v, true_len, slot))
+                        ctx = attn.gated(p["mixer"], u[None],
+                                         attn.attend(q, k, v))
                         y = attn.project_out(p["mixer"],
                                              ctx.reshape(u.shape[0], -1))
-                    n_kv += 1
+                    n_kv += kind == "attn"
+                    n_win += kind == "attn_w"
                 x = self._residual(p, x, y)
             return x, kv_cache, acc
 
         x, kv_cache, _, t_star = self._stack(cp, self._embed(cp, toks),
                                              kv_cache, (), once)
+        if head_at is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, head_at, 1, axis=0)
         return self._head_logits(cp, x)[None], kv_cache, t_star
 
+    #: :meth:`apply_prefill` takes ``head_at`` (what an engine asks before
+    #: it hands one over)
+    prefill_head_at = True
+
     def apply_prefill(self, params, state, kv_cache, table_row, tokens,
-                      true_len=None, slot=None):
+                      true_len=None, slot=None, head_at=None):
         """One sequence's prompt: ``tokens`` ``[1, P_pad]`` end-padded,
         ``true_len`` its real length (None = all of it), ``slot`` the batch
-        slot whose recurrent state the prompt leaves behind.  -> (logits
-        ``[1, P_pad, V]`` fp32, cache').  Causal attention keeps padding
-        out of real positions by itself; the ``M`` layers are told
-        ``true_len``.  ``kv_cache=None`` runs the same spine with nothing
-        kept (:meth:`apply_logits`)."""
+        slot whose recurrent state and window rings the prompt leaves
+        behind.  -> (logits ``[1, P_pad, V]`` fp32, or with ``head_at`` (a
+        position, traced) that one position's ``[1, 1, V]``; cache').
+        Causal attention keeps padding out of real positions by itself; the
+        ``M`` and ``w`` layers are told ``true_len``.  ``kv_cache=None``
+        runs the same spine with nothing kept (:meth:`apply_logits`)."""
         del state
         return self._prefill(params, kv_cache, table_row, tokens, true_len,
-                             slot)[:2]
+                             slot, head_at)[:2]
 
     def apply_logits(self, params, state, tokens, exit_steps: bool = False):
         """Full-sequence forward, ``tokens`` ``[B, T]`` -> logits
@@ -381,19 +449,19 @@ class HybridLM(Model):
         steps."""
         del state
         cp = self.precision.cast_to_compute(params)
-        attn = self._mixers["attn"]
         active = positions > 0
 
         def once(x, kv_cache, acc, t):
             hits, peak = acc
-            n_kv = n_state = 0
+            n_kv = n_win = n_state = 0
             for name, kind in self.layers:
                 p = cp[name]
                 u = self._normed(p, x)
                 if kind == "mamba":
                     y, pools = self._mixers[kind].decode(
                         p["mixer"], u, kv_cache.state, n_state)
-                    kv_cache = dataclasses.replace(kv_cache, state=pools)
+                    kv_cache = dataclasses.replace(
+                        kv_cache, state={**kv_cache.state, **pools})
                     n_state += 1
                 elif kind == "moe":
                     y, st = self._mixers[kind].apply_tokens(p["mixer"], u,
@@ -403,16 +471,26 @@ class HybridLM(Model):
                 elif kind == "mlp":
                     y = self._mlp(p["mixer"], u)
                 else:
-                    with jax.named_scope("attn"):
-                        entry = self._entry(t, n_kv)
+                    attn = self._mixers[kind]
+                    with jax.named_scope(_ATTN_SCOPES[kind]):
+                        entry = self._entry(t, n_kv)  # of a ``*`` layer
                         q, k, v = attn.project_qkv(p["mixer"], u[:, None],
                                                    positions[:, None])
-                        kv_cache = kv_cache.write_decode(entry, k[:, 0],
-                                                         v[:, 0], positions)
-                        ctx = kv_cache.attend_decode(entry, q[:, 0], positions)
+                        if kind == "attn":
+                            kv_cache = kv_cache.write_decode(
+                                entry, k[:, 0], v[:, 0], positions)
+                            ctx = kv_cache.attend_decode(entry, q[:, 0],
+                                                         positions)
+                        else:
+                            kv_cache = kv_cache.write_window_decode(
+                                n_win, k[:, 0], v[:, 0], positions)
+                            ctx = kv_cache.attend_window_decode(
+                                n_win, q[:, 0], positions)
+                        ctx = attn.gated(p["mixer"], u, ctx)
                         y = attn.project_out(p["mixer"],
                                              ctx.reshape(ctx.shape[0], -1))
-                    n_kv += 1
+                    n_kv += kind == "attn"
+                    n_win += kind == "attn_w"
                 x = self._residual(p, x, y)
             return x, kv_cache, (hits, peak)
 
@@ -433,9 +511,16 @@ class HybridLM(Model):
 
     def resolved_paths(self) -> dict:
         """``state_update``: what runs an ``M`` layer's decode state update
-        (``kernel`` | ``plain``), where the pattern has such a layer."""
+        (``kernel`` | ``plain``), where the pattern has such a layer;
+        ``window_attention``: where a ``w`` layer's K/V lives and what its
+        decode step reads, where the pattern has one."""
+        kinds = [k for _, k in self.layers]
         paths = {"attention": self.attention_impl(self.config["seq_len"]),
                  "experts": self._mixers["moe"].products}
-        if "mamba" in [k for _, k in self.layers]:
+        if "mamba" in kinds:
             paths["state_update"] = self._mixers["mamba"].state_update_impl()
+        if "attn_w" in kinds:
+            paths["window_attention"] = (
+                f"slot ring of {int(self.config['window'])} tokens, masked "
+                f"grouped softmax")
         return paths

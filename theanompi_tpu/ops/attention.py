@@ -11,6 +11,7 @@ tensor-parallel-ready: Q/K/V are column-parallel (heads shard over the
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -141,19 +142,23 @@ class MultiHeadAttention(L.Layer):
         v = v.reshape(b, t, h_local, head_dim)
         return q, k, v
 
-    def attend(self, q, k, v):
+    def attend(self, q, k, v, window: int | None = None):
         """The attention core over ``[B, T, H, Dh]``: ring under a sharded
         seq axis, else the resolved pallas/blockwise path.  The serving
         prefill reuses exactly this dispatch (so a TPU prefill rides the
-        flash kernels whenever the shape gate admits them)."""
+        flash kernels whenever the shape gate admits them).  ``window``
+        (causal, forward only, never the ring): query ``i`` sees keys
+        ``i - window < j <= i``."""
         t, head_dim = q.shape[1], q.shape[3]
         if axis_bound(SEQ_AXIS) and jax.lax.axis_size(SEQ_AXIS) > 1:
+            if window is not None:
+                raise NotImplementedError("a window over a sharded seq axis")
             return ring_attention(q, k, v, causal=self.causal)
         from theanompi_tpu.ops.pallas_attention import flash_attention
 
         if resolve_attn_impl(self.impl, t, head_dim) == "pallas":
-            return flash_attention(q, k, v, causal=self.causal)
-        return blockwise_attention(q, k, v, causal=self.causal)
+            return flash_attention(q, k, v, causal=self.causal, window=window)
+        return blockwise_attention(q, k, v, causal=self.causal, window=window)
 
     def project_out(self, params, out):
         """Output projection over the flattened head dim ``[B, T, h*Dh]``."""
@@ -170,22 +175,64 @@ class MultiHeadAttention(L.Layer):
         return self.project_out(params, out), state
 
 
-def rotary(q, k, positions, theta: float):
-    """Rotary position embedding over the whole head, rotate-half pairing
-    (dim ``i`` with ``i + Dh // 2``): ``q`` ``[..., T, H, Dh]`` and ``k``
-    ``[..., T, Hkv, Dh]`` at ``positions`` ``[..., T]`` -> the rotated pair
-    in their own dtypes.  Angles ``position * theta ** (-2 i / Dh)`` and the
+def yarn_inv_freq(theta: float, rot: int, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """YaRN's ``rot // 2`` inverse frequencies (Peng et al. 2023, as the
+    ``transformers`` initialiser computes them): ``f_i = theta ** (-2 i /
+    rot)`` where dim ``i`` makes more than ``beta_fast`` turns in
+    ``original_max`` positions, ``f_i / factor`` where it makes fewer than
+    ``beta_slow``, and between those two dims (the first floored, the
+    second ceiled, both held to ``0..rot - 1``) a linear blend."""
+    def turns_dim(turns):
+        return (rot * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), rot - 1)
+    if low == high:
+        high += 0.001
+    half = rot // 2
+    f = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    keep = 1.0 - jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                          / (high - low), 0.0, 1.0)
+    return f / factor * (1.0 - keep) + f * keep
+
+
+def rotary(q, k, positions, theta: float, share: float = 1.0,
+           yarn: dict | None = None):
+    """Rotary position embedding, rotate-half pairing: ``q`` ``[..., T, H,
+    Dh]`` and ``k`` ``[..., T, Hkv, Dh]`` at ``positions`` ``[..., T]`` ->
+    the rotated pair in their own dtypes.  The leading ``rot = share * Dh``
+    dims of a head are turned (dim ``i`` with ``i + rot // 2``), the rest
+    pass as they are.  Angles ``position * theta ** (-2 i / rot)``, or with
+    ``yarn`` (``factor``, ``original_max_position``, ``beta_fast``,
+    ``beta_slow``, ``attention_factor``) ``position *``
+    :func:`yarn_inv_freq` with ``cos`` and ``sin`` multiplied by
+    ``attention_factor`` (default ``0.1 ln factor + 1``); angles and the
     rotation itself are float32.  Applied before a cache write, so a K pool
     holds rotated keys and a decode step rotates its own token only."""
-    half = q.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    rot = int(q.shape[-1] * share)
+    half = rot // 2
+    if yarn is None:
+        freq, scale = theta ** (-jnp.arange(half, dtype=jnp.float32) / half), None
+    else:
+        freq = yarn_inv_freq(theta, rot, yarn["factor"],
+                             yarn["original_max_position"],
+                             yarn.get("beta_fast", 32.0),
+                             yarn.get("beta_slow", 1.0))
+        scale = yarn.get("attention_factor")
+        if scale is None:
+            scale = 0.1 * math.log(yarn["factor"]) + 1.0
     ang = jnp.asarray(positions, jnp.float32)[..., None, None] * freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
 
     def turn(x):
         xf = x.astype(jnp.float32)
-        a, b = xf[..., :half], xf[..., half:]
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+        a, b = xf[..., :half], xf[..., half:rot]
+        rest = [xf[..., rot:]] if rot < x.shape[-1] else []
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, *rest],
                                axis=-1).astype(x.dtype)
 
     return turn(q), turn(k)
@@ -197,11 +244,16 @@ class GroupedQueryAttention(L.Layer):
     ``heads // kv_heads`` query heads (query head ``h`` reads K/V head
     ``h // (heads // kv_heads)``); head size ``head_dim`` independent of
     ``dim``, no bias.  ``rope_theta=None``: no positional term;
-    a number: :func:`rotary` positions at that base, for which
-    :meth:`project_qkv` is told the tokens' ``positions``.  A cache holds
-    the ``kv_heads`` only; :meth:`attend` repeats them to the query heads
-    and takes :meth:`MultiHeadAttention.attend`'s dispatch, so a TPU
-    prefill rides the flash kernel where its gate admits the shape."""
+    a number: :func:`rotary` positions at that base over ``rope_share`` of
+    the head, with ``rope_yarn`` its YaRN parameters, for which
+    :meth:`project_qkv` is told the tokens' ``positions``.  ``window``:
+    position ``i`` attends ``j`` with ``0 <= i - j < window`` (None: every
+    ``j <= i``).  ``gate``: a sigmoid gate a head, ``sigmoid(x W_g)`` read
+    from the layer's input, on the context before the output projection
+    (:meth:`gated`).  A cache holds the ``kv_heads`` only; :meth:`attend`
+    repeats them to the query heads and takes
+    :meth:`MultiHeadAttention.attend`'s dispatch, so a TPU prefill rides
+    the flash kernel where its gate admits the shape."""
 
     dim: int
     heads: int
@@ -217,6 +269,10 @@ class GroupedQueryAttention(L.Layer):
     #: that a product folded with its reshape wants (1.2 GB either way for
     #: 48 layers of 2048, compiled for a v5e, ISSUE 31)
     fused_qkv: bool = True
+    rope_share: float = 1.0
+    rope_yarn: dict | None = None
+    window: int | None = None
+    gate: bool = False
 
     def init(self, key, in_shape):
         if in_shape[-1] != self.dim:
@@ -231,6 +287,9 @@ class GroupedQueryAttention(L.Layer):
                   "k": {"w": w02(kk, (self.dim, hkv))},
                   "v": {"w": w02(kv, (self.dim, hkv))},
                   "o": {"w": w02(ko, (hq, self.dim))}}
+        if self.gate:
+            params["gate"] = {"w": w02(jax.random.fold_in(key, 4),
+                                       (self.dim, self.heads))}
         return params, {}, tuple(in_shape)
 
     def project_qkv(self, params, x, positions=None):
@@ -250,7 +309,8 @@ class GroupedQueryAttention(L.Layer):
                        for y in jax.lax.optimization_barrier(tuple(
                            x @ params[n]["w"].astype(x.dtype) for n in "qkv")))
         if self.rope_theta is not None:
-            q, k = rotary(q, k, positions, float(self.rope_theta))
+            q, k = rotary(q, k, positions, float(self.rope_theta),
+                          self.rope_share, self.rope_yarn)
         return q, k, v
 
     def attend(self, q, k, v):
@@ -258,7 +318,17 @@ class GroupedQueryAttention(L.Layer):
         core = MultiHeadAttention(self.heads * self.head_dim, self.heads,
                                   impl=self.impl)
         return core.attend(q, jnp.repeat(k, rep, axis=2),
-                           jnp.repeat(v, rep, axis=2))
+                           jnp.repeat(v, rep, axis=2), window=self.window)
+
+    def gated(self, params, x, ctx):
+        """``ctx`` ``[..., H, Dh]`` with head ``h`` multiplied by ``sigmoid(x
+        W_g)_h`` (float32), ``x`` ``[..., D]`` the layer's input; ``ctx``
+        itself without a ``gate``."""
+        if not self.gate:
+            return ctx
+        g = jax.nn.sigmoid(
+            (x @ params["gate"]["w"].astype(x.dtype)).astype(jnp.float32))
+        return (ctx.astype(jnp.float32) * g[..., None]).astype(ctx.dtype)
 
     def project_out(self, params, out):
         return out @ params["o"]["w"].astype(out.dtype)

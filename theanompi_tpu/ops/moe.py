@@ -210,16 +210,21 @@ class MoEFFN(L.Layer):
 
 @dataclasses.dataclass(frozen=True)
 class DroplessMoE(L.Layer):
-    """Top-k sigmoid-routed latent experts with a shared expert, no capacity
-    and no dropped token (the ``E`` layer of the Nemotron-H family), over
-    tokens ``[N, D]``::
+    """Top-k sigmoid-routed experts with a shared expert, no capacity and no
+    dropped token (the ``E`` layer of ``HybridLM``), over tokens ``[N, D]``::
 
         s = sigmoid(W_r u)                     float32, all ``n_experts``
         selected = top_k(s + b_corr)           b_corr biases selection only
         w_i = route_scale * s_i / sum_selected s_j
         l = W_down u                           D -> latent
-        r = sum_i w_i W2_i relu(W1_i l)^2      latent -> expert_dim -> latent
-        out = W_up r + V2 relu(V1 u)^2         the shared expert sees every token
+        r = sum_i w_i W2_i act(W1_i l)         latent -> expert_dim -> latent
+        out = W_up r + V2 act(V1 u)            the shared expert sees every token
+
+    ``latent=None``: no ``W_down`` / ``W_up``, the experts multiply at model
+    width (``l = u``, ``out = r + ...``).  ``activation``, for the experts
+    and the shared expert alike: ``"relu2"`` (``relu(h)^2``) or
+    ``"silu_gated"`` (``silu(h[:F]) * h[F:]``: ``W1`` and ``V1`` are then
+    ``2 F`` wide, gate and up halves side by side in ONE product).
 
     ``experts_held = (lo, hi)`` is this chip's share of an expert-parallel
     deployment: the router stays ``n_experts`` wide and selects over all of
@@ -253,12 +258,36 @@ class DroplessMoE(L.Layer):
     dim: int
     n_experts: int
     top_k: int
-    latent: int
+    latent: int | None
     expert_dim: int
     shared_dim: int
     route_scale: float = 1.0
     experts_held: tuple[int, int] | None = None
     products: str = "ragged_dot"
+    activation: str = "relu2"
+
+    def __post_init__(self):
+        if self.activation not in ("relu2", "silu_gated"):
+            raise ValueError(f"DroplessMoE activation={self.activation!r} "
+                             f"not in ('relu2', 'silu_gated')")
+
+    @property
+    def width(self) -> int:
+        """The width the experts multiply at: ``latent``, or ``dim``."""
+        return self.dim if self.latent is None else self.latent
+
+    @property
+    def product_shapes(self) -> tuple:
+        """``(K, N)`` of the layer's two grouped products."""
+        up = self.expert_dim * (2 if self.activation == "silu_gated" else 1)
+        return (self.width, up), (self.expert_dim, self.width)
+
+    def _act(self, h):
+        """``h`` ``[..., F]`` or (gated) ``[..., 2 F]`` -> ``[..., F]``."""
+        if self.activation == "relu2":
+            return jnp.square(jax.nn.relu(h))
+        gate, up = jnp.split(h, 2, axis=-1)
+        return jax.nn.silu(gate) * up
 
     @property
     def held(self) -> tuple[int, int]:
@@ -273,16 +302,19 @@ class DroplessMoE(L.Layer):
         lo, hi = self.held
         ks = jax.random.split(key, 7)
         w02 = init_lib.normal(0.02)
+        (k1, n1), (k2, n2) = self.product_shapes
+        fan = n1 // self.expert_dim  # 2 where gate and up share a product
         params = {
             "router": {"w": w02(ks[0], (self.dim, self.n_experts)),
                        "b_corr": jnp.zeros((self.n_experts,), jnp.float32)},
-            "down": {"w": w02(ks[1], (self.dim, self.latent))},
-            "w1": w02(ks[2], (hi - lo, self.latent, self.expert_dim)),
-            "w2": w02(ks[3], (hi - lo, self.expert_dim, self.latent)),
-            "up": {"w": w02(ks[4], (self.latent, self.dim))},
-            "shared": {"v1": w02(ks[5], (self.dim, self.shared_dim)),
+            "w1": w02(ks[2], (hi - lo, k1, n1)),
+            "w2": w02(ks[3], (hi - lo, k2, n2)),
+            "shared": {"v1": w02(ks[5], (self.dim, fan * self.shared_dim)),
                        "v2": w02(ks[6], (self.shared_dim, self.dim))},
         }
+        if self.latent is not None:
+            params["down"] = {"w": w02(ks[1], (self.dim, self.latent))}
+            params["up"] = {"w": w02(ks[4], (self.latent, self.dim))}
         return params, {}, tuple(in_shape)
 
     def route(self, params, u):
@@ -327,7 +359,8 @@ class DroplessMoE(L.Layer):
         if routed:
             idx, w = self.route(params, u)
             with jax.named_scope("moe.experts"):
-                lat = u @ params["down"]["w"].astype(u.dtype)
+                lat = (u if self.latent is None
+                       else u @ params["down"]["w"].astype(u.dtype))
                 local = idx - lo
                 is_held = (local >= 0) & (local < e_held)
                 # absent experts sort past every group
@@ -338,17 +371,18 @@ class DroplessMoE(L.Layer):
                 dot = self._grouped_dot(sizes.astype(jnp.int32),
                                         rows.shape[0], u.dtype)
                 h = dot(rows, params["w1"].astype(u.dtype), u.dtype)
-                h = jnp.square(jax.nn.relu(h.astype(jnp.float32)))
+                h = self._act(h.astype(jnp.float32))
                 y = dot(h.astype(u.dtype), params["w2"].astype(u.dtype),
                         jnp.float32)
                 # back to token order; rows past the last group belong to
                 # no expert (the kernel never writes them) and are selected
                 # away below, not multiplied away
                 y = jnp.take(y, jnp.argsort(order), axis=0).reshape(
-                    n, self.top_k, self.latent)
+                    n, self.top_k, self.width)
                 r = jnp.sum(jnp.where(is_held[..., None], y * w[..., None],
-                                      0.0), axis=1)
-                out = out + r.astype(u.dtype) @ params["up"]["w"].astype(u.dtype)
+                                      0.0), axis=1).astype(u.dtype)
+                out = out + (r if self.latent is None
+                             else r @ params["up"]["w"].astype(u.dtype))
                 counted = is_held if active is None else (
                     is_held & active[:, None])
                 stats = {
@@ -358,8 +392,7 @@ class DroplessMoE(L.Layer):
                         length=e_held + 1)[:e_held]).astype(jnp.int32)}
         if shared:
             with jax.named_scope("moe.shared"):
-                hs = u @ params["shared"]["v1"].astype(u.dtype)
-                hs = jnp.square(jax.nn.relu(hs))
+                hs = self._act(u @ params["shared"]["v1"].astype(u.dtype))
                 out = out + hs @ params["shared"]["v2"].astype(u.dtype)
         return out, stats
 

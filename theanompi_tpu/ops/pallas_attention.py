@@ -53,14 +53,26 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _tile_needed(qi, ki, block_q, block_k, causal):
-    """Whether tile (qi, ki) has any visible keys (causal skip predicate)."""
-    return (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+def _tile_needed(qi, ki, block_q, block_k, causal, window=None):
+    """Whether tile (qi, ki) has any visible keys (causal skip predicate;
+    with a ``window`` also: its last key is not behind the first query's)."""
+    if not causal:
+        return True
+    needed = qi * block_q + block_q - 1 >= ki * block_k
+    if window is not None:
+        needed = jnp.logical_and(
+            needed, ki * block_k + block_k - 1 > qi * block_q - window)
+    return needed
 
 
 def _last_needed_k(qi, block_q, block_k):
     """Last k-tile index with visible keys for q-tile ``qi`` (causal)."""
     return (qi * block_q + block_q - 1) // block_k
+
+
+def _first_needed_k(qi, block_q, block_k, window):
+    """First k-tile index with keys inside q-tile ``qi``'s ``window``."""
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
 
 
 def _first_needed_q(ki, block_q, block_k):
@@ -78,12 +90,14 @@ def _first_needed_q(ki, block_q, block_k):
 # via ``_tile_needed``, so numerics are untouched.
 
 
-def _causal_tile_mask(qi, ki, block_q, block_k):
+def _causal_tile_mask(qi, ki, block_q, block_k, window=None):
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
 
 
 def _dot(a, b, dims):
@@ -96,31 +110,37 @@ def _dot(a, b, dims):
 # ---------------------------------------------------------------------------
 
 
-def _tile_full(qi, ki, block_q, block_k):
-    """Tile entirely below the diagonal: every key visible, no mask ops."""
-    return qi * block_q >= ki * block_k + block_k - 1
+def _tile_full(qi, ki, block_q, block_k, window=None):
+    """Tile entirely below the diagonal (and, with a ``window``, entirely
+    inside the last query's): every key visible, no mask ops."""
+    full = qi * block_q >= ki * block_k + block_k - 1
+    if window is not None:
+        full = jnp.logical_and(
+            full, ki * block_k >= qi * block_q + block_q - window)
+    return full
 
 
-def _when_causal_tiles(causal, qi, ki, block_q, block_k, body):
+def _when_causal_tiles(causal, qi, ki, block_q, block_k, body, window=None):
     """Run ``body(masked: bool)`` per tile, splitting full from diagonal.
 
     Only diagonal-straddling tiles pay the mask's VPU cost (2 iotas +
     compare + 2 selects over block_q x block_k fp32) — on the old
     every-tile mask that elementwise work rivaled the matmuls themselves.
     Non-causal runs the unmasked body unconditionally; above-diagonal
-    tiles run nothing (and their DMA is elided via the clamped index_map).
+    tiles run nothing (and their DMA is elided via the clamped index_map),
+    nor do tiles wholly behind a ``window``.
     """
     if not causal:
         body(False)
         return
-    needed = _tile_needed(qi, ki, block_q, block_k, True)
-    full = _tile_full(qi, ki, block_q, block_k)
+    needed = _tile_needed(qi, ki, block_q, block_k, True, window)
+    full = _tile_full(qi, ki, block_q, block_k, window)
     pl.when(jnp.logical_and(needed, full))(lambda: body(False))
     pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(lambda: body(True))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
-                *, scale, causal, block_q, block_k, d):
+                *, scale, causal, block_q, block_k, d, window=None):
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -145,7 +165,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
         vb = v_ref[0, 0]
         s = _dot(q, kb, ((1,), (1,)))
         if masked:
-            mask = _causal_tile_mask(qi, ki, block_q, block_k)
+            mask = _causal_tile_mask(qi, ki, block_q, block_k, window)
             s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -161,7 +181,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
         acc_scr[:, :] = (acc_scr[:, :] * corr[:, None]
                          + _dot(p.astype(vb.dtype), vcat, ((1,), (0,))))
 
-    _when_causal_tiles(causal, qi, ki, block_q, block_k, body)
+    _when_causal_tiles(causal, qi, ki, block_q, block_k, body, window)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -172,8 +192,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
 
 
 @jax.named_scope("flash_fwd")  # names the custom call in a device trace
-def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret):
+def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret, window=None):
     """q/k/v: [B, H, T, D] -> (out [B,H,T,D], lse [B,H,nq,8,block_q]).
+    ``window``: the causal band ``i - window < j <= i``; tiles wholly behind
+    it are skipped like tiles above the diagonal, compute and DMA alike.
 
     lse rows are broadcast across the 8 sublanes: Mosaic rejects output
     blocks thinner than an (8, 128) tile, so the per-row vector rides in a
@@ -183,7 +205,8 @@ def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret):
     scale = d ** -0.5
     nq, nk = t // block_q, t // block_k
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, d=d)
+                               block_q=block_q, block_k=block_k, d=d,
+                               window=window)
     # accumulator width: d data columns + a lane-aligned block whose first
     # column carries the softmax normalizer (see kernel comment)
     acc_cols = d + (128 - d % 128 if d % 128 else 128)
@@ -191,6 +214,8 @@ def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret):
     def kv_map(bi, hi, qi, ki):
         if causal:  # masked tiles re-reference the diagonal tile: DMA elided
             ki = jnp.minimum(ki, _last_needed_k(qi, block_q, block_k))
+        if window is not None:  # and the window's first tile, from below
+            ki = jnp.maximum(ki, _first_needed_k(qi, block_q, block_k, window))
         return (bi, hi, ki, 0)
 
     return pl.pallas_call(
@@ -411,8 +436,11 @@ def flash_attention_supported(t: int, d: int, block_q: int = 512,
 
 
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
-                    block_k: int = 1024, interpret: bool | None = None):
+                    block_k: int = 1024, interpret: bool | None = None,
+                    window: int | None = None):
     """Flash attention over ``[B, T, H, D]`` (the stack's layout).
+    ``window`` (causal only, forward only: no gradient is defined through
+    it): query ``i`` sees keys ``i - window < j <= i``.
 
     ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere
     (so the same code path is unit-testable on the CPU mesh).  In
@@ -423,6 +451,9 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     t = q.shape[1]
+    if window is not None:
+        # a key tile no wider than the band: a query tile then meets two
+        block_k = min(block_k, max(128, 1 << (window - 1).bit_length()))
     block_q, block_k = _fit_block(block_q, t), _fit_block(block_k, t)
     ok = (t % block_q == 0 and t % block_k == 0
           and (interpret or flash_attention_supported(
@@ -435,5 +466,12 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
         )
     # [B,T,H,D] -> [B,H,T,D] for head-major tiling
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = _flash(qt, kt, vt, causal, block_q, block_k, interpret)
+    if window is None:
+        out = _flash(qt, kt, vt, causal, block_q, block_k, interpret)
+    else:
+        if not causal or window < 1:
+            raise ValueError(f"flash_attention: window={window} needs causal "
+                             f"attention and at least the query itself")
+        out, _ = _fwd_call(qt, kt, vt, causal=True, block_q=block_q,
+                           block_k=block_k, interpret=interpret, window=window)
     return out.transpose(0, 2, 1, 3)

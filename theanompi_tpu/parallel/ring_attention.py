@@ -60,9 +60,15 @@ def _block_attend(q, k, v, m_prev, l_prev, acc, mask=None):
     return m_new, l_new, acc_new
 
 
-def blockwise_attention(q, k, v, causal: bool = False, block_size: int | None = None):
+def blockwise_attention(q, k, v, causal: bool = False, block_size: int | None = None,
+                        window: int | None = None):
     """Single-device flash-style attention (the ring's n=1 case / reference
-    implementation for tests).  [B, T, H, D] layout."""
+    implementation for tests).  [B, T, H, D] layout.  ``window`` (with
+    ``causal``): query ``i`` sees keys ``i - window < j <= i``, a band (the queries
+    are not tiled here, so no key block lies behind all of them: the band
+    is a mask and skips nothing; the flash kernel skips its tiles)."""
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     b, t, h, d = q.shape
     if block_size is None or block_size >= k.shape[1]:
         blocks = [(0, k.shape[1])]
@@ -82,6 +88,9 @@ def blockwise_attention(q, k, v, causal: bool = False, block_size: int | None = 
         mask = None
         if causal:
             mask = q_pos[:, None] >= jnp.arange(start, stop)[None, :]
+            if window is not None:
+                mask &= (q_pos[:, None] - jnp.arange(start, stop)[None, :]
+                         < window)
             mask = mask[None, None]
         m, l, acc = _block_attend(qf, kb, vb, m, l, acc, mask)
     out = acc / l.transpose(0, 2, 1)[..., None]

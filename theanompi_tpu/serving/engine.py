@@ -182,12 +182,11 @@ class InferenceEngine:
         # the engine sets its model's path, once, before it builds a program
         experts = getattr(model, "expert_layer", None)
         if experts is not None:
-            widths = (experts.latent, experts.expert_dim)
             use_grouped = decode_kernel == "on" or (
                 decode_kernel == "auto" and on_tpu and all(
                     grouped_matmul_supported(k, n,
                                              model.precision.compute_dtype)
-                    for k, n in (widths, widths[::-1])))
+                    for k, n in experts.product_shapes))
             self.expert_impl = kernel if use_grouped else "ragged_dot"
             model.set_expert_products(self.expert_impl)
             n = model.expert_products
@@ -205,6 +204,12 @@ class InferenceEngine:
             self._state_tags = {
                 "state_updates": n, "state_kernel_updates":
                     0 if self.state_update_impl == "plain" else n}
+        #: the window layers' band, where the model has such layers: their
+        #: K/V is a ring a slot beside the pool (``serve.decode``'s tags
+        #: ``kv_full_tokens`` / ``kv_window_tokens``), else None
+        self.window = (spec.get("window") or {}).get("size")
+        #: the model's prefill reads its head at the one position sampled
+        self._head_at = bool(getattr(model, "prefill_head_at", False))
         # int8 leaves the fused matmul can consume stay quantized inside
         # the decode step; the rest (odd-vocab head, MoE stacks)
         # dequantize as before.  None = dequantize everything.
@@ -295,6 +300,9 @@ class InferenceEngine:
             out["expert_products"] = self.expert_impl
         if self.state_update_impl is not None:
             out["state_update"] = self.state_update_impl
+        if self.window is not None:
+            out["window_attention"] = \
+                self.model.resolved_paths()["window_attention"]
         if self.quantized:
             leaves = [leaf for leaf in jax.tree.leaves(
                 self.params, is_leaf=_is_quantized) if _is_quantized(leaf)]
@@ -379,10 +387,13 @@ class InferenceEngine:
         # a model with per-slot state is told where the prompt really ends
         # and whose state it leaves behind
         own = {} if slot is None else {"true_len": true_len, "slot": slot}
+        if self._head_at:  # one row of logits, not the bucket's every row
+            own["head_at"] = true_len - 1
         logits, cache = self.model.apply_prefill(
             params, {}, cache, table_row, tokens[None, :], **own)
         with jax.named_scope("sample"):
-            last = jnp.take(logits[0], true_len - 1, axis=0)
+            last = (logits[0, 0] if self._head_at
+                    else jnp.take(logits[0], true_len - 1, axis=0))
             key = _sample_key(base_key, rid, true_len)
             nxt = sample_tokens(last[None], temp[None], key[None],
                                 self.top_k)
@@ -554,11 +565,18 @@ class InferenceEngine:
         # ``batch``, ``kv_tokens`` and ``requests`` are the launched
         # step's; the device counters the span is tagged with below are
         # those of the step it READ, one behind where the call runs ahead
+        context = lengths[active] + 1
+        # a model with window layers: the keys a full layer attends, and
+        # the same capped at the window a slot (what a window layer does)
+        window_tags = {} if self.window is None else {
+            "kv_full_tokens": int(context.sum()),
+            "kv_window_tokens": int(np.minimum(context, self.window).sum())}
         with spans.span(_SPAN_DECODE, step=self.n_decodes, batch=len(active),
-                        kv_tokens=int(lengths[active].sum()) + len(active),
+                        kv_tokens=int(context.sum()),
                         requests=np.asarray(rids)[active].tolist(),
                         launched=1, ran_ahead=int(prev is not None),
-                        **self._moe_tags, **self._state_tags) as span:
+                        **self._moe_tags, **self._state_tags,
+                        **window_tags) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):
                 args = jax.device_put((np.array(tables, np.int32),

@@ -46,6 +46,9 @@ import jax.numpy as jnp
 from theanompi_tpu.ops.pallas_paged_attention import paged_attend_decode
 
 _NEG_INF = -1e30
+#: the widest context the grouped fallback gathers in one piece; a wider
+#: table goes through in pieces of this many tokens (``_attend_decode_grouped``)
+_GROUPED_CHUNK_TOKENS = 4096
 
 
 @jax.tree_util.register_pytree_node_class
@@ -82,6 +85,12 @@ class PagedKVCache:
     #: ...]``.  Not paged: a slot owns one fixed-size entry per layer, a
     #: prefill writes its slot's entry whole and a decode step updates
     #: every slot's.  Empty for a model whose layers all cache K/V.
+    #: The window layers' K/V live here too (``cache_spec()["window"]``):
+    #: ``window_k`` / ``window_v`` ``[window layers, max_batch, window,
+    #: H, Dh]``, a ring a slot — the token at position ``p`` at ``p %
+    #: window`` — that costs ``window`` tokens however long the sequence
+    #: grows, is no part of the paged pool and is never read by the slot's
+    #: next owner (:meth:`write_window_prefill`).
     state: dict = dataclasses.field(default_factory=dict)
 
     NULL_BLOCK = 0
@@ -116,14 +125,22 @@ class PagedKVCache:
         """The cache a model's ``cache_spec()`` asks for: K/V pools over its
         ``kv`` layers (``layers``, ``heads``, ``head_dim``) and, per entry
         of ``state`` (name -> (per-sequence shape, dtype)), a slot-indexed
-        pool ``[state_layers, max_batch, *shape]`` of zeros."""
+        pool ``[state_layers, max_batch, *shape]`` of zeros; per ``window``
+        layer (``layers``, ``size``, ``heads``, ``head_dim``) a ring of
+        ``size`` tokens a slot, beside the paged pool."""
         kv = spec["kv"]
         cache = cls.create(kv["layers"], num_blocks, block_size, kv["heads"],
                            kv["head_dim"], max_batch, max_context, dtype,
                            decode_impl)
-        return dataclasses.replace(cache, state={
-            name: jnp.zeros((spec["state_layers"], max_batch, *shape), dt)
-            for name, (shape, dt) in spec["state"].items()})
+        state = {name: jnp.zeros((spec["state_layers"], max_batch, *shape), dt)
+                 for name, (shape, dt) in spec["state"].items()}
+        win = spec.get("window")
+        if win:
+            ring = (win["layers"], max_batch, win["size"], win["heads"],
+                    win["head_dim"])
+            state.update(window_k=jnp.zeros(ring, dtype),
+                         window_v=jnp.zeros(ring, dtype))
+        return dataclasses.replace(cache, state=state)
 
     @classmethod
     def create(cls, n_layers: int, num_blocks: int, block_size: int,
@@ -188,9 +205,51 @@ class PagedKVCache:
         owner of the slot left there is gone).  A decode step hands the
         layer ``state`` itself, every layer and slot of it, and takes it
         back (``Mamba2.decode``)."""
+        return dataclasses.replace(self, state={**self.state, **{
+            name: self.state[name].at[layer, slot].set(
+                x.astype(self.state[name].dtype)) for name, x in new.items()}})
+
+    # -- window layers: a ring a slot ------------------------------------------
+    def write_window_prefill(self, layer: int, k, v, true_len,
+                             slot) -> "PagedKVCache":
+        """Leave a prompt's last ``window`` tokens in ``slot``'s ring of
+        window layer ``layer``: ``k``/``v`` ``[1, P_pad, H, Dh]``, the prompt
+        ``true_len`` long.  Ring entry ``r`` takes the last position ``j <
+        true_len`` with ``j % window == r``; where there is none (a prompt
+        shorter than the window) it takes position 0's, which no decode step
+        reads before it has written the entry itself.  The whole ring is
+        written, so nothing of the slot's previous owner stays."""
+        size = self.state["window_k"].shape[2]
+        r = jnp.arange(size, dtype=jnp.int32)
+        last = jnp.asarray(true_len, jnp.int32) - 1
+        src = jnp.clip(last - (last - r) % size, 0, k.shape[1] - 1)
+        return self.write_state(layer, {
+            "window_k": jnp.take(k[0], src, axis=0),
+            "window_v": jnp.take(v[0], src, axis=0)}, slot)
+
+    def write_window_decode(self, layer: int, k, v, positions) -> "PagedKVCache":
+        """One token's K/V per slot into its ring: ``k``/``v`` ``[B, H, Dh]``
+        at ``positions`` ``[B]``.  A slot at position 0 is inactive and
+        keeps what it holds (it may have been prefilled for the next step)."""
+        pool_k, pool_v = self.state["window_k"], self.state["window_v"]
+        b = jnp.arange(k.shape[0])
+        at = positions % pool_k.shape[2]
+        live = (positions > 0)[:, None, None]
+        k = jnp.where(live, k.astype(pool_k.dtype), pool_k[layer, b, at])
+        v = jnp.where(live, v.astype(pool_v.dtype), pool_v[layer, b, at])
         return dataclasses.replace(self, state={
-            name: pool.at[layer, slot].set(new[name].astype(pool.dtype))
-            for name, pool in self.state.items()})
+            **self.state, "window_k": pool_k.at[layer, b, at].set(k),
+            "window_v": pool_v.at[layer, b, at].set(v)})
+
+    def attend_window_decode(self, layer: int, q, positions):
+        """One query token per slot over its ring: ``q`` ``[B, H, Dh]`` at
+        ``positions`` ``[B]`` (written already) -> context ``[B, H, Dh]``.
+        The ring holds positions ``p - window < j <= p`` once ``p >= window
+        - 1``; before that entry ``r`` is this sequence's only if ``r <=
+        p``.  Grouped K/V heads, one masked float32 softmax, as
+        :meth:`_attend_decode_grouped`."""
+        return _grouped_attend(q, self.state["window_k"][layer],
+                               self.state["window_v"][layer], positions)
 
     # -- paged attention (suffix prefill) --------------------------------------
     def attend_prefill(self, layer: int, q, table_row, prefix_len):
@@ -304,25 +363,72 @@ class PagedKVCache:
         ``h // (H // Hkv)``): one masked fp32 softmax over each slot's
         gathered context, the K/V heads never repeated.  The kernel's gate
         refuses such a pool, so no bit-parity is owed and the products are
-        plain einsums."""
+        plain einsums.  A table wider than ``_GROUPED_CHUNK_TOKENS`` (and a
+        whole number of such pieces) is gathered a piece at a time, the
+        pieces joined by the online-softmax recurrence: the gather and its
+        transposed copy are held for one piece, not for the whole width
+        (4.3 GB at 32 slots x 16 384 tokens x 8 heads x 128, ISSUE 33)."""
         b, h, d = q.shape
         hkv = self.k.shape[3]
+        nb = self.block_tables.shape[1]
+        t_max = nb * self.block_size
+        per = _GROUPED_CHUNK_TOKENS // self.block_size
+        if nb <= per or nb % per:
+            kb = jnp.take(self.k[layer], self.block_tables, axis=0)
+            vb = jnp.take(self.v[layer], self.block_tables, axis=0)
+            return _grouped_attend(q, kb.reshape(b, t_max, hkv, d),
+                                   vb.reshape(b, t_max, hkv, d), positions)
         if h % hkv:
             raise ValueError(f"{h} query heads over {hkv} K/V heads")
-        t_max = self.block_tables.shape[1] * self.block_size
-        kb = jnp.take(self.k[layer], self.block_tables, axis=0)
-        vb = jnp.take(self.v[layer], self.block_tables, axis=0)
-        kb = kb.reshape(b, t_max, hkv, d)
-        vb = vb.reshape(b, t_max, hkv, d)
+        t = per * self.block_size
         qg = (q.astype(jnp.float32) * d ** -0.5).reshape(b, hkv, h // hkv, d)
-        s = jnp.einsum("bgrd,btgd->bgrt", qg.astype(kb.dtype), kb,
-                       preferred_element_type=jnp.float32)
-        valid = jnp.arange(t_max)[None, :] <= positions[:, None]
-        s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        ctx = jnp.einsum("bgrt,btgd->bgrd", p.astype(vb.dtype), vb,
-                         preferred_element_type=jnp.float32)
-        return ctx.reshape(b, h, d).astype(q.dtype)
+
+        def piece(c, carry):
+            m, l, acc = carry
+            rows = jax.lax.dynamic_slice_in_dim(self.block_tables, c * per,
+                                                per, axis=1)
+            kb = jnp.take(self.k[layer], rows, axis=0).reshape(b, t, hkv, d)
+            vb = jnp.take(self.v[layer], rows, axis=0).reshape(b, t, hkv, d)
+            s = jnp.einsum("bgrd,btgd->bgrt", qg.astype(kb.dtype), kb,
+                           preferred_element_type=jnp.float32)
+            valid = (c * t + jnp.arange(t))[None, :] <= positions[:, None]
+            valid = valid[:, None, None, :]
+            s = jnp.where(valid, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            # a piece wholly past a slot's context: exp(0) = 1 a key
+            p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+            corr = jnp.exp(m - m_new)
+            ctx = jnp.einsum("bgrt,btgd->bgrd", p.astype(vb.dtype), vb,
+                             preferred_element_type=jnp.float32)
+            return (m_new, l * corr + jnp.sum(p, axis=-1),
+                    acc * corr[..., None] + ctx)
+
+        shape = (b, hkv, h // hkv)
+        _, l, acc = jax.lax.fori_loop(
+            0, nb // per, piece,
+            (jnp.full(shape, _NEG_INF, jnp.float32),
+             jnp.zeros(shape, jnp.float32),
+             jnp.zeros((*shape, d), jnp.float32)))
+        return (acc / l[..., None]).reshape(b, h, d).astype(q.dtype)
+
+
+def _grouped_attend(q, kb, vb, positions):
+    """``q`` ``[B, H, Dh]`` over each slot's keys and values ``[B, T, Hkv,
+    Dh]``, entry ``t`` of them where ``t <= positions[b]``: one masked
+    float32 softmax, the K/V heads never repeated."""
+    b, h, d = q.shape
+    hkv = kb.shape[2]
+    if h % hkv:
+        raise ValueError(f"{h} query heads over {hkv} K/V heads")
+    qg = (q.astype(jnp.float32) * d ** -0.5).reshape(b, hkv, h // hkv, d)
+    s = jnp.einsum("bgrd,btgd->bgrt", qg.astype(kb.dtype), kb,
+                   preferred_element_type=jnp.float32)
+    valid = jnp.arange(kb.shape[1])[None, :] <= positions[:, None]
+    s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bgrt,btgd->bgrd", p.astype(vb.dtype), vb,
+                     preferred_element_type=jnp.float32)
+    return ctx.reshape(b, h, d).astype(q.dtype)
 
 
 def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,

@@ -110,6 +110,13 @@ SERVE_STATE_UPDATE_TAGS = ("state_updates", "state_kernel_updates")
 #: of the 1-based loop step whose state the head read (over ``batch``:
 #: ``loops`` at ``exit_threshold`` 1, where every token reads the last step)
 SERVE_DECODE_LOOP_TAGS = ("loop_exit_steps",)
+#: ``serve.decode`` tags of a ``HybridLM`` with window (``w``) layers
+#: (ISSUE 33), counted on the host for the launched step: ``kv_full_tokens``
+#: — the keys a full layer attends, each active slot's context with the
+#: token it writes (``kv_tokens``); ``kv_window_tokens`` — the keys a window
+#: layer attends, the same capped at the window a slot (their ratio: 1.0
+#: for traffic that never leaves the window)
+SERVE_DECODE_WINDOW_TAGS = ("kv_full_tokens", "kv_window_tokens")
 #: ``serve.decode`` tags of the decode pipeline (ISSUE 32), on every call:
 #: ``launched`` — 1, the step this call gave the device; ``ran_ahead`` — 1
 #: where it went out with the previous launch unread, 0 where the pipeline
@@ -141,7 +148,9 @@ SERVE_COLLECT_SPAN = "serve.collect"
 #: ``embed``, ``mamba`` (a Mamba-2 mixer, projections and state update),
 #: ``moe.route`` (router, top-k), ``moe.experts`` (latent projections,
 #: sort, the kernel's schedule, grouped products), ``moe.shared`` (the
-#: shared expert), ``attn``, ``mlp`` (the ``-`` letter's gated FFN),
+#: shared expert), ``attn`` (a ``*`` layer) and ``attn.window`` (a ``w``
+#: layer), each with its projections, rotary, cache write, the attention
+#: itself and the head gate inside, ``mlp`` (the ``-`` letter's gated FFN),
 #: ``loop.exit`` (a looped stack's end of step: final norm, exit gate,
 #: read-out), ``head``
 DEVICE_SCOPES = {
@@ -151,7 +160,7 @@ DEVICE_SCOPES = {
     "kernels": ("paged_decode", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "grouped_matmul", "mamba_state_update"),
     "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
-                 "attn", "mlp", "loop.exit", "head"),
+                 "attn", "attn.window", "mlp", "loop.exit", "head"),
 }
 #: one ``train_iter`` (tags ``step``, ``epoch``; ``loss`` at fenced steps)
 TRAIN_SPANS = ("train.step",)
