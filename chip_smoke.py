@@ -22,6 +22,10 @@ supports, with depth and run length cut and weights random from a seed:
                     ``PagedKVCache.attend_decode``'s pure-JAX path, at
                     layer 1 of a two-layer pool (the kernel's layer index
                     is checked too).
+   ``decode_parity_grouped`` the same through the kernel of grouped pools
+                    and the grouped gather at the two served geometries:
+                    48 query heads over 8 K/V heads with a 16 384-token
+                    table, 32 over 2 with a 4096-token one.
 6. ``state_update_parity`` one decode step of a Mamba-2 layer at the served
                     state geometry (128 heads in 8 groups, 64 x 128 float32
                     a head) through the state-update kernel and through
@@ -87,9 +91,16 @@ WIDTHS = {
         "serve_args": ["--requests", "8", "--prompt-len", "300",
                        "--max-new-tokens", "32", "--max-batch", "8",
                        "--decode-kernel", "auto"],
-        "parity": dict(heads=16, head_dim=128, block_size=16, max_batch=8,
-                       max_context=2048, dtype="bfloat16",
-                       decode_impl="kernel"),
+        "parity": [dict(heads=16, head_dim=128, block_size=16, max_batch=8,
+                        max_context=2048, dtype="bfloat16",
+                        decode_impl="kernel")],
+        "parity_grouped": [
+            dict(heads=48, kv_heads=8, head_dim=128, block_size=16,
+                 max_batch=8, max_context=16384, dtype="bfloat16",
+                 decode_impl="kernel"),
+            dict(heads=32, kv_heads=2, head_dim=128, block_size=16,
+                 max_batch=8, max_context=4096, dtype="bfloat16",
+                 decode_impl="kernel")],
         "state_parity": dict(heads=128, groups=8, head_dim=64, state=128,
                              max_batch=4, impl="kernel"),
         "expect": {"platform": "tpu", "attention": "pallas",
@@ -108,9 +119,13 @@ WIDTHS = {
         "serve_args": ["--requests", "2", "--prompt-len", "10",
                        "--max-new-tokens", "4", "--max-batch", "2",
                        "--block-size", "4", "--decode-kernel", "on"],
-        "parity": dict(heads=2, head_dim=32, block_size=4, max_batch=2,
-                       max_context=32, dtype="float32",
-                       decode_impl="kernel_interpret"),
+        "parity": [dict(heads=2, head_dim=32, block_size=4, max_batch=2,
+                        max_context=32, dtype="float32",
+                        decode_impl="kernel_interpret")],
+        "parity_grouped": [
+            dict(heads=6, kv_heads=2, head_dim=32, block_size=4, max_batch=8,
+                 max_context=64, dtype="float32",
+                 decode_impl="kernel_interpret")],
         "state_parity": dict(heads=8, groups=2, head_dim=8, state=128,
                              dim=32, max_batch=2, impl="kernel_interpret"),
         "expect": {"platform": "cpu", "attention": "pallas_interpret",
@@ -361,18 +376,24 @@ def _parity_child(name: str, imports: str, call: str, device: dict,
     return res, wall
 
 
-def parity_phase(name: str, mode: str, device: dict,
+def parity_phase(name: str, mode: str, key: str, device: dict,
                  deadline: float) -> dict:
-    kw = dict(WIDTHS[mode]["parity"])
-    dtype = kw.pop("dtype")
+    """``decode_parity`` at each geometry of ``WIDTHS[mode][key]``, all in
+    one child (``cases`` of the report)."""
+    calls = ", ".join(
+        "decode_parity(dtype=jnp.{}, **{!r})".format(
+            kw["dtype"], {k: v for k, v in kw.items() if k != "dtype"})
+        for kw in WIDTHS[mode][key])
     res, wall = _parity_child(
         name, "from theanompi_tpu.serving.kv_cache import decode_parity",
-        f"decode_parity(dtype=jnp.{dtype}, **{kw!r})", device, deadline)
-    if not res["ok"]:
-        raise PhaseFailed(
-            f"{name}: kernel vs PagedKVCache.attend_decode fallback: max "
-            f"abs err {res['max_abs_err']:.3g} > tolerance "
-            f"{res['tolerance']:.3g} (finite={res['finite']})")
+        f"{{'cases': [{calls}]}}", device, deadline)
+    for case in res["cases"]:
+        if not case["ok"]:
+            raise PhaseFailed(
+                f"{name}: kernel vs PagedKVCache.attend_decode fallback at "
+                f"{case['heads']} heads over {case['kv_heads']}: max abs err "
+                f"{case['max_abs_err']:.3g} > tolerance "
+                f"{case['tolerance']:.3g} (finite={case['finite']})")
     return {**res, "child_wall_s": round(wall, 1)}
 
 
@@ -439,7 +460,8 @@ def main(argv: list[str] | None = None) -> int:
             ("resnet_train", resnet_phase, (mode, 1)),
             ("serve_bf16", serve_phase, (mode, False)),
             ("serve_int8", serve_phase, (mode, True)),
-            ("decode_parity", parity_phase, (mode,)),
+            ("decode_parity", parity_phase, (mode, "parity")),
+            ("decode_parity_grouped", parity_phase, (mode, "parity_grouped")),
             ("state_update_parity", state_parity_phase, (mode,)),
         ]
         if device["count"] >= 4:

@@ -67,8 +67,8 @@ def test_smoke_parent_is_stdlib_only():
 def test_smoke_cpu_rehearsal_runs_every_phase(tmp_path):
     """The one explicit CPU rehearsal: tmlauncher (LM with the flash
     kernels interpreted, ResNet-50), tmserve plain and int8 with the
-    paged-decode and fused-int8 kernels interpreted, and the decode and
-    state-update parity checks — each a fresh child, none of it a result
+    paged-decode and fused-int8 kernels interpreted, and the decode (one
+    K/V head a query head, and grouped) and state-update parity checks — each a fresh child, none of it a result
     for the chip."""
     # one CPU device: the session's 8 virtual devices would add the
     # multichip phases (they run on the four-chip host, not in tier-1)
@@ -79,7 +79,8 @@ def test_smoke_cpu_rehearsal_runs_every_phase(tmp_path):
     assert last == {"rehearsal": True, "ok": True,
                     "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
     for phase in ("lm_train", "resnet_train", "serve_bf16", "serve_int8",
-                  "decode_parity", "state_update_parity"):
+                  "decode_parity", "decode_parity_grouped",
+                  "state_update_parity"):
         assert f"chip_smoke: {phase} ok on platform=cpu" in out.stdout
     assert "multichip phases skipped" in out.stdout
     assert '"attention": "pallas_interpret"' in out.stdout

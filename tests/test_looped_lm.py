@@ -433,25 +433,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill16", "prefill128"])
-def test_the_served_widths_compile_for_a_v5e_around_one_pool(
-        one_chip, monkeypatch, program):
-    """What the interpreter cannot show, at the served widths, 16 slots and
-    pool geometry with 4 of the 48 layers (16 entries of 321 blocks): the
-    pools go through the program's loop as ONE buffer each — aliased in and
-    out, and no temporary the size of a pool, which is what a loop-carried
-    pool re-laid out for the prefill's scatter cost (two copies of both
-    pools) — and nothing loop-invariant is hoisted out for every layer at
-    once (a second layout of each of q, k, v's weights: 8 MB a weight)."""
-    from jax.experimental.compilation_cache import compilation_cache
-    from theanompi_tpu.models.hybrid_lm import HybridLM
+def _described_engine(model, one_chip, max_batch=1):
+    """An engine over ``model``'s shapes, its weights bf16 on a described
+    chip -> (engine, ``shape(s, dtype)`` of an argument on that chip)."""
     from theanompi_tpu.serving.engine import InferenceEngine
-
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                           "configs", "ouro-2.6b.json")) as f:
-        cfg = dict(json.load(f), num_hidden_layers=4)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
-    model = HybridLM(arch.model_config(cfg))
 
     def shape(s, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
@@ -462,7 +447,41 @@ def test_the_served_widths_compile_for_a_v5e_around_one_pool(
 
     eng = Engine(model, jax.eval_shape(model.init_params,
                                        jax.random.PRNGKey(0))[0],
-                 block_size=16, num_blocks=2, max_batch=16)
+                 block_size=16, num_blocks=2, max_batch=max_batch)
+    return eng, shape
+
+
+def _compiled(jitted, *args):
+    """``jitted`` compiled for the described chip, the persistent cache off
+    (it cannot read such an entry back and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jitted.lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill16", "prefill128"])
+def test_the_served_widths_compile_for_a_v5e_around_one_pool(
+        one_chip, monkeypatch, program):
+    """What the interpreter cannot show, at the served widths, 16 slots and
+    pool geometry with 4 of the 48 layers (16 entries of 321 blocks): the
+    pools go through the program's loop as ONE buffer each — aliased in and
+    out, and no temporary the size of a pool, which is what a loop-carried
+    pool re-laid out for the prefill's scatter cost (two copies of both
+    pools) — and nothing loop-invariant is hoisted out for every layer at
+    once (a second layout of each of q, k, v's weights: 8 MB a weight)."""
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "ouro-2.6b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=4)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    eng, shape = _described_engine(HybridLM(arch.model_config(cfg)), one_chip,
+                                   max_batch=16)
     assert eng.decode_impl == "kernel"
     pool = shape((16, 321, 16, 16, 128), jnp.bfloat16)
     key, b = shape((2,), jnp.uint32), eng.max_batch
@@ -475,13 +494,8 @@ def test_the_served_widths_compile_for_a_v5e_around_one_pool(
         fn, args = eng._prefill_impl, (
             shape((p // 16,)), shape((p,)), shape(()), shape((), jnp.float32),
             shape(()), key)
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-            eng.params, pool, pool, *args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
+    compiled = _compiled(jax.jit(fn, donate_argnums=(1, 2)),
+                         eng.params, pool, pool, *args)
     mem, text = compiled.memory_analysis(), compiled.as_text()
     pool_bytes = 16 * 321 * 16 * 16 * 128 * 2
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
@@ -499,35 +513,24 @@ def test_the_window_models_served_widths_compile_for_a_v5e_and_fit(
     """The other architecture of this spine at ITS served widths
     (``benchmarks/configs/laguna-xs2-pp8.json``: five layers, 32 slots,
     contexts to 16 384): the programs compile, the pool and the window
-    rings are aliased in and out, the grouped products and (prefill) the
-    flash kernel with its band are custom calls, and arguments +
-    temporaries leave the chip room — the decode step's gather is held a
-    piece at a time (4.3 GB whole), the longest bucket's head is one row
-    (6.6 GB of float32 logits whole)."""
-    from jax.experimental.compilation_cache import compilation_cache
+    rings are aliased in and out, the grouped products, the full layers'
+    paged-decode kernel of grouped pools (ISSUE 34) and (prefill) the flash
+    kernel with its band are custom calls, and arguments + temporaries
+    leave the chip room — the decode step gathers no context (the fallback
+    held a 4096-token piece and its copy, 1.4 GB; whole: 4.3 GB), the
+    longest bucket's head is one row (6.6 GB of float32 logits whole)."""
     from benchmarks.arch import laguna
     from theanompi_tpu.models.hybrid_lm import HybridLM
-    from theanompi_tpu.serving.engine import InferenceEngine
 
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                            "configs", "laguna-xs2-pp8.json")) as f:
         cfg = json.load(f)
     run = cfg["run"]
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
-    model = HybridLM(laguna.model_config(cfg))
-
-    def shape(s, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
-
-    class Engine(InferenceEngine):
-        def _held(self, params):
-            return jax.tree.map(lambda x: shape(x.shape, jnp.bfloat16), params)
-
-    eng = Engine(model, jax.eval_shape(model.init_params,
-                                       jax.random.PRNGKey(0))[0],
-                 block_size=16, num_blocks=2, max_batch=1)
-    assert (eng.decode_impl, eng.expert_impl) == ("fallback", "kernel")
     b = run["max_batch"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    eng, shape = _described_engine(HybridLM(laguna.model_config(cfg)), one_chip)
+    assert (eng.decode_impl, eng.decode_call, eng.expert_impl) == (
+        "kernel", "paged_decode_grouped", "kernel")
     pool = shape((2, run["num_blocks"], 16, 8, 128), jnp.bfloat16)
     ring = shape((3, b, 512, 8, 128), jnp.bfloat16)
     state = {"window_k": ring, "window_v": ring}
@@ -541,22 +544,163 @@ def test_the_window_models_served_widths_compile_for_a_v5e_and_fit(
         fn, args = eng._prefill_impl, (
             shape((p // 16,)), shape((p,)), shape(()), shape((), jnp.float32),
             shape(()), key, state, shape(()))
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(fn, donate_argnums=(1, 2, 9)).lower(
-            eng.params, pool, pool, *args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
+    compiled = _compiled(jax.jit(fn, donate_argnums=(1, 2, 9)),
+                         eng.params, pool, pool, *args)
     mem, text = compiled.memory_analysis(), compiled.as_text()
     held = 2 * (2 * run["num_blocks"] * 16 + 3 * b * 512) * 8 * 128 * 2
     assert mem.alias_size_in_bytes >= held
     assert 9.6e9 < mem.argument_size_in_bytes < 9.8e9   # 7.74 GB of weights
-    # decode 1.4 GB (one 4096-token piece of the gather and its copy),
-    # prefill 2.5 GB (131 072 expert rows at model width)
-    assert mem.temp_size_in_bytes < {"decode": 1.6e9, "prefill16384": 2.8e9}[
+    # decode 11 MB (no gathered context); prefill 2.5 GB (131 072 expert
+    # rows at model width)
+    assert mem.temp_size_in_bytes < {"decode": 64e6, "prefill16384": 2.8e9}[
         program], mem
-    calls = text.count("tpu_custom_call")
-    # eight grouped products; in prefill also five flash kernels, three of
-    # them over the band
-    assert calls == {"decode": 8, "prefill16384": 13}[program]
+    # eight grouped products; in decode also the two full layers' paged
+    # kernel, in prefill five flash kernels, three of them over the band
+    assert text.count("tpu_custom_call") == {"decode": 10,
+                                             "prefill16384": 13}[program]
+    if program == "decode":
+        _no_gathered_context(text, pool.shape, b, table_tokens=16384)
+
+
+def _no_gathered_context(text, pool_shape, slots, table_tokens):
+    """A compiled decode program reads its grouped pool through the kernel:
+    the ``paged_decode_grouped`` custom call takes the pools whole, nothing
+    else is as large as a layer of the pool but the decode scatter (in
+    place) — no copy or re-layout ahead of the call — and no gather is an
+    eighth of the slots' tables wide (the fallback's pieces are a quarter)."""
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "paged_decode_grouped" in line]
+    dims = ",".join(str(d) for d in pool_shape)
+    assert calls and all(line.count(f"bf16[{dims}]") >= 2 for line in calls)
+    layer = ",".join(str(d) for d in pool_shape[1:])
+    for line in text.splitlines():
+        inst = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(",
+                        line)
+        if not inst:
+            continue
+        dtype, shape, op = inst.groups()
+        if dtype == "bf16" and shape in (dims, layer):
+            assert op in ("parameter", "scatter", "fusion", "bitcast",
+                          "get-tuple-element"), line[:200]
+            assert op != "fusion" or "/scatter" in line, line[:200]
+        if op == "gather":  # the fallback's: [slots, tokens, Hkv, Dh], or a piece
+            size = int(np.prod([int(d) for d in shape.split(",") if d]))
+            assert size < slots * table_tokens * np.prod(pool_shape[3:]) // 8, \
+                line[:200]
+
+
+def test_the_hybrids_served_widths_decode_through_the_grouped_kernel(
+        one_chip, monkeypatch):
+    """``benchmarks/configs/nemotron3-super-ep4.json`` as it is served (11
+    layers, 128 slots, contexts to 4096, a pool of 2 K/V heads under 32
+    query heads): the decode program holds ten grouped products, five state
+    updates and ONE ``paged_decode_grouped`` call, and gathers no slot's
+    4096-token context."""
+    from benchmarks.arch import nemotron_h
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.ops import mamba2
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "nemotron3-super-ep4.json")) as f:
+        cfg = json.load(f)
+    run = cfg["run"]
+    b = run["max_batch"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    model = HybridLM(nemotron_h.model_config(cfg))
+    with mamba2.pin_state_update("kernel"):
+        eng, shape = _described_engine(model, one_chip)
+        assert (eng.decode_impl, eng.decode_call) == ("kernel",
+                                                      "paged_decode_grouped")
+        assert eng._paged_tags == {"paged_layers": 1, "paged_kernel_layers": 1}
+        spec = model.cache_spec()
+        pool = shape((1, run["num_blocks"], 16, 2, 128), jnp.bfloat16)
+        state = {name: shape((spec["state_layers"], b, *s), dt)
+                 for name, (s, dt) in spec["state"].items()}
+        compiled = _compiled(
+            jax.jit(eng._decode_impl, donate_argnums=(1, 2, 9)),
+            eng.params, pool, pool, shape((b, eng.max_blocks_per_seq)),
+            shape((b,)), shape((b,)), shape((b,), jnp.float32), shape((b,)),
+            shape((2,), jnp.uint32), state, shape((b,)))
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("tpu_custom_call") == 10 + 5 + 1
+    assert mem.temp_size_in_bytes < 64e6, mem
+    _no_gathered_context(text, pool.shape, b, table_tokens=4096)
+
+
+@pytest.mark.parametrize("heads,kv_heads,dh,bs,dtype", [
+    (48, 8, 128, 16, jnp.bfloat16), (32, 2, 128, 16, jnp.bfloat16),
+    (48, 8, 128, 16, jnp.float32), (16, 4, 128, 16, jnp.bfloat16),
+    (8, 2, 256, 16, jnp.bfloat16), (8, 1, 128, 8, jnp.float32)])
+def test_the_grouped_kernels_gate_is_what_compiles_for_a_v5e(
+        one_chip, heads, kv_heads, dh, bs, dtype):
+    """Every shape the gate's test accepts compiles alone, the pool its
+    only operand of that size and nothing copied."""
+    from theanompi_tpu.ops.pallas_paged_attention import (
+        paged_attend_decode_grouped,
+        paged_decode_grouped_supported,
+    )
+
+    assert paged_decode_grouped_supported(heads, kv_heads, dh, bs, dtype)
+
+    def shape(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    pool = shape((2, 1025, bs, kv_heads, dh), dtype)
+    compiled = _compiled(
+        jax.jit(lambda k, v, t, q, p: paged_attend_decode_grouped(
+            k, v, 1, t, bs, q, p, interpret=False)),
+        pool, pool, shape((8, 64)), shape((8, heads, dh), dtype), shape((8,)))
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+#: sha256 (first 16 hex) of the StableHLO text the decode step of the two
+#: one-K/V-head-a-query-head configurations lowered to for the TPU at the
+#: parent of PR 34 (commit bb31a71, jax 0.9.0), at their served shapes (a
+#: pool of 64 blocks; the kernel's serialized body left out): PR 34 gave
+#: grouped pools a kernel of their own and must leave these programs alone.
+GOLDEN_SERVED_DECODE = {"cgpt-1.3b": "296f8dc2efcb3a57",
+                        "ouro-2.6b": "98ac4963790912eb"}
+
+
+@pytest.mark.parametrize("name", GOLDEN_SERVED_DECODE)
+def test_the_one_head_a_query_decode_programs_lower_to_the_text_they_had(name):
+    from benchmarks.common import model_config
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.models.transformer_lm import TransformerLM
+    from theanompi_tpu.serving.engine import InferenceEngine
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", f"{name}.json")) as f:
+        cfg = json.load(f)
+    if name == "cgpt-1.3b":
+        model, b, state = TransformerLM(model_config(cfg)), 32, ()
+    else:
+        model, b, state = HybridLM(arch.model_config(cfg)), 16, ({},)
+    sds = jax.ShapeDtypeStruct
+
+    class Engine(InferenceEngine):
+        def _held(self, params):
+            dtype = getattr(self.model, "weight_dtype", None) or jnp.float32
+            return jax.tree.map(lambda x: sds(x.shape, dtype), params)
+
+    eng = Engine(model, jax.eval_shape(model.init_params,
+                                       jax.random.PRNGKey(0))[0],
+                 block_size=16, num_blocks=2, max_batch=b, decode_kernel="on")
+    assert eng.decode_call == "paged_decode"
+    eng.decode_impl = "kernel"      # the compiled call, lowered for the TPU
+    kv = model.cache_spec()["kv"]
+    pool = sds((kv["layers"], 64, 16, kv["heads"], kv["head_dim"]),
+               jnp.bfloat16)
+    i32 = jnp.int32
+    text = jax.jit(eng._decode_impl, donate_argnums=(1, 2)).trace(
+        eng.params, pool, pool, sds((b, eng.max_blocks_per_seq), i32),
+        sds((b,), i32), sds((b,), i32), sds((b,), jnp.float32), sds((b,), i32),
+        sds((2,), jnp.uint32), *state).lower(
+            lowering_platforms=("tpu",)).as_text()
+    text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', 'backend_config = ""',
+                  text)
+    assert text.count("tpu_custom_call") == kv["layers"] // cfg.get(
+        "total_ut_steps", 1)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == GOLDEN_SERVED_DECODE[name]

@@ -142,6 +142,19 @@ def test_decode_parity_at_a_tileable_geometry():
                         decode_impl="kernel_interpret")
     assert res["ok"] and res["finite"], res
     assert res["shape"] == [4, 16, 128] and res["dtype"] == "bfloat16"
+    assert res["kv_heads"] == 16
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(48, 8), (32, 2)])
+def test_decode_parity_of_a_grouped_pool(heads, kv_heads):
+    """``kv_heads`` makes the pool a grouped one: the grouped kernel against
+    the grouped gather, the served head counts at a short context."""
+    from theanompi_tpu.serving.kv_cache import decode_parity
+
+    res = decode_parity(heads, 128, kv_heads=kv_heads, max_batch=4,
+                        max_context=128, decode_impl="kernel_interpret")
+    assert res["ok"] and res["finite"], res
+    assert res["shape"] == [4, heads, 128] and res["kv_heads"] == kv_heads
 
 
 # -- fallback vs the PR 17 global softmax -------------------------------------

@@ -224,6 +224,12 @@ class HybridLM(Model):
         """Grouped products in one forward pass: two an ``E`` layer."""
         return 2 * [k for _, k in self.layers].count("moe")
 
+    @property
+    def paged_layers(self) -> int:
+        """Layers of one forward pass that attend through the paged pool: a
+        ``*`` layer once a loop step."""
+        return self.config["loops"] * self.config["pattern"].count("*")
+
     def set_expert_products(self, products: str) -> None:
         """What multiplies in the expert layers from now on
         (:class:`DroplessMoE`'s ``products``).  A serving engine calls this
@@ -237,14 +243,15 @@ class HybridLM(Model):
 
     def cache_spec(self) -> dict:
         """What a serving cache must hold for this model: ``kv`` — paged
-        K/V, an entry for each ``*`` layer of each loop step; ``state``
+        K/V, an entry for each ``*`` layer of each loop step (a pattern
+        without a ``*`` holds one entry for nobody); ``state``
         — per slot and per ``M`` layer, name -> (shape, dtype);
         ``state_layers`` their count; ``window`` (where the pattern has a
         ``w``) — per slot and per ``w`` layer a ring of ``size`` tokens of
         K/V, apart from the paged pool."""
         cfg = self.config
         kinds = [k for _, k in self.layers]
-        spec = {"kv": {"layers": max(cfg["loops"] * kinds.count("attn"), 1),
+        spec = {"kv": {"layers": max(self.paged_layers, 1),
                        "heads": cfg["kv_heads"], "head_dim": cfg["head_dim"]},
                 "state": (self._mixers["mamba"].state_shapes()
                           if "mamba" in kinds else {}),

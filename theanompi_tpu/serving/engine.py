@@ -58,7 +58,12 @@ import jax
 import jax.numpy as jnp
 
 from theanompi_tpu.ops.pallas_grouped_matmul import grouped_matmul_supported
-from theanompi_tpu.ops.pallas_paged_attention import paged_decode_supported
+from theanompi_tpu.ops.pallas_paged_attention import (
+    SCOPE,
+    SCOPE_GROUPED,
+    paged_decode_grouped_supported,
+    paged_decode_supported,
+)
 from theanompi_tpu.ops.quant import int8_matmul_supported
 from theanompi_tpu.serving.kv_cache import PagedKVCache, blocks_for
 from theanompi_tpu.serving.quant import (
@@ -158,11 +163,15 @@ class InferenceEngine:
         spec = model.cache_spec()
         heads, head_dim = spec["kv"]["heads"], spec["kv"]["head_dim"]
         on_tpu = jax.default_backend() == "tpu"
-        # the kernel reads one K/V head per query head: a pool of fewer
-        # (grouped) K/V heads takes the fallback whatever was asked
-        use_kernel = heads == cfg["heads"] and (decode_kernel == "on" or (
-            decode_kernel == "auto" and on_tpu and paged_decode_supported(
-                heads, head_dim, model.precision.compute_dtype)))
+        # two kernels, each with a shape gate of its own: one K/V head a
+        # query head, or a pool of fewer (grouped) K/V heads (ISSUE 34)
+        dtype = model.precision.compute_dtype
+        grouped = heads != cfg["heads"]
+        fits = (paged_decode_grouped_supported(cfg["heads"], heads, head_dim,
+                                               self.block_size, dtype)
+                if grouped else paged_decode_supported(heads, head_dim, dtype))
+        use_kernel = decode_kernel == "on" or (
+            decode_kernel == "auto" and on_tpu and fits)
         #: resolved decode-attention variant — "kernel" (compiled pallas,
         #: TPU), "kernel_interpret" (same kernel through the pallas
         #: interpreter, the off-TPU "on" mode the parity locks run) or
@@ -170,6 +179,10 @@ class InferenceEngine:
         #: report whether the kernel tier is active.
         kernel = "kernel" if on_tpu else "kernel_interpret"
         self.decode_impl = kernel if use_kernel else "fallback"
+        #: the kernel's custom call by the name a trace shows it under,
+        #: None on the fallback
+        self.decode_call = ((SCOPE_GROUPED if grouped else SCOPE)
+                            if use_kernel else None)
         #: resolved expert-product variant — "kernel", "kernel_interpret",
         #: "ragged_dot", or None for a model without an expert layer
         self.expert_impl = None
@@ -192,6 +205,13 @@ class InferenceEngine:
             n = model.expert_products
             self._moe_tags = {"moe_products": n,
                               "moe_kernel_products": n if use_grouped else 0}
+        #: the ``paged_layers`` / ``paged_kernel_layers`` tags of
+        #: ``serve.decode`` and ``serve.prefill``: layers of the decode
+        #: program that attend through the paged pool (a looped stack's once
+        #: a loop step), and those of them a Pallas kernel runs
+        n = getattr(model, "paged_layers", spec["kv"]["layers"])
+        self._paged_tags = {"paged_layers": n,
+                            "paged_kernel_layers": n if use_kernel else 0}
         #: what runs the decode step's recurrent-state update, as the model's
         #: op resolved it from platform and shape — "kernel",
         #: "kernel_interpret", "plain", or None for a model without such a
@@ -212,11 +232,13 @@ class InferenceEngine:
         self._head_at = bool(getattr(model, "prefill_head_at", False))
         # int8 leaves the fused matmul can consume stay quantized inside
         # the decode step; the rest (odd-vocab head, MoE stacks)
-        # dequantize as before.  None = dequantize everything.
+        # dequantize as before.  None = dequantize everything: the
+        # fallback, and a grouped pool's model (the fused matmul is in
+        # ``TransformerLM``'s layers, whose pool is never grouped).
         self._keep_quant = (
             (lambda qt: int8_matmul_supported(
                 qt.shape, int(qt.q.shape[1]), compiled=on_tpu))
-            if use_kernel else None)
+            if use_kernel and not grouped else None)
         # kept for swap_params: a live weight rollout must re-quantize the
         # incoming tree EXACTLY as __init__ did (same key, same chunking)
         self._quantize_int8 = bool(quantize_int8)
@@ -291,11 +313,14 @@ class InferenceEngine:
     def resolved_paths(self) -> dict:
         """Which implementation serves each hot op of this engine — the
         gates pick from platform and shape, so SERVE.json states the
-        outcome: the decode-attention variant, how many int8 leaves the
+        outcome: the decode-attention variant (and, on a kernel tier, which
+        of the two kernels: the pool's K/V heads decide), how many int8 leaves the
         decode step feeds to the fused matmul vs dequantizes (prefill
         always dequantizes), and the attention path of every prefill
         bucket compiled so far."""
         out: dict = {"decode_attention": self.decode_impl}
+        if self.decode_call is not None:
+            out["decode_attention_call"] = self.decode_call
         if self.expert_impl is not None:
             out["expert_products"] = self.expert_impl
         if self.state_update_impl is not None:
@@ -458,7 +483,8 @@ class InferenceEngine:
         # the padded length (of the uncached part) that picks the program
         with spans.span(_SPAN_PREFILL, request=rid, prompt=p,
                         bucket=self.pad_len(p - prefix_len),
-                        prefix_len=prefix_len, **self._moe_tags):
+                        prefix_len=prefix_len, **self._moe_tags,
+                        **self._paged_tags):
             if prefix_len:
                 if self._state:
                     raise ValueError(
@@ -576,7 +602,7 @@ class InferenceEngine:
                         requests=np.asarray(rids)[active].tolist(),
                         launched=1, ran_ahead=int(prev is not None),
                         **self._moe_tags, **self._state_tags,
-                        **window_tags) as span:
+                        **self._paged_tags, **window_tags) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):
                 args = jax.device_put((np.array(tables, np.int32),

@@ -33,7 +33,13 @@ block_size, q, positions)``, one kernel per (layer, step) over the WHOLE
 ``[L, ...]`` pools with the layer in the index_map.  Both compute the
 SAME blockwise online-softmax recurrence in the same op order, so they are bit-identical
 on CPU (`interpret=True`) — the parity lock the HLO audit and
-tests/test_paged_decode_kernel.py enforce.
+tests/test_paged_decode_kernel.py enforce.  A pool of FEWER K/V heads than
+the queries handed in have heads (grouped K/V) has a pair of its own
+(ISSUE 34): ``paged_attend_decode_grouped`` on a kernel tier — each slot's
+blocks fetched by the table, many a step, one product for a K/V head's
+whole group of queries — and the gather of ``_attend_decode_grouped`` on the
+fallback, alike in operand and accumulation types and held to a tolerance
+(tests/test_paged_decode_grouped_kernel.py).
 """
 
 from __future__ import annotations
@@ -43,7 +49,10 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from theanompi_tpu.ops.pallas_paged_attention import paged_attend_decode
+from theanompi_tpu.ops.pallas_paged_attention import (
+    paged_attend_decode,
+    paged_attend_decode_grouped,
+)
 
 _NEG_INF = -1e30
 #: the widest context the grouped fallback gathers in one piece; a wider
@@ -296,8 +305,10 @@ class PagedKVCache:
         discarded by the scheduler, and crucially never NaN (an all-masked
         softmax would poison the lane).
 
-        ``decode_impl == "kernel"`` dispatches to the fused pallas kernel
-        (:mod:`theanompi_tpu.ops.pallas_paged_attention`); the default is
+        ``decode_impl == "kernel"`` dispatches to a fused pallas kernel
+        (:mod:`theanompi_tpu.ops.pallas_paged_attention`: one for a pool of
+        as many K/V heads as ``q`` has heads, one for a grouped pool, which
+        has its own fallback too); the default is
         the pure-JAX masked gather below, restructured (ISSUE 18) from one
         global softmax into the blockwise online-softmax recurrence so the
         two paths share an op-for-op schedule and stay BIT-identical on
@@ -308,14 +319,20 @@ class PagedKVCache:
         running max ends at the global max; only the rounding association
         of the normalizer differs), which test_paged_decode_kernel.py pins
         against the verbatim old formula."""
+        # a pool of fewer K/V heads than ``q`` has query heads: the grouped
+        # kernel, or the grouped gather (what is handed in decides, so a
+        # model's layers of different query-head counts share one pool)
+        grouped = q.shape[1] != self.k.shape[3]
         if self.decode_impl != "fallback":
             # the whole pools, never ``self.k[layer]``: XLA copies a
             # sliced operand of a custom call (see the kernel's module)
-            return paged_attend_decode(
+            kernel = paged_attend_decode_grouped if grouped \
+                else paged_attend_decode
+            return kernel(
                 self.k, self.v, layer, self.block_tables,
                 self.block_size, q, jnp.asarray(positions, jnp.int32),
                 interpret=(self.decode_impl == "kernel_interpret"))
-        if q.shape[1] != self.k.shape[3]:
+        if grouped:
             return self._attend_decode_grouped(layer, q, positions)
         # [B, nb, bs, H, Dh]: gather each slot's blocks, then run the
         # recurrence over the block axis
@@ -361,9 +378,11 @@ class PagedKVCache:
         """:meth:`attend_decode`'s fallback where the pool holds fewer K/V
         heads than ``q`` has query heads (query head ``h`` reads K/V head
         ``h // (H // Hkv)``): one masked fp32 softmax over each slot's
-        gathered context, the K/V heads never repeated.  The kernel's gate
-        refuses such a pool, so no bit-parity is owed and the products are
-        plain einsums.  A table wider than ``_GROUPED_CHUNK_TOKENS`` (and a
+        gathered context, the K/V heads never repeated.  On a kernel tier
+        such a pool is read by a kernel of its own
+        (``paged_attend_decode_grouped``) that sums in another order, so no
+        bit-parity is owed and the products are plain einsums.  A table
+        wider than ``_GROUPED_CHUNK_TOKENS`` (and a
         whole number of such pieces) is gathered a piece at a time, the
         pieces joined by the online-softmax recurrence: the gather and its
         transposed copy are held for one piece, not for the whole width
@@ -434,34 +453,37 @@ def _grouped_attend(q, kb, vb, positions):
 def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,
                   max_batch: int = 8, max_context: int = 2048,
                   dtype=jnp.bfloat16, decode_impl: str = "kernel",
-                  seed: int = 0) -> dict:
+                  kv_heads: int | None = None, seed: int = 0) -> dict:
     """One decode-attention step through ``decode_impl`` and through the
     pure-JAX fallback over the same random pools; -> the largest absolute
     difference and the tolerance it is held to.
 
-    The slots sit at ragged positions (first token, a block boundary, the
-    last position of the context) with scattered block tables, one slot
-    inactive.  The pool has two layers of different values and both paths
-    attend at layer 1, so the kernel's layer index is under the check too
-    (ISSUE 26).  The two paths run one online-softmax recurrence and differ
-    by fp32 rounding only, so the bound is four rounding steps of the
-    OUTPUT dtype at the output's magnitude — the check ``chip_smoke.py``
-    runs on the chip at the served head geometry, and tier-1 runs under
-    the interpreter.
+    The slots sit at ragged positions (first token, a block boundary, a
+    boundary of the grouped kernel's steps, the last position of the
+    context) with scattered block tables, one slot inactive.  The pool has
+    two layers of different values and both paths attend at layer 1, so the
+    kernel's layer index is under the check too (ISSUE 26).  ``kv_heads``
+    (fewer than ``heads``) makes the pool a grouped one: the grouped kernel
+    against the grouped gather (ISSUE 34).  Either pair of paths runs one
+    online-softmax recurrence over the same operand types and differs by
+    rounding only, so the bound is four rounding steps of the OUTPUT dtype
+    at the output's magnitude — the check ``chip_smoke.py`` runs on the
+    chip at the served head geometries, and tier-1 runs under the
+    interpreter.
     """
     import numpy as np
 
     nb = blocks_for(max_context, block_size)
     num_blocks = max_batch * nb + 1
     kk, kv, kq = jax.random.split(jax.random.PRNGKey(seed), 3)
-    shape = (2, num_blocks, block_size, heads, head_dim)
+    shape = (2, num_blocks, block_size, kv_heads or heads, head_dim)
     k = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
     v = jax.random.normal(kv, shape, jnp.float32).astype(dtype)
     q = jax.random.normal(kq, (max_batch, heads, head_dim),
                           jnp.float32).astype(dtype)
     rng = np.random.RandomState(seed)
     edges = [0, block_size - 1, block_size, max_context // 2,
-             max_context - 1]
+             max_context - 1, max_context // 4 - 1, max_context // 4]
     positions = np.asarray(
         [edges[i % len(edges)] for i in range(max_batch)], np.int32)
     tables = np.zeros((max_batch, nb), np.int32)
@@ -481,6 +503,7 @@ def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,
     tol = 4 * float(jnp.finfo(dtype).eps) * max(1.0, float(np.abs(ref).max()))
     err = float(np.abs(got - ref).max())
     return {"decode_impl": decode_impl, "heads": heads,
+            "kv_heads": kv_heads or heads, "max_context": max_context,
             "head_dim": head_dim, "dtype": jnp.dtype(dtype).name,
             "shape": list(got.shape), "finite": bool(np.isfinite(got).all()),
             "max_abs_err": err, "tolerance": tol,

@@ -144,7 +144,10 @@ SERVE_COLLECT_SPAN = "serve.collect"
 #: ``lax.ragged_dot`` it replaces carries no ``op_name`` and reads as
 #: unscoped ``custom-call/ragged-dot-none`` — and ``mamba_state_update``:
 #: the decode step's recurrent-state update, ``ops/pallas_state_update.py``,
-#: ``custom-call/mamba_state_update`` under ``mamba``); ``HybridLM``:
+#: ``custom-call/mamba_state_update`` under ``mamba`` — and
+#: ``paged_decode_grouped``: the paged-decode kernel of a pool of fewer
+#: K/V heads than query heads, ``custom-call/paged_decode_grouped`` under
+#: ``attn``); ``HybridLM``:
 #: ``embed``, ``mamba`` (a Mamba-2 mixer, projections and state update),
 #: ``moe.route`` (router, top-k), ``moe.experts`` (latent projections,
 #: sort, the kernel's schedule, grouped products), ``moe.shared`` (the
@@ -157,8 +160,9 @@ DEVICE_SCOPES = {
     "engine": ("recast", "sample"),
     "TransformerLM": ("embed", "block", "attn", "mlp", "head", "loss"),
     "trainer": ("clip", "exchange", "optimizer"),
-    "kernels": ("paged_decode", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                "grouped_matmul", "mamba_state_update"),
+    "kernels": ("paged_decode", "paged_decode_grouped", "flash_fwd",
+                "flash_bwd_dq", "flash_bwd_dkv", "grouped_matmul",
+                "mamba_state_update"),
     "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
                  "attn", "attn.window", "mlp", "loop.exit", "head"),
 }
