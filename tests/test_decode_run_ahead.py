@@ -326,6 +326,129 @@ def test_the_ring_shows_the_next_launch_ahead_of_the_read(engines, kind):
     assert direct.tags["bytes"] >= SLOTS * VOCAB * 4
 
 
+_PREFILL_PARTS = ("place", "dispatch", "drain", "wait", "fetch")
+
+
+def _prefills(records):
+    """-> [(a ``serve.prefill`` record, its children by their last name)]
+    in the order the calls were made."""
+    out = []
+    for r in records:
+        if r.name == "serve.prefill":
+            kids = [c for c in records if c.parent == r.id and not c.instant]
+            assert all(c.name.startswith("serve.prefill.") for c in kids)
+            out.append((r, {c.name.rsplit(".", 1)[1]: c for c in kids}))
+    return sorted(out, key=lambda pair: pair[0].t0)
+
+
+def _says_a_launch_is_running(eng, monkeypatch):
+    """A tiny step on the CPU may be over before the host comes back: the
+    engine's one readiness query says "still running" while a launch is
+    unread, which is what a chip's step of tens of ms answers."""
+    monkeypatch.setattr(eng, "_step_running",
+                        lambda: eng._unread is not None, raising=True)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_prefill_call_from_inside(engines, kind, monkeypatch):
+    """ISSUE 35: ``serve.prefill`` > place, dispatch, wait, fetch on every
+    call, in that order and covering it; a drain between dispatch and wait
+    exactly where the prefill went out behind an unread launch — not on a
+    first admission, not after ``collect``; the tokens served are still the
+    synchronous loop's."""
+    eng = engines(kind)
+    _says_a_launch_is_running(eng, monkeypatch)
+    t0 = time.perf_counter()
+    sched = Scheduler(eng)
+    reqs = _requests([(90, 5, 6), (91, 6, 6), (92, 3, 4)])
+    sched.submit(reqs[0])
+    sched.submit(reqs[1])
+    sched.step()                 # both admitted: nothing is out yet
+    sched.submit(reqs[2])
+    sched.step()                 # behind the first step's launch
+    assert sched.preempt_all() == 3 and eng._unread is None  # collected
+    _run(sched, [])              # all three again, from an empty pipeline
+    calls = _prefills([r for r in spans.snapshot() if r.t0 >= t0])
+    assert [p.tags["request"] for p, _ in calls] == [90, 91, 92, 92, 91, 90]
+    assert ["drain" in parts for _, parts in calls] == [
+        False, False, True, False, False, False]
+    for whole, parts in calls:
+        assert set(parts) - {"drain"} == set(_PREFILL_PARTS) - {"drain"}
+        order = [parts[n] for n in _PREFILL_PARTS if n in parts]
+        assert whole.t0 <= order[0].t0 and order[-1].t1 <= whole.t1
+        assert all(a.t1 <= b.t0 for a, b in zip(order, order[1:]))
+        assert parts["fetch"].tags["bytes"] == 4 * VOCAB  # [V] float32
+        assert whole.tags["tokens"] == (whole.tags["prompt"]
+                                        - whole.tags["prefix_len"])
+        assert whole.tags["tokens"] <= whole.tags["bucket"] == eng.pad_len(
+            whole.tags["tokens"])
+    # the parts account for the call: what is left is a few spans' own cost
+    shares = sorted(sum(c.dur for c in parts.values()) / whole.dur
+                    for whole, parts in calls)
+    assert 0.8 <= shares[len(shares) // 2] <= 1.0
+    for r in reqs:
+        assert r.generated == plain(eng, r.prompt, r.max_new_tokens,
+                                    rid=r.rid), r.rid
+
+
+def test_a_prefix_hits_suffix_prefill_has_the_same_parts(engines,
+                                                         monkeypatch):
+    eng = engines("dense")
+    _says_a_launch_is_running(eng, monkeypatch)
+    sched = Scheduler(eng, prefix_cache=True)
+    first, other = _requests([(93, 8, 6), (94, 5, 14)])
+    sched.submit(first)
+    sched.submit(other)
+    while first.state != "done":
+        sched.step()
+    t0 = time.perf_counter()
+    turn = Request(rid=95, prompt=first.prompt + first.generated[:5] + [7, 9],
+                   max_new_tokens=3)
+    sched.submit(turn)
+    sched.step()
+    assert sched.n_prefix_hits == 1 and other.state == "active"
+    ((whole, parts),) = _prefills([r for r in spans.snapshot() if r.t0 >= t0])
+    assert whole.tags["prefix_len"] == 12 and whole.tags["tokens"] == 3
+    assert whole.tags["bucket"] == 4
+    assert set(parts) == set(_PREFILL_PARTS)  # behind ``other``'s launch
+    _run(sched, [])
+    assert turn.generated == plain(eng, turn.prompt, 3, rid=95)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_launch_says_whether_it_found_the_device_idle(engines, kind,
+                                                        monkeypatch):
+    """``starved``: 1 on a launch into an empty pipeline and — asked of
+    the device itself — on the launch after a prefill, which is synchronous
+    and queued behind the step before it; 0 on a launch that ran ahead of a
+    step still running."""
+    eng = engines(kind)
+    work = [(96, 5, 5), (97, 4, 8)]
+
+    def serve():
+        t0 = time.perf_counter()
+        sched = Scheduler(eng)
+        reqs = _requests(work)
+        sched.submit(reqs[0])
+        for _ in range(3):
+            sched.step()
+        sched.submit(reqs[1])    # its prefill lies in the fourth step
+        _run(sched, [])
+        return [d.tags for d in _decode_spans(t0)]
+
+    tags = serve()
+    assert [t["ran_ahead"] for t in tags] == [0] + [1] * (len(tags) - 1)
+    assert tags[0]["starved"] == 1 and tags[3]["starved"] == 1
+    assert {t["starved"] for t in tags} <= {0, 1}
+    # a direct caller reads its own launch: every call finds nothing queued
+    t0 = time.perf_counter()
+    plain(eng, _prompt(96, 5), 3)
+    assert [d.tags["starved"] for d in _decode_spans(t0)] == [1, 1]
+    _says_a_launch_is_running(eng, monkeypatch)
+    tags = serve()
+    assert [t["starved"] for t in tags] == [1] + [0] * (len(tags) - 1)
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_one_decode_program_serves_both_kinds_of_call(models, kind):
     t0 = time.perf_counter()

@@ -29,7 +29,10 @@ jitted call), ``.wait`` (a launch's tokens reach the host: that device
 step is over), ``.fetch`` (what else comes with them: the step's device
 counters and, for a direct caller, the logits).  Under a scheduler
 (``run_ahead_for``) the launch a call reads is the PREVIOUS call's, so
-the wait is for a step the device was given a whole call earlier.  On the
+the wait is for a step the device was given a whole call earlier.  A
+prefill has the same four and, between its dispatch and its wait, a
+``.drain`` where a decode step was still running as it went out: the
+wait then starts with the prefill program and is its device time.  On the
 device, ``recast`` and
 ``sample`` scopes name the weight cast and the sampler beside the model's
 own ``embed`` / ``block`` / ``attn`` / ``mlp`` / ``head`` (``HybridLM``:
@@ -76,11 +79,14 @@ from theanompi_tpu.telemetry import spans
 from theanompi_tpu.telemetry.metrics import (
     SERVE_COLLECT_SPAN,
     SERVE_DECODE_SPANS,
+    SERVE_PREFILL_SPANS,
     SERVE_SPANS,
 )
 
 _SPAN_PREFILL, _SPAN_DECODE = SERVE_SPANS
 _SPAN_PLACE, _SPAN_DISPATCH, _SPAN_WAIT, _SPAN_FETCH = SERVE_DECODE_SPANS
+(_SPAN_PREFILL_PLACE, _SPAN_PREFILL_DISPATCH, _SPAN_PREFILL_DRAIN,
+ _SPAN_PREFILL_WAIT, _SPAN_PREFILL_FETCH) = SERVE_PREFILL_SPANS
 
 
 def _is_quantized(leaf) -> bool:
@@ -482,6 +488,7 @@ class InferenceEngine:
         # the whole call, fenced by the host int it returns; ``bucket`` is
         # the padded length (of the uncached part) that picks the program
         with spans.span(_SPAN_PREFILL, request=rid, prompt=p,
+                        tokens=p - prefix_len,
                         bucket=self.pad_len(p - prefix_len),
                         prefix_len=prefix_len, **self._moe_tags,
                         **self._paged_tags):
@@ -500,26 +507,26 @@ class InferenceEngine:
             p_pad = self.pad_len(p)
             if p_pad < p:
                 raise ValueError(f"prompt {p} > padded bucket {p_pad}")
-            row = list(table_row) + [PagedKVCache.NULL_BLOCK] * (
-                p_pad // self.block_size - len(table_row))
             fn = self._prefill_fns.get(p_pad)
             if fn is None:
                 fn = self._prefill_fns[p_pad] = jax.jit(
                     self._prefill_impl, donate_argnums=self._donate)
-            toks = np.zeros((p_pad,), np.int32)
-            toks[:p] = tokens
-            own = ((self._state, jnp.asarray(slot, jnp.int32))
-                   if self._state else ())
-            nxt, last, self._k, self._v, self._state = fn(
-                self.params, self._k, self._v,
-                jnp.asarray(row, jnp.int32), jnp.asarray(toks),
-                jnp.asarray(p, jnp.int32),
-                jnp.asarray(temperature, jnp.float32),
-                jnp.asarray(rid, jnp.int32), self._base_key, *own)
-            # lint: donated-escape-ok — prefill outputs are fresh XLA result
-            # buffers; only the k/v pools are donated, never sampled tokens
-            # lint: host-sync-ok — the span closes over materialized results
-            return int(nxt), np.asarray(last)
+            with spans.span(_SPAN_PREFILL_PLACE):
+                row = list(table_row) + [PagedKVCache.NULL_BLOCK] * (
+                    p_pad // self.block_size - len(table_row))
+                toks = np.zeros((p_pad,), np.int32)
+                toks[:p] = tokens
+                args = (jnp.asarray(row, jnp.int32), jnp.asarray(toks),
+                        jnp.asarray(p, jnp.int32),
+                        jnp.asarray(temperature, jnp.float32),
+                        jnp.asarray(rid, jnp.int32))
+                own = ((self._state, jnp.asarray(slot, jnp.int32))
+                       if self._state else ())
+            with spans.span(_SPAN_PREFILL_DISPATCH):
+                nxt, last, self._k, self._v, self._state = fn(
+                    self.params, self._k, self._v, *args, self._base_key,
+                    *own)
+            return self._prefill_read(nxt, last)
 
     def _prefill_suffix(self, table_row, tokens, temperature, rid,
                         prefix_len):
@@ -534,29 +541,50 @@ class InferenceEngine:
                              f"at least one token must stay uncached")
         s = p - prefix_len
         s_pad = self.pad_len(s)
-        # the full row at FIXED width: program shape keyed on s_pad only
-        full_row = list(table_row) + [PagedKVCache.NULL_BLOCK] * (
-            self.max_blocks_per_seq - len(table_row))
-        n_prefix = prefix_len // self.block_size
-        suffix_row = list(table_row[n_prefix:]) + [
-            PagedKVCache.NULL_BLOCK] * (
-            s_pad // self.block_size - (len(table_row) - n_prefix))
         fn = self._prefill_suffix_fns.get(s_pad)
         if fn is None:
             fn = self._prefill_suffix_fns[s_pad] = jax.jit(
                 self._prefill_suffix_impl, donate_argnums=(1, 2))
-        toks = np.zeros((s_pad,), np.int32)
-        toks[:s] = tokens[prefix_len:]
-        nxt, last, self._k, self._v = fn(
-            self.params, self._k, self._v,
-            jnp.asarray(full_row, jnp.int32),
-            jnp.asarray(suffix_row, jnp.int32), jnp.asarray(toks),
-            jnp.asarray(prefix_len, jnp.int32), jnp.asarray(p, jnp.int32),
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(rid, jnp.int32), self._base_key)
-        # lint: donated-escape-ok — prefill outputs are fresh XLA result
-        # buffers; only the k/v pools are donated, never sampled tokens
-        return int(nxt), np.asarray(last)
+        with spans.span(_SPAN_PREFILL_PLACE):
+            # the full row at FIXED width: program shape keyed on s_pad only
+            full_row = list(table_row) + [PagedKVCache.NULL_BLOCK] * (
+                self.max_blocks_per_seq - len(table_row))
+            n_prefix = prefix_len // self.block_size
+            suffix_row = list(table_row[n_prefix:]) + [
+                PagedKVCache.NULL_BLOCK] * (
+                s_pad // self.block_size - (len(table_row) - n_prefix))
+            toks = np.zeros((s_pad,), np.int32)
+            toks[:s] = tokens[prefix_len:]
+            args = (jnp.asarray(full_row, jnp.int32),
+                    jnp.asarray(suffix_row, jnp.int32), jnp.asarray(toks),
+                    jnp.asarray(prefix_len, jnp.int32),
+                    jnp.asarray(p, jnp.int32),
+                    jnp.asarray(temperature, jnp.float32),
+                    jnp.asarray(rid, jnp.int32))
+        with spans.span(_SPAN_PREFILL_DISPATCH):
+            nxt, last, self._k, self._v = fn(
+                self.params, self._k, self._v, *args, self._base_key)
+        return self._prefill_read(nxt, last)
+
+    def _prefill_read(self, nxt, last):
+        """The ``.drain``, ``.wait`` and ``.fetch`` of a prefill that has
+        gone out: -> (its sampled token, its last position's logits), on
+        the host."""
+        if self._step_running():
+            # the prefill queued behind that step: the host would wait it
+            # out inside ``int(nxt)`` anyway.  Nothing is read — the launch
+            # stays unread for its owner
+            with spans.span(_SPAN_PREFILL_DRAIN):
+                # lint: host-sync-ok — this span IS the rest of that step
+                self._unread.nxt.block_until_ready()
+        with spans.span(_SPAN_PREFILL_WAIT):
+            # lint: host-sync-ok — this span IS the prefill program's run
+            tok = int(nxt)
+        with spans.span(_SPAN_PREFILL_FETCH, bytes=last.nbytes):
+            # lint: host-sync-ok — this span IS the copy to the host
+            # lint: donated-escape-ok — prefill outputs are fresh XLA result
+            # buffers; only the k/v pools are donated, never sampled tokens
+            return tok, np.asarray(last)
 
     def decode(self, tables, lengths, tokens, temps, rids):
         """One decode step over the fixed batch; -> (next tokens ``[B]``
@@ -610,6 +638,7 @@ class InferenceEngine:
                                        np.array(tokens, np.int32),
                                        np.array(temps, np.float32),
                                        np.array(rids, np.int32)))
+            span.tag(starved=int(not self._step_running()))
             with spans.span(_SPAN_DISPATCH):
                 nxt, logits, self._k, self._v, self._state, stats = \
                     self._decode_fn(
@@ -625,6 +654,12 @@ class InferenceEngine:
                 return self._read(_Launch(nxt, logits, stats, None), span)
             self._unread = _Launch(nxt, None, stats, owner)
             return self._read(prev or _NO_LAUNCH, span)
+
+    def _step_running(self) -> bool:
+        """A launch is out (its reader's or not) and the device has not
+        finished it: one non-blocking query."""
+        out = self._unread
+        return out is not None and not out.nxt.is_ready()
 
     def _read(self, launch, span):
         """The ``.wait`` and ``.fetch`` of one call: ``launch``'s tokens,

@@ -64,8 +64,9 @@ def hlo_collective_counts(hlo_text: str) -> dict[str, int]:
 SERVE_SPANS = ("serve.prefill", "serve.decode")
 #: ISSUE 25 — the spans the serving loops open in the span ring
 #: (``telemetry/spans.py``), beside the two above which the ENGINE opens
-#: now (``serve.prefill`` tags: ``request``, ``prompt``, ``bucket`` — the
-#: padded length that picks the program, ``prefix_len``; ``serve.decode``
+#: now (``serve.prefill`` tags: ``request``, ``prompt``, ``tokens`` — the
+#: rows computed, ``bucket`` — their padded count, which picks the program,
+#: ``prefix_len``; ``serve.decode``
 #: tags: ``step`` — the engine's decode ordinal, ``batch``, ``requests``,
 #: ``kv_tokens`` — the tokens the step's attention reads: each active
 #: slot's context with the token it writes, counted on the host, for every
@@ -87,6 +88,20 @@ SERVE_STEP_SPANS = ("serve.step", "serve.admit")
 #: of ``InferenceEngine.decode``, never on the scheduler's path)
 SERVE_DECODE_SPANS = ("serve.decode.place", "serve.decode.dispatch",
                       "serve.decode.wait", "serve.decode.fetch")
+#: the prefill call from inside, in order (ISSUE 35; ``serve.prefill``'s
+#: tag ``tokens`` is the rows the program computes, ``prompt`` less
+#: ``prefix_len``, and ``bucket`` their padded count): the host arrays and
+#: their puts; the jitted call (a bucket's first call builds under it); the
+#: rest of the decode step in flight — only where a launch is unread and
+#: its tokens are not ready once the prefill has gone out: it ends when
+#: that step ends, which is when the device starts the prefill program;
+#: the wait for the sampled token, from there (or from the dispatch, on an
+#: idle device) to the program's end: the prefill's DEVICE time as the host
+#: sees it; and the last position's logits coming to the host (tag
+#: ``bytes``)
+SERVE_PREFILL_SPANS = ("serve.prefill.place", "serve.prefill.dispatch",
+                       "serve.prefill.drain", "serve.prefill.wait",
+                       "serve.prefill.fetch")
 #: ``serve.decode`` tags of a model with expert layers, one value a step:
 #: ``moe_local_hits`` — selected experts that this process holds, summed
 #: over the expert layers and the active slots (over ``batch`` and the
@@ -125,8 +140,14 @@ SERVE_DECODE_WINDOW_TAGS = ("kv_full_tokens", "kv_window_tokens")
 #: request which had already ended on a stop token (their tokens are
 #: dropped).  ``step`` / ``batch`` / ``kv_tokens`` / ``requests`` are the
 #: launched step's; the device counters above are those of the step read,
-#: one behind
-SERVE_DECODE_AHEAD_TAGS = ("launched", "ran_ahead", "overrun_slots")
+#: one behind.  ``starved`` (ISSUE 35) — 1 where, as the jitted call went
+#: out, the device had nothing of this engine's queued: no launch unread
+#: (the pipeline was empty) or the unread launch's tokens ready already
+#: (the call after a synchronous prefill), so the device idled through this
+#: call's place and dispatch; 0 where the launch ran ahead of a step still
+#: running
+SERVE_DECODE_AHEAD_TAGS = ("launched", "ran_ahead", "overrun_slots",
+                           "starved")
 #: ``InferenceEngine.collect``: the wait for the launch a drain reads,
 #: with no launch of its own (under ``serve.step``, or under nothing where
 #: ``preempt_all`` / ``expire_all_active`` drained between steps); tag
