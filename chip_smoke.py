@@ -104,7 +104,7 @@ WIDTHS = {
         "state_parity": dict(heads=128, groups=8, head_dim=64, state=128,
                              max_batch=4, impl="kernel"),
         "expect": {"platform": "tpu", "attention": "pallas",
-                   "decode": "kernel"},
+                   "decode": "kernel", "weights_held": "bfloat16"},
     },
     "rehearsal": {
         "steps": 2,
@@ -129,7 +129,7 @@ WIDTHS = {
         "state_parity": dict(heads=8, groups=2, head_dim=8, state=128,
                              dim=32, max_batch=2, impl="kernel_interpret"),
         "expect": {"platform": "cpu", "attention": "pallas_interpret",
-                   "decode": "kernel_interpret"},
+                   "decode": "kernel_interpret", "weights_held": "float32"},
     },
 }
 
@@ -338,6 +338,10 @@ def serve_phase(name: str, mode: str, int8: bool, device: dict,
             "pallas"}:
         raise PhaseFailed(f"{name}: prefill attention "
                           f"{paths['prefill_attention']}, wanted pallas")
+    if paths.get("weights_held") != w["expect"]["weights_held"]:
+        raise PhaseFailed(f"{name}: the engine holds its weights in "
+                          f"{paths.get('weights_held')!r}, not "
+                          f"{w['expect']['weights_held']!r}")
     if int8:
         i8 = paths.get("int8_matmul") or {}
         if not i8.get("decode_fused") or i8.get("decode_dequantized"):

@@ -434,8 +434,10 @@ def one_chip():
 
 
 def _described_engine(model, one_chip, max_batch=1):
-    """An engine over ``model``'s shapes, its weights bf16 on a described
-    chip -> (engine, ``shape(s, dtype)`` of an argument on that chip)."""
+    """An engine over ``model``'s shapes, its weights as the engine's own
+    rule holds them (``_held``, evaluated abstractly: bf16 for these models)
+    on a described chip -> (engine, ``shape(s, dtype)`` of an argument on
+    that chip)."""
     from theanompi_tpu.serving.engine import InferenceEngine
 
     def shape(s, dtype=jnp.int32):
@@ -443,7 +445,8 @@ def _described_engine(model, one_chip, max_batch=1):
 
     class Engine(InferenceEngine):
         def _held(self, params):
-            return jax.tree.map(lambda x: shape(x.shape, jnp.bfloat16), params)
+            return jax.tree.map(lambda x: shape(x.shape, x.dtype),
+                                jax.eval_shape(super()._held, params))
 
     eng = Engine(model, jax.eval_shape(model.init_params,
                                        jax.random.PRNGKey(0))[0],
@@ -659,7 +662,12 @@ def test_the_grouped_kernels_gate_is_what_compiles_for_a_v5e(
 #: parent of PR 34 (commit bb31a71, jax 0.9.0), at their served shapes (a
 #: pool of 64 blocks; the kernel's serialized body left out): PR 34 gave
 #: grouped pools a kernel of their own and must leave these programs alone.
-GOLDEN_SERVED_DECODE = {"cgpt-1.3b": "296f8dc2efcb3a57",
+#: PR 36 replaced ``cgpt-1.3b`` (296f8dc2efcb3a57 until then): ``TransformerLM``
+#: states a ``weight_dtype`` now, so the engine holds its tree in bf16 and the
+#: program takes every weight as bf16 — the 390 ``convert`` ops of its
+#: ``recast`` scope are gone from the text and nothing else of it moved;
+#: ``ouro-2.6b`` (``HybridLM``, which always stated one) is PR 34's parent's.
+GOLDEN_SERVED_DECODE = {"cgpt-1.3b": "5c03fe830de1e048",
                         "ouro-2.6b": "98ac4963790912eb"}
 
 
@@ -704,3 +712,35 @@ def test_the_one_head_a_query_decode_programs_lower_to_the_text_they_had(name):
         "total_ut_steps", 1)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == GOLDEN_SERVED_DECODE[name]
+
+
+def test_cgpt13s_decode_program_compiled_for_a_v5e_holds_no_weight_sized_temporary(
+        one_chip, monkeypatch):
+    """PR 36, what the lowered text (``test_engine_held_weights.py``) cannot
+    show: after XLA's passes, at the served widths and pool with 2 of the 24
+    layers, the program's arguments are the bf16 tree and its temporaries
+    hold nothing the size of the embedding — the parent re-cast the whole
+    ``f32[50257,2048]`` every step, 206 MB of bf16, for a gather of 32 rows."""
+    from benchmarks.common import model_config
+    from theanompi_tpu.models.transformer_lm import TransformerLM
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "cgpt-1.3b.json")) as f:
+        cfg = dict(json.load(f), n_layer=2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    eng, shape = _described_engine(TransformerLM(model_config(cfg)), one_chip,
+                                   max_batch=32)
+    assert eng.decode_impl == "kernel"
+    assert eng.resolved_paths()["weights_held"] == "bfloat16"
+    pool, b = shape((2, 2048, 16, 16, 128), jnp.bfloat16), eng.max_batch
+    compiled = _compiled(
+        jax.jit(eng._decode_impl, donate_argnums=(1, 2)), eng.params, pool,
+        pool, shape((b, eng.max_blocks_per_seq)), shape((b,)), shape((b,)),
+        shape((b,), jnp.float32), shape((b,)), shape((2,), jnp.uint32))
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    weights = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(eng.params))
+    pools = 2 * 2 * 2048 * 16 * 16 * 128 * 2
+    assert abs(mem.argument_size_in_bytes - (2 * weights + pools)) < 1 << 20
+    assert mem.temp_size_in_bytes < 64e6, mem  # the embedding alone: 206 MB
+    assert "f32[50257,2048]" not in text and "f32[2048,8192]" not in text
+    assert text.count("tpu_custom_call") == 2

@@ -24,8 +24,13 @@ EXPECTED = {
     "train": (*MODEL, "loss", "clip", "exchange", "optimizer"),
     "train_plain_loss": (*MODEL, "head", "loss", "clip", "exchange",
                          "optimizer"),
-    "decode": (*MODEL, "head", "recast", "sample", "paged_decode"),
-    "prefill": (*MODEL, "head", "recast", "sample"),
+    # the engine's own programs take the tree it holds, already in the
+    # dtype they read (PR 36): their ``recast`` scope lowers to nothing ...
+    "decode": (*MODEL, "head", "sample", "paged_decode"),
+    "prefill": (*MODEL, "head", "sample"),
+    # ... and is there for a caller that hands the fp32 tree in directly
+    "decode_fp32_tree": (*MODEL, "head", "recast", "sample", "paged_decode"),
+    "prefill_fp32_tree": (*MODEL, "head", "recast", "sample"),
     # HybridLM (ISSUE 27): one mixer a layer, each under its own scope
     "hybrid_decode": (*HYBRID, "sample"),
     "hybrid_prefill": (*HYBRID, "sample"),
@@ -111,16 +116,18 @@ def lowered():
     eng = InferenceEngine(model, params, block_size=8, max_batch=2,
                           decode_kernel="on")
     b, i32 = eng.max_batch, jnp.int32
-    out["decode"] = eng._decode_fn.lower(
-        eng.params, eng._k, eng._v,
-        jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
-        jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
-        jnp.zeros((b,), i32), eng._base_key).as_text(debug_info=True)
-    out["prefill"] = jax.jit(eng._prefill_impl, donate_argnums=(1, 2)).lower(
-        eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
-        jnp.zeros((16,), i32), jnp.asarray(5, i32),
-        jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
-        eng._base_key).as_text(debug_info=True)
+    for key, tree in (("", eng.params), ("_fp32_tree", params)):
+        out["decode" + key] = eng._decode_fn.lower(
+            tree, eng._k, eng._v,
+            jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+            jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+            jnp.zeros((b,), i32), eng._base_key).as_text(debug_info=True)
+        out["prefill" + key] = jax.jit(
+            eng._prefill_impl, donate_argnums=(1, 2)).lower(
+                tree, eng._k, eng._v, jnp.zeros((2,), i32),
+                jnp.zeros((16,), i32), jnp.asarray(5, i32),
+                jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
+                eng._base_key).as_text(debug_info=True)
 
     from theanompi_tpu.models.hybrid_lm import HybridLM
 

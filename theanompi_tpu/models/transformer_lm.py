@@ -319,6 +319,14 @@ class TransformerLM(SupervisedModel):
             impl = "pallas_interpret"
         return impl
 
+    @property
+    def weight_dtype(self):
+        """The dtype a serving engine holds the weights in: the one the
+        serving entry points read them in (``cast_to_compute``), so the
+        engine rounds the tree once and their ``recast`` of it lowers to
+        nothing.  Training never reads this: a trainer keeps fp32 masters."""
+        return self.precision.compute_dtype
+
     def resolved_paths(self) -> dict:
         cfg = self.config
         attn = ("ring" if cfg["seq_parallel"]

@@ -47,8 +47,18 @@ state instead, a slot-indexed state pool ``[layers, max_batch, ...]``
 donated through the steps like the K/V pools.  A prefill writes its
 slot's state whole, taken at the prompt's TRUE length (padding to a bucket
 is invisible to causal attention but not to a recurrence); a decode step
-updates every slot's in place.  A model that states a ``weight_dtype``
-has its weights cast to it once, here, and held so.
+updates every slot's in place.
+
+A model states the dtype its serving programs read weights in
+(``weight_dtype``; both families do — ``HybridLM`` its ``weights`` key,
+``TransformerLM`` its precision policy's compute dtype), and the engine
+casts the tree it is handed to it ONCE, here and at every ``swap_params``,
+and holds it so: the ``recast`` at the top of each program is then a no-op
+that lowers to nothing, where a float32 tree under a bf16 policy was read
+whole and rounded again in every decode step and every prefill.  The same
+operand bits either way (on the CPU the same logits to the bit; on a TPU
+XLA fuses the two programs differently, so bf16 sums round in another
+order); ``resolved_paths()["weights_held"]`` names the dtype.
 """
 
 from __future__ import annotations
@@ -91,6 +101,12 @@ _SPAN_PLACE, _SPAN_DISPATCH, _SPAN_WAIT, _SPAN_FETCH = SERVE_DECODE_SPANS
 
 def _is_quantized(leaf) -> bool:
     return isinstance(leaf, QuantizedTensor)
+
+
+def _is_float_weight(leaf) -> bool:
+    """A floating leaf of a tree flattened with ``is_leaf=_is_quantized``:
+    a weight, not an int8 leaf (whose float32 scales stay as they are)."""
+    return not _is_quantized(leaf) and jnp.issubdtype(leaf.dtype, jnp.floating)
 
 
 class _Launch:
@@ -308,13 +324,16 @@ class InferenceEngine:
     def _held(self, params):
         """``params`` as the engine keeps them: cast once to the model's
         stated ``weight_dtype`` (a model that states none, or float32,
-        keeps the tree as handed in)."""
+        keeps the tree as handed in).  An int8 leaf stays whole — its
+        float32 scales are not weights — and the cast goes a leaf at a
+        time: the caller's tree is alive beside the copy until the caller
+        drops it, and a whole-tree temporary on top of both need not fit."""
         dtype = getattr(self.model, "weight_dtype", None)
         if dtype is None or dtype == jnp.float32:
             return params
         return jax.tree.map(
-            lambda x: x.astype(dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
+            lambda x: x.astype(dtype) if _is_float_weight(x) else x,
+            params, is_leaf=_is_quantized)
 
     def resolved_paths(self) -> dict:
         """Which implementation serves each hot op of this engine — the
@@ -325,6 +344,11 @@ class InferenceEngine:
         always dequantizes), and the attention path of every prefill
         bucket compiled so far."""
         out: dict = {"decode_attention": self.decode_impl}
+        # the dtype of the floating leaves as held (see :meth:`_held`)
+        out["weights_held"] = "/".join(sorted({
+            jnp.dtype(leaf.dtype).name for leaf in jax.tree.leaves(
+                self.params, is_leaf=_is_quantized)
+            if _is_float_weight(leaf)}))
         if self.decode_call is not None:
             out["decode_attention_call"] = self.decode_call
         if self.expert_impl is not None:
