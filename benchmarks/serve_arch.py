@@ -7,7 +7,11 @@ configuration's ``model_type``: ``benchmarks/arch/<model_type>.py`` gives
 ``build`` (model, engine and scheduler as ``tmserve`` builds them, holding
 the seed's weights), ``vocab``, ``served_gaps`` (the plain reference over
 what was served) and ``prefill_flops`` / ``decode_flops`` /
-``decode_bytes`` (the work a step needs).  A new architecture adds an
+``decode_bytes`` (the work a step needs), and optionally
+``served_record`` (a finished request -> what the program kept of how its
+tokens were produced, handed to ``served_gaps`` beside prompt and tokens:
+a model that commits tokens out of order needs the state each token was
+chosen in, which the tokens alone do not say).  A new architecture adds an
 adapter, not a driver.
 """
 
@@ -80,6 +84,15 @@ class _EngineSpans:
                    prefill_ms=self.prefill_ms, prefill_end=self.prefill_end)
         self.reset()
         return out
+
+
+def served_sample(arch, picks) -> list[tuple]:
+    """``(prompt, generated)`` of each picked request, in order, with the
+    adapter's ``served_record(request)`` as a third entry where it has one."""
+    record = getattr(arch, "served_record", None)
+    if record is None:
+        return [(list(r.prompt), list(r.generated)) for r in picks]
+    return [(list(r.prompt), list(r.generated), record(r)) for r in picks]
 
 
 def run(ctx: dict) -> dict:
@@ -215,7 +228,7 @@ def run(ctx: dict) -> dict:
     done = sorted(done_in_window, key=lambda r: (len(r.prompt) + len(r.generated), r.rid))
     picks = [done[-1]] + [done[i] for i in rng.permutation(len(done) - 1)
                           [:int(traffic["check_requests"]) - 1]]
-    sample = [(list(r.prompt), list(r.generated)) for r in picks]
+    sample = served_sample(arch, picks)
     short = [r.rid for r in done_in_window
              if len(r.generated) != r.max_new_tokens]
     del sched, engine, model, spans, by_rid, done, picks, done_in_window

@@ -1,5 +1,6 @@
 """BENCHMARK.json against the files it names and the contract's characters."""
 
+import copy
 import json
 import os
 import re
@@ -37,7 +38,7 @@ def test_every_named_file_exists(bench):
             assert d[key] == m[key], (m["name"], key)
 
 
-def test_names_units_and_lengths(bench):
+def check_names_units_and_lengths(bench: dict) -> None:
     names = []
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         for e in bench[group]:
@@ -56,10 +57,14 @@ def test_names_units_and_lengths(bench):
         assert NAME.match(w["traffic"]) and NAME.match(w["config"])
     pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(pairs) == len(set(pairs))  # a configuration under a mix, once
+
+
+def test_names_units_and_lengths(bench):
+    check_names_units_and_lengths(bench)
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
 
 
-def test_metrics_cover_every_cell(bench):
+def check_metrics_cover_every_cell(bench: dict) -> None:
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert e2e["setup_s"]["bound"] <= 0.1
     for m in bench["end_to_end"]:
@@ -77,6 +82,42 @@ def test_metrics_cover_every_cell(bench):
     four = [w for w in bench["workloads"] if w["chips"] == 4]
     assert len(four) <= max(1, len(cells) // 4)
     assert all(w["chips"] in (1, 4) for w in bench["workloads"])
+
+
+def test_metrics_cover_every_cell(bench):
+    check_metrics_cover_every_cell(bench)
+
+
+def appended(bench: dict) -> dict:
+    """``bench`` with what a ``model_config`` PR adds at the ends of its
+    lists: a configuration, a one-chip cell on it that the serving rate
+    lists, and a per-layer metric declared for that cell alone."""
+    out = copy.deepcopy(bench)
+    out["configs"].append(dict(out["configs"][-1], name="room-test-model",
+                               file="benchmarks/configs/room-test-model.json"))
+    out["workloads"].append({"name": "roomtest.serve.backlog",
+                             "config": "room-test-model",
+                             "traffic": "room_test.backlog", "chips": 1,
+                             "why": "a cell appended after every other"})
+    rate = next(m for m in out["end_to_end"] if m["name"] == "serve_tokens_per_s")
+    rate["workloads"].append("roomtest.serve.backlog")
+    out["per_layer"].append(dict(out["per_layer"][-1], name="sched.room_test",
+                                 workloads=["roomtest.serve.backlog"]))
+    return out
+
+
+def test_the_benchmark_takes_a_configuration_its_cell_and_metric_by_addition():
+    """Every check of ``BENCHMARK.json``'s lists holds over a copy with
+    entries appended: a check that pins an entry to a position fails here
+    first, before a ``model_config`` PR's appended cell meets it."""
+    from test_bench_laguna import check_this_cells_entries
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = appended(json.load(f))
+    assert bench["workloads"][-1]["name"] == "roomtest.serve.backlog"
+    check_names_units_and_lengths(bench)
+    check_metrics_cover_every_cell(bench)
+    check_this_cells_entries(bench)
 
 
 def test_configs_state_published_sizes_and_refuse_unlisted_changes(bench):

@@ -243,17 +243,50 @@ def test_the_window_keys_share_reads_the_decode_spans_two_tags(monkeypatch):
     assert span_tags.read(run, **decl["args"]) is None
 
 
-def test_every_new_metric_is_declared_for_this_cell_alone():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+def test_the_paged_kernel_share_reads_the_decode_spans_two_tags(monkeypatch):
+    from benchmarks.common import load_json
+    from theanompi_tpu.telemetry import spans
+
+    decl = load_json("metrics", "kv.paged_kernel_share.json")
+    assert decl["reader"] == "span_tags"
+    assert decl["workloads"] == ["nemotron3s.serve.backlog", WORKLOAD]
+    records = [_span("serve.step", 1, None, 0.0),
+               _span("serve.decode", 2, 1, 0.1, batch=2, paged_layers=2,
+                     paged_kernel_layers=2),
+               _span("serve.step", 3, None, 2.0),
+               _span("serve.decode", 4, 3, 2.1, batch=2, paged_layers=2,
+                     paged_kernel_layers=2)]
+    monkeypatch.setattr(spans, "snapshot", lambda: records)
+    monkeypatch.setattr(spans, "dropped", lambda: 0)
+    run = {"counters": {"steps": 2}}
+    assert span_tags.read(run, **decl["args"]) == 1.0
+    for r in records[1::2]:  # the engine's gate sent the pool to the gather
+        r.tags["paged_kernel_layers"] = 0
+    assert span_tags.read(run, **decl["args"]) == 0.0
+    for r in records:  # a program that tags neither reads nothing
+        r.tags.pop("paged_layers", None)
+        r.tags.pop("paged_kernel_layers", None)
+    assert span_tags.read(run, **decl["args"]) is None
+
+
+def check_this_cells_entries(bench: dict) -> None:
+    """What this cell brought to ``BENCHMARK.json``, found by name and
+    membership, never by position, so entries appended after it leave it
+    whole: its 14 ``_swa`` metrics declared for it alone, the cell once on
+    one chip over its configuration, and the rate it reports listing it."""
     mine = [m for m in bench["per_layer"] if m["name"].endswith("_swa")]
     assert len(mine) == 14 and all(m["workloads"] == [WORKLOAD] for m in mine)
-    assert bench["per_layer"][-14:] == mine       # appended, nothing moved
-    assert bench["workloads"][-1]["name"] == WORKLOAD
-    assert bench["workloads"][-1]["chips"] == 1
-    assert bench["configs"][-1]["name"] == "laguna-xs2-pp8"
+    cells = [w for w in bench["workloads"] if w["name"] == WORKLOAD]
+    assert len(cells) == 1 and cells[0]["chips"] == 1
+    assert cells[0]["config"] == "laguna-xs2-pp8"
+    assert [c["name"] for c in bench["configs"]].count("laguna-xs2-pp8") == 1
     e2e = next(m for m in bench["end_to_end"] if m["name"] == "serve_tokens_per_s")
-    assert e2e["workloads"][-1] == WORKLOAD
+    assert WORKLOAD in e2e["workloads"]
+
+
+def test_every_new_metric_is_declared_for_this_cell_alone():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        check_this_cells_entries(json.load(f))
 
 
 def test_the_tiny_configuration_passes_the_adapters_check():
@@ -268,6 +301,9 @@ def test_the_tiny_configuration_passes_the_adapters_check():
 
 @pytest.fixture(scope="module")
 def sound():
+    from theanompi_tpu.telemetry import spans
+
+    spans.RING.clear()  # the tests below count this rehearsal's steps alone
     return rehearse()
 
 
@@ -291,7 +327,7 @@ def test_the_new_tags_ride_on_the_tiny_runs_decode_spans(sound):
     share = span_tags.read(run, root="serve.step", span="serve.decode",
                            num="kv_window_tokens", den="kv_full_tokens")
     assert 0.2 < share < 0.7
-    # the ring is the process's: other cells' rehearsals may lie in it too
+    # the fixture cleared the ring: these are this rehearsal's decode spans
     decodes = [r for r in spans.snapshot()
                if r.name == "serve.decode" and "kv_full_tokens" in r.tags]
     assert decodes and all(r.tags["kv_full_tokens"] == r.tags["kv_tokens"] for r in decodes)
