@@ -630,8 +630,60 @@ def test_the_hybrids_served_widths_decode_through_the_grouped_kernel(
     _no_gathered_context(text, pool.shape, b, table_tokens=4096)
 
 
+@pytest.mark.parametrize("program", ["block", "prefill4096"])
+def test_the_block_diffusion_stage_compiles_for_a_v5e_and_fits(
+        one_chip, monkeypatch, program):
+    """``benchmarks/configs/sdar-30b-a3b-pp8.json`` as it is served (six
+    layers of 128 experts, 64 slots, contexts to 4096): the pass program
+    holds the twelve grouped products and six ``paged_decode_grouped``
+    calls — a slot's 4 x 32 queries as the query heads of one slot — and
+    gathers no context; the 4096 bucket's block prefill runs the flash
+    kernel with its block-causal mask (the last layer's attention and
+    experts feed nothing and are gone); arguments and temporaries leave
+    the chip room."""
+    from benchmarks.arch import sdar_moe
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "sdar-30b-a3b-pp8.json")) as f:
+        cfg = json.load(f)
+    run = cfg["run"]
+    b = run["max_batch"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    eng, shape = _described_engine(HybridLM(sdar_moe.model_config(cfg)),
+                                   one_chip, max_batch=b)
+    assert (eng.decode_impl, eng.decode_call, eng.expert_impl) == (
+        "kernel", "paged_decode_grouped", "kernel")
+    pool = shape((6, run["num_blocks"], 16, 4, 128), jnp.bfloat16)
+    if program == "block":
+        fn, args = eng._block_impl, (
+            shape((b, eng.max_blocks_per_seq)), shape((b,)), shape((b, 6)),
+            shape((b,), jnp.float32), shape((b,)), shape((2,), jnp.uint32),
+            {}, shape((b, 14)))
+    else:
+        fn, args = eng._prefill_blocks_impl, (shape((256,)), shape((4096,)))
+    compiled = _compiled(jax.jit(fn, donate_argnums=(1, 2)),
+                         eng.params, pool, pool, *args)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2
+    # 8.72 GB of weights + the pool; a prefill leaves out the last layer's
+    # experts (0.94 GB), which feed nothing
+    assert {"block": 11.9e9, "prefill4096": 10.0e9}[program] \
+        < mem.argument_size_in_bytes < {"block": 12.0e9,
+                                        "prefill4096": 10.2e9}[program]
+    assert mem.temp_size_in_bytes < {"block": 0.3e9,
+                                     "prefill4096": 2.0e9}[program], mem
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    grouped = [line for line in calls if "paged_decode_grouped" in line]
+    assert (len(calls), len(grouped)) == {"block": (18, 6),
+                                          "prefill4096": (15, 0)}[program]
+    if program == "block":
+        _no_gathered_context(text, pool.shape, b, table_tokens=4096)
+
+
 @pytest.mark.parametrize("heads,kv_heads,dh,bs,dtype", [
     (48, 8, 128, 16, jnp.bfloat16), (32, 2, 128, 16, jnp.bfloat16),
+    (4 * 32, 4, 128, 16, jnp.bfloat16),
     (48, 8, 128, 16, jnp.float32), (16, 4, 128, 16, jnp.bfloat16),
     (8, 2, 256, 16, jnp.bfloat16), (8, 1, 128, 8, jnp.float32)])
 def test_the_grouped_kernels_gate_is_what_compiles_for_a_v5e(

@@ -19,6 +19,8 @@ HYBRID = ("embed", "mamba", "moe.route", "moe.experts", "moe.shared", "attn",
 LOOPED = ("embed", "attn", "mlp", "loop.exit", "head", "sample")
 WINDOWED = ("embed", "attn", "attn.window", "mlp", "moe.route", "moe.experts",
             "moe.shared", "head", "sample")
+BLOCKDIFF = ("embed", "attn", "moe.route", "moe.experts", "head",
+             "diffusion.select")
 #: program -> the scopes it must show
 EXPECTED = {
     "train": (*MODEL, "loss", "clip", "exchange", "optimizer"),
@@ -40,6 +42,10 @@ EXPECTED = {
     # window and full attention layers in one model (ISSUE 33)
     "window_decode": WINDOWED,
     "window_prefill": WINDOWED,
+    # block diffusion: a pass's selection in its own scope, a prefill that
+    # reads out nothing
+    "blockdiff_decode": BLOCKDIFF,
+    "blockdiff_prefill": ("embed", "attn", "moe.route", "moe.experts"),
 }
 SCOPES = sorted({s for names in EXPECTED.values() for s in names}
                 | {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
@@ -193,6 +199,23 @@ def lowered():
         jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
         eng._base_key, eng._state,
         jnp.asarray(1, i32)).as_text(debug_info=True)
+    block = HybridLM({"pattern": "*E*", "dim": 32, "vocab": 61, "seq_len": 32,
+                      "heads": 4, "kv_heads": 2, "head_dim": 8,
+                      "rope_theta": 1e6, "qk_norm": True, "n_experts": 8,
+                      "top_k": 2, "latent": None, "expert_dim": 16,
+                      "shared_dim": 0, "expert_act": "silu_gated",
+                      "router": "softmax", "block_len": 4, "mask_id": 60})
+    eng = InferenceEngine(block, block.init_params(jax.random.PRNGKey(0))[0],
+                          block_size=8, max_batch=2)
+    out["blockdiff_decode"] = eng._decode_fn.lower(
+        eng.params, eng._k, eng._v,
+        jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+        jnp.zeros((b, 6), i32), jnp.zeros((b,), jnp.float32),
+        jnp.zeros((b,), i32), eng._base_key).as_text(debug_info=True)
+    out["blockdiff_prefill"] = jax.jit(
+        eng._prefill_blocks_impl, donate_argnums=(1, 2)).lower(
+        eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
+        jnp.zeros((16,), i32)).as_text(debug_info=True)
     return out
 
 
@@ -248,6 +271,7 @@ def test_the_jitted_programs_keep_their_names(lowered):
     assert "module @jit__decode_impl" in lowered["decode"]
     assert "module @jit__decode_impl" in lowered["hybrid_decode"]
     assert "module @jit__decode_impl" in lowered["looped_decode"]
+    assert "module @jit__block_impl" in lowered["blockdiff_decode"]
 
 
 def test_the_documented_scopes_are_the_ones_the_programs_show(lowered):
@@ -256,4 +280,4 @@ def test_the_documented_scopes_are_the_ones_the_programs_show(lowered):
     documented = {s for names in DEVICE_SCOPES.values() for s in names}
     assert set(SCOPES) <= documented
     assert set(DEVICE_SCOPES["HybridLM"]) == (
-        set(HYBRID) | set(LOOPED) | set(WINDOWED)) - {"sample"}
+        set(HYBRID) | set(LOOPED) | set(WINDOWED) | set(BLOCKDIFF)) - {"sample"}

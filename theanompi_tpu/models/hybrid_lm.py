@@ -29,6 +29,11 @@ layer's letter in ``pattern`` (the Nemotron-H family's
 - ``-``: a bias-free gated feed-forward of ``ffn_dim``
   (:class:`theanompi_tpu.ops.layers.GatedFFN`).
 
+``qk_norm`` gives the ``*`` and ``w`` layers a per-head RMSNorm on queries
+and keys before rotary (the Qwen3 block's); ``router`` ``"softmax"`` and
+``shared_dim`` 0 give the ``E`` layers a softmax router with renormalised
+top-k weights and no shared expert (Qwen3-MoE's).
+
 then a final RMSNorm and an untied head; no bias except the convolution's
 and the exit gate's.  ``vocab`` is the number of vocabulary rows held
 (embedding and head alike).  ``post_norm`` gives every layer a second scale
@@ -50,6 +55,20 @@ x head_dim x itemsize`` bytes of cache a token, which is what
 ``--num-blocks`` x ``--block-size`` tokens must be sized by.  A pattern with
 ``M`` or ``w`` refuses ``loops`` > 1 (their slot-owned pools have one entry
 a layer).
+
+**Block diffusion** (``block_len`` B; patterns of ``*``, ``E`` and ``-``
+only, no loop): positions are cut into blocks of B aligned from position 0,
+and a block is generated by passes over all of it at once —
+:meth:`apply_block` runs each slot's B positions ``[L, L + B)`` (``L`` the
+tokens cached, a multiple of B) against the cached K/V of ``[0, L)`` and
+the block's own, bidirectional inside the block, and writes the block's
+K/V; :meth:`commit_block` then fixes the ``ceil(B / BLOCK_PASSES)``
+masked positions (``mask_id``) whose greedy token is the most probable,
+ties to the lower position.  ``BLOCK_PASSES`` passes fix a block whose B
+positions start masked; one more pass over its final tokens writes the K/V
+the next block reads.  Position ``i``'s logits predict position ``i``'s token (no
+shift).  A prefill is block-causal too (position ``i`` sees ``j < (i // B
++ 1) B``) and covers whole blocks only (:meth:`apply_prefill_blocks`).
 
 **Serving only.**  The model exposes what
 :class:`theanompi_tpu.serving.engine.InferenceEngine` calls —
@@ -98,6 +117,10 @@ from theanompi_tpu.ops.moe import DroplessMoE
 _KINDS = {"M": "mamba", "E": "moe", "*": "attn", "w": "attn_w", "-": "mlp"}
 #: the device scope of each attention kind
 _ATTN_SCOPES = {"attn": "attn", "attn_w": "attn.window"}
+#: block diffusion's denoising passes a block, the static schedule: half the
+#: passes of committing one position a pass, the parallel decoding a block
+#: diffusion model is served for
+BLOCK_PASSES = 2
 
 
 def _row_major(cache):
@@ -154,6 +177,13 @@ class HybridLM(Model):
         "shared_dim": 256,
         "route_scale": 1.0,
         "expert_act": "relu2",
+        "router": "sigmoid",
+        # ``*`` and ``w``: a per-head RMSNorm on q and k before rotary
+        "qk_norm": False,
+        # block diffusion (None: one token a slot a step): positions a
+        # block, the mask token's id
+        "block_len": None,
+        "mask_id": None,
         # the spine: a second norm a layer, after the mixer; the pattern
         # applied ``loops`` times (``exit_threshold`` is read when > 1)
         "post_norm": False,
@@ -180,6 +210,17 @@ class HybridLM(Model):
                 f"looped stack needs loops >= 1 and no M or w layer (a "
                 f"slot-owned pool holds one entry a layer, not one a loop "
                 f"step)")
+        #: block diffusion's positions a pass (None: one token a step)
+        self.block_len = cfg["block_len"]
+        if self.block_len is not None:
+            if (set(cfg["pattern"]) - set("*E-") or cfg["loops"] != 1
+                    or not 0 <= int(cfg["mask_id"]) < cfg["vocab"]):
+                raise ValueError(
+                    f"block_len={self.block_len} with pattern "
+                    f"{cfg['pattern']!r}, loops={cfg['loops']}, mask_id="
+                    f"{cfg['mask_id']}: block diffusion takes *, E and - "
+                    f"layers, no loop and a mask token inside the "
+                    f"vocabulary")
         #: the dtype a serving engine holds the weights in
         self.weight_dtype = (jnp.bfloat16 if cfg["weights"] == "bf16"
                              else jnp.float32)
@@ -194,19 +235,22 @@ class HybridLM(Model):
                                cfg["latent"], cfg["expert_dim"],
                                cfg["shared_dim"], float(cfg["route_scale"]),
                                tuple(held) if held is not None else None,
-                               activation=cfg["expert_act"]),
+                               activation=cfg["expert_act"],
+                               router=cfg["router"]),
             "attn": GroupedQueryAttention(
                 cfg["dim"], cfg["heads"], cfg["kv_heads"], cfg["head_dim"],
                 cfg["attn_impl"], cfg["rope_theta"],
                 fused_qkv=cfg["loops"] == 1, rope_share=cfg["rope_share"],
-                rope_yarn=cfg["rope_yarn"], gate=cfg["attn_gate"]),
+                rope_yarn=cfg["rope_yarn"], gate=cfg["attn_gate"],
+                qk_norm=cfg["qk_norm"], norm_eps=cfg["norm_eps"]),
             "attn_w": GroupedQueryAttention(
                 cfg["dim"], cfg["window_heads"] or cfg["heads"],
                 cfg["kv_heads"], cfg["head_dim"], cfg["attn_impl"],
                 cfg["window_rope_theta"],
                 rope_share=cfg["window_rope_share"],
                 rope_yarn=cfg["window_rope_yarn"], window=int(cfg["window"]),
-                gate=cfg["attn_gate"]),
+                gate=cfg["attn_gate"], qk_norm=cfg["qk_norm"],
+                norm_eps=cfg["norm_eps"]),
             "mlp": L.GatedFFN(cfg["ffn_dim"]),
         }
         #: (param-tree name, kind) per layer, in order
@@ -365,8 +409,9 @@ class HybridLM(Model):
         return t * self.config["pattern"].count("*") + n_kv
 
     def _prefill(self, params, kv_cache, table_row, tokens, true_len, slot,
-                 head_at=None):
-        """:meth:`apply_prefill` and each position's exit step beside it."""
+                 head_at=None, head=True):
+        """:meth:`apply_prefill` and each position's exit step beside it
+        (``head=False``: no head, no logits)."""
         cp = self.precision.cast_to_compute(params)
         toks = tokens[0]
         if true_len is None:
@@ -400,8 +445,9 @@ class HybridLM(Model):
                                 if kind == "attn" else
                                 kv_cache.write_window_prefill(
                                     n_win, k, v, true_len, slot))
-                        ctx = attn.gated(p["mixer"], u[None],
-                                         attn.attend(q, k, v))
+                        ctx = attn.gated(
+                            p["mixer"], u[None],
+                            attn.attend(q, k, v, block=self.block_len))
                         y = attn.project_out(p["mixer"],
                                              ctx.reshape(u.shape[0], -1))
                     n_kv += kind == "attn"
@@ -411,6 +457,8 @@ class HybridLM(Model):
 
         x, kv_cache, _, t_star = self._stack(cp, self._embed(cp, toks),
                                              kv_cache, (), once)
+        if not head:
+            return None, kv_cache, t_star
         if head_at is not None:
             x = jax.lax.dynamic_slice_in_dim(x, head_at, 1, axis=0)
         return self._head_logits(cp, x)[None], kv_cache, t_star
@@ -445,6 +493,91 @@ class HybridLM(Model):
         if exit_steps:
             return logits, jnp.stack([r[2] for r in rows])
         return logits
+
+    def apply_prefill_blocks(self, params, state, kv_cache, table_row,
+                             tokens):
+        """A block-diffusion prompt's whole blocks: ``tokens`` ``[1,
+        P_pad]``, the first ``P`` of them real and ``P`` a whole number of
+        blocks, end-padded (block-causal attention keeps padding out of
+        every real position).  Their K/V written, nothing read out: ->
+        cache'.  The prompt's tail (``len % block_len`` tokens) is the first
+        block's committed part, for :meth:`apply_block`."""
+        del state
+        return self._prefill(params, kv_cache, table_row, tokens, None, None,
+                             head=False)[1]
+
+    def apply_block(self, params, state, kv_cache, lengths, tokens):
+        """One block-diffusion pass for each slot of the fixed batch:
+        ``tokens`` ``[B, n]`` (``n`` = ``block_len``; ``mask_id`` where a
+        position is not committed yet) at positions ``lengths[b] ..
+        lengths[b] + n - 1``, ``lengths`` ``[B]`` the tokens cached (a
+        multiple of ``n``).  Writes the block's K/V at those positions and
+        attends each query over ``[0, lengths[b] + n)``.  -> (logits ``[B,
+        n, V]`` fp32, cache', stats) — ``stats`` as :meth:`apply_decode`'s,
+        over the rows of slots at ``lengths > 0``."""
+        del state
+        cp = self.precision.cast_to_compute(params)
+        b, n = tokens.shape
+        positions = lengths[:, None] + jnp.arange(n, dtype=jnp.int32)
+        active = jnp.repeat(lengths > 0, n)
+        x = self._embed(cp, tokens.reshape(-1))
+        hits = peak = jnp.int32(0)
+        n_kv = 0
+        for name, kind in self.layers:
+            p = cp[name]
+            u = self._normed(p, x)
+            if kind == "moe":
+                y, st = self._mixers[kind].apply_tokens(p["mixer"], u,
+                                                        active=active)
+                hits = hits + st["local_hits"]
+                peak = jnp.maximum(peak, st["load_peak"])
+            elif kind == "mlp":
+                y = self._mlp(p["mixer"], u)
+            else:
+                attn = self._mixers[kind]
+                with jax.named_scope("attn"):
+                    q, k, v = attn.project_qkv(p["mixer"], u.reshape(b, n, -1),
+                                               positions)
+                    kv_cache = kv_cache.write_block(n_kv, k, v, lengths)
+                    ctx = kv_cache.attend_block(n_kv, q, lengths)
+                    ctx = attn.gated(p["mixer"], u, ctx.reshape(b * n,
+                                                                *q.shape[2:]))
+                    y = attn.project_out(p["mixer"],
+                                         ctx.reshape(b * n, -1))
+                n_kv += 1
+            x = self._residual(p, x, y)
+        logits = self._head_logits(cp, x).reshape(b, n, -1)
+        return logits, kv_cache, {"moe_local_hits": hits, "moe_load_peak": peak}
+
+    @property
+    def commits_per_pass(self) -> int:
+        """Masked positions a denoising pass commits (fewer where fewer are
+        left): ``ceil(block_len / BLOCK_PASSES)``."""
+        return -(-self.block_len // BLOCK_PASSES)
+
+    @jax.named_scope("diffusion.select")
+    def commit_block(self, logits, tokens, masked):
+        """The selection of a block-diffusion pass: of each slot's
+        ``masked`` positions (``[B, n]`` bool), the :attr:`commits_per_pass`
+        whose greedy token has the highest softmax probability (ties to the
+        lower position) take that token.  -> (tokens ``[B, n]`` with the
+        commits in, each position's state ``[B, n]`` int32: 0 still masked,
+        1 committed before this pass, 2 committed by it, and each
+        position's greedy log-probability ``[B, n]`` float32, the confidence
+        the selection ranked by)."""
+        n = tokens.shape[1]
+        best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        # the greedy token's log-probability: max - logsumexp
+        top = jnp.max(logits, axis=-1)
+        conf = -jnp.log(jnp.sum(jnp.exp(logits - top[..., None]), axis=-1))
+        rank = jnp.where(masked, conf, -jnp.inf)
+        i = jnp.arange(n)
+        ahead = (rank[:, None, :] > rank[:, :, None]) | (
+            (rank[:, None, :] == rank[:, :, None]) & (i[None, :] < i[:, None]))
+        take = masked & (jnp.sum(ahead, axis=-1) < jnp.minimum(
+            self.commits_per_pass, jnp.sum(masked, axis=-1))[:, None])
+        state = jnp.where(take, 2, jnp.where(masked, 0, 1)).astype(jnp.int32)
+        return jnp.where(take, best, tokens), state, conf
 
     def apply_decode(self, params, state, kv_cache, positions, tokens):
         """One token for each slot of the fixed batch: ``tokens`` ``[B]`` at
@@ -530,4 +663,8 @@ class HybridLM(Model):
             paths["window_attention"] = (
                 f"slot ring of {int(self.config['window'])} tokens, masked "
                 f"grouped softmax")
+        if self.block_len is not None:
+            paths["block_diffusion"] = (
+                f"blocks of {self.block_len}, {BLOCK_PASSES} "
+                f"denoising passes + 1 that writes the block")
         return paths

@@ -142,23 +142,29 @@ class MultiHeadAttention(L.Layer):
         v = v.reshape(b, t, h_local, head_dim)
         return q, k, v
 
-    def attend(self, q, k, v, window: int | None = None):
+    def attend(self, q, k, v, window: int | None = None,
+               block: int | None = None):
         """The attention core over ``[B, T, H, Dh]``: ring under a sharded
         seq axis, else the resolved pallas/blockwise path.  The serving
         prefill reuses exactly this dispatch (so a TPU prefill rides the
         flash kernels whenever the shape gate admits them).  ``window``
         (causal, forward only, never the ring): query ``i`` sees keys
-        ``i - window < j <= i``."""
+        ``i - window < j <= i``.  ``block`` (the same terms): block-causal,
+        query ``i`` sees keys ``j < (i // block + 1) * block``."""
         t, head_dim = q.shape[1], q.shape[3]
         if axis_bound(SEQ_AXIS) and jax.lax.axis_size(SEQ_AXIS) > 1:
-            if window is not None:
-                raise NotImplementedError("a window over a sharded seq axis")
+            if window is not None or block is not None:
+                raise NotImplementedError(
+                    "a window or a block mask over a sharded seq axis")
             return ring_attention(q, k, v, causal=self.causal)
         from theanompi_tpu.ops.pallas_attention import flash_attention
 
+        masks = {} if block is None else {"block": block}
         if resolve_attn_impl(self.impl, t, head_dim) == "pallas":
-            return flash_attention(q, k, v, causal=self.causal, window=window)
-        return blockwise_attention(q, k, v, causal=self.causal, window=window)
+            return flash_attention(q, k, v, causal=self.causal, window=window,
+                                   **masks)
+        return blockwise_attention(q, k, v, causal=self.causal, window=window,
+                                   **masks)
 
     def project_out(self, params, out):
         """Output projection over the flattened head dim ``[B, T, h*Dh]``."""
@@ -250,7 +256,10 @@ class GroupedQueryAttention(L.Layer):
     position ``i`` attends ``j`` with ``0 <= i - j < window`` (None: every
     ``j <= i``).  ``gate``: a sigmoid gate a head, ``sigmoid(x W_g)`` read
     from the layer's input, on the context before the output projection
-    (:meth:`gated`).  A cache holds the ``kv_heads`` only; :meth:`attend`
+    (:meth:`gated`).  ``qk_norm``: each query and key head RMS-normed over
+    its ``head_dim`` at ``norm_eps`` with a learned scale of its own (one
+    for the queries, one for the keys), before rotary (the Qwen3 block's
+    norm).  A cache holds the ``kv_heads`` only; :meth:`attend`
     repeats them to the query heads and takes
     :meth:`MultiHeadAttention.attend`'s dispatch, so a TPU prefill rides
     the flash kernel where its gate admits the shape."""
@@ -273,6 +282,8 @@ class GroupedQueryAttention(L.Layer):
     rope_yarn: dict | None = None
     window: int | None = None
     gate: bool = False
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     def init(self, key, in_shape):
         if in_shape[-1] != self.dim:
@@ -290,6 +301,9 @@ class GroupedQueryAttention(L.Layer):
         if self.gate:
             params["gate"] = {"w": w02(jax.random.fold_in(key, 4),
                                        (self.dim, self.heads))}
+        if self.qk_norm:
+            for name in ("q_norm", "k_norm"):
+                params[name] = L.RMSNorm().init(None, (self.head_dim,))[0]
         return params, {}, tuple(in_shape)
 
     def project_qkv(self, params, x, positions=None):
@@ -308,17 +322,25 @@ class GroupedQueryAttention(L.Layer):
             q, k, v = (y.reshape(b, t, -1, self.head_dim)
                        for y in jax.lax.optimization_barrier(tuple(
                            x @ params[n]["w"].astype(x.dtype) for n in "qkv")))
+        if self.qk_norm:
+            norm = L.RMSNorm(eps=self.norm_eps)
+            q = norm.apply(params["q_norm"], {}, q)[0]
+            k = norm.apply(params["k_norm"], {}, k)[0]
         if self.rope_theta is not None:
             q, k = rotary(q, k, positions, float(self.rope_theta),
                           self.rope_share, self.rope_yarn)
         return q, k, v
 
-    def attend(self, q, k, v):
+    def attend(self, q, k, v, block: int | None = None):
+        """Causal (or banded) attention over a whole sequence; ``block``:
+        block-causal instead, every key of the query's own block of
+        ``block`` positions and of every earlier block."""
         rep = self.heads // self.kv_heads
         core = MultiHeadAttention(self.heads * self.head_dim, self.heads,
                                   impl=self.impl)
         return core.attend(q, jnp.repeat(k, rep, axis=2),
-                           jnp.repeat(v, rep, axis=2), window=self.window)
+                           jnp.repeat(v, rep, axis=2), window=self.window,
+                           block=block)
 
     def gated(self, params, x, ctx):
         """``ctx`` ``[..., H, Dh]`` with head ``h`` multiplied by ``sigmoid(x

@@ -220,6 +220,12 @@ class DroplessMoE(L.Layer):
         r = sum_i w_i W2_i act(W1_i l)         latent -> expert_dim -> latent
         out = W_up r + V2 act(V1 u)            the shared expert sees every token
 
+    ``router="softmax"``: ``s = softmax(W_r u)`` in float32 over all
+    ``n_experts``, the ``top_k`` largest selected with no correction bias
+    (no ``b_corr`` leaf) and their ``s`` renormalised to sum 1 (times
+    ``route_scale``) — the Qwen3-MoE router with ``norm_topk_prob``.
+    ``shared_dim=0``: no shared expert, no ``shared`` leaves, no product.
+
     ``latent=None``: no ``W_down`` / ``W_up``, the experts multiply at model
     width (``l = u``, ``out = r + ...``).  ``activation``, for the experts
     and the shared expert alike: ``"relu2"`` (``relu(h)^2``) or
@@ -265,11 +271,15 @@ class DroplessMoE(L.Layer):
     experts_held: tuple[int, int] | None = None
     products: str = "ragged_dot"
     activation: str = "relu2"
+    router: str = "sigmoid"
 
     def __post_init__(self):
         if self.activation not in ("relu2", "silu_gated"):
             raise ValueError(f"DroplessMoE activation={self.activation!r} "
                              f"not in ('relu2', 'silu_gated')")
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"DroplessMoE router={self.router!r} not in "
+                             f"('sigmoid', 'softmax')")
 
     @property
     def width(self) -> int:
@@ -305,13 +315,17 @@ class DroplessMoE(L.Layer):
         (k1, n1), (k2, n2) = self.product_shapes
         fan = n1 // self.expert_dim  # 2 where gate and up share a product
         params = {
-            "router": {"w": w02(ks[0], (self.dim, self.n_experts)),
-                       "b_corr": jnp.zeros((self.n_experts,), jnp.float32)},
+            "router": {"w": w02(ks[0], (self.dim, self.n_experts))},
             "w1": w02(ks[2], (hi - lo, k1, n1)),
             "w2": w02(ks[3], (hi - lo, k2, n2)),
-            "shared": {"v1": w02(ks[5], (self.dim, fan * self.shared_dim)),
-                       "v2": w02(ks[6], (self.shared_dim, self.dim))},
         }
+        if self.router == "sigmoid":
+            params["router"]["b_corr"] = jnp.zeros((self.n_experts,),
+                                                   jnp.float32)
+        if self.shared_dim:
+            params["shared"] = {
+                "v1": w02(ks[5], (self.dim, fan * self.shared_dim)),
+                "v2": w02(ks[6], (self.shared_dim, self.dim))}
         if self.latent is not None:
             params["down"] = {"w": w02(ks[1], (self.dim, self.latent))}
             params["up"] = {"w": w02(ks[4], (self.latent, self.dim))}
@@ -321,10 +335,16 @@ class DroplessMoE(L.Layer):
         """-> (selected experts ``[N, top_k]`` int32, their weights
         ``[N, top_k]`` float32)."""
         with jax.named_scope("moe.route"):
-            s = jax.nn.sigmoid(u.astype(jnp.float32)
-                               @ params["router"]["w"].astype(jnp.float32))
-            _, idx = lax.top_k(
-                s + params["router"]["b_corr"].astype(jnp.float32), self.top_k)
+            logits = (u.astype(jnp.float32)
+                      @ params["router"]["w"].astype(jnp.float32))
+            if self.router == "softmax":
+                s = jax.nn.softmax(logits, axis=-1)
+                _, idx = lax.top_k(s, self.top_k)
+            else:
+                s = jax.nn.sigmoid(logits)
+                _, idx = lax.top_k(
+                    s + params["router"]["b_corr"].astype(jnp.float32),
+                    self.top_k)
             w = jnp.take_along_axis(s, idx, axis=-1)
             w = self.route_scale * w / jnp.sum(w, axis=-1, keepdims=True)
             return idx.astype(jnp.int32), w
@@ -390,7 +410,7 @@ class DroplessMoE(L.Layer):
                     "load_peak": jnp.max(jnp.bincount(
                         jnp.where(counted, local, e_held).reshape(-1),
                         length=e_held + 1)[:e_held]).astype(jnp.int32)}
-        if shared:
+        if shared and self.shared_dim:
             with jax.named_scope("moe.shared"):
                 hs = self._act(u @ params["shared"]["v1"].astype(u.dtype))
                 out = out + hs @ params["shared"]["v2"].astype(u.dtype)

@@ -53,11 +53,15 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _tile_needed(qi, ki, block_q, block_k, causal, window=None):
+def _tile_needed(qi, ki, block_q, block_k, causal, window=None, block=None):
     """Whether tile (qi, ki) has any visible keys (causal skip predicate;
-    with a ``window`` also: its last key is not behind the first query's)."""
+    with a ``window`` also: its last key is not behind the first query's;
+    with a ``block``: its first key is in the last query's block or an
+    earlier one)."""
     if not causal:
         return True
+    if block is not None:
+        return (qi * block_q + block_q - 1) // block >= (ki * block_k) // block
     needed = qi * block_q + block_q - 1 >= ki * block_k
     if window is not None:
         needed = jnp.logical_and(
@@ -65,8 +69,12 @@ def _tile_needed(qi, ki, block_q, block_k, causal, window=None):
     return needed
 
 
-def _last_needed_k(qi, block_q, block_k):
-    """Last k-tile index with visible keys for q-tile ``qi`` (causal)."""
+def _last_needed_k(qi, block_q, block_k, block=None):
+    """Last k-tile index with visible keys for q-tile ``qi`` (causal; with
+    a ``block``: the last key of the last query's block)."""
+    if block is not None:
+        return (((qi * block_q + block_q - 1) // block + 1) * block - 1) \
+            // block_k
     return (qi * block_q + block_q - 1) // block_k
 
 
@@ -90,11 +98,13 @@ def _first_needed_q(ki, block_q, block_k):
 # via ``_tile_needed``, so numerics are untouched.
 
 
-def _causal_tile_mask(qi, ki, block_q, block_k, window=None):
+def _causal_tile_mask(qi, ki, block_q, block_k, window=None, block=None):
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
+    if block is not None:  # block-causal: ``j < (i // block + 1) * block``
+        return q_pos // block >= k_pos // block
     if window is None:
         return q_pos >= k_pos
     return jnp.logical_and(q_pos >= k_pos, q_pos - k_pos < window)
@@ -110,9 +120,12 @@ def _dot(a, b, dims):
 # ---------------------------------------------------------------------------
 
 
-def _tile_full(qi, ki, block_q, block_k, window=None):
+def _tile_full(qi, ki, block_q, block_k, window=None, block=None):
     """Tile entirely below the diagonal (and, with a ``window``, entirely
-    inside the last query's): every key visible, no mask ops."""
+    inside the last query's; with a ``block``, its last key in the first
+    query's block or an earlier one): every key visible, no mask ops."""
+    if block is not None:
+        return (qi * block_q) // block >= (ki * block_k + block_k - 1) // block
     full = qi * block_q >= ki * block_k + block_k - 1
     if window is not None:
         full = jnp.logical_and(
@@ -120,7 +133,8 @@ def _tile_full(qi, ki, block_q, block_k, window=None):
     return full
 
 
-def _when_causal_tiles(causal, qi, ki, block_q, block_k, body, window=None):
+def _when_causal_tiles(causal, qi, ki, block_q, block_k, body, window=None,
+                       block=None):
     """Run ``body(masked: bool)`` per tile, splitting full from diagonal.
 
     Only diagonal-straddling tiles pay the mask's VPU cost (2 iotas +
@@ -133,14 +147,15 @@ def _when_causal_tiles(causal, qi, ki, block_q, block_k, body, window=None):
     if not causal:
         body(False)
         return
-    needed = _tile_needed(qi, ki, block_q, block_k, True, window)
-    full = _tile_full(qi, ki, block_q, block_k, window)
+    needed = _tile_needed(qi, ki, block_q, block_k, True, window, block)
+    full = _tile_full(qi, ki, block_q, block_k, window, block)
     pl.when(jnp.logical_and(needed, full))(lambda: body(False))
     pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(lambda: body(True))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
-                *, scale, causal, block_q, block_k, d, window=None):
+                *, scale, causal, block_q, block_k, d, window=None,
+                block=None):
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -165,7 +180,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
         vb = v_ref[0, 0]
         s = _dot(q, kb, ((1,), (1,)))
         if masked:
-            mask = _causal_tile_mask(qi, ki, block_q, block_k, window)
+            mask = _causal_tile_mask(qi, ki, block_q, block_k, window, block)
             s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -181,7 +196,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
         acc_scr[:, :] = (acc_scr[:, :] * corr[:, None]
                          + _dot(p.astype(vb.dtype), vcat, ((1,), (0,))))
 
-    _when_causal_tiles(causal, qi, ki, block_q, block_k, body, window)
+    _when_causal_tiles(causal, qi, ki, block_q, block_k, body, window, block)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -192,10 +207,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
 
 
 @jax.named_scope("flash_fwd")  # names the custom call in a device trace
-def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret, window=None):
+def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret, window=None,
+              block=None):
     """q/k/v: [B, H, T, D] -> (out [B,H,T,D], lse [B,H,nq,8,block_q]).
     ``window``: the causal band ``i - window < j <= i``; tiles wholly behind
     it are skipped like tiles above the diagonal, compute and DMA alike.
+    ``block``: block-causal, ``j < (i // block + 1) * block`` (bidirectional
+    inside a block of ``block`` positions aligned from 0); tiles wholly
+    above the block diagonal are skipped the same way.
 
     lse rows are broadcast across the 8 sublanes: Mosaic rejects output
     blocks thinner than an (8, 128) tile, so the per-row vector rides in a
@@ -206,14 +225,14 @@ def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret, window=None):
     nq, nk = t // block_q, t // block_k
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, d=d,
-                               window=window)
+                               window=window, block=block)
     # accumulator width: d data columns + a lane-aligned block whose first
     # column carries the softmax normalizer (see kernel comment)
     acc_cols = d + (128 - d % 128 if d % 128 else 128)
 
     def kv_map(bi, hi, qi, ki):
         if causal:  # masked tiles re-reference the diagonal tile: DMA elided
-            ki = jnp.minimum(ki, _last_needed_k(qi, block_q, block_k))
+            ki = jnp.minimum(ki, _last_needed_k(qi, block_q, block_k, block))
         if window is not None:  # and the window's first tile, from below
             ki = jnp.maximum(ki, _first_needed_k(qi, block_q, block_k, window))
         return (bi, hi, ki, 0)
@@ -437,10 +456,13 @@ def flash_attention_supported(t: int, d: int, block_q: int = 512,
 
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
                     block_k: int = 1024, interpret: bool | None = None,
-                    window: int | None = None):
+                    window: int | None = None, block: int | None = None):
     """Flash attention over ``[B, T, H, D]`` (the stack's layout).
     ``window`` (causal only, forward only: no gradient is defined through
-    it): query ``i`` sees keys ``i - window < j <= i``.
+    it): query ``i`` sees keys ``i - window < j <= i``.  ``block`` (the
+    same terms): block-causal, query ``i`` sees keys ``j < (i // block +
+    1) * block`` — every key of its own block of ``block`` positions
+    (aligned from position 0) and of every earlier block.
 
     ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere
     (so the same code path is unit-testable on the CPU mesh).  In
@@ -466,12 +488,15 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
         )
     # [B,T,H,D] -> [B,H,T,D] for head-major tiling
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    if window is None:
+    if window is None and block is None:
         out = _flash(qt, kt, vt, causal, block_q, block_k, interpret)
     else:
-        if not causal or window < 1:
-            raise ValueError(f"flash_attention: window={window} needs causal "
-                             f"attention and at least the query itself")
+        if not causal or (window is not None and window < 1) or (
+                block is not None and (block < 1 or window is not None)):
+            raise ValueError(f"flash_attention: window={window} / block="
+                             f"{block} needs causal attention, at least the "
+                             f"query itself, and one of the two")
         out, _ = _fwd_call(qt, kt, vt, causal=True, block_q=block_q,
-                           block_k=block_k, interpret=interpret, window=window)
+                           block_k=block_k, interpret=interpret, window=window,
+                           block=block)
     return out.transpose(0, 2, 1, 3)

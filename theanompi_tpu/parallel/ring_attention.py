@@ -61,14 +61,19 @@ def _block_attend(q, k, v, m_prev, l_prev, acc, mask=None):
 
 
 def blockwise_attention(q, k, v, causal: bool = False, block_size: int | None = None,
-                        window: int | None = None):
+                        window: int | None = None, block: int | None = None):
     """Single-device flash-style attention (the ring's n=1 case / reference
     implementation for tests).  [B, T, H, D] layout.  ``window`` (with
     ``causal``): query ``i`` sees keys ``i - window < j <= i``, a band (the queries
     are not tiled here, so no key block lies behind all of them: the band
-    is a mask and skips nothing; the flash kernel skips its tiles)."""
-    if window is not None and not causal:
-        raise ValueError("a window is causal")
+    is a mask and skips nothing; the flash kernel skips its tiles).
+    ``block`` (with ``causal``): block-causal, query ``i`` sees keys ``j <
+    (i // block + 1) * block``, bidirectional inside its block (a mask
+    here too)."""
+    if (window is not None or block is not None) and not causal:
+        raise ValueError("a window or a block mask is causal")
+    if window is not None and block is not None:
+        raise ValueError("a window or a block mask, not both")
     b, t, h, d = q.shape
     if block_size is None or block_size >= k.shape[1]:
         blocks = [(0, k.shape[1])]
@@ -86,7 +91,10 @@ def blockwise_attention(q, k, v, causal: bool = False, block_size: int | None = 
         kb = k[:, start:stop].astype(jnp.float32)
         vb = v[:, start:stop]
         mask = None
-        if causal:
+        if causal and block is not None:
+            mask = (q_pos[:, None] // block
+                    >= jnp.arange(start, stop)[None, :] // block)[None, None]
+        elif causal:
             mask = q_pos[:, None] >= jnp.arange(start, stop)[None, :]
             if window is not None:
                 mask &= (q_pos[:, None] - jnp.arange(start, stop)[None, :]

@@ -39,6 +39,26 @@ own ``embed`` / ``block`` / ``attn`` / ``mlp`` / ``head`` (``HybridLM``:
 ``mamba`` / ``moe.route`` / ``moe.experts`` / ``moe.shared`` /
 ``loop.exit``).
 
+**Block diffusion** (a model with a ``block_len``, ``HybridLM``'s): the one
+decode program is a block pass (``_block_impl``) — every slot's block of B
+positions through the model at once, the pass's commits chosen in the
+program (:meth:`HybridLM.commit_block` under the ``diffusion.select``
+scope) — and a prefill writes the prompt's whole blocks and samples
+nothing.  ``decode`` keeps its arguments: ``lengths`` the tokens cached a
+slot (the block's first position), ``tokens`` ``[max_batch, 2 + B]`` a
+row a slot — the pass's index in its block, the positions still masked
+at it (both the scheduler's count), then the block's tokens with
+``mask_id`` where a position is masked, or ``-1`` to take the block as the
+previous pass left it on the device.  What comes back is a row a slot,
+``[L, pass, B tokens, B states, B confidences]`` (states 0 masked, 1
+committed before the pass, 2 committed by it; a confidence is the greedy
+token's log-probability, the float32's bits).  The schedule and both
+layouts live here (``block_row``, ``advance_block_row``, ``block_result``),
+and ``serve.decode`` carries ``committed`` (counted on
+the device), ``store_slots`` (slots whose pass only writes a finished
+block's K/V) and ``masked_rows`` beside ``kv_tokens``, the keys the pass
+reads (``L + B`` a slot).
+
 The cache is whatever the model's ``cache_spec()`` asks for: paged K/V
 pools over the layers that attend (an entry per loop step and layer for a
 stack that is applied several times: the pools ride through the
@@ -188,8 +208,21 @@ class InferenceEngine:
         # two kernels, each with a shape gate of its own: one K/V head a
         # query head, or a pool of fewer (grouped) K/V heads (ISSUE 34)
         dtype = model.precision.compute_dtype
-        grouped = heads != cfg["heads"]
-        fits = (paged_decode_grouped_supported(cfg["heads"], heads, head_dim,
+        #: block diffusion's positions a pass, None for one token a step
+        self.block_len = getattr(model, "block_len", None)
+        if self.block_len is not None and self.block_size % self.block_len:
+            raise ValueError(f"block_len {self.block_len} does not divide "
+                             f"the cache's {self.block_size}-token blocks")
+        if self.block_len is not None:
+            #: the token a position still to fill holds, and the masked
+            #: positions a denoising pass commits: the static schedule
+            self.mask_id = int(model.config["mask_id"])
+            self.commits_per_pass = int(model.commits_per_pass)
+        # a block pass hands the kernel a slot's ``block_len x heads``
+        # queries as the query heads of one slot (``attend_block``)
+        q_heads = cfg["heads"] * (self.block_len or 1)
+        grouped = heads != q_heads
+        fits = (paged_decode_grouped_supported(q_heads, heads, head_dim,
                                                self.block_size, dtype)
                 if grouped else paged_decode_supported(heads, head_dim, dtype))
         use_kernel = decode_kernel == "on" or (
@@ -283,8 +316,9 @@ class InferenceEngine:
         # argument and is donated the same way; a model without one passes
         # nothing there (a decode step: the empty dict) and donates nothing.
         self._donate = (1, 2, 9) if self._state else (1, 2)
-        self._decode_fn = jax.jit(self._decode_impl,
-                                  donate_argnums=self._donate)
+        self._decode_fn = jax.jit(
+            self._decode_impl if self.block_len is None else self._block_impl,
+            donate_argnums=self._donate)
         self._prefill_fns: dict[int, object] = {}
         # partial-prefill programs, keyed on PADDED SUFFIX length (same
         # power-of-two bucketing as full prefill -> same log2 bound on
@@ -303,7 +337,9 @@ class InferenceEngine:
         self.run_ahead_for = None
         self._unread: _Launch | None = None
         # what a step with no launch before it takes for ``carry``
-        self._no_carry = jax.device_put(np.zeros((self.max_batch,), np.int32))
+        self._no_carry = jax.device_put(np.zeros(
+            (self.max_batch,) if self.block_len is None
+            else (self.max_batch, 2 + 3 * self.block_len), np.int32))
 
     @property
     def quantized(self) -> bool:
@@ -358,6 +394,9 @@ class InferenceEngine:
         if self.window is not None:
             out["window_attention"] = \
                 self.model.resolved_paths()["window_attention"]
+        if self.block_len is not None:
+            out["block_diffusion"] = \
+                self.model.resolved_paths()["block_diffusion"]
         if self.quantized:
             leaves = [leaf for leaf in jax.tree.leaves(
                 self.params, is_leaf=_is_quantized) if _is_quantized(leaf)]
@@ -432,6 +471,79 @@ class InferenceEngine:
         return (nxt, logits, cache.k, cache.v, cache.state,
                 stats[0] if stats else {})
 
+    def block_row(self, tail=()) -> np.ndarray:
+        """A block's first pass, as ``decode`` takes it: ``[0, masked,
+        tail..., mask...]`` — ``tail`` the block's positions already
+        committed (a prompt's last ``len(prompt) % block_len`` tokens)."""
+        n, tail = self.block_len, list(tail)
+        return np.array([0, n - len(tail), *tail,
+                         *[self.mask_id] * (n - len(tail))], np.int32)
+
+    def advance_block_row(self, row) -> bool:
+        """A slot's ``row`` as it was just launched, in place to the slot's
+        next pass under the static schedule: a denoising pass leaves
+        ``commits_per_pass`` fewer positions masked and its block on the
+        device (``-1``).  -> whether the launched pass was the one that
+        writes a finished block's K/V: ``L`` then advances by
+        ``block_len``, and ``row`` opens the next block all masked."""
+        if row[1] == 0:
+            row[:] = self.block_row()
+            return True
+        row[0] += 1
+        row[1] = max(row[1] - self.commits_per_pass, 0)
+        row[2:] = -1
+        return False
+
+    def block_result(self, out_row):
+        """One slot's row of a pass's output -> (``L``, the pass's index,
+        the block's tokens, their states, their confidences)."""
+        n = self.block_len
+        return (int(out_row[0]), int(out_row[1]), out_row[2:2 + n],
+                out_row[2 + n:2 + 2 * n],
+                np.asarray(out_row[2 + 2 * n:], np.int32).view(np.float32))
+
+    def _block_impl(self, params, k, v, tables, lengths, tokens, temps, rids,
+                    base_key, state=None, carry=None):
+        """One block-diffusion pass over the fixed batch (module docstring):
+        ``tokens`` ``[B, 2 + n]``; ``carry`` the previous pass's output, from
+        which a row whose tokens are ``-1`` takes its block.  -> (``[B, 2 +
+        3 n]`` rows ``[L, pass, tokens, states, confidences]``, None, k, v,
+        state, stats with ``committed``).  Greedy: ``temps``, ``rids`` and
+        ``base_key`` are not read."""
+        del temps, rids, base_key
+        n = self.block_len
+        block = tokens[:, 2:]
+        masked = block == self.model.config["mask_id"]
+        if carry is not None:
+            fed = block[:, :1] < 0
+            block = jnp.where(fed, carry[:, 2:2 + n], block)
+            masked = jnp.where(fed, carry[:, 2 + n:2 + 2 * n] == 0, masked)
+        with jax.named_scope("recast"):
+            params = dequantize_tree(params, keep=self._keep_quant)
+        cache = PagedKVCache(k, v, tables, self.block_size,
+                             decode_impl=self.decode_impl, state=state or {})
+        logits, cache, stats = self.model.apply_block(params, {}, cache,
+                                                      lengths, block)
+        block, states, conf = self.model.commit_block(logits, block, masked)
+        stats["committed"] = jnp.sum((states == 2) & (lengths > 0)[:, None],
+                                     dtype=jnp.int32)
+        out = jnp.concatenate(
+            [lengths[:, None], tokens[:, :1], block, states,
+             jax.lax.bitcast_convert_type(conf, jnp.int32)], axis=1)
+        return out, None, cache.k, cache.v, cache.state, stats
+
+    def _prefill_blocks_impl(self, params, k, v, table_row, tokens):
+        """A block-diffusion prompt's whole blocks into the pool; -> (a
+        scalar that is ready when they are, k, v)."""
+        with jax.named_scope("recast"):
+            params = dequantize_tree(params)
+        cache = PagedKVCache(
+            k, v, jnp.zeros((1, self.max_blocks_per_seq), jnp.int32),
+            self.block_size)
+        cache = self.model.apply_prefill_blocks(params, {}, cache, table_row,
+                                                tokens[None, :])
+        return tokens[0], cache.k, cache.v
+
     def _prefill_impl(self, params, k, v, table_row, tokens, true_len,
                       temp, rid, base_key, state=None, slot=None):
         with jax.named_scope("recast"):
@@ -504,11 +616,17 @@ class InferenceEngine:
         hit); only the suffix is computed, in a program bucketed on the
         padded SUFFIX length.  ``prefix_len`` must be a whole number of
         blocks (the cache shares full blocks only) and must leave at least
-        one uncached token to produce the next-token logits."""
+        one uncached token to produce the next-token logits.
+
+        A block-diffusion model's prefill writes the prompt's whole blocks
+        only and samples nothing: -> (the prompt's tail, ``len(tokens) %
+        block_len`` tokens that open the first block, None)."""
         p = len(tokens)
         if p > self.max_context:
             raise ValueError(f"prompt of {p} tokens > max context "
                              f"{self.max_context}")
+        if self.block_len is not None:
+            return self._prefill_blocks(table_row, tokens, rid, prefix_len)
         # the whole call, fenced by the host int it returns; ``bucket`` is
         # the padded length (of the uncached part) that picks the program
         with spans.span(_SPAN_PREFILL, request=rid, prompt=p,
@@ -552,6 +670,37 @@ class InferenceEngine:
                     *own)
             return self._prefill_read(nxt, last)
 
+    def _prefill_blocks(self, table_row, tokens, rid, prefix_len):
+        """:meth:`prefill` for a block-diffusion model, in a ``serve.prefill``
+        span of its own (tag ``block_tail``: the tokens left for the first
+        block).  A prompt shorter than one block runs no program."""
+        if prefix_len:
+            raise ValueError("block diffusion has no partial prefill: it "
+                             "cannot prefill from a cached prefix")
+        n = len(tokens) - len(tokens) % self.block_len
+        with spans.span(_SPAN_PREFILL, request=rid, prompt=len(tokens),
+                        tokens=n, bucket=self.pad_len(n) if n else 0,
+                        prefix_len=0, block_tail=len(tokens) - n,
+                        **self._moe_tags, **self._paged_tags):
+            if n:
+                p_pad = self.pad_len(n)
+                fn = self._prefill_fns.get(p_pad)
+                if fn is None:
+                    fn = self._prefill_fns[p_pad] = jax.jit(
+                        self._prefill_blocks_impl, donate_argnums=(1, 2))
+                with spans.span(_SPAN_PREFILL_PLACE):
+                    row = list(table_row[:p_pad // self.block_size]) + [
+                        PagedKVCache.NULL_BLOCK] * (
+                        p_pad // self.block_size - len(table_row))
+                    toks = np.zeros((p_pad,), np.int32)
+                    toks[:n] = tokens[:n]
+                    args = jax.device_put((np.array(row, np.int32), toks))
+                with spans.span(_SPAN_PREFILL_DISPATCH):
+                    done, self._k, self._v = fn(self.params, self._k, self._v,
+                                                *args)
+                self._prefill_read(done, None)
+            return list(tokens[n:]), None
+
     def _prefill_suffix(self, table_row, tokens, temperature, rid,
                         prefix_len):
         """The ``prefix_len > 0`` half of :meth:`prefill`, inside its
@@ -593,7 +742,7 @@ class InferenceEngine:
     def _prefill_read(self, nxt, last):
         """The ``.drain``, ``.wait`` and ``.fetch`` of a prefill that has
         gone out: -> (its sampled token, its last position's logits), on
-        the host."""
+        the host; ``last`` None (a block prefill): no ``.fetch``."""
         if self._step_running():
             # the prefill queued behind that step: the host would wait it
             # out inside ``int(nxt)`` anyway.  Nothing is read — the launch
@@ -604,6 +753,8 @@ class InferenceEngine:
         with spans.span(_SPAN_PREFILL_WAIT):
             # lint: host-sync-ok — this span IS the prefill program's run
             tok = int(nxt)
+        if last is None:
+            return tok, None
         with spans.span(_SPAN_PREFILL_FETCH, bytes=last.nbytes):
             # lint: host-sync-ok — this span IS the copy to the host
             # lint: donated-escape-ok — prefill outputs are fresh XLA result
@@ -612,7 +763,9 @@ class InferenceEngine:
 
     def decode(self, tables, lengths, tokens, temps, rids):
         """One decode step over the fixed batch; -> (next tokens ``[B]``
-        np.int32, logits ``[B, V]`` np).  All arguments are host arrays of
+        np.int32, logits ``[B, V]`` np) — for a block-diffusion model (module
+        docstring) one pass: -> (rows ``[B, 2 + 3 n]``, None).  All
+        arguments are host arrays of
         length ``max_batch``; inactive slots pass table rows of nulls and
         length 0 (their outputs are garbage by contract).  The arrays are
         copied on the way in: the caller may change them once this returns.
@@ -639,11 +792,15 @@ class InferenceEngine:
             raise ValueError("a slot asks for the previous step's token and "
                              "no launch of this caller's is unread")
         # ``kv_tokens``: the tokens this step's attention reads, each
-        # active slot's context with the token it writes.  ``step``,
-        # ``batch``, ``kv_tokens`` and ``requests`` are the launched
-        # step's; the device counters the span is tagged with below are
-        # those of the step it READ, one behind where the call runs ahead
-        context = lengths[active] + 1
+        # active slot's context with the token it writes (a block pass: with
+        # the whole block).  ``step``, ``batch``, ``kv_tokens`` and
+        # ``requests`` are the launched step's; the device counters the span
+        # is tagged with below are those of the step it READ, one behind
+        # where the call runs ahead
+        context = lengths[active] + (self.block_len or 1)
+        block_tags = {} if self.block_len is None else {
+            "store_slots": int((np.asarray(tokens)[active, 1] == 0).sum()),
+            "masked_rows": int(np.asarray(tokens)[active, 1].sum())}
         # a model with window layers: the keys a full layer attends, and
         # the same capped at the window a slot (what a window layer does)
         window_tags = {} if self.window is None else {
@@ -654,7 +811,8 @@ class InferenceEngine:
                         requests=np.asarray(rids)[active].tolist(),
                         launched=1, ran_ahead=int(prev is not None),
                         **self._moe_tags, **self._state_tags,
-                        **self._paged_tags, **window_tags) as span:
+                        **self._paged_tags, **window_tags,
+                        **block_tags) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):
                 args = jax.device_put((np.array(tables, np.int32),

@@ -207,6 +207,35 @@ class PagedKVCache:
             self, k=self.k.at[layer, blk, off].set(k.astype(self.k.dtype)),
             v=self.v.at[layer, blk, off].set(v.astype(self.v.dtype)))
 
+    def write_block(self, layer, k, v, lengths) -> "PagedKVCache":
+        """Write a block of ``n`` positions' K/V per slot: ``k``/``v`` ``[B,
+        n, H, Dh]`` at positions ``lengths[b] .. lengths[b] + n - 1``, where
+        ``lengths`` is a multiple of ``n`` and ``n`` divides ``block_size``,
+        so a slot's block lies in one cache block (a block-diffusion pass)."""
+        n = k.shape[1]
+        blk = jnp.take_along_axis(
+            self.block_tables, (lengths // self.block_size)[:, None], axis=1)
+        off = lengths[:, None] % self.block_size + jnp.arange(n)[None, :]
+        return dataclasses.replace(
+            self, k=self.k.at[layer, blk, off].set(k.astype(self.k.dtype)),
+            v=self.v.at[layer, blk, off].set(v.astype(self.v.dtype)))
+
+    def attend_block(self, layer, q, lengths):
+        """Every query of a slot's block over the keys ``[0, lengths[b] +
+        n)`` — its cached context and the whole block, no mask inside it:
+        ``q`` ``[B, n, H, Dh]`` (the block's K/V written already) -> context
+        ``[B, n, H, Dh]``.  The block's ``n x H`` queries see the same keys,
+        so they go through :meth:`attend_decode` as ``n x H`` query heads of
+        one slot, K/V-head-major (row ``r`` reads K/V head ``r // (n H /
+        Hkv)``), on whichever path it takes for a grouped pool."""
+        b, n, h, d = q.shape
+        hkv = self.k.shape[3]
+        rows = q.reshape(b, n, hkv, h // hkv, d).transpose(0, 2, 1, 3, 4)
+        ctx = self.attend_decode(layer, rows.reshape(b, n * h, d),
+                                 lengths + n - 1)
+        return ctx.reshape(b, hkv, n, h // hkv, d).transpose(
+            0, 2, 1, 3, 4).reshape(b, n, h, d)
+
     # -- per-slot recurrent state ----------------------------------------------
     def write_state(self, layer: int, new: dict, slot) -> "PagedKVCache":
         """Replace state layer ``layer`` of ``slot`` whole (``new`` leaves

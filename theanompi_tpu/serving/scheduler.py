@@ -32,6 +32,26 @@ deadline's expiry, ``preempt_all``, ``expire_all_active``, an injected
 fault — first reads the launch still out (``_drain``), and the next launch
 starts the pipeline anew.
 
+**Block diffusion** (an engine whose model has a ``block_len`` B): a step
+is one pass over every slot's block of B positions, and a pass commits
+several positions, out of order (the engine's module docstring).  The
+schedule is static, so the host knows without reading a token which pass
+each slot is in: a block opened with ``m`` positions masked takes ``ceil(m
+/ c)`` denoising passes (``c`` = the engine's ``commits_per_pass``), then one
+pass over its final tokens that writes its K/V, after which ``L`` — the
+tokens cached, ``_lengths`` — advances by B and the next block opens with
+all B masked.  The prompt's whole blocks are prefilled; its tail opens the
+first block committed.  ``req.generated`` grows only by tokens whose
+position and every position before it are committed, in position order,
+to exactly ``max_new_tokens``: the positions of the last block past that
+are computed, dropped, and kept in ``req.committed`` (position -> (token,
+pass, confidence)) with every other committed position, for a check that
+needs the state each token was chosen in.  The schedule and the rows'
+layout are the engine's (``block_row``, ``advance_block_row``,
+``block_result``).  The last block needs no K/V pass: the
+request gives its slot up when that block's last denoising pass is
+launched.
+
 Request lifecycle (ISSUE 14): every request ends in exactly one typed
 terminal state —
 
@@ -118,6 +138,11 @@ class Request:
     t_first_token: float | None = None
     t_last_token: float | None = None  # stamp of the newest token
     t_done: float | None = None
+    #: block diffusion: every position a pass committed -> (token, the
+    #: pass's index in its block, the token's log-probability in that pass;
+    #: -1 and None: committed before the block's first pass, the tail of
+    #: the prompt the block was prefilled from)
+    committed: dict = field(default_factory=dict)
 
     @property
     def terminal(self) -> bool:
@@ -187,7 +212,15 @@ class Scheduler:
         self._blocks: list[list[int]] = [[] for _ in range(b)]
         self._tables = np.zeros((b, nb), np.int32)
         self._lengths = np.zeros((b,), np.int32)
-        self._tokens = np.zeros((b,), np.int32)
+        #: block diffusion's positions a pass (None: one token a step); a
+        #: slot's row of ``_tokens`` is then the engine's (``block_row``)
+        self.block = getattr(engine, "block_len", None)
+        #: what a read launch's tokens go through: one a slot, or a pass's
+        #: rows (block diffusion)
+        self._account_launch = (self._account if self.block is None
+                                else self._account_block)
+        self._tokens = np.zeros(
+            (b,) if self.block is None else (b, 2 + self.block), np.int32)
         self._temps = np.zeros((b,), np.float32)
         self._rids = np.zeros((b,), np.int32)
         #: the launch whose tokens are still on the device: slot -> the
@@ -279,6 +312,15 @@ class Scheduler:
         total = len(req.prompt) + req.max_new_tokens
         if not req.prompt:
             raise ValueError(f"request {req.rid}: empty prompt")
+        if self.block is not None:
+            # the last block is computed whole
+            total = -(-total // self.block) * self.block
+            mask = self.engine.mask_id
+            if req.temperature > 0 or mask in req.prompt:
+                raise ValueError(
+                    f"request {req.rid}: block diffusion commits greedily "
+                    f"and reads the mask token {mask} as a position "
+                    f"still to fill; no temperature, no mask in a prompt")
         if total > self.engine.max_context:
             raise ValueError(
                 f"request {req.rid}: prompt+max_new_tokens = {total} > "
@@ -564,6 +606,9 @@ class Scheduler:
             tok, _ = self.engine.prefill(row, prefix, req.temperature,
                                          req.rid, prefix_len=prefix_len,
                                          **own)
+            if self.block is not None:
+                self._open_first_block(slot, req, row, need, prefix, tok)
+                continue
             if prefix_len:
                 # exact accounting: tokens_saved is the sum of matched-
                 # prefix lengths — prefill K/V the engine did not recompute
@@ -601,6 +646,112 @@ class Scheduler:
             if self._done(req):
                 self._finish(slot, finished)
 
+    def _open_first_block(self, slot: int, req: Request, row, need: int,
+                          prefix: list[int], tail: list[int]) -> None:
+        """Block diffusion's half of an admission: the prompt's whole blocks
+        are cached; its ``tail`` opens the slot's first block, the rest of
+        the block masked.  No token is produced here."""
+        n = len(prefix) - len(tail)
+        # a request admitted again after a preemption: what the passes of
+        # its unfinished block had committed out of order is computed anew,
+        # and the generated tokens in the tail are now committed before it
+        for pos in [p for p in req.committed if p >= n]:
+            del req.committed[pos]
+        for pos in range(max(n, len(req.prompt)), len(prefix)):
+            req.committed[pos] = (prefix[pos], -1, None)
+        self._emit(_INST_ADMIT, request=req.rid, slot=slot,
+                   prefix=len(prefix), blocks=need, prefix_cached=0,
+                   resumed=req.n_preemptions > 0)
+        req.state = "active"
+        self.slots[slot] = req
+        self._blocks[slot] = row
+        self._tables[slot, :] = PagedKVCache.NULL_BLOCK
+        self._tables[slot, :need] = row
+        self._lengths[slot] = n
+        self._tokens[slot] = self.engine.block_row(tail)
+        self._temps[slot] = req.temperature
+        self._rids[slot] = req.rid
+
+    def _advance_block(self, slot: int) -> bool:
+        """The host's half of a launched pass: the slot's next pass,
+        counted (module docstring).  -> whether the launched pass was the
+        request's last: the last denoising pass of the block that holds its
+        ``max_new_tokens``-th token."""
+        row, req = self._tokens[slot], self.slots[slot]
+        if self.engine.advance_block_row(row):  # it wrote the block's K/V
+            self._lengths[slot] += self.block
+            return False
+        end = len(req.prompt) + req.max_new_tokens
+        return row[1] == 0 and self._lengths[slot] + self.block >= end
+
+    def _account_block(self, launch: dict[int, Request], out, now: float,
+                       finished: list[Request]) -> int:
+        """:meth:`_account` for block passes: ``out`` a row a slot (the
+        engine's ``block_result``).  Each position the pass committed is
+        kept in ``req.committed`` with the pass and its confidence;
+        ``req.generated`` takes every committed position in order from
+        where it stands, up to ``max_new_tokens``."""
+        ahead = self._unread or {}
+        b, n = self.block, 0
+        for slot, req in launch.items():
+            if req.state != "active":
+                continue  # ended on a stop token a pass ago: an overrun
+            at, pas, toks, states, conf = self.engine.block_result(out[slot])
+            for i in np.flatnonzero(states == 2):
+                req.committed[at + int(i)] = (int(toks[i]), pas,
+                                              float(conf[i]))
+            pos = len(req.prompt) + len(req.generated)
+            end = len(req.prompt) + req.max_new_tokens
+            new = []
+            while pos < end and pos in req.committed:
+                new.append(req.committed[pos][0])
+                pos += 1
+            if self.eos_token is not None and self.eos_token in new:
+                new = new[:new.index(self.eos_token) + 1]
+            holds = self.slots[slot] is req
+            if holds and not ahead and self._tokens[slot, 2] < 0:
+                # nothing is out to carry the block: the host hands it over
+                self._tokens[slot, 2:] = np.where(
+                    states == 0, self.engine.mask_id, toks)
+            if new:
+                self._stamp(req, new, now)
+                n += len(new)
+            # done once its last block is whole (the positions past
+            # ``max_new_tokens`` recorded too), or on a stop token
+            if not ((pos == end and all(
+                    p in req.committed for p in range(end, -(-end // b) * b)))
+                    or (new and new[-1] == self.eos_token)):
+                continue
+            if ahead.get(slot) is req:
+                self.n_overrun_slots += 1
+                self.engine.overrun()
+            if holds:
+                self._finish(slot, finished)
+            else:  # its last pass was launched: the slot went then
+                self._complete(req, finished)
+        return n
+
+    def _stamp(self, req: Request, new: list[int], now: float) -> None:
+        """Tokens that reached the host together: appended, stamped, the
+        first with the wait since the request's previous token (a stall for
+        another request's prefill and the scheduler's own time are inside
+        it, as the request's user feels them) and the rest with none (they
+        arrived with it)."""
+        if req.t_first_token is None:
+            req.t_first_token = now
+            self.ttft_ms.append((now - req.t_submit) * 1e3)
+            if self.telemetry is not None:
+                self.telemetry.observe(_HIST_TTFT_MS, self.ttft_ms[-1])
+        gaps = [(now - (req.t_last_token or now)) * 1e3] + [0.0] * (
+            len(new) - 1)
+        req.t_last_token = now
+        req.generated.extend(new)
+        self.token_ms.extend(gaps)
+        if self.telemetry is not None:
+            for gap_ms in gaps:
+                self.telemetry.count(_CNT_TOKENS)
+                self.telemetry.observe(_HIST_TOKEN_MS, gap_ms)
+
     def _done(self, req: Request) -> bool:
         if len(req.generated) >= req.max_new_tokens:
             return True
@@ -616,7 +767,11 @@ class Scheduler:
         for slot in range(self.engine.max_batch):
             if self.slots[slot] is None:
                 continue
-            if self._lengths[slot] % self.engine.block_size != 0:
+            if self.block is not None:  # the pass writes [L, L + B)
+                if blocks_for(int(self._lengths[slot]) + self.block,
+                              self.engine.block_size) <= len(self._blocks[slot]):
+                    continue
+            elif self._lengths[slot] % self.engine.block_size != 0:
                 continue
             while self.slots[slot] is not None:
                 got = self.pool.alloc(1)
@@ -662,17 +817,8 @@ class Scheduler:
             if req.state != "active":
                 continue  # ended on a stop token a step ago: an overrun
             tok = int(nxt[slot])
-            req.generated.append(tok)
+            self._stamp(req, [tok], now)
             n += 1
-            # the gap since this request's previous token: a stall for
-            # another request's prefill and the scheduler's own time are
-            # inside it, as the request's user feels them
-            gap_ms = (now - req.t_last_token) * 1e3
-            req.t_last_token = now
-            self.token_ms.append(gap_ms)
-            if self.telemetry is not None:
-                self.telemetry.count(_CNT_TOKENS)
-                self.telemetry.observe(_HIST_TOKEN_MS, gap_ms)
             holds = self.slots[slot] is req
             if holds and not ahead:
                 self._tokens[slot] = tok  # the next launch takes it from here
@@ -697,7 +843,7 @@ class Scheduler:
             return
         launch, self._unread = self._unread, None
         nxt = self.engine.collect()
-        self._account(launch, nxt, time.perf_counter(), finished)
+        self._account_launch(launch, nxt, time.perf_counter(), finished)
 
     def step(self) -> list[Request]:
         """One scheduler iteration: enforce deadlines, admit, secure
@@ -741,7 +887,12 @@ class Scheduler:
             t1 = time.perf_counter()
             self.step_ms.append((t1 - t0) * 1e3)
             self.n_steps += 1
+            last = set()
             for slot in launch:
+                if self.block is not None:
+                    if self._advance_block(slot):
+                        last.add(slot)
+                    continue
                 self._lengths[slot] += 1  # the fed token is now cached
                 self._tokens[slot] = -1   # the next one is on the device
             read, self._unread = self._unread, launch
@@ -749,12 +900,14 @@ class Scheduler:
                 # an engine that read its own launch: nothing stays out
                 read, self._unread = launch, None
             self._rate.append(
-                (t1, self._account(read, nxt, t1, finished) if read else 0))
+                (t1, self._account_launch(read, nxt, t1, finished)
+                 if read else 0))
             for slot, req in (self._unread or {}).items():
                 # the launch that is out is the last of a request that ends
                 # by length: its slot and blocks are free for the next step
-                if (self.slots[slot] is req
-                        and len(req.generated) + 1 >= req.max_new_tokens):
+                if self.slots[slot] is req and (
+                        slot in last if self.block is not None
+                        else len(req.generated) + 1 >= req.max_new_tokens):
                     self._evict(slot)
             if self.telemetry is not None and self.n_steps % 16 == 0:
                 # periodic flush (ISSUE 13): the ttft/token histograms must

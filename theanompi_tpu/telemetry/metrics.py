@@ -185,7 +185,8 @@ DEVICE_SCOPES = {
                 "flash_bwd_dq", "flash_bwd_dkv", "grouped_matmul",
                 "mamba_state_update"),
     "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
-                 "attn", "attn.window", "mlp", "loop.exit", "head"),
+                 "attn", "attn.window", "mlp", "loop.exit", "head",
+                 "diffusion.select"),
 }
 #: one ``train_iter`` (tags ``step``, ``epoch``; ``loss`` at fenced steps)
 TRAIN_SPANS = ("train.step",)
