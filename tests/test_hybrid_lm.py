@@ -396,8 +396,13 @@ def test_device_scopes_name_the_new_layers(tiny):
 #: ``hlo_audit``'s two serving configurations; the paged-decode call's
 #: serialized kernel body is left out of the text (it embeds source lines).
 #: A PR that means to change these programs replaces the hashes.
+#: Replaced since commit c10db4e: ``fallback/prefill`` (0c1dad5a85c7e652
+#: there), whose pool holds 2 K/V heads, which ``write_prefill`` now writes
+#: a token row at a time (``prefill_write_form``) so that XLA does not re-lay
+#: the pools out around the scatter; ``kernel/prefill``'s 8 heads keep the
+#: whole-block write, and no decode program moved.
 GOLDEN = {"fallback/decode": "b105afd5f576134c",
-          "fallback/prefill": "0c1dad5a85c7e652",
+          "fallback/prefill": "7ad3557c24905450",
           "kernel/decode": "319206e897e6ae95",
           "kernel/prefill": "f9bae97553af55ad"}
 

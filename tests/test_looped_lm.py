@@ -382,7 +382,11 @@ def test_the_loop_is_a_loop_of_the_program(tiny):
 #: the hashes.
 #: PR 33 replaced ``prefill``: the head reads the one position that is
 #: sampled (``head_at``), not the bucket's every row; ``decode`` is PR 31's.
-GOLDEN_HYBRID = {"decode": "09e39c4d17dbe50b", "prefill": "474deaf959a5d613"}
+#: Replaced since commit c10db4e: ``prefill`` (474deaf959a5d613 there): the
+#: prompt's K/V goes into its 2-K/V-head pool a token row at a time
+#: (``prefill_write_form``), which keeps the pool's layout where the
+#: whole-block scatter had XLA re-lay both pools out and back.
+GOLDEN_HYBRID = {"decode": "09e39c4d17dbe50b", "prefill": "6afe2e9b3f12736f"}
 
 
 @pytest.mark.parametrize("name", ["decode", "prefill"])
@@ -508,6 +512,7 @@ def test_the_served_widths_compile_for_a_v5e_around_one_pool(
     # kernel from 128 tokens on, the plain blockwise attention below
     assert calls == {"decode": 4, "prefill16": 0, "prefill128": 4}[program]
     assert len(re.findall(r"\bwhile\(", text)) >= 1
+    _no_pool_relayout(text, pool.shape)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill16384"])
@@ -561,8 +566,22 @@ def test_the_window_models_served_widths_compile_for_a_v5e_and_fit(
     # kernel, in prefill five flash kernels, three of them over the band
     assert text.count("tpu_custom_call") == {"decode": 10,
                                              "prefill16384": 13}[program]
+    _no_pool_relayout(text, pool.shape)
     if program == "decode":
         _no_gathered_context(text, pool.shape, b, table_tokens=16384)
+
+
+def _no_pool_relayout(text, pool_shape):
+    """No ``copy`` (nor ``copy-start``) in a compiled program has the pool's
+    shape in its result, in any layout: the program writes the pools where
+    they lie.  A prompt's whole-block scatter into a pool of fewer K/V heads
+    than a sublane tile holds had XLA copy both pools to another layout and
+    back around every prefill (four copies of 1.61 GB in ``sdar30``'s)."""
+    dims = ",".join(str(d) for d in pool_shape)
+    for line in text.splitlines():
+        inst = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w\-]+)\(", line)
+        if inst and inst.group(2) in ("copy", "copy-start"):
+            assert f"[{dims}]" not in inst.group(1), line[:200]
 
 
 def _no_gathered_context(text, pool_shape, slots, table_tokens):
@@ -630,17 +649,53 @@ def test_the_hybrids_served_widths_decode_through_the_grouped_kernel(
     _no_gathered_context(text, pool.shape, b, table_tokens=4096)
 
 
-@pytest.mark.parametrize("program", ["block", "prefill4096"])
+def test_the_hybrids_served_prefill_writes_its_two_head_pool_where_it_lies(
+        one_chip, monkeypatch):
+    """The same configuration's 512 bucket, its mean prompt's: the prompt's
+    K/V goes into the ``bf16[1, 32769, 16, 2, 128]`` pool a token row at a
+    time, so no copy of either pool re-lays it out (the whole-block scatter
+    made four, and 0.31 GB of temporaries)."""
+    from benchmarks.arch import nemotron_h
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+    from theanompi_tpu.ops import mamba2
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "nemotron3-super-ep4.json")) as f:
+        cfg = json.load(f)
+    run = cfg["run"]
+    b = run["max_batch"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    model = HybridLM(nemotron_h.model_config(cfg))
+    with mamba2.pin_state_update("kernel"):
+        eng, shape = _described_engine(model, one_chip, max_batch=b)
+        assert eng._write_tags == {"pool_writes": 1, "pool_writes_in_place": 1}
+        spec = model.cache_spec()
+        pool = shape((1, run["num_blocks"], 16, 2, 128), jnp.bfloat16)
+        state = {name: shape((spec["state_layers"], b, *s), dt)
+                 for name, (s, dt) in spec["state"].items()}
+        compiled = _compiled(
+            jax.jit(eng._prefill_impl, donate_argnums=(1, 2, 9)),
+            eng.params, pool, pool, shape((32,)), shape((512,)), shape(()),
+            shape((), jnp.float32), shape(()), shape((2,), jnp.uint32), state,
+            shape(()))
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2
+    assert mem.temp_size_in_bytes < 0.2e9, mem
+    _no_pool_relayout(text, pool.shape)
+
+
+@pytest.mark.parametrize("program", ["block", "prefill1024", "prefill4096"])
 def test_the_block_diffusion_stage_compiles_for_a_v5e_and_fits(
         one_chip, monkeypatch, program):
     """``benchmarks/configs/sdar-30b-a3b-pp8.json`` as it is served (six
     layers of 128 experts, 64 slots, contexts to 4096): the pass program
     holds the twelve grouped products and six ``paged_decode_grouped``
     calls — a slot's 4 x 32 queries as the query heads of one slot — and
-    gathers no context; the 4096 bucket's block prefill runs the flash
-    kernel with its block-causal mask (the last layer's attention and
-    experts feed nothing and are gone); arguments and temporaries leave
-    the chip room."""
+    gathers no context; the block prefill (the 1024 bucket holds the mean
+    prompt) runs the flash kernel with its block-causal mask (the last
+    layer's attention and experts feed nothing and are gone) and writes the
+    4-K/V-head pools where they lie, no copy re-laying them out; arguments
+    and temporaries leave the chip room."""
     from benchmarks.arch import sdar_moe
     from theanompi_tpu.models.hybrid_lm import HybridLM
 
@@ -661,22 +716,26 @@ def test_the_block_diffusion_stage_compiles_for_a_v5e_and_fits(
             shape((b,), jnp.float32), shape((b,)), shape((2,), jnp.uint32),
             {}, shape((b, 14)))
     else:
-        fn, args = eng._prefill_blocks_impl, (shape((256,)), shape((4096,)))
+        p = int(program[len("prefill"):])
+        fn, args = eng._prefill_blocks_impl, (shape((p // 16,)), shape((p,)))
     compiled = _compiled(jax.jit(fn, donate_argnums=(1, 2)),
                          eng.params, pool, pool, *args)
     mem, text = compiled.memory_analysis(), compiled.as_text()
     assert mem.alias_size_in_bytes >= 2 * np.prod(pool.shape) * 2
     # 8.72 GB of weights + the pool; a prefill leaves out the last layer's
     # experts (0.94 GB), which feed nothing
-    assert {"block": 11.9e9, "prefill4096": 10.0e9}[program] \
-        < mem.argument_size_in_bytes < {"block": 12.0e9,
-                                        "prefill4096": 10.2e9}[program]
-    assert mem.temp_size_in_bytes < {"block": 0.3e9,
-                                     "prefill4096": 2.0e9}[program], mem
+    low, high = (11.9e9, 12.0e9) if program == "block" else (10.0e9, 10.2e9)
+    assert low < mem.argument_size_in_bytes < high
+    # 0.10 / 0.62 GB for the prefills: 1.64 / 1.68 GB while a pool-sized
+    # copy stood in for each pool's scatter
+    assert mem.temp_size_in_bytes < {"block": 0.3e9, "prefill1024": 0.15e9,
+                                     "prefill4096": 0.7e9}[program], mem
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     grouped = [line for line in calls if "paged_decode_grouped" in line]
     assert (len(calls), len(grouped)) == {"block": (18, 6),
+                                          "prefill1024": (15, 0),
                                           "prefill4096": (15, 0)}[program]
+    _no_pool_relayout(text, pool.shape)
     if program == "block":
         _no_gathered_context(text, pool.shape, b, table_tokens=4096)
 

@@ -200,15 +200,18 @@ def test_an_engine_on_a_cpu_reports_plain_and_tags_its_decode_spans():
     assert eng.state_update_impl == "plain"
     assert eng.resolved_paths()["state_update"] == "plain"
     sched = Scheduler(eng)
+    # the ring is the process's: read what this scheduler recorded alone
+    seq0 = max((r.seq for r in spans.snapshot()), default=-1)
     sched.submit(Request(rid=30, prompt=[1, 2, 3], max_new_tokens=3))
     while not sched.idle:
         sched.step()
-    ours = [r for r in spans.snapshot()
+    mine = [r for r in spans.snapshot() if r.seq > seq0]
+    ours = [r for r in mine
             if r.name == "serve.decode" and r.tags["requests"] == [30]]
     assert len(ours) == 2
     for r in ours:  # two state layers, none through the kernel off the chip
         assert [r.tags[t] for t in SERVE_STATE_UPDATE_TAGS] == [2, 0]
-    assert not any(t in r.tags for r in spans.snapshot()
+    assert not any(t in r.tags for r in mine
                    if r.name == "serve.prefill" for t in SERVE_STATE_UPDATE_TAGS)
 
 

@@ -98,7 +98,11 @@ from theanompi_tpu.ops.pallas_paged_attention import (
     paged_decode_supported,
 )
 from theanompi_tpu.ops.quant import int8_matmul_supported
-from theanompi_tpu.serving.kv_cache import PagedKVCache, blocks_for
+from theanompi_tpu.serving.kv_cache import (
+    PagedKVCache,
+    blocks_for,
+    prefill_write_form,
+)
 from theanompi_tpu.serving.quant import (
     QuantizedTensor,
     dequantize_tree,
@@ -267,6 +271,12 @@ class InferenceEngine:
         n = getattr(model, "paged_layers", spec["kv"]["layers"])
         self._paged_tags = {"paged_layers": n,
                             "paged_kernel_layers": n if use_kernel else 0}
+        #: the ``pool_writes`` / ``pool_writes_in_place`` tags of
+        #: ``serve.prefill``: paged layers the prefill program writes (loop
+        #: steps counted), and those written without re-laying the pool
+        #: out — all of them, the pool's K/V heads picking the form that
+        #: keeps its layout (``prefill_write_form``)
+        self._write_tags = {"pool_writes": n, "pool_writes_in_place": n}
         #: what runs the decode step's recurrent-state update, as the model's
         #: op resolved it from platform and shape — "kernel",
         #: "kernel_interpret", "plain", or None for a model without such a
@@ -379,7 +389,8 @@ class InferenceEngine:
         decode step feeds to the fused matmul vs dequantizes (prefill
         always dequantizes), and the attention path of every prefill
         bucket compiled so far."""
-        out: dict = {"decode_attention": self.decode_impl}
+        out: dict = {"decode_attention": self.decode_impl,
+                     "prefill_kv_write": prefill_write_form(self._k.shape[3])}
         # the dtype of the floating leaves as held (see :meth:`_held`)
         out["weights_held"] = "/".join(sorted({
             jnp.dtype(leaf.dtype).name for leaf in jax.tree.leaves(
@@ -633,7 +644,7 @@ class InferenceEngine:
                         tokens=p - prefix_len,
                         bucket=self.pad_len(p - prefix_len),
                         prefix_len=prefix_len, **self._moe_tags,
-                        **self._paged_tags):
+                        **self._paged_tags, **self._write_tags):
             if prefix_len:
                 if self._state:
                     raise ValueError(
@@ -681,7 +692,8 @@ class InferenceEngine:
         with spans.span(_SPAN_PREFILL, request=rid, prompt=len(tokens),
                         tokens=n, bucket=self.pad_len(n) if n else 0,
                         prefix_len=0, block_tail=len(tokens) - n,
-                        **self._moe_tags, **self._paged_tags):
+                        **self._moe_tags, **self._paged_tags,
+                        **self._write_tags):
             if n:
                 p_pad = self.pad_len(n)
                 fn = self._prefill_fns.get(p_pad)
