@@ -768,6 +768,90 @@ def test_the_grouped_kernels_gate_is_what_compiles_for_a_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill16384"])
+def test_the_latent_stage_compiles_for_a_v5e_and_fits(one_chip, monkeypatch,
+                                                      program):
+    """``benchmarks/configs/joyai-llm-flash-ep4.json`` as it is served (five
+    layers, 64 of 256 experts, 128 slots, contexts to 16 384, a pool of
+    latent rows of 640 lanes): the decode program holds the eight grouped
+    products and five ``paged_decode_latent`` calls, each taking the pool
+    whole, and gathers no context; the widest prefill runs the flash
+    kernel with values narrower than its keys on every layer; both write
+    the pool where it lies and leave the chip its 16.9 GB: 13.9 GB of
+    arguments, the 16 384 bucket's 2.4 GB of temporaries."""
+    from benchmarks.arch import joyai_llm_flash
+    from theanompi_tpu.models.hybrid_lm import HybridLM
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "joyai-llm-flash-ep4.json")) as f:
+        cfg = json.load(f)
+    run = cfg["run"]
+    b = run["max_batch"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the chip's gates
+    eng, shape = _described_engine(HybridLM(joyai_llm_flash.model_config(cfg)),
+                                   one_chip, max_batch=b)
+    assert (eng.decode_impl, eng.decode_call, eng.expert_impl) == (
+        "kernel", "paged_decode_latent", "kernel")
+    pool = shape((5, run["num_blocks"], 16, 640), jnp.bfloat16)
+    if program == "decode":
+        fn, args = eng._decode_impl, (
+            shape((b, eng.max_blocks_per_seq)), shape((b,)), shape((b,)),
+            shape((b,), jnp.float32), shape((b,)), shape((2,), jnp.uint32),
+            {}, shape((b,)))
+    else:
+        fn, args = eng._prefill_impl, (
+            shape((1024,)), shape((16384,)), shape(()), shape((), jnp.float32),
+            shape(()), shape((2,), jnp.uint32))
+    compiled = _compiled(jax.jit(fn, donate_argnums=(1, 2)),
+                         eng.params, pool, None, *args)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= np.prod(pool.shape) * 2
+    assert 13.9e9 < mem.argument_size_in_bytes < 14.0e9
+    assert mem.temp_size_in_bytes < {"decode": 0.05e9,
+                                     "prefill16384": 2.6e9}[program], mem
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    latent = [line for line in calls if "paged_decode_latent" in line]
+    assert len(calls) == 13
+    assert len(latent) == {"decode": 5, "prefill16384": 0}[program]
+    dims = ",".join(str(d) for d in pool.shape)
+    assert all(f"bf16[{dims}]" in line for line in latent)
+    _no_pool_relayout(text, pool.shape)
+    if program == "decode":  # the gather's [slots, tokens, 640] is not there
+        for line in text.splitlines():
+            inst = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* gather\(",
+                            line)
+            if inst:
+                size = np.prod([int(d) for d in inst.group(1).split(",") if d])
+                assert size < b * 16384 * 640 // 8, line[:200]
+
+
+@pytest.mark.parametrize("heads,width,values,bs,dtype", [
+    (32, 640, 512, 16, jnp.bfloat16), (32, 640, 512, 8, jnp.float32),
+    (16, 256, 128, 16, jnp.bfloat16), (8, 384, 256, 32, jnp.bfloat16)])
+def test_the_latent_kernels_gate_is_what_compiles_for_a_v5e(
+        one_chip, heads, width, values, bs, dtype):
+    """Every shape the gate's test accepts compiles alone, the pool its
+    only operand of that size and nothing copied."""
+    from theanompi_tpu.ops.pallas_paged_attention import (
+        paged_attend_decode_latent,
+        paged_decode_latent_supported,
+    )
+
+    assert paged_decode_latent_supported(heads, width, values, bs, dtype)
+
+    def shape(s, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    pool = shape((2, 1025, bs, width), dtype)
+    compiled = _compiled(
+        jax.jit(lambda k, t, q, p: paged_attend_decode_latent(
+            k, 1, t, p, q, block_size=bs, values=values, scale=0.07,
+            interpret=False)),
+        pool, shape((8, 64)), shape((8, heads, width), dtype), shape((8,)))
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 #: sha256 (first 16 hex) of the StableHLO text the decode step of the two
 #: one-K/V-head-a-query-head configurations lowered to for the TPU at the
 #: parent of PR 34 (commit bb31a71, jax 0.9.0), at their served shapes (a

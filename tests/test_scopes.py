@@ -21,6 +21,8 @@ WINDOWED = ("embed", "attn", "attn.window", "mlp", "moe.route", "moe.experts",
             "moe.shared", "head", "sample")
 BLOCKDIFF = ("embed", "attn", "moe.route", "moe.experts", "head",
              "diffusion.select")
+LATENT = ("embed", "attn.latent", "mlp", "moe.route", "moe.experts",
+          "moe.shared", "head", "sample")
 #: program -> the scopes it must show
 EXPECTED = {
     "train": (*MODEL, "loss", "clip", "exchange", "optimizer"),
@@ -46,6 +48,10 @@ EXPECTED = {
     # reads out nothing
     "blockdiff_decode": BLOCKDIFF,
     "blockdiff_prefill": ("embed", "attn", "moe.route", "moe.experts"),
+    # latent attention: the decode step absorbs W_kvb, through the latent
+    # kernel; the prefill decompresses it
+    "latent_decode": (*LATENT, "attn.latent.absorb", "paged_decode_latent"),
+    "latent_prefill": (*LATENT, "attn.latent.decompress"),
 }
 SCOPES = sorted({s for names in EXPECTED.values() for s in names}
                 | {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"})
@@ -216,6 +222,27 @@ def lowered():
         eng._prefill_blocks_impl, donate_argnums=(1, 2)).lower(
         eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
         jnp.zeros((16,), i32)).as_text(debug_info=True)
+    latent = HybridLM({"pattern": "L-LE", "dim": 32, "vocab": 61,
+                       "seq_len": 32, "heads": 4, "q_lora": 16, "kv_lora": 16,
+                       "qk_nope": 8, "qk_rope": 8, "v_head": 8,
+                       "rope_theta": 1e4, "rope_interleave": True,
+                       "ffn_dim": 48, "n_experts": 8, "experts_held": (0, 4),
+                       "top_k": 2, "latent": None, "expert_dim": 16,
+                       "shared_dim": 16, "expert_act": "silu_gated",
+                       "route_scale": 2.5})
+    eng = InferenceEngine(latent, latent.init_params(jax.random.PRNGKey(0))[0],
+                          block_size=8, max_batch=2, decode_kernel="on")
+    out["latent_decode"] = eng._decode_fn.lower(
+        eng.params, eng._k, eng._v,
+        jnp.zeros((b, eng.max_blocks_per_seq), i32), jnp.zeros((b,), i32),
+        jnp.zeros((b,), i32), jnp.zeros((b,), jnp.float32),
+        jnp.zeros((b,), i32), eng._base_key).as_text(debug_info=True)
+    out["latent_prefill"] = jax.jit(
+        eng._prefill_impl, donate_argnums=(1, 2)).lower(
+        eng.params, eng._k, eng._v, jnp.zeros((2,), i32),
+        jnp.zeros((16,), i32), jnp.asarray(5, i32),
+        jnp.asarray(0.0, jnp.float32), jnp.asarray(1, i32),
+        eng._base_key).as_text(debug_info=True)
     return out
 
 
@@ -280,4 +307,6 @@ def test_the_documented_scopes_are_the_ones_the_programs_show(lowered):
     documented = {s for names in DEVICE_SCOPES.values() for s in names}
     assert set(SCOPES) <= documented
     assert set(DEVICE_SCOPES["HybridLM"]) == (
-        set(HYBRID) | set(LOOPED) | set(WINDOWED) | set(BLOCKDIFF)) - {"sample"}
+        set(HYBRID) | set(LOOPED) | set(WINDOWED) | set(BLOCKDIFF)
+        | set(LATENT) | {"attn.latent.absorb", "attn.latent.decompress"}) \
+        - {"sample"}

@@ -27,7 +27,16 @@ layer's letter in ``pattern`` (the Nemotron-H family's
   only.  ``attn_gate`` gives both kinds a sigmoid gate a head on the
   context, read from the layer's input;
 - ``-``: a bias-free gated feed-forward of ``ffn_dim``
-  (:class:`theanompi_tpu.ops.layers.GatedFFN`).
+  (:class:`theanompi_tpu.ops.layers.GatedFFN`);
+- ``L``: latent attention (:class:`theanompi_tpu.ops.attention.
+  LatentAttention`) of ``heads`` heads: queries through a ``q_lora``
+  bottleneck, keys and values through ONE cached row a token — ``c_kv``
+  (``kv_lora``) and a rotary key of ``qk_rope`` shared by every head, at
+  ``rope_theta``, adjacent pairs with ``rope_interleave`` — decompressed to
+  ``qk_nope`` + ``qk_rope`` keys and ``v_head`` values a head.  The paged
+  pool holds the rows (``cache_spec()["kv"]["latent"]``); a prefill
+  decompresses, a decode step absorbs ``W_kvb`` into its query and its
+  context.  No ``*``, ``w``, loop or block diffusion beside it.
 
 ``qk_norm`` gives the ``*`` and ``w`` layers a per-head RMSNorm on queries
 and keys before rotary (the Qwen3 block's); ``router`` ``"softmax"`` and
@@ -81,6 +90,8 @@ head reads ONE position (``head_at``: the one that is sampled), not the
 bucket's every row; ``apply_logits`` reads them all.  Device
 scopes: ``embed``, ``mamba``, ``moe.route``, ``moe.experts``,
 ``moe.shared``, ``attn``, ``attn.window`` (the gate inside either),
+``attn.latent`` (with ``attn.latent.absorb`` and
+``attn.latent.decompress`` inside it),
 ``mlp``, ``loop.exit`` (a step's final norm, gate and read-out), ``head``.
 
     tmserve --modelfile theanompi_tpu.models.hybrid_lm --modelclass HybridLM \\
@@ -110,11 +121,12 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from theanompi_tpu.models.contract import Model
 from theanompi_tpu.ops import initializers as init_lib
 from theanompi_tpu.ops import layers as L
-from theanompi_tpu.ops.attention import GroupedQueryAttention
+from theanompi_tpu.ops.attention import GroupedQueryAttention, LatentAttention
 from theanompi_tpu.ops.mamba2 import Mamba2
 from theanompi_tpu.ops.moe import DroplessMoE
 
-_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "w": "attn_w", "-": "mlp"}
+_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "w": "attn_w", "-": "mlp",
+          "L": "mla"}
 #: the device scope of each attention kind
 _ATTN_SCOPES = {"attn": "attn", "attn_w": "attn.window"}
 #: block diffusion's denoising passes a block, the static schedule: half the
@@ -151,6 +163,14 @@ class HybridLM(Model):
         "rope_theta": None,
         "rope_share": 1.0,
         "rope_yarn": None,
+        # ``L``: the query bottleneck, the cached latent, each head's
+        # unrotated and rotary key dims, its value dims, the rotary pairing
+        "q_lora": 96,
+        "kv_lora": 64,
+        "qk_nope": 32,
+        "qk_rope": 16,
+        "v_head": 32,
+        "rope_interleave": False,
         # ``*`` and ``w``: a sigmoid gate a head on the context
         "attn_gate": False,
         # ``w``: the band, its query heads (None: ``heads``), its rotary rule
@@ -202,7 +222,14 @@ class HybridLM(Model):
             raise ValueError(f"pattern {cfg['pattern']!r}: letters are "
                              f"{sorted(_KINDS)} (M Mamba-2, E experts, "
                              f"* attention, w window attention, - gated "
-                             f"FFN)")
+                             f"FFN, L latent attention)")
+        if "L" in cfg["pattern"] and (
+                set(cfg["pattern"]) & set("*w") or cfg["loops"] != 1
+                or cfg["block_len"] is not None):
+            raise ValueError(
+                f"pattern {cfg['pattern']!r}, loops={cfg['loops']}, "
+                f"block_len={cfg['block_len']}: latent attention's pool of "
+                f"rows is the model's one paged pool, one entry a layer")
         if cfg["loops"] < 1 or (cfg["loops"] > 1
                                 and set(cfg["pattern"]) & set("Mw")):
             raise ValueError(
@@ -252,6 +279,12 @@ class HybridLM(Model):
                 gate=cfg["attn_gate"], qk_norm=cfg["qk_norm"],
                 norm_eps=cfg["norm_eps"]),
             "mlp": L.GatedFFN(cfg["ffn_dim"]),
+            "mla": LatentAttention(
+                cfg["dim"], cfg["heads"], cfg["q_lora"], cfg["kv_lora"],
+                cfg["qk_nope"], cfg["qk_rope"], cfg["v_head"],
+                float(cfg["rope_theta"] or 1e4),
+                bool(cfg["rope_interleave"]), cfg["norm_eps"],
+                cfg["attn_impl"]),
         }
         #: (param-tree name, kind) per layer, in order
         self.layers = [(f"{i:02d}_{_KINDS[c]}", _KINDS[c])
@@ -274,6 +307,11 @@ class HybridLM(Model):
         ``*`` layer once a loop step."""
         return self.config["loops"] * self.config["pattern"].count("*")
 
+    @property
+    def latent_layers(self) -> int:
+        """``L`` layers: each reads its latent rows out of the paged pool."""
+        return self.config["pattern"].count("L")
+
     def set_expert_products(self, products: str) -> None:
         """What multiplies in the expert layers from now on
         (:class:`DroplessMoE`'s ``products``).  A serving engine calls this
@@ -288,15 +326,21 @@ class HybridLM(Model):
     def cache_spec(self) -> dict:
         """What a serving cache must hold for this model: ``kv`` — paged
         K/V, an entry for each ``*`` layer of each loop step (a pattern
-        without a ``*`` holds one entry for nobody); ``state``
+        without a ``*`` holds one entry for nobody), or for a pattern of
+        ``L`` layers a row of ``latent`` numbers a token and layer whose
+        first ``values`` are the values; ``state``
         — per slot and per ``M`` layer, name -> (shape, dtype);
         ``state_layers`` their count; ``window`` (where the pattern has a
         ``w``) — per slot and per ``w`` layer a ring of ``size`` tokens of
         K/V, apart from the paged pool."""
         cfg = self.config
         kinds = [k for _, k in self.layers]
-        spec = {"kv": {"layers": max(self.paged_layers, 1),
-                       "heads": cfg["kv_heads"], "head_dim": cfg["head_dim"]},
+        mla = self._mixers["mla"]
+        spec = {"kv": ({"layers": self.latent_layers, "latent": mla.latent,
+                        "values": mla.kv_lora} if self.latent_layers else
+                       {"layers": max(self.paged_layers, 1),
+                        "heads": cfg["kv_heads"],
+                        "head_dim": cfg["head_dim"]}),
                 "state": (self._mixers["mamba"].state_shapes()
                           if "mamba" in kinds else {}),
                 "state_layers": kinds.count("mamba")}
@@ -404,6 +448,31 @@ class HybridLM(Model):
                                               (x, cache, acc, ex))
         return ex["chosen"], cache, acc, ex["t_star"]
 
+    @jax.named_scope("attn.latent")
+    def _latent_prefill(self, p, u, positions, kv_cache, entry, table_row):
+        """An ``L`` layer over a prompt ``u`` ``[P, D]``: its rows written
+        to pool entry ``entry``, attention decompressed -> (out, cache)."""
+        mla = self._mixers["mla"]
+        row, q_nope, q_pe = mla.project_latent(p, u[None], positions)
+        if kv_cache is not None:
+            kv_cache = kv_cache.write_prefill(entry, row, None, table_row)
+        ctx = mla.attend(p, row, q_nope, q_pe)
+        return mla.project_out(p, ctx.reshape(u.shape[0], -1)), kv_cache
+
+    @jax.named_scope("attn.latent")
+    def _latent_decode(self, p, u, positions, kv_cache, entry):
+        """An ``L`` layer for one token a slot, ``u`` ``[B, D]``: its row
+        written, the absorbed query over the slots' rows -> (out, cache)."""
+        mla = self._mixers["mla"]
+        row, q_nope, q_pe = mla.project_latent(p, u[:, None],
+                                               positions[:, None])
+        kv_cache = kv_cache.write_decode(entry, row[:, 0], None, positions)
+        o_lat = kv_cache.attend_latent(
+            entry, mla.absorb(p, q_nope[:, 0], q_pe[:, 0]), positions,
+            values=mla.kv_lora, scale=mla.scale)
+        ctx = mla.expand(p, o_lat)
+        return mla.project_out(p, ctx.reshape(ctx.shape[0], -1)), kv_cache
+
     def _entry(self, t, n_kv: int):
         """The K/V pool entry of attention layer ``n_kv`` at loop step ``t``."""
         return t * self.config["pattern"].count("*") + n_kv
@@ -419,11 +488,15 @@ class HybridLM(Model):
         positions = jnp.arange(toks.shape[0], dtype=jnp.int32)[None]
 
         def once(x, kv_cache, acc, t):
-            n_kv = n_win = n_state = 0
+            n_kv = n_win = n_state = n_lat = 0
             for name, kind in self.layers:
                 p = cp[name]
                 u = self._normed(p, x)
-                if kind == "mamba":
+                if kind == "mla":
+                    y, kv_cache = self._latent_prefill(
+                        p["mixer"], u, positions, kv_cache, n_lat, table_row)
+                    n_lat += 1
+                elif kind == "mamba":
                     y, s = self._mixers[kind].prefill(p["mixer"], u, true_len)
                     if kv_cache is not None:
                         with jax.named_scope("mamba"):  # the state's write-back
@@ -593,11 +666,15 @@ class HybridLM(Model):
 
         def once(x, kv_cache, acc, t):
             hits, peak = acc
-            n_kv = n_win = n_state = 0
+            n_kv = n_win = n_state = n_lat = 0
             for name, kind in self.layers:
                 p = cp[name]
                 u = self._normed(p, x)
-                if kind == "mamba":
+                if kind == "mla":
+                    y, kv_cache = self._latent_decode(
+                        p["mixer"], u, positions, kv_cache, n_lat)
+                    n_lat += 1
+                elif kind == "mamba":
                     y, pools = self._mixers[kind].decode(
                         p["mixer"], u, kv_cache.state, n_state)
                     kv_cache = dataclasses.replace(
@@ -646,8 +723,9 @@ class HybridLM(Model):
     def attention_impl(self, t: int) -> str:
         from theanompi_tpu.ops.attention import resolve_attn_impl
 
-        return resolve_attn_impl(self.config["attn_impl"], t,
-                                 self.config["head_dim"])
+        head = (self.config["qk_nope"] + self.config["qk_rope"]
+                if self.latent_layers else self.config["head_dim"])
+        return resolve_attn_impl(self.config["attn_impl"], t, head)
 
     def resolved_paths(self) -> dict:
         """``state_update``: what runs an ``M`` layer's decode state update
@@ -663,6 +741,11 @@ class HybridLM(Model):
             paths["window_attention"] = (
                 f"slot ring of {int(self.config['window'])} tokens, masked "
                 f"grouped softmax")
+        if self.latent_layers:
+            mla = self._mixers["mla"]
+            paths["latent_attention"] = (
+                f"one paged row of {mla.latent} a token and layer, "
+                f"absorbed decode, decompressed prefill")
         if self.block_len is not None:
             paths["block_diffusion"] = (
                 f"blocks of {self.block_len}, {BLOCK_PASSES} "

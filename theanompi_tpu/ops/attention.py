@@ -205,12 +205,15 @@ def yarn_inv_freq(theta: float, rot: int, factor: float, original_max: int,
 
 
 def rotary(q, k, positions, theta: float, share: float = 1.0,
-           yarn: dict | None = None):
+           yarn: dict | None = None, interleave: bool = False):
     """Rotary position embedding, rotate-half pairing: ``q`` ``[..., T, H,
     Dh]`` and ``k`` ``[..., T, Hkv, Dh]`` at ``positions`` ``[..., T]`` ->
     the rotated pair in their own dtypes.  The leading ``rot = share * Dh``
     dims of a head are turned (dim ``i`` with ``i + rot // 2``), the rest
-    pass as they are.  Angles ``position * theta ** (-2 i / rot)``, or with
+    pass as they are.  ``interleave``: adjacent pairs ``(2 i, 2 i + 1)``
+    turn at frequency ``i`` instead, laid out as the ``transformers``
+    interleaved rule leaves them — the even dims first, then the odd ones —
+    so a query and a key turned alike meet as the pairs would.  Angles ``position * theta ** (-2 i / rot)``, or with
     ``yarn`` (``factor``, ``original_max_position``, ``beta_fast``,
     ``beta_slow``, ``attention_factor``) ``position *``
     :func:`yarn_inv_freq` with ``cos`` and ``sin`` multiplied by
@@ -236,6 +239,9 @@ def rotary(q, k, positions, theta: float, share: float = 1.0,
 
     def turn(x):
         xf = x.astype(jnp.float32)
+        if interleave:
+            xf = jnp.concatenate([xf[..., 0:rot:2], xf[..., 1:rot:2],
+                                  xf[..., rot:]], axis=-1)
         a, b = xf[..., :half], xf[..., half:rot]
         rest = [xf[..., rot:]] if rot < x.shape[-1] else []
         return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, *rest],
@@ -351,6 +357,133 @@ class GroupedQueryAttention(L.Layer):
         g = jax.nn.sigmoid(
             (x @ params["gate"]["w"].astype(x.dtype)).astype(jnp.float32))
         return (ctx.astype(jnp.float32) * g[..., None]).astype(ctx.dtype)
+
+    def project_out(self, params, out):
+        return out @ params["o"]["w"].astype(out.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention(L.Layer):
+    """Multi-head latent attention (DeepSeek-V2's MLA), no bias.
+
+    Queries through a rank-``q_lora`` bottleneck with its own RMSNorm:
+    ``q = RMSNorm(x W_qa) W_qb``, ``heads`` heads of ``qk_nope + qk_rope``
+    (``q_nope``, then ``q_pe``).  Keys and values through ONE latent row a
+    token: ``[c_kv | k_pe] = x W_kva``, ``c_kv`` (``kv_lora`` wide)
+    RMS-normed, ``k_pe`` (``qk_rope``) a rotary key shared by every head.
+    Decompressed, ``[k_nope | v] = c_kv W_kvb`` a head (``qk_nope`` +
+    ``v_head``), and head ``h`` scores ``(q_nope . k_nope + q_pe . k_pe) /
+    sqrt(qk_nope + qk_rope)``; the ``heads`` contexts of ``v_head`` go
+    through ``W_o``.  Rotary over the ``qk_rope`` dims at ``rope_theta``,
+    adjacent pairs with ``rope_interleave`` (:func:`rotary`).
+
+    A cache holds the row ``[c_kv | k_pe]`` alone, :attr:`latent` numbers a
+    token, read by every head.  Two routes to the same context: a prompt's
+    is decompressed (:meth:`attend`: keys and values of every head inside
+    the program, none of them cached); a decode step's is absorbed — ``q_lat
+    = q_nope W_UK^T`` (:meth:`absorb`) scores the row itself, ``o_lat = sum
+    p c_kv`` is the context in the latent space, and ``o_lat W_UV``
+    (:meth:`expand`) is the head's.  ``W_UK`` and ``W_UV`` are the two
+    halves of each head's columns of ``W_kvb``, views of the one leaf."""
+
+    dim: int
+    heads: int
+    q_lora: int
+    kv_lora: int
+    qk_nope: int
+    qk_rope: int
+    v_head: int
+    rope_theta: float = 1e4
+    rope_interleave: bool = False
+    norm_eps: float = 1e-6
+    impl: str = "auto"
+
+    @property
+    def latent(self) -> int:
+        """Numbers of the cached row a token: ``c_kv``, then ``k_pe``."""
+        return self.kv_lora + self.qk_rope
+
+    @property
+    def scale(self) -> float:
+        return (self.qk_nope + self.qk_rope) ** -0.5
+
+    def init(self, key, in_shape):
+        if in_shape[-1] != self.dim:
+            raise ValueError(f"MLA dim {self.dim} != input {in_shape[-1]}")
+        ks = jax.random.split(key, 5)
+        w02 = init_lib.normal(0.02)
+        h = self.heads
+        norm = L.RMSNorm()
+        params = {
+            "q_a": {"w": w02(ks[0], (self.dim, self.q_lora))},
+            "q_norm": norm.init(None, (self.q_lora,))[0],
+            "q_b": {"w": w02(ks[1], (self.q_lora,
+                                     h * (self.qk_nope + self.qk_rope)))},
+            "kv_a": {"w": w02(ks[2], (self.dim, self.latent))},
+            "kv_norm": norm.init(None, (self.kv_lora,))[0],
+            "kv_b": {"w": w02(ks[3], (self.kv_lora,
+                                      h * (self.qk_nope + self.v_head)))},
+            "o": {"w": w02(ks[4], (h * self.v_head, self.dim))}}
+        return params, {}, tuple(in_shape)
+
+    def project_latent(self, params, x, positions):
+        """``x`` ``[..., T, D]`` at ``positions`` ``[..., T]`` -> (the
+        cached row ``[..., T, kv_lora + qk_rope]``: ``c_kv`` normed, ``k_pe``
+        rotated; ``q_nope`` ``[..., T, H, qk_nope]``; ``q_pe`` ``[..., T,
+        H, qk_rope]`` rotated)."""
+        norm = L.RMSNorm(eps=self.norm_eps)
+        c_q = norm.apply(params["q_norm"], {},
+                         x @ params["q_a"]["w"].astype(x.dtype))[0]
+        q = (c_q @ params["q_b"]["w"].astype(x.dtype)).reshape(
+            *x.shape[:-1], self.heads, self.qk_nope + self.qk_rope)
+        kv = x @ params["kv_a"]["w"].astype(x.dtype)
+        c_kv = norm.apply(params["kv_norm"], {}, kv[..., :self.kv_lora])[0]
+        q_pe, k_pe = rotary(q[..., self.qk_nope:],
+                            kv[..., None, self.kv_lora:], positions,
+                            float(self.rope_theta),
+                            interleave=self.rope_interleave)
+        return (jnp.concatenate([c_kv, k_pe[..., 0, :]], axis=-1),
+                q[..., :self.qk_nope], q_pe)
+
+    def _kv_b(self, params, dtype):
+        """``W_kvb`` as ``[kv_lora, H, qk_nope + v_head]``: ``[..., :qk_nope]``
+        is ``W_UK``, the rest ``W_UV``."""
+        return params["kv_b"]["w"].astype(dtype).reshape(
+            self.kv_lora, self.heads, self.qk_nope + self.v_head)
+
+    def attend(self, params, row, q_nope, q_pe, block: int | None = None):
+        """Causal attention over a whole sequence, decompressed: ``row``
+        ``[B, T, latent]``, the queries ``[B, T, H, ...]`` -> context ``[B,
+        T, H, v_head]``.  Keys of ``qk_nope + qk_rope`` and values of
+        ``v_head`` a head go through :meth:`MultiHeadAttention.attend`'s
+        dispatch (the flash kernel takes values narrower than the keys)."""
+        with jax.named_scope("attn.latent.decompress"):
+            kv = jnp.einsum("btc,chn->bthn", row[..., :self.kv_lora],
+                            self._kv_b(params, row.dtype))
+            k_pe = jnp.broadcast_to(row[..., None, self.kv_lora:],
+                                    (*row.shape[:-1], self.heads,
+                                     self.qk_rope))
+            k = jnp.concatenate([kv[..., :self.qk_nope], k_pe], axis=-1)
+            v = kv[..., self.qk_nope:]
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        core = MultiHeadAttention(self.heads * q.shape[-1], self.heads,
+                                  impl=self.impl)
+        return core.attend(q, k, v, block=block)
+
+    @jax.named_scope("attn.latent.absorb")
+    def absorb(self, params, q_nope, q_pe):
+        """Queries ``[..., H, ...]`` -> ``[..., H, latent]``: ``q_nope
+        W_UK^T`` beside ``q_pe``, a query that scores the cached row."""
+        w = self._kv_b(params, q_nope.dtype)[..., :self.qk_nope]
+        q_lat = jnp.einsum("...hn,chn->...hc", q_nope, w)
+        return jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], axis=-1)
+
+    @jax.named_scope("attn.latent.absorb")
+    def expand(self, params, o_lat):
+        """A context in the latent space ``[..., H, kv_lora]`` -> each
+        head's ``[..., H, v_head]``: ``o_lat W_UV``."""
+        w = self._kv_b(params, o_lat.dtype)[..., self.qk_nope:]
+        return jnp.einsum("...hc,chv->...hv", o_lat, w)
 
     def project_out(self, params, out):
         return out @ params["o"]["w"].astype(out.dtype)

@@ -209,7 +209,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, acc_scr,
 @jax.named_scope("flash_fwd")  # names the custom call in a device trace
 def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret, window=None,
               block=None):
-    """q/k/v: [B, H, T, D] -> (out [B,H,T,D], lse [B,H,nq,8,block_q]).
+    """q/k/v: [B, H, T, D] -> (out [B,H,T,D], lse [B,H,nq,8,block_q]);
+    ``v`` may be narrower than ``q`` and ``k`` (latent attention's values):
+    the output then has v's width.
     ``window``: the causal band ``i - window < j <= i``; tiles wholly behind
     it are skipped like tiles above the diagonal, compute and DMA alike.
     ``block``: block-causal, ``j < (i // block + 1) * block`` (bidirectional
@@ -221,14 +223,15 @@ def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret, window=None,
     padded tile (row 0 is authoritative; all rows are equal).
     """
     b, h, t, d = q.shape
+    dv = v.shape[3]
     scale = d ** -0.5
     nq, nk = t // block_q, t // block_k
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, d=d,
+                               block_q=block_q, block_k=block_k, d=dv,
                                window=window, block=block)
-    # accumulator width: d data columns + a lane-aligned block whose first
+    # accumulator width: dv data columns + a lane-aligned block whose first
     # column carries the softmax normalizer (see kernel comment)
-    acc_cols = d + (128 - d % 128 if d % 128 else 128)
+    acc_cols = dv + (128 - dv % 128 if dv % 128 else 128)
 
     def kv_map(bi, hi, qi, ki):
         if causal:  # masked tiles re-reference the diagonal tile: DMA elided
@@ -244,16 +247,16 @@ def _fwd_call(q, k, v, *, causal, block_q, block_k, interpret, window=None,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d), kv_map),
-            pl.BlockSpec((1, 1, block_k, d), kv_map),
+            pl.BlockSpec((1, 1, block_k, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, 1, 8, block_q),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, nq, 8, block_q), jnp.float32),
         ],
         scratch_shapes=[
@@ -462,7 +465,9 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
     it): query ``i`` sees keys ``i - window < j <= i``.  ``block`` (the
     same terms): block-causal, query ``i`` sees keys ``j < (i // block +
     1) * block`` — every key of its own block of ``block`` positions
-    (aligned from position 0) and of every earlier block.
+    (aligned from position 0) and of every earlier block.  ``v`` narrower
+    than ``q`` and ``k`` (the same terms: forward only) gives a context of
+    v's width, at the FLOPs of the widths as they are.
 
     ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere
     (so the same code path is unit-testable on the CPU mesh).  In
@@ -488,14 +493,15 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
         )
     # [B,T,H,D] -> [B,H,T,D] for head-major tiling
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    if window is None and block is None:
+    if window is None and block is None and v.shape[3] == q.shape[3]:
         out = _flash(qt, kt, vt, causal, block_q, block_k, interpret)
-    else:
+    else:  # a band, a block mask or narrower values: forward only
         if not causal or (window is not None and window < 1) or (
                 block is not None and (block < 1 or window is not None)):
             raise ValueError(f"flash_attention: window={window} / block="
-                             f"{block} needs causal attention, at least the "
-                             f"query itself, and one of the two")
+                             f"{block} / narrower values need causal "
+                             f"attention, at least the query itself, and "
+                             f"not both masks")
         out, _ = _fwd_call(qt, kt, vt, causal=True, block_q=block_q,
                            block_k=block_k, interpret=interpret, window=window,
                            block=block)

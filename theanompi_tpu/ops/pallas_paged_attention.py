@@ -66,6 +66,14 @@ float32, a float32 online softmax.  Measured alone on a v5e (PERF.md
 section 6, PR 34): 0.915 ms a layer for the 0.61 GB in context at 48 over 8
 heads, 671 GB/s, the copies alone 0.889 ms; 0.95 ms for 0.13 GB at 32 over
 2 (copies alone 0.40 ms: there the products and masks lead).
+
+**A pool of latent rows** (latent attention's, the third kernel):
+``paged_attend_decode_latent`` reads ONE row ``[c_kv | k_pe]`` a token and
+layer, shared by every query head — absorbed queries ``[H, W]`` score a
+step's rows ``[tokens, W]`` in one MXU product and the context is the same
+buffer's first ``values`` columns, so each block is fetched once for keys
+and values alike.  Its schedule is the grouped kernel's (a grid step a
+slot, a loop as long as the slot's context, double-buffered block copies).
 """
 
 from __future__ import annotations
@@ -78,11 +86,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-#: the names the two kernels' custom calls carry in a trace
-#: (``DEVICE_SCOPES["kernels"]``): one K/V head a query head, and a pool of
-#: fewer (grouped) K/V heads (ISSUE 34)
+#: the names the kernels' custom calls carry in a trace
+#: (``DEVICE_SCOPES["kernels"]``): one K/V head a query head, a pool of
+#: fewer (grouped) K/V heads, and a pool of latent rows
 SCOPE = "paged_decode"
 SCOPE_GROUPED = "paged_decode_grouped"
+SCOPE_LATENT = "paged_decode_latent"
 
 
 def _decode_kernel(tables_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -388,3 +397,161 @@ def paged_attend_decode_grouped(k_pool, v_pool, layer, tables,
                   jnp.asarray(positions, jnp.int32),
                   jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool,
                   v_pool)
+
+
+# -- one latent row a token, read by every head ---------------------------------
+#: tokens of one slot's context a loop step of the latent kernel reads
+#: (PERF.md section 6: the kernel alone over 128 slots x 7 696 tokens on a
+#: v5e, 280 / 401 / 503 / 576 / 587 GB/s at 128 / 256 / 512 / 1024 /
+#: 2048 tokens a step; a step past a slot's context re-reads its last block)
+_LATENT_STEP_TOKENS = 1024
+
+
+def _latent_kernel(tables_ref, pos_ref, layer_ref, q_ref, pool_hbm, o_ref,
+                   buf, sems, *, block_size, per, values, scale):
+    """One slot of the batch: its context in steps of ``per`` blocks of
+    rows ``[c_kv | k_pe]``, each block fetched ONCE by the table into one
+    of two buffers while the step before it is computed.  Every query head
+    scores the step's rows over all their columns in one MXU product, and
+    the context is the same rows' first ``values`` columns: the values are
+    never fetched apart from the keys."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    pos = pos_ref[b]
+    bs = block_size
+    t = per * bs
+    last = pos // bs              # the block that holds the query's own token
+    steps = pos // t + 1          # steps that hold a position <= ``pos``
+
+    def fetch(c, slot):
+        # blocks past the last one re-read it: finite values that the mask
+        # drops, and nothing outside the slot's own blocks is touched
+        for r in range(per):
+            blk = tables_ref[b, jnp.minimum(c * per + r, last)]
+            pltpu.make_async_copy(pool_hbm.at[layer, blk],
+                                  buf.at[slot, pl.ds(r * bs, bs)],
+                                  sems.at[slot]).start()
+
+    def arrived(slot):
+        for r in range(per):      # a wait reads its copy's size only
+            pltpu.make_async_copy(pool_hbm.at[0, 0],
+                                  buf.at[slot, pl.ds(r * bs, bs)],
+                                  sems.at[slot]).wait()
+
+    fetch(0, 0)
+    # the gather's operands: the scaled query in the pool's dtype
+    q = (q_ref[...].astype(jnp.float32) * scale).astype(buf.dtype)
+    token = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+
+    def step(c, carry):
+        m, l, acc = carry
+        slot = c % 2
+
+        @pl.when(c + 1 < steps)
+        def _():
+            fetch(c + 1, 1 - slot)
+
+        arrived(slot)
+        rows = buf[slot]
+        s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(c * t + token <= pos, s, _NEG_INF)
+        # every step holds a position <= ``pos``, so ``m_new`` is a real
+        # score and a masked column's ``exp`` underflows to exactly 0
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        ctx = jnp.dot(p.astype(buf.dtype), rows[:, :values],
+                      preferred_element_type=jnp.float32)
+        return (m_new, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+                acc * corr + ctx)
+
+    h = q_ref.shape[0]
+    _, l, acc = jax.lax.fori_loop(
+        0, steps, step, (jnp.full((h, 1), _NEG_INF, jnp.float32),
+                         jnp.zeros((h, 1), jnp.float32),
+                         jnp.zeros((h, values), jnp.float32)))
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+def paged_decode_latent_supported(heads: int, width: int, values: int,
+                                  block_size: int,
+                                  dtype=jnp.bfloat16) -> bool:
+    """Shape gate for the COMPILED latent kernel, as wide as a v5e compile
+    has shown it (tests/test_latent_attention.py): a bfloat16 or float32
+    pool of rows ``width`` wide whose first ``values`` columns — whole
+    lanes, fewer than the row — are the values; query heads of whole
+    sublanes; a block of whole tiles of rows.  Callers take the pure-JAX
+    gather when False; the interpreter (tests) runs any shape."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return False
+    packed = 4 // dtype.itemsize
+    return (heads % 8 == 0 and values % 128 == 0 and 0 < values < width
+            and block_size % (8 * packed) == 0)
+
+
+def paged_attend_decode_latent(pool, layer, tables, positions, q, *,
+                               block_size: int, values: int, scale: float,
+                               interpret: bool | None = None,
+                               blocks_per_step: int | None = None):
+    """Decode attention of latent attention's absorbed queries over one
+    paged pool of rows.
+
+    ``pool`` ``[L, num_blocks, block_size, W]`` whole (a row a token: keys
+    over all ``W`` columns, values its first ``values``), ``layer`` a
+    Python int or a traced int32 scalar, ``tables`` ``[B,
+    max_blocks_per_seq]`` int32, ``positions`` ``[B]`` (each query's own
+    position, already written), ``q`` ``[B, H, W]`` -> context ``[B, H,
+    values]``, scores multiplied by ``scale``.  A grid step is one slot;
+    inside it a loop as long as the slot's own context reads
+    ``blocks_per_step`` blocks a step (None: ``_LATENT_STEP_TOKENS``), each
+    by its table entry, double-buffered.  Operand and accumulation types are
+    the gather's (``PagedKVCache._attend_decode_latent``): products of the
+    pool's dtype accumulated in float32, a float32 online softmax; the two
+    differ by rounding, not to the bit.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, w = q.shape
+    n_layers, _, bs, width = pool.shape
+    if bs != block_size or width != w:
+        raise ValueError(f"paged_attend_decode_latent: queries of {w} over "
+                         f"rows of {width}, {block_size}-token blocks over a "
+                         f"pool of {bs}")
+    traced = isinstance(layer, jax.Array)
+    if not traced and not 0 <= int(layer) < n_layers:
+        raise ValueError(f"paged_attend_decode_latent: layer {layer} outside "
+                         f"the pool's {n_layers} layers")
+    if not interpret and not paged_decode_latent_supported(
+            h, w, values, bs, pool.dtype):
+        raise ValueError(
+            f"paged_attend_decode_latent: unsupported shape H={h} W={w} "
+            f"values={values} block={bs} ({pool.dtype}) for compiled Mosaic "
+            "tiling; gate with paged_decode_latent_supported()")
+    per = blocks_per_step or max(1, _LATENT_STEP_TOKENS // bs)
+    per = min(int(per), tables.shape[1])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((None, h, w), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # the pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((None, h, values), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, per * bs, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    fn = pl.pallas_call(
+        functools.partial(_latent_kernel, block_size=bs, per=per,
+                          values=values, scale=float(scale)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, values), q.dtype),
+        interpret=interpret,
+    )
+    with jax.named_scope(SCOPE_LATENT):
+        return fn(jnp.asarray(tables, jnp.int32),
+                  jnp.asarray(positions, jnp.int32),
+                  jnp.asarray(layer, jnp.int32).reshape(1), q, pool)

@@ -85,7 +85,7 @@ def blockwise_attention(q, k, v, causal: bool = False, block_size: int | None = 
     qf = q.astype(jnp.float32)
     m = jnp.full((b, h, t), _NEG_INF, jnp.float32)
     l = jnp.zeros((b, h, t), jnp.float32)
-    acc = jnp.zeros((b, t, h, d), jnp.float32)
+    acc = jnp.zeros((b, t, h, v.shape[3]), jnp.float32)  # v may be narrower
     q_pos = jnp.arange(t)
     for start, stop in blocks:
         kb = k[:, start:stop].astype(jnp.float32)

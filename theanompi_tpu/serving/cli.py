@@ -38,6 +38,18 @@ loops x n_attn x kv_heads x head_dim x itemsize`` bytes a token::
         --set "pattern='*-*-'" --set loops=4 --set post_norm=True \
         --set rope_theta=1e6 --set ffn_dim=704 --set kv_heads=8 \
         --requests 16 --max-batch 8 --num-blocks 129 --out SERVE.json
+
+Latent attention (``L``) over a dense first layer and bias-corrected
+sigmoid experts; the pool holds ONE row of ``kv_lora + qk_rope`` a token
+and layer (laid out as whole lanes: 128 for the row below), so size
+``--num-blocks`` by ``n_L x 128 x itemsize`` bytes a token::
+
+    tmserve --modelfile theanompi_tpu.models.hybrid_lm --modelclass HybridLM \
+        --set "pattern='L-LELE'" --set heads=4 --set q_lora=96 \
+        --set kv_lora=64 --set qk_nope=32 --set qk_rope=16 --set v_head=32 \
+        --set rope_theta=1e4 --set rope_interleave=True --set latent=None \
+        --set "expert_act='silu_gated'" --set route_scale=2.5 \
+        --requests 16 --max-batch 8 --num-blocks 129 --out SERVE.json
 """
 
 from __future__ import annotations
@@ -67,7 +79,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="a model with the serving interface (apply_prefill, "
                    "apply_decode, cache_spec): TransformerLM and its MoE "
                    "variant, or HybridLM — a per-layer pattern of Mamba-2 "
-                   "(M), expert (E), attention (*) and gated-FFN (-) mixers, "
+                   "(M), expert (E), attention (*), window attention (w), "
+                   "latent attention (L) and gated-FFN (-) mixers, "
                    "serving-only, configured by --set pattern=... and its "
                    "widths; --set loops=T applies the pattern T times with "
                    "shared weights and an exit gate (exit_threshold), "
@@ -96,7 +109,7 @@ def build_parser() -> argparse.ArgumentParser:
                    "values oversubscribe and rely on preemption); a block "
                    "holds --block-size tokens over every entry the model's "
                    "cache_spec() asks for — loops x attention layers for a "
-                   "looped HybridLM")
+                   "looped HybridLM, one latent row a layer for L layers")
     p.add_argument("--quantize-int8", action="store_true",
                    help="int8 weight-only quantization of matmul weights "
                    "(ring_int8 per-chunk-scale format)")

@@ -59,6 +59,13 @@ the device), ``store_slots`` (slots whose pass only writes a finished
 block's K/V) and ``masked_rows`` beside ``kv_tokens``, the keys the pass
 reads (``L + B`` a slot).
 
+**Latent attention** (``HybridLM``'s ``L`` layers): the pool is ONE array
+of rows ``[c_kv | k_pe]`` and the V pool is None — the programs take and
+return ``v`` as None — read by the latent kernel where its gate admits the
+shape (``decode_call`` ``paged_decode_latent``); ``serve.decode`` carries
+``latent_layers`` / ``latent_kernel_layers`` and ``serve.prefill``
+``latent_layers``.
+
 The cache is whatever the model's ``cache_spec()`` asks for: paged K/V
 pools over the layers that attend (an entry per loop step and layer for a
 stack that is applied several times: the pools ride through the
@@ -94,7 +101,9 @@ from theanompi_tpu.ops.pallas_grouped_matmul import grouped_matmul_supported
 from theanompi_tpu.ops.pallas_paged_attention import (
     SCOPE,
     SCOPE_GROUPED,
+    SCOPE_LATENT,
     paged_decode_grouped_supported,
+    paged_decode_latent_supported,
     paged_decode_supported,
 )
 from theanompi_tpu.ops.quant import int8_matmul_supported
@@ -207,10 +216,13 @@ class InferenceEngine:
                              f"('auto', 'on', 'off')")
         self.decode_kernel = decode_kernel
         spec = model.cache_spec()
-        heads, head_dim = spec["kv"]["heads"], spec["kv"]["head_dim"]
+        kv = spec["kv"]
+        #: the pool holds latent attention's rows, one a token and layer
+        self.latent = "latent" in kv
         on_tpu = jax.default_backend() == "tpu"
-        # two kernels, each with a shape gate of its own: one K/V head a
-        # query head, or a pool of fewer (grouped) K/V heads (ISSUE 34)
+        # three kernels, each with a shape gate of its own: one K/V head a
+        # query head, a pool of fewer (grouped) K/V heads, or a
+        # pool of latent rows
         dtype = model.precision.compute_dtype
         #: block diffusion's positions a pass, None for one token a step
         self.block_len = getattr(model, "block_len", None)
@@ -225,10 +237,15 @@ class InferenceEngine:
         # a block pass hands the kernel a slot's ``block_len x heads``
         # queries as the query heads of one slot (``attend_block``)
         q_heads = cfg["heads"] * (self.block_len or 1)
-        grouped = heads != q_heads
-        fits = (paged_decode_grouped_supported(q_heads, heads, head_dim,
-                                               self.block_size, dtype)
-                if grouped else paged_decode_supported(heads, head_dim, dtype))
+        grouped = not self.latent and kv["heads"] != q_heads
+        if self.latent:
+            fits = paged_decode_latent_supported(
+                q_heads, kv["latent"], kv["values"], self.block_size, dtype)
+        elif grouped:
+            fits = paged_decode_grouped_supported(
+                q_heads, kv["heads"], kv["head_dim"], self.block_size, dtype)
+        else:
+            fits = paged_decode_supported(kv["heads"], kv["head_dim"], dtype)
         use_kernel = decode_kernel == "on" or (
             decode_kernel == "auto" and on_tpu and fits)
         #: resolved decode-attention variant — "kernel" (compiled pallas,
@@ -240,8 +257,8 @@ class InferenceEngine:
         self.decode_impl = kernel if use_kernel else "fallback"
         #: the kernel's custom call by the name a trace shows it under,
         #: None on the fallback
-        self.decode_call = ((SCOPE_GROUPED if grouped else SCOPE)
-                            if use_kernel else None)
+        self.decode_call = ((SCOPE_LATENT if self.latent else SCOPE_GROUPED
+                             if grouped else SCOPE) if use_kernel else None)
         #: resolved expert-product variant — "kernel", "kernel_interpret",
         #: "ragged_dot", or None for a model without an expert layer
         self.expert_impl = None
@@ -271,12 +288,21 @@ class InferenceEngine:
         n = getattr(model, "paged_layers", spec["kv"]["layers"])
         self._paged_tags = {"paged_layers": n,
                             "paged_kernel_layers": n if use_kernel else 0}
+        #: the ``latent_layers`` / ``latent_kernel_layers`` tags of
+        #: ``serve.decode`` (``serve.prefill``: the first), where the model
+        #: has latent attention: its layers, and those of them whose rows
+        #: the latent kernel reads
+        n_lat = getattr(model, "latent_layers", 0)
+        self._latent_tags = {"latent_layers": n_lat, "latent_kernel_layers":
+                             n_lat if use_kernel else 0} if n_lat else {}
+        self._latent_prefill_tags = {"latent_layers": n_lat} if n_lat else {}
         #: the ``pool_writes`` / ``pool_writes_in_place`` tags of
         #: ``serve.prefill``: paged layers the prefill program writes (loop
-        #: steps counted), and those written without re-laying the pool
-        #: out — all of them, the pool's K/V heads picking the form that
-        #: keeps its layout (``prefill_write_form``)
-        self._write_tags = {"pool_writes": n, "pool_writes_in_place": n}
+        #: steps counted; latent layers too), and those written without
+        #: re-laying the pool out — all of them, the pool's K/V heads
+        #: picking the form that keeps its layout (``prefill_write_form``)
+        self._write_tags = {"pool_writes": n + n_lat,
+                            "pool_writes_in_place": n + n_lat}
         #: what runs the decode step's recurrent-state update, as the model's
         #: op resolved it from platform and shape — "kernel",
         #: "kernel_interpret", "plain", or None for a model without such a
@@ -303,7 +329,7 @@ class InferenceEngine:
         self._keep_quant = (
             (lambda qt: int8_matmul_supported(
                 qt.shape, int(qt.q.shape[1]), compiled=on_tpu))
-            if use_kernel and not grouped else None)
+            if use_kernel and not (grouped or self.latent) else None)
         # kept for swap_params: a live weight rollout must re-quantize the
         # incoming tree EXACTLY as __init__ did (same key, same chunking)
         self._quantize_int8 = bool(quantize_int8)
@@ -390,7 +416,8 @@ class InferenceEngine:
         always dequantizes), and the attention path of every prefill
         bucket compiled so far."""
         out: dict = {"decode_attention": self.decode_impl,
-                     "prefill_kv_write": prefill_write_form(self._k.shape[3])}
+                     "prefill_kv_write": "whole_blocks" if self.latent
+                     else prefill_write_form(self._k.shape[3])}
         # the dtype of the floating leaves as held (see :meth:`_held`)
         out["weights_held"] = "/".join(sorted({
             jnp.dtype(leaf.dtype).name for leaf in jax.tree.leaves(
@@ -405,6 +432,9 @@ class InferenceEngine:
         if self.window is not None:
             out["window_attention"] = \
                 self.model.resolved_paths()["window_attention"]
+        if self.latent:
+            out["latent_attention"] = \
+                self.model.resolved_paths()["latent_attention"]
         if self.block_len is not None:
             out["block_diffusion"] = \
                 self.model.resolved_paths()["block_diffusion"]
@@ -644,7 +674,8 @@ class InferenceEngine:
                         tokens=p - prefix_len,
                         bucket=self.pad_len(p - prefix_len),
                         prefix_len=prefix_len, **self._moe_tags,
-                        **self._paged_tags, **self._write_tags):
+                        **self._paged_tags, **self._latent_prefill_tags,
+                        **self._write_tags):
             if prefix_len:
                 if self._state:
                     raise ValueError(
@@ -823,7 +854,7 @@ class InferenceEngine:
                         requests=np.asarray(rids)[active].tolist(),
                         launched=1, ran_ahead=int(prev is not None),
                         **self._moe_tags, **self._state_tags,
-                        **self._paged_tags, **window_tags,
+                        **self._paged_tags, **self._latent_tags, **window_tags,
                         **block_tags) as span:
             self.n_decodes += 1
             with spans.span(_SPAN_PLACE):

@@ -39,7 +39,15 @@ the queries handed in have heads (grouped K/V) has a pair of its own
 blocks fetched by the table, many a step, one product for a K/V head's
 whole group of queries — and the gather of ``_attend_decode_grouped`` on the
 fallback, alike in operand and accumulation types and held to a tolerance
-(tests/test_paged_decode_grouped_kernel.py).
+(tests/test_paged_decode_grouped_kernel.py).  A model of latent attention
+caches ONE row a token and layer, read by every head (``cache_spec()``'s
+``kv`` gives ``latent`` in place of ``heads`` / ``head_dim``): the pool is
+``[L, num_blocks, block_size, W]``, ``W`` the row padded to whole lanes
+(576 -> 640: a v5e lays a 576-wide pool out as 640 lanes in HBM anyway, and
+Mosaic copies only whole lanes of it), ``v`` is None, and a decode step
+reads it through :meth:`PagedKVCache.attend_latent` —
+``paged_attend_decode_latent`` on a kernel tier, ``_attend_decode_latent``'s
+gather on the fallback.
 """
 
 from __future__ import annotations
@@ -52,6 +60,7 @@ import jax.numpy as jnp
 from theanompi_tpu.ops.pallas_paged_attention import (
     paged_attend_decode,
     paged_attend_decode_grouped,
+    paged_attend_decode_latent,
 )
 
 _NEG_INF = -1e30
@@ -59,6 +68,9 @@ _NEG_INF = -1e30
 #: dimension, and XLA re-lays out a pool of fewer heads around a
 #: whole-block scatter (:func:`prefill_write_form`)
 _SUBLANES = 8
+#: lanes of a TPU tile: a pool of latent rows is this many columns wide or
+#: a whole number of them (:meth:`PagedKVCache.create`)
+_LANES = 128
 #: the widest context the grouped fallback gathers in one piece; a wider
 #: table goes through in pieces of this many tokens (``_attend_decode_grouped``)
 _GROUPED_CHUNK_TOKENS = 4096
@@ -82,7 +94,10 @@ class PagedKVCache:
     # Every method's ``layer`` is an entry: a Python int, or a traced int32
     # scalar inside a loop of the program (ISSUE 31)
     k: jax.Array             # [L, num_blocks, block_size, H, Dh]
-    v: jax.Array             # [L, num_blocks, block_size, H, Dh]
+    #: [L, num_blocks, block_size, H, Dh]; None for a pool of latent rows
+    #: (``k`` then ``[L, num_blocks, block_size, W]``, a row padded to
+    #: whole lanes: :attr:`latent`)
+    v: jax.Array | None
     block_tables: jax.Array  # [max_batch, max_blocks_per_seq] int32
     block_size: int
     #: decode attention implementation, static: "fallback" (pure-JAX
@@ -120,6 +135,11 @@ class PagedKVCache:
 
     # -- shape properties ----------------------------------------------------
     @property
+    def latent(self) -> bool:
+        """A pool of latent rows, one a token and layer, no V pool."""
+        return self.v is None
+
+    @property
     def n_layers(self) -> int:
         return self.k.shape[0]
 
@@ -136,15 +156,17 @@ class PagedKVCache:
                   max_batch: int, max_context: int, dtype=jnp.float32,
                   decode_impl: str = "fallback") -> "PagedKVCache":
         """The cache a model's ``cache_spec()`` asks for: K/V pools over its
-        ``kv`` layers (``layers``, ``heads``, ``head_dim``) and, per entry
+        ``kv`` layers (``layers``, ``heads``, ``head_dim``) — or, where it
+        gives ``latent``, one pool of rows that wide — and, per entry
         of ``state`` (name -> (per-sequence shape, dtype)), a slot-indexed
         pool ``[state_layers, max_batch, *shape]`` of zeros; per ``window``
         layer (``layers``, ``size``, ``heads``, ``head_dim``) a ring of
         ``size`` tokens a slot, beside the paged pool."""
         kv = spec["kv"]
-        cache = cls.create(kv["layers"], num_blocks, block_size, kv["heads"],
-                           kv["head_dim"], max_batch, max_context, dtype,
-                           decode_impl)
+        cache = cls.create(kv["layers"], num_blocks, block_size,
+                           kv.get("heads"), kv.get("head_dim"), max_batch,
+                           max_context, dtype, decode_impl,
+                           latent=kv.get("latent"))
         state = {name: jnp.zeros((spec["state_layers"], max_batch, *shape), dt)
                  for name, (shape, dt) in spec["state"].items()}
         win = spec.get("window")
@@ -159,17 +181,23 @@ class PagedKVCache:
     def create(cls, n_layers: int, num_blocks: int, block_size: int,
                heads: int, head_dim: int, max_batch: int,
                max_context: int, dtype=jnp.float32,
-               decode_impl: str = "fallback") -> "PagedKVCache":
+               decode_impl: str = "fallback",
+               latent: int | None = None) -> "PagedKVCache":
+        """Zeroed pools; ``latent``: one pool of rows that wide, padded with
+        zero columns to whole lanes, no V pool (``heads`` / ``head_dim``
+        unread)."""
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved null block)")
         if decode_impl not in ("fallback", "kernel", "kernel_interpret"):
             raise ValueError(f"unknown decode_impl {decode_impl!r}")
         max_blocks_per_seq = -(-max_context // block_size)
-        shape = (n_layers, num_blocks, block_size, heads, head_dim)
+        shape = ((n_layers, num_blocks, block_size,
+                  -(-latent // _LANES) * _LANES) if latent
+                 else (n_layers, num_blocks, block_size, heads, head_dim))
         return cls(
             k=jnp.zeros(shape, dtype),
-            v=jnp.zeros(shape, dtype),
+            v=None if latent else jnp.zeros(shape, dtype),
             block_tables=jnp.zeros((max_batch, max_blocks_per_seq),
                                    jnp.int32),
             block_size=block_size,
@@ -190,9 +218,16 @@ class PagedKVCache:
         point at the null block — duplicate scatter indices are fine, the
         null block's content is never read unmasked).  The pool's shape
         picks the form (:func:`prefill_write_form`); both land the same
-        bytes in the same blocks, and neither re-lays the pool out."""
+        bytes in the same blocks, and neither re-lays the pool out.  A
+        pool of latent rows takes ``k`` ``[1, P_pad, latent]``, ``v`` None,
+        in whole blocks (a block's ``[block_size, latent]`` is whole tiles
+        of the pool's layout)."""
         bs = self.block_size
         idx = jnp.asarray(table_row, jnp.int32)
+        if self.latent:
+            rows = self._lanes(k[0]).reshape(k.shape[1] // bs, bs, -1)
+            return dataclasses.replace(
+                self, k=self.k.at[layer, idx].set(rows.astype(self.k.dtype)))
         if prefill_write_form(self.k.shape[3]) == "token_rows":
             return dataclasses.replace(
                 self, k=_write_token_rows(self.k, layer, k, idx, bs),
@@ -207,12 +242,15 @@ class PagedKVCache:
     def write_decode(self, layer, k, v, positions) -> "PagedKVCache":
         """Append one token's K/V per batch slot: ``k``/``v`` ``[B, H, Dh]``
         at ``positions`` ``[B]`` (inactive slots' tables point at the null
-        block, so their writes land in reserved garbage)."""
-        b = k.shape[0]
+        block, so their writes land in reserved garbage); a pool of latent
+        rows: ``k`` ``[B, latent]``, ``v`` None."""
         blk_idx = positions // self.block_size
         blk = jnp.take_along_axis(
             self.block_tables, blk_idx[:, None], axis=1)[:, 0]
         off = positions % self.block_size
+        if self.latent:
+            return dataclasses.replace(self, k=self.k.at[layer, blk, off].set(
+                self._lanes(k).astype(self.k.dtype)))
         return dataclasses.replace(
             self, k=self.k.at[layer, blk, off].set(k.astype(self.k.dtype)),
             v=self.v.at[layer, blk, off].set(v.astype(self.v.dtype)))
@@ -413,6 +451,38 @@ class PagedKVCache:
         m, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, a0))
         return (acc / jnp.swapaxes(l, 1, 2)).astype(q.dtype)
 
+    def attend_latent(self, layer, q, positions, *, values: int,
+                      scale: float):
+        """One absorbed query token per slot over its cached latent rows:
+        ``q`` ``[B, H, latent]``, ``positions`` ``[B]`` (the query's own
+        position, already written) -> context ``[B, H, values]``, the rows'
+        first ``values`` columns weighted by the softmax of ``scale`` x the
+        scores over all of them.  ``paged_attend_decode_latent`` on a
+        kernel tier, :meth:`_attend_decode_latent` on the fallback (both
+        over the pool's padded rows, the query padded alike with zeros)."""
+        q = self._lanes(q)
+        if self.decode_impl != "fallback":
+            return paged_attend_decode_latent(
+                self.k, layer, self.block_tables,
+                jnp.asarray(positions, jnp.int32), q,
+                block_size=self.block_size, values=values, scale=scale,
+                interpret=(self.decode_impl == "kernel_interpret"))
+        return self._attend_decode_latent(layer, q, positions, values, scale)
+
+    def _attend_decode_latent(self, layer, q, positions, values, scale):
+        """:meth:`attend_latent`'s fallback: each slot's rows gathered
+        whole, one masked float32 softmax, the values the same rows' first
+        ``values`` columns (the grouped gather with one K/V head)."""
+        b, _, w = q.shape
+        rows = jnp.take(self.k[layer], self.block_tables, axis=0).reshape(
+            b, -1, 1, w)
+        return _grouped_attend(q, rows, rows[..., :values], positions, scale)
+
+    def _lanes(self, x):
+        """``x`` ``[..., latent]`` with zero columns up to the pool's rows."""
+        pad = self.k.shape[-1] - x.shape[-1]
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
     def _attend_decode_grouped(self, layer, q, positions):
         """:meth:`attend_decode`'s fallback where the pool holds fewer K/V
         heads than ``q`` has query heads (query head ``h`` reads K/V head
@@ -493,15 +563,17 @@ def _write_token_rows(pool, layer, x, table_row, block_size):
     return pool.at[layer, blk, off].set(x[0].astype(pool.dtype))
 
 
-def _grouped_attend(q, kb, vb, positions):
-    """``q`` ``[B, H, Dh]`` over each slot's keys and values ``[B, T, Hkv,
-    Dh]``, entry ``t`` of them where ``t <= positions[b]``: one masked
-    float32 softmax, the K/V heads never repeated."""
+def _grouped_attend(q, kb, vb, positions, scale=None):
+    """``q`` ``[B, H, Dh]`` over each slot's keys ``[B, T, Hkv, Dh]`` and
+    values ``[B, T, Hkv, Dv]``, entry ``t`` of them where ``t <=
+    positions[b]``: one masked float32 softmax of ``scale`` (None: ``Dh **
+    -0.5``) x the scores, the K/V heads never repeated."""
     b, h, d = q.shape
     hkv = kb.shape[2]
     if h % hkv:
         raise ValueError(f"{h} query heads over {hkv} K/V heads")
-    qg = (q.astype(jnp.float32) * d ** -0.5).reshape(b, hkv, h // hkv, d)
+    scale = d ** -0.5 if scale is None else scale
+    qg = (q.astype(jnp.float32) * scale).reshape(b, hkv, h // hkv, d)
     s = jnp.einsum("bgrd,btgd->bgrt", qg.astype(kb.dtype), kb,
                    preferred_element_type=jnp.float32)
     valid = jnp.arange(kb.shape[1])[None, :] <= positions[:, None]
@@ -509,7 +581,7 @@ def _grouped_attend(q, kb, vb, positions):
     p = jax.nn.softmax(s, axis=-1)
     ctx = jnp.einsum("bgrt,btgd->bgrd", p.astype(vb.dtype), vb,
                      preferred_element_type=jnp.float32)
-    return ctx.reshape(b, h, d).astype(q.dtype)
+    return ctx.reshape(b, h, vb.shape[-1]).astype(q.dtype)
 
 
 def decode_parity(heads: int, head_dim: int, *, block_size: int = 16,

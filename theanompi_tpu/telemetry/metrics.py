@@ -181,11 +181,12 @@ DEVICE_SCOPES = {
     "engine": ("recast", "sample"),
     "TransformerLM": ("embed", "block", "attn", "mlp", "head", "loss"),
     "trainer": ("clip", "exchange", "optimizer"),
-    "kernels": ("paged_decode", "paged_decode_grouped", "flash_fwd",
-                "flash_bwd_dq", "flash_bwd_dkv", "grouped_matmul",
+    "kernels": ("paged_decode", "paged_decode_grouped", "paged_decode_latent",
+                "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "grouped_matmul",
                 "mamba_state_update"),
     "HybridLM": ("embed", "mamba", "moe.route", "moe.experts", "moe.shared",
-                 "attn", "attn.window", "mlp", "loop.exit", "head",
+                 "attn", "attn.window", "attn.latent", "attn.latent.absorb",
+                 "attn.latent.decompress", "mlp", "loop.exit", "head",
                  "diffusion.select"),
 }
 #: one ``train_iter`` (tags ``step``, ``epoch``; ``loss`` at fenced steps)
