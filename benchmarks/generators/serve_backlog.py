@@ -21,7 +21,9 @@ are the first ``max_batch`` of the queue, each with the tokens it had
 already produced appended to its prompt and only the rest left to
 produce.  The window so opens on the contexts and the spread of
 completions the backlog would have reached by itself; the whole stream
-follows behind them.
+follows behind them, ``passes`` times over (a traffic key, default 1).
+More passes lengthen the queue and change nothing ahead of their start:
+the same requests with the same ids, drawn in queue order.
 """
 
 from __future__ import annotations
@@ -74,7 +76,9 @@ def generate(traffic: dict, seed: int, *, vocab: int, max_batch: int) -> list[di
     already produced and carries in the prompt)."""
     queue = stream(traffic)
     rng = np.random.Generator(np.random.PCG64(int(seed)))
-    order = in_flight_after_one_pass(queue, max_batch) + [(*p, 0) for p in queue]
+    passes = int(traffic.get("passes", 1))
+    order = in_flight_after_one_pass(queue, max_batch) + [
+        (*p, 0) for p in queue * passes]
     return [{"rid": i,
              "prompt": rng.integers(0, vocab, size=int(prompt + produced)).tolist(),
              "max_new_tokens": int(output - produced),

@@ -207,6 +207,9 @@ def run(ctx: dict) -> dict:
                            f"the window; tpot_ms_p90 needs more")
     e2e = {"serve_tokens_per_s": n_tokens / window_s,
            "tpot_ms_p90": percentile(tpots, 90)}
+    # as in ``serve_arch``: a traffic file may name which of the two its
+    # cell reports end to end (the ``tpot_ms`` series below keeps the tail)
+    e2e = {k: e2e[k] for k in traffic.get("end_to_end", list(e2e))}
 
     step_ms = sched.step_ms[n_before[0]:]
     dec = [s for s in steps if s["slots"]]

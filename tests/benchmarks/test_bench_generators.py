@@ -1,6 +1,8 @@
 """Each generator is a pure function of its file and the seed, and offers
 the same work under every seed."""
 
+import heapq
+import os
 from collections import Counter
 
 import numpy as np
@@ -27,7 +29,9 @@ def test_backlog_is_a_pure_function_of_file_and_seed(backlog):
 
 
 def test_backlog_same_lengths_in_the_same_order_under_two_seeds(backlog):
-    want = Counter(tuple(p) for p in backlog["pairs"])
+    passes = backlog.get("passes", 1)
+    want = Counter({tuple(p): n * passes
+                    for p, n in Counter(map(tuple, backlog["pairs"])).items()})
     for seed in SEEDS:
         reqs = _gen(backlog, seed)
         got = Counter((len(r["prompt"]), r["full_output"]) for r in reqs[32:])
@@ -49,10 +53,48 @@ def test_backlog_every_stretch_of_the_queue_holds_the_same_mix(backlog):
     for i, p in enumerate(ranked):
         cls.setdefault(p, set()).add(i // per)
     queue = _gen(backlog, SEEDS[0])[32:]
+    assert len(queue) == backlog.get("passes", 1) * len(backlog["pairs"])  # all of it
     for g in range(0, len(queue), strata):
         group = [(len(r["prompt"]), r["full_output"]) for r in queue[g:g + strata]]
         # one pair of every class in every group
         assert set().union(*(cls[p] for p in group)) == set(range(strata))
+
+
+def test_backlog_passes_repeat_the_stream_behind_the_one_pass_queue(backlog):
+    one = dict(backlog, passes=1)
+    n, per = 32 + len(backlog["pairs"]), len(backlog["pairs"])
+    stream = serve_backlog.stream(backlog)
+    for seed in SEEDS:
+        reqs = _gen(dict(backlog, passes=3), seed)
+        assert len(reqs) == 32 + 3 * per
+        # the head is the one-pass queue's: lengths, produced and ids
+        assert reqs[:n] == _gen(one, seed)
+        for k in range(3):  # each pass is the stream again, fresh
+            got = [(len(r["prompt"]), r["full_output"], r["produced"])
+                   for r in reqs[32 + k * per:32 + (k + 1) * per]]
+            assert got == [(*p, 0) for p in stream]
+
+
+def _empties_at(reqs, slots_n):
+    """The step at which a server that emits one token per slot and step,
+    a freed slot taking the next request, admits the queue's last one."""
+    running, now = [], 0
+    for r in reqs:
+        if len(running) == slots_n:
+            now = heapq.heappop(running)
+        heapq.heappush(running, now + r["max_new_tokens"])
+    return now
+
+
+def test_backlog_outlasts_the_run_at_two_thirds_of_the_steps_floor(backlog):
+    # the cell's least-bytes floor is ~8.6 ms a scheduler step; the queue
+    # has to outlast the lead-in and the window at 5.8 ms a step
+    bench = load_json(os.pardir, "BENCHMARK.json")
+    run_s = backlog["lead_in_s"] + bench["run_seconds"]
+    steps = _empties_at(_gen(backlog, SEEDS[1]), 32)
+    assert steps * 5.8e-3 >= run_s, steps
+    # one pass alone would empty inside the run at the floor (below 23.0 ms)
+    assert _empties_at(_gen(dict(backlog, passes=1), SEEDS[1]), 32) * 8.6e-3 < run_s
 
 
 def _unit_steps(reqs, slots_n, steps):

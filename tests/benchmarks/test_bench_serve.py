@@ -5,15 +5,43 @@ import numpy as np
 
 from bench_tiny import failed_names, well_formed, rehearse, tiny_cell
 
+from benchmarks import run as bench_run
 from benchmarks import serve
+from benchmarks.common import load_json
 
 
 def test_sound_run_is_correct():
     line = rehearse("serve")
     well_formed(line, "serve_tokens_per_s")
-    assert line["metrics"]["tpot_ms_p90"]["value"] > 0
+    # the traffic file names the rate alone: the tail is a per-layer metric
+    assert set(line["metrics"]) == {"serve_tokens_per_s", "setup_s"}
     assert line["correct"] is True, line["compared"]
     assert line["extra"]["tokens_compared"] > 20
+
+
+def test_a_traffic_file_without_the_key_reports_both_end_to_end_metrics():
+    import jax
+
+    workload, loaded = tiny_cell("serve")
+    assert loaded["traffic"].pop("end_to_end") == ["serve_tokens_per_s"]
+    loaded["bench"]["end_to_end"].append(
+        {"name": "tpot_ms_p90", "unit": "ms", "better": "lower", "bound": 0.04,
+         "source": "host_clock"})
+    line = bench_run.execute(loaded, workload, seed=2**31 + 5, seconds=2.0,
+                             trace=0, devices=jax.devices()[:1])
+    assert line["metrics"]["tpot_ms_p90"]["value"] > 0
+    assert line["metrics"]["serve_tokens_per_s"]["value"] > 0
+
+
+def test_the_tail_is_read_per_layer_from_the_runs_series():
+    from benchmarks.readers import percentile
+
+    decl = load_json("metrics", "sched.tpot_ms_p90_backlog.json")
+    assert decl["workloads"] == ["cgpt13.serve.backlog"]
+    tpots = [30.0 + i for i in range(11)]
+    run = {"series": {"tpot_ms": tpots}}
+    assert percentile.read(run, **decl["args"]) == 39.0
+    assert percentile.read({"series": {}}, **decl["args"]) is None
 
 
 def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch):
